@@ -49,6 +49,7 @@ asserts, against the `MergedAllreduce` that built it:
 
 from __future__ import annotations
 
+import collections
 import re
 from typing import Any, Iterator, Optional, Sequence
 
@@ -56,16 +57,22 @@ import numpy as np
 
 from mgwfbp_tpu.analysis.rules import Finding
 
-# --- primitive taxonomy (names as of jax 0.4.x; matching is by name so the
-# verifier needs no private jax imports) ------------------------------------
-REDUCTION_PRIMS = frozenset({"psum", "reduce_scatter", "psum_scatter"})
+# --- primitive classes (names as jax 0.9 binds them; matching is by name so
+# the verifier needs no private jax imports). `lax.psum_scatter` binds
+# `reduce_scatter`. Every shard_map in this repo runs check_vma=False, where
+# a psum binds `psum`; under check_vma=True the same call binds
+# `psum_invariant` (plus a `pvary` cast) and `all_gather_invariant` exists
+# beside `all_gather`, so those are listed too -----------------------------
+REDUCTION_PRIMS = frozenset({"psum", "psum_invariant", "reduce_scatter"})
 OTHER_COLLECTIVE_PRIMS = frozenset({
-    "all_gather", "all_to_all", "pmax", "pmin", "ppermute", "pgather",
+    "all_gather", "all_gather_invariant", "all_to_all", "ragged_all_to_all",
+    "pmax", "pmin", "ppermute", "pgather",
 })
 COLLECTIVE_PRIMS = REDUCTION_PRIMS | OTHER_COLLECTIVE_PRIMS
+# host round trips: `jax.debug.print` binds `debug_print`, `jax.debug.callback`
+# `debug_callback` (the host_callback primitives of jax 0.4 are gone)
 CALLBACK_PRIMS = frozenset({
-    "debug_callback", "pure_callback", "io_callback", "outside_call",
-    "host_callback_call", "python_callback",
+    "debug_callback", "debug_print", "pure_callback", "io_callback",
 })
 
 # scopes the train step declares for its OWN auxiliary collectives
@@ -212,10 +219,11 @@ def collect_collectives(closed_jaxpr: Any) -> dict[str, list]:
 
 
 def find_donated(closed_jaxpr: Any) -> Optional[tuple[bool, ...]]:
-    """donated_invars of the outermost pjit eqn, or None when untraceable."""
+    """donated_invars of the outermost jit eqn (primitive `jit` as of jax
+    0.9, formerly `pjit`), or None when untraceable."""
     jaxpr = getattr(closed_jaxpr, "jaxpr", closed_jaxpr)
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pjit":
+        if eqn.primitive.name == "jit":
             d = eqn.params.get("donated_invars")
             if d is not None:
                 return tuple(bool(x) for x in d)
@@ -634,18 +642,21 @@ def verify_jaxpr_against_reducer(
 
 
 def collective_footprint(closed_jaxpr: Any) -> dict[str, int]:
-    """Collective/callback primitive counts of a traced program — the
-    SCH010 comparison unit. Counting by primitive NAME (not scope) makes
-    the footprint insensitive to where the stats sit in the program and
-    sensitive to exactly what the rule forbids: any additional
-    collective or host callback."""
+    """Collective/callback SITES of a traced program per primitive name —
+    the SCH010 comparison unit. jax 0.9 binds one psum eqn PER LEAF of a
+    `lax.psum(tree)` call (XLA's all-reduce combiner fuses them again), so
+    eqns are counted once per (primitive, name scope): a statistic added
+    to the metrics dict rides the `metrics_reduce` site, while a
+    collective or host callback anywhere else is a new site — exactly what
+    the rule forbids."""
     jaxpr = getattr(closed_jaxpr, "jaxpr", closed_jaxpr)
-    counts: dict[str, int] = {}
-    for eqn in iter_eqns(jaxpr):
-        name = eqn.primitive.name
-        if name in COLLECTIVE_PRIMS or name in CALLBACK_PRIMS:
-            counts[name] = counts.get(name, 0) + 1
-    return counts
+    sites = {
+        (eqn.primitive.name, _scope_of(eqn))
+        for eqn in iter_eqns(jaxpr)
+        if eqn.primitive.name in COLLECTIVE_PRIMS
+        or eqn.primitive.name in CALLBACK_PRIMS
+    }
+    return dict(collections.Counter(name for name, _ in sites))
 
 
 def compare_collective_footprints(
@@ -715,18 +726,12 @@ def verify_health_stats_footprint(
 # ---------------------------------------------------------------------------
 
 def _ensure_cpu_devices(n: int = 8) -> None:
-    """Force an n-device virtual CPU platform if jax has not initialized yet
-    (tracing needs a mesh, not real hardware)."""
-    from mgwfbp_tpu.utils.platform import (
-        already_initialized_platforms,
-        apply_platform_overrides,
-        force_host_device_count,
-    )
+    """Pin an n-device virtual CPU platform (tracing needs a mesh, not real
+    hardware). Once a backend is up this changes nothing and the trace
+    uses whatever devices exist."""
+    from mgwfbp_tpu.utils.platform import apply_platform_overrides
 
-    if already_initialized_platforms():
-        return  # too late to change; use whatever devices exist
-    force_host_device_count(n)
-    apply_platform_overrides("cpu")
+    apply_platform_overrides("cpu", host_device_count=n)
 
 
 def trace_train_step(
@@ -869,7 +874,7 @@ def step_subjaxprs(closed_jaxpr: Any) -> list:
     splits on; named scopes cannot mark it, because pjit caches the first
     call's trace and would stamp both steps with the first scope)."""
     jaxpr = getattr(closed_jaxpr, "jaxpr", closed_jaxpr)
-    return [e for e in jaxpr.eqns if e.primitive.name == "pjit"]
+    return [e for e in jaxpr.eqns if e.primitive.name == "jit"]
 
 
 def verify_cross_step_jaxpr(

@@ -112,9 +112,13 @@ def main(argv: Optional[list[str]] = None) -> int:
     if args.two_level:
         return _two_level_main(args)
 
-    from mgwfbp_tpu.utils.platform import apply_platform_overrides
+    from mgwfbp_tpu.utils.platform import (
+        apply_platform_overrides,
+        enable_compile_cache,
+    )
 
     apply_platform_overrides()
+    enable_compile_cache()
     import dataclasses
 
     from mgwfbp_tpu.parallel.costmodel import (
@@ -292,9 +296,13 @@ def main(argv: Optional[list[str]] = None) -> int:
 def _two_level_main(args) -> int:
     """--two-level: per-axis (ici, dcn) calibration -> two_level profile
     (`profiling.profile_two_level`; schema-stamped via save_profile)."""
-    from mgwfbp_tpu.utils.platform import apply_platform_overrides
+    from mgwfbp_tpu.utils.platform import (
+        apply_platform_overrides,
+        enable_compile_cache,
+    )
 
     apply_platform_overrides()
+    enable_compile_cache()
     import os
 
     import jax
@@ -345,9 +353,13 @@ def _two_level_main(args) -> int:
 def _forward_main(args) -> int:
     """--forward: per-layer backward + forward benchmark -> layer profile
     (the tb_profile.json format trainers persist, schema_version=2)."""
-    from mgwfbp_tpu.utils.platform import apply_platform_overrides
+    from mgwfbp_tpu.utils.platform import (
+        apply_platform_overrides,
+        enable_compile_cache,
+    )
 
     apply_platform_overrides()
+    enable_compile_cache()
     import os
 
     import jax

@@ -215,9 +215,13 @@ def main(argv: Optional[list[str]] = None) -> int:
     p.add_argument("--batch-size", dest="batch_size", type=int, default=None)
     p.add_argument("--synthetic", action="store_true")
     args = p.parse_args(argv)
-    from mgwfbp_tpu.utils.platform import apply_platform_overrides
+    from mgwfbp_tpu.utils.platform import (
+        apply_platform_overrides,
+        enable_compile_cache,
+    )
 
     apply_platform_overrides()
+    enable_compile_cache()
     overrides = {
         k: getattr(args, k)
         for k in ("dataset", "data_dir", "batch_size")

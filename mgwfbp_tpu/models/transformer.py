@@ -48,9 +48,11 @@ class Block(nn.Module):
         elif self.attn_impl == "flash":
             # Pallas kernel (ops/flashattn.py): scores never leave VMEM —
             # for long contexts where the dense (T, T) matrix can't fit.
-            # Dense XLA is the measured default on this chip
-            # (profiles/flashattn_tpu.json). Shapes outside the kernel's
-            # block contract fall back to dense.
+            # FORWARD-ONLY on the chip (no custom_vjp; the compiled
+            # kernel cannot be differentiated), so this branch trains
+            # only on the CPU interpreter and dense stays the default.
+            # Shapes outside the kernel's block contract fall back to
+            # dense.
             from mgwfbp_tpu.ops import flash_attention, flash_supported
 
             if flash_supported(t, dh):
@@ -82,7 +84,8 @@ class TransformerLM(nn.Module):
     max_len: int = 4096
     dropout: float = 0.1
     seq_axis: Optional[str] = None
-    attn_impl: str = "dense"  # dense | flash (ops/flashattn.py Pallas kernel)
+    # dense | flash (ops/flashattn.py Pallas kernel; forward-only on a TPU)
+    attn_impl: str = "dense"
 
     @nn.compact
     def __call__(self, x: jax.Array, train: bool = False) -> jax.Array:

@@ -16,7 +16,13 @@ attention blocks strictly above the diagonal are skipped entirely.
 `flash_attention` is numerically equivalent to `ringattn.local_attention`
 (same online-softmax math); tests pin them against each other. On CPU the
 kernel runs in interpreter mode (slow but exact), so the suite exercises
-the real kernel logic without a TPU.
+the real kernel logic without a TPU. On a TPU it is always compiled; any
+other platform is an error, never a quiet demotion to the interpreter.
+
+FORWARD-ONLY on the chip: the kernel has no `custom_vjp`, and Pallas
+cannot differentiate the compiled kernel (`jax.grad` through it fails with
+an AssertionError in Pallas AD). Only interpret mode differentiates, so a
+model built with it trains on the CPU and cannot train on a TPU yet.
 """
 
 from __future__ import annotations
@@ -131,6 +137,20 @@ def _flash_bhtd(q, k, v, causal, scale, block_q, block_k, interpret):
     )(q, k, v)
 
 
+def _default_interpret() -> bool:
+    """Compiled on a TPU, interpreted on the CPU, refused anywhere else: a
+    platform-name test must never pick the interpreter on an accelerator."""
+    platform = jax.devices()[0].platform
+    if platform == "tpu":
+        return False
+    if platform == "cpu":
+        return True
+    raise RuntimeError(
+        f"flash_attention: platform {platform!r} is neither 'tpu' (compiled "
+        "kernel) nor 'cpu' (interpreter); pass interpret= explicitly"
+    )
+
+
 def flash_attention(
     q: jax.Array,
     k: jax.Array,
@@ -145,7 +165,8 @@ def flash_attention(
 
     Drop-in equivalent of `ringattn.local_attention`; raises ValueError for
     unsupported shapes (callers guard with `flash_supported`). `interpret`
-    defaults to True off-TPU so the kernel logic runs everywhere.
+    left at None means compiled on a TPU and interpreted on the CPU
+    (`_default_interpret`). Forward-only when compiled (module docstring).
 
     Sharding contract: operates on LOCAL (per-device) arrays. Inside the
     framework's train step this holds by construction (the whole model runs
@@ -163,7 +184,7 @@ def flash_attention(
     if scale is None:
         scale = 1.0 / (d ** 0.5)
     if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
+        interpret = _default_interpret()
 
     def to_bhtd(x):
         return x.transpose(0, 2, 1, 3).reshape(b * h, t, d)

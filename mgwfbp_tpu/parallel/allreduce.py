@@ -38,7 +38,6 @@ from mgwfbp_tpu.parallel.solver import (
     simulate_groups,
     size_prior_tb,
 )
-from mgwfbp_tpu.utils.platform import axis_size
 
 # Name-scope prefix stamped on every merge-group collective (the group index
 # is appended, zero-padded). XLA/jaxpr preserve the scope in op metadata, so
@@ -127,7 +126,7 @@ def _scatter_mid_gather(
     semantics), all-gather back, trim the pad."""
     n = buf.shape[0]
     # static extents: mesh axis sizes are known at trace time
-    parts = axis_size(scatter_axes)
+    parts = lax.axis_size(scatter_axes)
     pad = (-n) % parts
     if pad:
         buf = jnp.pad(buf, (0, pad))
@@ -148,7 +147,7 @@ def _rs_ag_allreduce(buf: jax.Array, axes, mean: bool) -> jax.Array:
     all-reduce's bytes, and XLA may overlap the all-gather of group k with
     other work more aggressively than a monolithic all-reduce. Numerically
     identical to pmean/psum."""
-    world = axis_size(axes)
+    world = lax.axis_size(axes)
     return _scatter_mid_gather(buf, axes, world if mean else 1)
 
 
@@ -172,7 +171,7 @@ def _hierarchical_allreduce(
     1/inner_size of it — the standard pod-slice hierarchy a flat psum over
     both axes leaves to XLA's discretion, made explicit so the solver's
     two-level cost predictions describe the actual wire traffic."""
-    world = axis_size((inner_axis, outer_axis))
+    world = lax.axis_size((inner_axis, outer_axis))
     return _scatter_mid_gather(
         buf,
         (inner_axis,),
@@ -682,7 +681,7 @@ def _device_rank(axes: Sequence[str]) -> jax.Array:
     `lax.all_gather` over multiple named axes, verified against both."""
     r = lax.axis_index(axes[0])
     for a in axes[1:]:
-        r = r * axis_size(a) + lax.axis_index(a)
+        r = r * lax.axis_size(a) + lax.axis_index(a)
     return r
 
 
@@ -815,7 +814,7 @@ def merged_rs_defer(
     equal the values an rs_opt_ag step N would have gathered in-step.
     """
     axes = (axis_name,) if isinstance(axis_name, str) else tuple(axis_name)
-    world = axis_size(axes)
+    world = lax.axis_size(axes)
     if world != optim.world:
         raise ValueError(
             f"rs_fwd_ag: mesh extent {world} over {axes} != the "
@@ -893,7 +892,7 @@ def merged_rs_opt_ag(
     consumed; callers skip `tx.update` entirely on this path.
     """
     axes = (axis_name,) if isinstance(axis_name, str) else tuple(axis_name)
-    world = axis_size(axes)
+    world = lax.axis_size(axes)
     if world != optim.world:
         raise ValueError(
             f"rs_opt_ag: mesh extent {world} over {axes} != the "
@@ -1012,8 +1011,8 @@ def merged_hier_allreduce(
             "merged_hier_allreduce needs axis_name=(inner_ici, outer_dcn)"
         )
     inner, outer = axis_name
-    world = axis_size(axis_name)
-    ici = axis_size((inner,))
+    world = lax.axis_size(axis_name)
+    ici = lax.axis_size((inner,))
     leaves, treedef = jax.tree_util.tree_flatten(tree)
     arr = [leaves[j] for j in perm]
     shapes = [l.shape for l in arr]

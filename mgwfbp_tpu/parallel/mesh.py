@@ -51,10 +51,7 @@ def _enable_cpu_collectives() -> None:
     client construction (a TPU run's secondary CPU backend is unharmed),
     and gating on platform env vars would silently re-kill a CPU-only
     launch that never exported JAX_PLATFORMS."""
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except (AttributeError, ValueError):  # older/newer jax renamed it
-        pass
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
 
 def _env_int(env, name: str) -> Optional[int]:

@@ -54,11 +54,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from mgwfbp_tpu.utils.platform import (
-    env_float,
-    get_shard_map,
-    run_with_deadline,
-)
+from mgwfbp_tpu.utils.platform import env_float, run_with_deadline
 
 # 1-axis mesh over every global device, used only by these primitives
 COORD_AXIS = "coord"
@@ -169,7 +165,6 @@ def _coord_mesh() -> Mesh:
 def _reduce_prog(kind: str):
     """Jitted (n_devices, k) -> replicated (k,) reduction program."""
     mesh = _coord_mesh()
-    shard_map = get_shard_map()
 
     def body(x):
         with jax.named_scope(COORD_SCOPE):
@@ -178,7 +173,9 @@ def _reduce_prog(kind: str):
             return lax.pmax(jnp.max(x, axis=0), COORD_AXIS)
 
     return jax.jit(
-        shard_map(body, mesh=mesh, in_specs=P(COORD_AXIS), out_specs=P())
+        jax.shard_map(
+            body, mesh=mesh, in_specs=P(COORD_AXIS), out_specs=P()
+        )
     )
 
 
@@ -357,11 +354,9 @@ def barrier(name: str, timeout_s: Optional[float] = None) -> None:
     """Named rendezvous across all processes, with a real timeout.
 
     Uses the jax.distributed coordination-service barrier (timeout
-    enforced server-side); a missing client degrades to
-    `multihost_utils.sync_global_devices` under a thread deadline. A
-    timeout raises CoordinationTimeout (a RuntimeError) — the caller
-    should treat the process group as broken and exit so the supervisor
-    can heal it.
+    enforced server-side). A timeout raises CoordinationTimeout (a
+    RuntimeError) — the caller should treat the process group as broken
+    and exit so the supervisor can heal it.
     """
     if process_count() == 1:
         return
@@ -380,22 +375,17 @@ def barrier(name: str, timeout_s: Optional[float] = None) -> None:
             timeout_s = DEFAULT_BARRIER_TIMEOUT_S
     key = f"mgwfbp:{name}:{_barrier_seq[name]}"
     _barrier_seq[name] += 1
-    try:
-        from jax._src import distributed
+    # Private reach, justified: jax 0.9 has no public accessor for the
+    # coordination-service client, and `wait_at_barrier` is the one
+    # barrier whose timeout the SERVICE enforces (jax's own
+    # multihost_utils reads the same attribute). process_count() > 1
+    # means jax.distributed.initialize() ran, so the client exists.
+    from jax._src import distributed
 
-        client = distributed.global_state.client
-    except Exception:  # noqa: BLE001 — private module moved; use fallback
-        client = None
     try:
-        if client is not None:
-            client.wait_at_barrier(key, int(timeout_s * 1000))
-        else:
-            from jax.experimental import multihost_utils
-
-            run_with_deadline(
-                lambda: multihost_utils.sync_global_devices(key),
-                timeout_s, what=f"barrier {name!r}",
-            )
+        distributed.global_state.client.wait_at_barrier(
+            key, int(timeout_s * 1000)
+        )
     except Exception as e:  # noqa: BLE001 — uniform failure surface
         raise CoordinationTimeout(
             f"barrier:{name}", timeout_s, detail=str(e)

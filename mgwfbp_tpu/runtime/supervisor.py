@@ -14,7 +14,7 @@ under the cluster's scheduler and only the rc contract below applies):
                  backoff, until the restart budget is spent.
   rc 86  (any)   watchdog abort: a wedged device/runtime; the aborting
                  process faulthandler-dumped every thread's stack first.
-                 Restarting a wedged grant loops forever, so STOP and
+                 Restarting a wedged device loops forever, so STOP and
                  surface where the dumps are.
   other  (any)   a HARD failure. With healing on (the default, ISSUE
                  20): classify it (classify_rc — crash / oom_kill /
@@ -47,7 +47,7 @@ drain end to end.
 Live observability plane (ISSUE 9): with MGWFBP_METRICS_PORT set, each
 child serves /metrics /healthz /status on port + process_index
 (telemetry/serve.py); the supervisor logs each child's port at launch,
-and an rc-86 stop (a wedged grant the watchdog aborted) includes every
+and an rc-86 stop (a wedged device the watchdog aborted) includes every
 still-reachable child's last /status snapshot in the stop message — the
 dead group's final state lands in the supervisor log next to the stack
 dumps it points at.
@@ -1242,7 +1242,7 @@ class Supervisor:
                         )
                 self.log.error(
                     "watchdog abort (rc %d): a process dumped all thread "
-                    "stacks before exiting%s. A wedged device grant does "
+                    "stacks before exiting%s. A wedged device does "
                     "not heal on restart — NOT resubmitting.%s",
                     WATCHDOG_RC, where, detail,
                 )

@@ -33,15 +33,12 @@ import jax
 import jax.numpy as jnp
 import optax
 from flax import struct
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from mgwfbp_tpu.models import ModelMeta
 from mgwfbp_tpu.parallel.allreduce import MergedAllreduce
 from mgwfbp_tpu.parallel.mesh import DATA_AXIS
-from mgwfbp_tpu.utils.platform import get_shard_map
-
-shard_map = get_shard_map()
 
 
 class TrainState(struct.PyTreeNode):

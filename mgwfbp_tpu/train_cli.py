@@ -297,10 +297,13 @@ def main(argv: Optional[list[str]] = None) -> int:
         print(json.dumps(cfg.__dict__, indent=2, default=str))
         return 0
     from mgwfbp_tpu.utils.platform import (
-        apply_platform_overrides, preflight_backend,
+        apply_platform_overrides,
+        enable_compile_cache,
+        preflight_backend,
     )
 
     apply_platform_overrides()
+    enable_compile_cache()
     coordinator, num_processes, process_id = resolve_multihost(args)
     # any explicit distributed signal skips the probe: initialize() must
     # be the first backend touch on every process of a group
@@ -310,8 +313,8 @@ def main(argv: Optional[list[str]] = None) -> int:
         or (num_processes or 0) > 1
     )
     if not multi_host:
-        # fail fast on a wedged device grant instead of hanging in PJRT
-        # init (MGWFBP_INIT_TIMEOUT_S tunes/disables). Single-process
+        # fail fast instead of hanging in PJRT init while another process
+        # holds the chip (MGWFBP_INIT_TIMEOUT_S tunes/disables). Single-process
         # only: jax.distributed.initialize() must run before any backend
         # touch, so a resolved multi-host launch skips the probe — there
         # the coordinator barrier itself surfaces a dead host.

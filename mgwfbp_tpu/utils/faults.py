@@ -2,7 +2,7 @@
 
 MG-WFBP is synchronous data-parallel SGD: every merge-group collective is a
 barrier, so the interesting failure modes — a non-finite gradient, a wedged
-dispatch, a preempted host, a chip that never grants — are all *rare* in CI
+dispatch, a preempted host, a chip that never answers — are all *rare* in CI
 and *routine* in production. This module makes each of them a first-class,
 reproducible test input: a fault plan names exactly which fault fires at
 which optimizer step (or phase), so every handling path (skip-step guard,

@@ -7,6 +7,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from mgwfbp_tpu.parallel.allreduce import (
@@ -15,11 +16,6 @@ from mgwfbp_tpu.parallel.allreduce import (
 )
 from mgwfbp_tpu.parallel.costmodel import AlphaBeta
 from mgwfbp_tpu.parallel.mesh import DATA_AXIS, MeshSpec, make_mesh
-from mgwfbp_tpu.utils.platform import get_shard_map
-
-# `from jax import shard_map` only exists on jax >= 0.6; the shim resolves
-# the right implementation (and kwarg spelling) for the running version.
-shard_map = get_shard_map()
 
 
 def _grad_tree(rng):

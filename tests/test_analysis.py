@@ -7,6 +7,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import pytest
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from mgwfbp_tpu.analysis import (
@@ -19,9 +20,6 @@ from mgwfbp_tpu.analysis import (
 from mgwfbp_tpu.analysis.rules import ERROR, RULES, has_errors
 from mgwfbp_tpu.parallel.allreduce import make_merged_allreduce
 from mgwfbp_tpu.parallel.mesh import DATA_AXIS, MeshSpec, make_mesh
-from mgwfbp_tpu.utils.platform import get_shard_map
-
-shard_map = get_shard_map()
 
 
 @pytest.fixture(scope="module")

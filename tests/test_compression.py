@@ -5,6 +5,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from mgwfbp_tpu.parallel.allreduce import make_merged_allreduce
@@ -20,9 +21,6 @@ from mgwfbp_tpu.parallel.costmodel import (
     topk_time,
 )
 from mgwfbp_tpu.parallel.mesh import DATA_AXIS, MeshSpec, make_mesh
-from mgwfbp_tpu.utils.platform import get_shard_map
-
-shard_map = get_shard_map()
 
 
 @pytest.fixture(scope="module")

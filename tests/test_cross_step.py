@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from mgwfbp_tpu.config import make_config
@@ -30,9 +31,7 @@ from mgwfbp_tpu.parallel.allreduce import make_merged_allreduce
 from mgwfbp_tpu.parallel.costmodel import AlphaBeta
 from mgwfbp_tpu.parallel.mesh import DATA_AXIS, MeshSpec, make_mesh
 from mgwfbp_tpu.utils.faults import Preempted
-from mgwfbp_tpu.utils.platform import get_shard_map
 
-shard_map = get_shard_map()
 
 WORLD = 8
 

@@ -57,3 +57,24 @@ def test_flash_supported_guard():
     with pytest.raises(ValueError):
         q, k, v = _qkv(t=24, d=300)
         flash_attention(q, k, v)
+
+
+def test_interpret_choice_is_explicit_per_platform(monkeypatch):
+    """Compiled on `tpu`, interpreted on `cpu`, refused anywhere else: a
+    renamed or unknown platform can never quietly demote the kernel to the
+    interpreter again."""
+    import types
+
+    from mgwfbp_tpu.ops import flashattn
+
+    def on(platform):
+        monkeypatch.setattr(
+            flashattn.jax, "devices",
+            lambda: [types.SimpleNamespace(platform=platform)],
+        )
+        return flashattn._default_interpret()
+
+    assert on("tpu") is False
+    assert on("cpu") is True
+    with pytest.raises(RuntimeError, match="neither 'tpu'"):
+        on("some-plugin")

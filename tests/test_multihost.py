@@ -284,7 +284,7 @@ def test_supervisor_stops_on_watchdog_abort():
         "import sys; sys.exit(86)", n=1, sleep=lambda s: None,
     )
     assert sup.run() == 86
-    assert len(sup.results) == 1  # a wedged grant is NOT resubmitted
+    assert len(sup.results) == 1  # a wedged device is NOT resubmitted
 
 
 def test_supervisor_tears_down_stragglers_on_crash():
@@ -525,7 +525,7 @@ def test_two_process_training_losses_agree(tmp_path):
         ]
 
     outs = _spawn_pair(cmd, timeout=540, env_extra={
-        "JAX_PLATFORMS": "cpu", "MGWFBP_PLATFORM": "cpu",
+        "JAX_PLATFORMS": "cpu",
         "MGWFBP_HOST_DEVICES": "4",
         "XLA_FLAGS": "--xla_force_host_platform_device_count=4",
         "PYTHONPATH": REPO,
@@ -798,7 +798,7 @@ def test_two_process_autotune_commits_identical_schedule(tmp_path):
         ]
 
     outs = _spawn_pair(cmd, timeout=540, env_extra={
-        "JAX_PLATFORMS": "cpu", "MGWFBP_PLATFORM": "cpu",
+        "JAX_PLATFORMS": "cpu",
         "MGWFBP_HOST_DEVICES": "4",
         "XLA_FLAGS": "--xla_force_host_platform_device_count=4",
         "PYTHONPATH": REPO,

@@ -709,9 +709,9 @@ def test_bench_chip_unavailable_injection(tmp_path, monkeypatch, capsys):
     monkeypatch.setenv("MGWFBP_FAULT_PLAN", "chip_unavailable")
     monkeypatch.setenv("MGWFBP_TELEMETRY_DIR", str(tmp_path))
     with pytest.raises(bench.ChipUnavailable):
-        bench._devices_with_retry(init_timeout_s=1.0)
+        bench._require_chip()
     rc = bench.main()
-    assert rc == 0  # structured skip, NOT a failure
+    assert rc != 0  # structured record, but never a success
     out = capsys.readouterr().out.strip().splitlines()
     payload = json.loads(out[-1])
     assert payload["skipped"] == "chip unavailable"

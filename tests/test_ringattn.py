@@ -6,13 +6,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from mgwfbp_tpu.parallel.mesh import MeshSpec, SEQ_AXIS, make_mesh
 from mgwfbp_tpu.parallel.ringattn import local_attention, ring_attention
-from mgwfbp_tpu.utils.platform import get_shard_map
-
-shard_map = get_shard_map()
 
 
 @pytest.fixture(scope="module")

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from mgwfbp_tpu import models as zoo
@@ -26,9 +27,6 @@ from mgwfbp_tpu.parallel.allreduce import (
 from mgwfbp_tpu.parallel.costmodel import AlphaBeta
 from mgwfbp_tpu.parallel.mesh import DATA_AXIS, SEQ_AXIS, MeshSpec, make_mesh
 from mgwfbp_tpu.train import create_train_state, make_train_step
-from mgwfbp_tpu.utils.platform import get_shard_map
-
-shard_map = get_shard_map()
 
 
 @pytest.fixture(scope="module")

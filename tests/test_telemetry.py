@@ -399,7 +399,7 @@ def test_bench_skip_record(tmp_path, monkeypatch):
     import bench
 
     monkeypatch.setenv("MGWFBP_TELEMETRY_DIR", str(tmp_path))
-    bench._record_bench_skip("ChipUnavailable: no grant")
+    bench._record_bench_skip("ChipUnavailable: no chip")
     recs = read_events(str(tmp_path / "telemetry.jsonl"))
     (ev,) = events_of(recs, "bench_skip")
-    assert "no grant" in ev["detail"]
+    assert "no chip" in ev["detail"]

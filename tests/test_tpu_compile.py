@@ -1,0 +1,168 @@
+"""Ask the TPU's compiler, without a chip.
+
+libtpu is installed here and compiles for a chip that is described, not
+attached (`topologies.get_topology_desc`, topology v5e:2x2), so what the
+chip's compiler would refuse — a Pallas block the tiling rejects, a step
+that does not fit 16 GB of HBM, a collective it cannot partition — fails
+in tier-1 at no chip time. Nothing runs: a compile that passes says
+nothing about results or speed, and is never reported as a chip run
+(`chip_smoke.py` is the run).
+
+Skipped where the topology cannot be described. The persistent compile
+cache is off around these tests: an entry written for a described chip
+cannot be read back without one, and the next compile would only warn.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import (
+    Mesh,
+    NamedSharding,
+    PartitionSpec as P,
+    SingleDeviceSharding,
+)
+
+from mgwfbp_tpu import models as zoo
+from mgwfbp_tpu.ops.flashattn import _flash_bhtd
+from mgwfbp_tpu.optim import make_optimizer
+from mgwfbp_tpu.parallel.allreduce import make_merged_allreduce
+from mgwfbp_tpu.parallel.costmodel import lookup_alpha_beta
+from mgwfbp_tpu.parallel.mesh import DATA_AXIS
+from mgwfbp_tpu.train import create_train_state, make_train_step
+
+HBM_BYTES = 16 * 1024**3  # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs to /tmp
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — no libtpu / unknown topology
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e}")
+
+
+@pytest.fixture(autouse=True)
+def _compile_cache_off():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.mark.parametrize(
+    "bh,t,d,dtype",
+    [
+        (32, 2048, 64, jnp.bfloat16),  # chip_smoke's kernel phase: B4 H8
+        (8, 512, 64, jnp.float32),
+        (8, 512, 128, jnp.bfloat16),
+    ],
+)
+def test_flash_forward_compiles_for_v5e(topo, bh, t, d, dtype):
+    s = SingleDeviceSharding(topo.devices[0])
+    x = jax.ShapeDtypeStruct((bh, t, d), dtype, sharding=s)
+    compiled = _flash_bhtd.lower(
+        x, x, x, causal=True, scale=float(d) ** -0.5, block_q=128,
+        block_k=128, interpret=False,
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()  # the kernel, compiled
+
+
+def test_flash_backward_is_not_compilable_yet(topo):
+    """The forward-only statement in ops/flashattn.py, pinned: the compiled
+    kernel has no custom_vjp, and Pallas refuses to differentiate it. The
+    PR that adds a backward turns this test around."""
+    s = SingleDeviceSharding(topo.devices[0])
+    x = jax.ShapeDtypeStruct((8, 512, 64), jnp.bfloat16, sharding=s)
+
+    def loss(q, k, v):
+        out = _flash_bhtd(
+            q, k, v, causal=True, scale=0.125, block_q=128, block_k=128,
+            interpret=False,
+        )
+        return out.astype(jnp.float32).sum()
+
+    with pytest.raises(Exception):  # noqa: B017 — AssertionError in Pallas AD
+        jax.jit(jax.grad(loss)).lower(x, x, x).compile()
+
+
+def _abstract_step_args(model, meta, tx, mesh, per_device_batch):
+    """(state, batch) as ShapeDtypeStructs sharded the way the Trainer
+    places them: state replicated, batch split over the data axis. A
+    described device holds no array, so nothing is materialized."""
+    example = jnp.zeros((1,) + tuple(meta.input_shape), meta.input_dtype)
+    state = jax.eval_shape(
+        lambda: create_train_state(jax.random.PRNGKey(0), model, example, tx)
+    )
+    rep = NamedSharding(mesh, P())
+    state = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rep), state
+    )
+    gb = per_device_batch * mesh.devices.size
+    split = NamedSharding(mesh, P(None, DATA_AXIS))
+    batch = {
+        "x": jax.ShapeDtypeStruct(
+            (1, gb) + tuple(meta.input_shape), meta.input_dtype,
+            sharding=split,
+        ),
+        "y": jax.ShapeDtypeStruct((1, gb), jnp.int32, sharding=split),
+    }
+    return state, batch
+
+
+def _imagenet_sgd():
+    return make_optimizer(
+        0.01, momentum=0.9, weight_decay=1e-4, lr_schedule="const",
+        dataset="imagenet", num_batches_per_epoch=1,
+    )[0]
+
+
+def test_merged_allreduce_step_compiles_on_4_chip_mesh(topo):
+    """The product's multi-chip program — jitted step + merged bucket
+    collectives over a `data` mesh of four described chips — at CIFAR
+    width so the compile stays in seconds."""
+    mesh = Mesh(np.asarray(topo.devices).reshape(4), (DATA_AXIS,))
+    model, meta = zoo.create_model("resnet20")
+    tx = _imagenet_sgd()
+    state, batch = _abstract_step_args(model, meta, tx, mesh, 32)
+    n_leaves = len(jax.tree_util.tree_leaves(state.params))
+    reducer = make_merged_allreduce(
+        state.params, axis_name=DATA_AXIS, policy="mgwfbp",
+        tb=[1.1e-5] * n_leaves, cost_model=lookup_alpha_beta("ici", 4),
+    )
+    step = make_train_step(
+        model, meta, tx, mesh, reducer, compute_dtype=jnp.bfloat16,
+        donate=True,
+    )
+    compiled = step.lower(state, batch).compile()
+    assert "all-reduce" in compiled.as_text()
+    assert reducer.schedule.num_groups >= 1
+
+
+def test_resnet50_bf16_b128_step_fits_one_v5e_chip(topo):
+    """chip_smoke's train phase, asked of the compiler: ResNet-50, bf16
+    compute, per-chip batch 128, one chip, no reducer (the Trainer drops it
+    at world size 1), donated state — compiles and fits 16 GB of HBM. The
+    one long compile of this file (~40 s); tier-1 has the room for it."""
+    mesh = Mesh(np.asarray(topo.devices[:1]), (DATA_AXIS,))
+    model, meta = zoo.create_model("resnet50")
+    tx = _imagenet_sgd()
+    state, batch = _abstract_step_args(model, meta, tx, mesh, 128)
+    step = make_train_step(
+        model, meta, tx, mesh, None, compute_dtype=jnp.bfloat16, donate=True,
+    )
+    mem = step.lower(state, batch).compile().memory_analysis()
+    need = mem.temp_size_in_bytes + mem.argument_size_in_bytes
+    assert need < HBM_BYTES, f"{need / 1e9:.1f} GB does not fit one chip"

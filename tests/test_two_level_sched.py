@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from mgwfbp_tpu.config import make_config
@@ -42,9 +43,7 @@ from mgwfbp_tpu.parallel.costmodel import (
     refit_two_level_from_observations,
     save_profile,
 )
-from mgwfbp_tpu.utils.platform import get_shard_map
 
-shard_map = get_shard_map()
 
 # the synthetic slow-DCN two-pod profile of the win condition: high DCN
 # startup (merging on DCN pays), non-trivial ICI per-byte cost (hiding

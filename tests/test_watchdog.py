@@ -76,7 +76,7 @@ def test_trainer_arms_watchdog(monkeypatch):
 def test_preflight_backend_returns_devices_and_times_out(monkeypatch):
     """Failure-detection seam for the launcher: backend init under a
     deadline raises an actionable error instead of blocking forever on a
-    wedged device grant."""
+    chip another process holds."""
     import jax
 
     from mgwfbp_tpu.utils.platform import preflight_backend
@@ -88,5 +88,5 @@ def test_preflight_backend_returns_devices_and_times_out(monkeypatch):
         time.sleep(30)
 
     monkeypatch.setattr(jax, "devices", hang)
-    with pytest.raises(RuntimeError, match="device grant"):
+    with pytest.raises(RuntimeError, match="chip unavailable"):
         preflight_backend(timeout_s=0.2)

@@ -1,8 +1,9 @@
-"""Fold an lstman4 train.log into profiles/an4_real_audio.json (VERDICT
+"""Fold an lstman4 train.log into profiles/an4_wer_trajectory.json (VERDICT
 r4 #4: the real-audio WER trajectory must MOVE, not sit at 1.0).
 
 Parses the trainer's per-epoch eval lines (loss + WER), summarizes the
-trajectory, and rewrites the artifact's run section. The memorization run
+trajectory, and writes it as one section of the artifact (created when it
+does not exist yet). The memorization run
 evaluates the TRAIN split (data/an4_memcheck's val manifest lists the 45
 real train utterances), so falling WER validates the full
 spectrogram -> CTC -> greedy decode -> WER path end to end on real
@@ -24,7 +25,7 @@ import sys
 
 ARTIFACT = os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-    "profiles", "an4_real_audio.json",
+    "profiles", "an4_wer_trajectory.json",
 )
 
 # loss may be nan/inf on a diverged run, negative or scientific-notation on
@@ -113,11 +114,11 @@ def main(argv=None) -> int:
         )
     print(json.dumps(section, indent=2))
     if args.save:
-        art = json.load(open(ARTIFACT))
+        art = json.load(open(ARTIFACT)) if os.path.exists(ARTIFACT) else {}
         art[args.key] = section
         with open(ARTIFACT, "w") as f:
             json.dump(art, f, indent=1)
-        print(f"updated {ARTIFACT}", file=sys.stderr)
+        print(f"wrote {ARTIFACT}", file=sys.stderr)
     return 0
 
 

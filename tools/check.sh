@@ -9,7 +9,7 @@ rc=0
 
 echo "== [1/10] ruff =="
 if command -v ruff >/dev/null 2>&1; then
-    ruff check mgwfbp_tpu tests tools bench.py || rc=1
+    ruff check mgwfbp_tpu tests tools bench.py chip_smoke.py || rc=1
 else
     echo "ruff not installed; skipping (config lives in pyproject.toml)"
 fi

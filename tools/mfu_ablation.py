@@ -1,25 +1,22 @@
 """MFU roofline counterfactuals for resnet50 (VERDICT r4 #6).
 
-The r4 roofline artifact (profiles/mfu_roofline_resnet50_tpu.json) argued
-MFU 0.30 is HBM-bound from bandwidth accounting alone; this tool turns the
-irreducibility claim empirical by MEASURING the counterfactual rows it only
-reasoned about:
+Whether ResNet-50's MFU on one chip is HBM-bound is an empirical question
+(ROADMAP S2); this tool MEASURES the counterfactual rows a bandwidth
+argument only reasons about:
 
   * batch 64 / 128 / 256 — per-sample HBM traffic is ~batch-invariant, so
-    throughput should be flat if the HBM diagnosis is right (the r3 sweep
-    saw this; re-measured here on the current code);
+    throughput should be flat if the HBM diagnosis is right;
   * uint8 input + on-device normalize — cuts the input-read traffic 4x
     (and models the H2D-lean production input path);
   * bf16 batch statistics (MGWFBP_BN_DTYPE=bfloat16) — runs the BN
-    reduce/broadcast passes in bf16, the ~5.5%-of-device-time 'reduce'
-    category in the r4 per-category table.
+    reduce/broadcast passes in bf16.
 
 Each row: bench-protocol timing (AOT-compiled donated step, >=30 timed
 iters, ONE host sync after the last chained step) + XLA cost analysis
-(flops, bytes_accessed). Writes an "ablations" section into the roofline
-artifact (v2).
+(flops, bytes_accessed). Writes profiles/mfu_ablation_resnet50_tpu.json.
+Never run on a chip yet: its rows are not measured until it has been.
 
-Run on the TPU chip (no platform override):  python tools/mfu_ablation.py
+Run on the TPU chip, as the only process on it:  python tools/mfu_ablation.py
 CPU smoke:  JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=1 \
     python tools/mfu_ablation.py --iters 3 --no-save
 """
@@ -36,7 +33,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 ARTIFACT = os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-    "profiles", "mfu_roofline_resnet50_tpu.json",
+    "profiles", "mfu_ablation_resnet50_tpu.json",
 )
 
 
@@ -180,6 +177,7 @@ def run_rows(iters):
             f"{iters} timed iters, ONE host sync after the last chained "
             "step; XLA cost analysis for flops/bytes"
         ),
+        "platform": jax.devices()[0].platform,
         "device": jax.devices()[0].device_kind,
         "rows": rows,
     }
@@ -190,9 +188,13 @@ def main(argv=None) -> int:
     ap.add_argument("--iters", type=int, default=30)
     ap.add_argument("--no-save", action="store_true")
     args = ap.parse_args(argv)
-    from mgwfbp_tpu.utils.platform import apply_platform_overrides
+    from mgwfbp_tpu.utils.platform import (
+        apply_platform_overrides,
+        enable_compile_cache,
+    )
 
     apply_platform_overrides()
+    enable_compile_cache()
     report = run_rows(args.iters)
     base = report["rows"]["baseline_b128"]
     verdicts = []
@@ -203,17 +205,15 @@ def main(argv=None) -> int:
         verdicts.append(f"{name}: {gain:+.1%} img/s vs baseline")
     report["conclusion"] = verdicts
     print(json.dumps(report, indent=2))
-    if not args.no_save and os.path.exists(ARTIFACT):
-        art = json.load(open(ARTIFACT))
-        art["ablations"] = report
-        art["answer_v2"] = (
-            "v2: the counterfactual rows are now MEASURED (see ablations) "
-            "— the irreducibility claim rests on these, not on bandwidth "
-            "accounting alone"
-        )
+    if not args.no_save:
+        if report["platform"] == "cpu":
+            raise SystemExit(
+                "a CPU run is never saved under a *_tpu artifact; "
+                "pass --no-save for a CPU smoke"
+            )
         with open(ARTIFACT, "w") as f:
-            json.dump(art, f, indent=1)
-        print(f"updated {ARTIFACT}")
+            json.dump(report, f, indent=1)
+        print(f"wrote {ARTIFACT}")
     return 0
 
 

@@ -262,9 +262,13 @@ def main(argv=None) -> int:
     ap.add_argument("--note", default=None)
     ap.add_argument("--out", default=None)
     args = ap.parse_args(argv)
-    from mgwfbp_tpu.utils.platform import apply_platform_overrides
+    from mgwfbp_tpu.utils.platform import (
+        apply_platform_overrides,
+        enable_compile_cache,
+    )
 
     apply_platform_overrides()
+    enable_compile_cache()
     report = run(
         args.model, args.batch, args.policy, args.comm_profile,
         [t for t in args.targets.split(",") if t], args.iters, args.warmup,

@@ -65,9 +65,8 @@ def _solved_schedule_check(model, raw, warmup, iters):
         singleton_dcn_groups,
         two_level_leg_costs,
     )
-    from mgwfbp_tpu.utils.platform import get_shard_map
+    from jax import shard_map
 
-    shard_map = get_shard_map()
     mesh = raw["mesh"]
     inner, outer = raw["inner_axis"], raw["outer_axis"]
 
@@ -161,10 +160,9 @@ def run(ici, dcn, min_log2, max_log2, warmup, iters):
 
     from mgwfbp_tpu.parallel.allreduce import _hierarchical_allreduce
     from mgwfbp_tpu.parallel.costmodel import SampledCost, fit_alpha_beta
-    from mgwfbp_tpu.profiling import profile_two_level
-    from mgwfbp_tpu.utils.platform import get_shard_map
+    from jax import shard_map
 
-    shard_map = get_shard_map()
+    from mgwfbp_tpu.profiling import profile_two_level
 
     # step 1: per-axis calibration — the shared engine behind
     # `calibrate --two-level` (this tool only CONSUMES it now)
