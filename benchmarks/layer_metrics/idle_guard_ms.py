@@ -1,0 +1,8 @@
+"""Device idle time under the `guard` span, per traced step, on the chip
+that idled most in the traced epoch."""
+
+import phase_spans
+
+
+def read(run: dict):
+    return phase_spans.idle_under_ms(run, "guard")

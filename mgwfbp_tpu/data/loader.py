@@ -184,6 +184,8 @@ class PrefetchLoader:
         self.workers = max(int(workers), 0)
         self.depth = max(int(depth), 1)
         self.device_put = device_put
+        # the running pool's deque of outstanding batches (`ready_batches`)
+        self._futs = None
 
     # epoch/batch-size/len plumbing passes through to the inner loader
     def set_epoch(self, epoch: int) -> None:
@@ -210,6 +212,12 @@ class PrefetchLoader:
 
     def __len__(self) -> int:
         return len(self.inner)
+
+    def ready_batches(self) -> Optional[int]:
+        """Batches the pool holds finished right now (telemetry's `ready`
+        counter asks before each `next`); None where no pool runs."""
+        futs = self._futs
+        return None if futs is None else sum(f.done() for f in futs)
 
     def _finalize(self, batch):
         if not self.device_put:
@@ -253,12 +261,16 @@ class PrefetchLoader:
                 ex.submit(job, b) for b in range(min(ahead, nb))
             )
             next_b = len(futs)
-            while futs:
-                out = futs.popleft().result()  # in-order consumption
-                if next_b < nb:
-                    futs.append(ex.submit(job, next_b))
-                    next_b += 1
-                yield out
+            self._futs = futs
+            try:
+                while futs:
+                    out = futs.popleft().result()  # in-order consumption
+                    if next_b < nb:
+                        futs.append(ex.submit(job, next_b))
+                        next_b += 1
+                    yield out
+            finally:
+                self._futs = None
 
     def _iter_thread(self):
         import queue

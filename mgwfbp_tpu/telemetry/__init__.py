@@ -2,7 +2,9 @@
 accounting, Chrome-trace / Prometheus export, and the LIVE plane.
 
 Every layer feeds one append-only, schema-versioned JSONL stream per run
-(`telemetry/events.py`); `telemetry/overlap.py` turns per-group comm times
+(`telemetry/events.py`); `telemetry/phases.py` times the parts of a train
+loop iteration onto the `step` records and into the profiler's trace;
+`telemetry/overlap.py` turns per-group comm times
 (trace-attributed or cost-model-predicted) into the paper's exposed-vs-
 hidden accounting; `telemetry/export.py` renders the stream for Perfetto
 and Prometheus (one metric registry shared with the live endpoint);

@@ -22,11 +22,12 @@ Hot-path discipline: the writer NEVER touches the device. ``emit`` rejects
 any field value that is not a plain JSON scalar/list/dict — handing it a
 jax array (whose serialization would force a device sync) raises
 ``TypeError`` instead of silently stalling the step loop. Step spans are
-host wall-clock around the *dispatch* of the async jitted step: once the
-dispatch pipeline fills, their cadence equals realized step throughput,
-and no block_until_ready / device_get is ever issued on their behalf
-(enforced by the zero-sync guard in tests/test_telemetry.py and lint rule
-JIT006 for the jitted side).
+host wall-clock around the *dispatch* of the async jitted step, and no
+block_until_ready / device_get is ever issued on their behalf (the zero-sync
+guard in tests/test_telemetry.py counts those two calls; lint rule JIT006
+covers the jitted side). What telemetry does pull from the device is the
+health drain (`Trainer._note_health_stats`, an ``__array__`` read the guard
+does not count); the `health` phase span times it (telemetry/phases.py).
 """
 
 from __future__ import annotations
@@ -52,7 +53,11 @@ EVENT_TYPES: dict[str, tuple[str, ...]] = {
     # run metadata; always the stream's first record
     "header": ("schema_version",),
     # one optimizer step: host wall-clock span around the async dispatch,
-    # start_s relative to the stream's epoch (header wall)
+    # start_s relative to the stream's epoch (header wall). The Trainer's
+    # records also carry `phases` ({name: [start_s, dur_s]} of the rest of
+    # the iteration, same clock) and the counters ready / lowered
+    # (telemetry/phases.py); such a record is written after the NEXT
+    # dispatch, once its iteration is over, not at its own
     "step": ("step", "epoch", "start_s", "dur_s"),
     # one merge group's comm span within the step timeline (model-replayed
     # start, measured or predicted duration; see telemetry.overlap).

@@ -4,8 +4,10 @@ Two targets:
 
   * **Chrome trace** (`chrome://tracing` / Perfetto): the run's step
     timeline as complete ("ph": "X") events — a ``steps`` track of step
-    spans, a ``backward`` track, one track per merge group's collective,
-    and an ``optimizer`` track. Step spans come straight from the recorded
+    spans, a ``host loop`` track of each iteration's phase spans (wait,
+    place, dispatch, guard, health, tail, log; telemetry.phases), a
+    ``backward`` track, one track per merge group's collective, and an
+    ``optimizer`` track. Step spans come straight from the recorded
     host wall-clock; the intra-step structure is the overlap snapshot's
     replayed timeline (telemetry.overlap) scaled into each step span, so
     what Perfetto shows per step is exactly what the overlap accounting
@@ -31,6 +33,7 @@ _TID_STEPS = 0
 _TID_BACKWARD = 1
 _TID_OPTIMIZER = 2
 _TID_FORWARD = 3  # cross-step (rs_fwd_ag) regimes only
+_TID_LOOP = 4  # the host loop's phase spans (telemetry/phases.py)
 _TID_GROUP0 = 10
 _PID = 1
 
@@ -93,13 +96,27 @@ def chrome_trace(records: list[dict]) -> dict:
             f"comm group {gi:04d}", _PID, _TID_GROUP0 + gi,
             kind="thread_name",
         ))
-    for s in events_of(records, "step"):
+    steps = events_of(records, "step")
+    if any(s.get("phases") for s in steps):
+        trace.append(_meta("host loop", _PID, _TID_LOOP, kind="thread_name"))
+    for s in steps:
         ts = float(s["start_s"]) * 1e6
         dur = float(s["dur_s"]) * 1e6
         trace.append(_span(
             f"step {int(s['step'])}", _TID_STEPS, ts, dur,
             args={"epoch": s.get("epoch")},
         ))
+        # the iteration's phases as the step's children on the loop's own
+        # track: the step span is the dispatch alone, and the phases lie
+        # before and after it on the same clock
+        if s.get("phases"):
+            child = {"step": int(s["step"])}
+            trace.append(_span("dispatch", _TID_LOOP, ts, dur, args=child))
+            for name, (start_s, dur_s) in s["phases"].items():
+                trace.append(_span(
+                    name, _TID_LOOP, float(start_s) * 1e6,
+                    float(dur_s) * 1e6, args=child,
+                ))
         if snap is None:
             continue
         # scale the replayed model timeline (backward + comm + optimizer

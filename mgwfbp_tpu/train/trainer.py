@@ -20,6 +20,7 @@ TPU shape of the same pipeline:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import os
 import signal as _signal
 import threading
@@ -46,6 +47,7 @@ from mgwfbp_tpu.parallel.mesh import DATA_AXIS, SEQ_AXIS, MeshSpec, make_mesh
 from mgwfbp_tpu.profiling import benchmark_trainer_backward
 from mgwfbp_tpu.runtime import ResizeUnsupported
 from mgwfbp_tpu.runtime import coordination as coord
+from mgwfbp_tpu.telemetry.phases import NO_SPAN, PhaseRecorder, no_span
 from mgwfbp_tpu.train.step import (
     create_train_state,
     make_eval_step,
@@ -344,6 +346,10 @@ class Trainer:
             if config.telemetry and config.health_stats and health_enabled()
             else None
         )
+        # the running epoch's span recorder (telemetry/phases.py), here for
+        # the watchdog's abort alone, which writes the record it holds back;
+        # None between epochs and with telemetry off
+        self._phase_rec: Optional[PhaseRecorder] = None
         self._pending_health: deque = deque()  # graft: group-uniform -- fills at the deterministic step cadence; identical length everywhere
         # straggler probe bookkeeping: synchronous SGD equalizes
         # END-TO-END step walls across the group (everyone waits for the
@@ -1094,10 +1100,20 @@ class Trainer:
         )
 
         def run():
+            # annotated like train_epoch's iterations (telemetry/phases.py),
+            # so the slice shows the host's spans above the device ops; the
+            # tuning feed waits for a batch and places it in one generator.
+            # The profiler keeps its default options: with the host tracer
+            # at annotations only (level 1) a TPU v5e's runtime still writes
+            # 12.9 million host events per 32-step ResNet-50 epoch and the
+            # chip idles 89% against 39% (PERF.md, PR 24)
             for _ in range(steps):
-                self.state = self._apply_train_step(
-                    self.state, next(batch_iter)
-                )
+                with jax.profiler.TraceAnnotation("wait,place"):
+                    batch = next(batch_iter)
+                with jax.profiler.StepTraceAnnotation(
+                    "train", step_num=self.iteration + 1
+                ):
+                    self.state = self._apply_train_step(self.state, batch)
                 # count each applied step as it happens: the traced steps
                 # are genuine optimizer steps, and on a failure below the
                 # group-uniform iteration counter (every agree-interval
@@ -1241,6 +1257,12 @@ class Trainer:
         event also flips /healthz unhealthy through the aggregator tee —
         BEFORE an rc-86 abort kills the process, so a prober sees 503,
         not a reset connection."""
+        rec = self._phase_rec
+        if abort and rec is not None:
+            # rc 86 follows and the loop's thread is stuck: the record of
+            # the step in flight, held back until the next dispatch, goes
+            # out first, with the spans the loop got through
+            rec.flush()
         self._emit_event(
             "watchdog_stall", phase=str(phase), idle_s=float(idle_s),
             timeout_s=float(timeout_s), abort=bool(abort),
@@ -2955,6 +2977,25 @@ class Trainer:
 
     # ------------------------------------------------------------------
     def train_epoch(self, epoch: int) -> dict:
+        """One epoch of the train loop. With telemetry on, every part of an
+        iteration is a span on the stream's clock and in the profiler's
+        trace (telemetry/phases.py); with it off no clock is read."""
+        if self.telemetry is None:
+            return self._run_epoch(epoch, None)
+        rec = self._phase_rec = PhaseRecorder(
+            self.telemetry.now, functools.partial(self._emit_event, "step"),
+        )
+        try:
+            return self._run_epoch(epoch, rec)
+        finally:
+            # a rollback or a drain that unwinds the loop still leaves the
+            # last dispatched step's record behind
+            self._phase_rec = None
+            rec.flush()
+
+    def _run_epoch(self, epoch: int, rec: Optional[PhaseRecorder]) -> dict:
+        entered_s = rec.now() if rec is not None else 0.0
+        span = rec.span if rec is not None else no_span
         cfg = self.config
         loader = self.bundle.train
         loader.set_epoch(epoch)
@@ -3009,14 +3050,18 @@ class Trainer:
         # and sync SGD equalizes exactly the signal a straggler probe
         # must not average away
         t_anchor = time.perf_counter()
-        for raw in loader:
+        for raw in (
+            loader if rec is None else rec.batches(loader, entered_s)
+        ):
             if skip_micro > 0:
                 skip_micro -= 1
                 continue
-            micro.append(self._to_model_batch(raw))
+            with span("place"):
+                micro.append(self._to_model_batch(raw))
+                if len(micro) == nsteps:
+                    batch = self._stack_micro(micro)
             if len(micro) < nsteps:
                 continue
-            batch = self._stack_micro(micro)
             micro = []
             stall_s = self._faults.stall_secs("train", self.iteration + 1)
             if stall_s > 0:
@@ -3050,115 +3095,120 @@ class Trainer:
                 wd.beat(f"compile train step (epoch {epoch})",
                         allow_s=COMPILE_ALLOW_S)
             self._local_busy_s += time.perf_counter() - t_anchor
-            # step span: host wall-clock around the ASYNC dispatch, emitted
-            # outside jit — no block_until_ready, no device_get (telemetry
-            # adds zero device syncs; once the dispatch pipeline fills,
-            # span cadence equals realized step throughput)
-            span0 = (
-                self.telemetry.now() if self.telemetry is not None else 0.0
-            )
-            if self.meta.has_carry:
-                # graft: group-uniform -- step outputs are SPMD-replicated; metrics ride the global psum
-                self.state, metrics, self.carry = self.train_step(
-                    self.state, batch, self.carry
-                )
-            else:
-                # graft: group-uniform -- step outputs are SPMD-replicated; metrics ride the global psum
-                self.state, metrics = self.train_step(self.state, batch)
+            # step span: host wall-clock around the ASYNC dispatch, taken
+            # outside jit — no block_until_ready, no device_get (the span
+            # itself syncs nothing; once the dispatch pipeline fills, span
+            # cadence equals realized step throughput)
+            with (
+                jax.profiler.StepTraceAnnotation(
+                    "train", step_num=self.iteration + 1
+                ) if rec is not None else NO_SPAN
+            ):
+                span0 = rec.now() if rec is not None else 0.0
+                if self.meta.has_carry:
+                    # graft: group-uniform -- step outputs are SPMD-replicated; metrics ride the global psum
+                    self.state, metrics, self.carry = self.train_step(
+                        self.state, batch, self.carry
+                    )
+                else:
+                    # graft: group-uniform -- step outputs are SPMD-replicated; metrics ride the global psum
+                    self.state, metrics = self.train_step(self.state, batch)
             self._train_step_compiled = True
             if wd is not None:
                 wd.beat(wd_phase)
             self.iteration += 1
             epoch_pos += 1
-            if self.telemetry is not None:
-                self._emit_event(
-                    "step", step=int(self.iteration), epoch=int(epoch),
-                    start_s=float(span0),
-                    dur_s=float(self.telemetry.now() - span0),
+            if rec is not None:
+                rec.dispatched(
+                    self.iteration, epoch, span0, rec.now() - span0
                 )
             window_iters += 1
             epoch_steps += 1
             # non-finite guard bookkeeping (one step LATE via the deque, so
             # the dispatch pipeline never stalls); may raise
             # _RollbackRequested after bad_step_limit consecutive bad steps
-            self._note_guard_flag(epoch, metrics)
+            with span("guard"):
+                self._note_guard_flag(epoch, metrics)
             # training-health statistics drain on the same late-deque
             # contract (and strip their keys from the log-facing metrics)
-            self._note_health_stats(epoch, metrics)
-            if (
-                cfg.ckpt_every_steps
-                and self.checkpointer is not None
-                and epoch_pos % cfg.ckpt_every_steps == 0
-            ):
-                if wd is not None:
-                    from mgwfbp_tpu.utils.watchdog import CHECKPOINT_ALLOW_S
+            with span("health"):
+                self._note_health_stats(epoch, metrics)
+            with span("tail"):
+                if (
+                    cfg.ckpt_every_steps
+                    and self.checkpointer is not None
+                    and epoch_pos % cfg.ckpt_every_steps == 0
+                ):
+                    if wd is not None:
+                        from mgwfbp_tpu.utils.watchdog import CHECKPOINT_ALLOW_S
 
-                    wd.beat(f"step checkpoint iter {self.iteration}",
-                            allow_s=CHECKPOINT_ALLOW_S)
-                self.save_step(epoch, epoch_pos)
-                if wd is not None:
-                    wd.beat(wd_phase)
-            # retire a completed async shard save. Multi-host this is a
-            # collective vote, so it runs on the SAME deterministic
-            # cadence as preemption agreement (every _agree_interval-th
-            # step, every process) — never gated on the local slot state
-            if self.checkpointer is not None and (
-                coord.process_count() == 1
-                or self.iteration % self._agree_interval == 0
-            ):
-                self._poll_async_ckpt()
-            sig = self._faults.preempt_signal_after(self.iteration)
-            if sig is not None:
-                self._deliver_preempt(sig)
-            if self._faults.kill_after(self.iteration):
-                # chaos (ISSUE 20): a drain-less HARD crash — no
-                # checkpoint barrier, no telemetry flush, nothing. The
-                # supervisor's healer is what recovers the group.
-                self.log.warning(
-                    "fault injection: SIGKILL self after step %d "
-                    "(drain-less hard crash)", self.iteration,
-                )
-                os.kill(os.getpid(), _signal.SIGKILL)
-            if self._agreed_preempt():
-                self._graceful_drain(epoch, epoch_pos)  # raises Preempted
-            # live observability (ISSUE 9): straggler probe + armed drift
-            # re-autotune, both at deterministic (group-uniform) steps;
-            # ISSUE 10 adds the armed /profile deep-trace window on the
-            # same cadence contract (disarmed = one lock read, zero sync)
-            self._maybe_straggler_probe()
-            self._maybe_drift_reautotune()
-            self._maybe_profile_window()
+                        wd.beat(f"step checkpoint iter {self.iteration}",
+                                allow_s=CHECKPOINT_ALLOW_S)
+                    self.save_step(epoch, epoch_pos)
+                    if wd is not None:
+                        wd.beat(wd_phase)
+                # retire a completed async shard save. Multi-host this is a
+                # collective vote, so it runs on the SAME deterministic
+                # cadence as preemption agreement (every _agree_interval-th
+                # step, every process) — never gated on the local slot state
+                if self.checkpointer is not None and (
+                    coord.process_count() == 1
+                    or self.iteration % self._agree_interval == 0
+                ):
+                    self._poll_async_ckpt()
+                sig = self._faults.preempt_signal_after(self.iteration)
+                if sig is not None:
+                    self._deliver_preempt(sig)
+                if self._faults.kill_after(self.iteration):
+                    # chaos (ISSUE 20): a drain-less HARD crash — no
+                    # checkpoint barrier, no telemetry flush, nothing. The
+                    # supervisor's healer is what recovers the group.
+                    self.log.warning(
+                        "fault injection: SIGKILL self after step %d "
+                        "(drain-less hard crash)", self.iteration,
+                    )
+                    os.kill(os.getpid(), _signal.SIGKILL)
+                if self._agreed_preempt():
+                    self._graceful_drain(epoch, epoch_pos)  # raises Preempted
+                # live observability (ISSUE 9): straggler probe + armed drift
+                # re-autotune, both at deterministic (group-uniform) steps;
+                # ISSUE 10 adds the armed /profile deep-trace window on the
+                # same cadence contract (disarmed = one lock read, zero sync)
+                self._maybe_straggler_probe()
+                self._maybe_drift_reautotune()
+                self._maybe_profile_window()
             if max_steps is not None and epoch_pos >= max_steps:
                 break
             if self.iteration % log_interval == 0:
-                metrics = {k: float(v) for k, v in metrics.items()}
-                dt = (time.time() - t_window) / max(window_iters, 1)
-                self._maybe_derive_agree_interval(dt)
-                self._observe_drift_window(dt)
-                global_batch = cfg.batch_size * self.data_size * nsteps
-                shown = {
-                    k: v for k, v in metrics.items()
-                    if k not in ("loss", "grads_nonfinite")
-                }
-                self.log.info(
-                    "epoch %d iter %d: loss %.4f%s | %.4f s/iter, %.1f samples/s",
-                    epoch, self.iteration, metrics.get("loss", float("nan")),
-                    "".join(f", {k} {v:.4f}" for k, v in shown.items()),
-                    dt, global_batch / dt,
-                )
-                if self.writer is not None:
-                    self.writer.add_scalars("train", shown | {
-                        "loss": metrics.get("loss", float("nan")),
-                    }, self.iteration)
-                    self.writer.add_scalar(
-                        "train/sec_per_iter", dt, self.iteration
+                with span("log"):
+                    metrics = {k: float(v) for k, v in metrics.items()}
+                    dt = (time.time() - t_window) / max(window_iters, 1)
+                    self._maybe_derive_agree_interval(dt)
+                    self._observe_drift_window(dt)
+                    global_batch = cfg.batch_size * self.data_size * nsteps
+                    shown = {
+                        k: v for k, v in metrics.items()
+                        if k not in ("loss", "grads_nonfinite")
+                    }
+                    self.log.info(
+                        "epoch %d iter %d: loss %.4f%s | %.4f s/iter, %.1f samples/s",
+                        epoch, self.iteration, metrics.get("loss", float("nan")),
+                        "".join(f", {k} {v:.4f}" for k, v in shown.items()),
+                        dt, global_batch / dt,
                     )
-                    self.writer.add_scalar(
-                        "train/samples_per_sec", global_batch / dt,
-                        self.iteration,
-                    )
-                t_window = time.time()
-                window_iters = 0
+                    if self.writer is not None:
+                        self.writer.add_scalars("train", shown | {
+                            "loss": metrics.get("loss", float("nan")),
+                        }, self.iteration)
+                        self.writer.add_scalar(
+                            "train/sec_per_iter", dt, self.iteration
+                        )
+                        self.writer.add_scalar(
+                            "train/samples_per_sec", global_batch / dt,
+                            self.iteration,
+                        )
+                    t_window = time.time()
+                    window_iters = 0
             # re-anchor the local-busy window: everything between the
             # pre-dispatch accumulation above and here (guard reads,
             # agreements, checkpoints, metric pulls) is group-coupled
@@ -3175,21 +3225,24 @@ class Trainer:
         # drain the guard deque: every dispatched step's flag has a value
         # by epoch end (the conversion below syncs anyway); a tail of bad
         # steps can still trigger the rollback here
-        self._drain_guard_flags()
-        self._drain_health_flags()
+        with span("drain"):
+            self._drain_guard_flags()
+            self._drain_health_flags()
         if self.telemetry is not None and epoch_steps > 0:
-            epoch_dur = time.time() - t_epoch
-            self._emit_event(
-                "epoch", epoch=int(epoch), steps=int(epoch_steps),
-                dur_s=float(epoch_dur),
-            )
-            # overlap-efficiency snapshot for this epoch's schedule regime
-            # (pure host arithmetic: measured step cadence + per-group comm
-            # times — trace-attributed when available, cost-model otherwise)
-            self._emit_overlap_snapshot(
-                step_s=epoch_dur / epoch_steps,
-                step=int(self.iteration), epoch=int(epoch),
-            )
+            with span("snapshot"):
+                epoch_dur = time.time() - t_epoch
+                self._emit_event(
+                    "epoch", epoch=int(epoch), steps=int(epoch_steps),
+                    dur_s=float(epoch_dur),
+                )
+                # overlap-efficiency snapshot for this epoch's schedule
+                # regime (pure host arithmetic: measured step cadence +
+                # per-group comm times — trace-attributed when available,
+                # cost-model otherwise)
+                self._emit_overlap_snapshot(
+                    step_s=epoch_dur / epoch_steps,
+                    step=int(self.iteration), epoch=int(epoch),
+                )
         metrics = {k: float(v) for k, v in metrics.items()}
         metrics.pop("grads_nonfinite", None)  # guard plumbing, not a metric
         self.log.info(

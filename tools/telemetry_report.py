@@ -42,6 +42,7 @@ from __future__ import annotations
 import argparse
 import json
 import os
+import statistics
 import sys
 import tempfile
 
@@ -55,6 +56,54 @@ def _fmt_s(v) -> str:
 def _window_mean(spans: list[dict], sl: slice) -> float:
     w = spans[sl]
     return sum(float(s["dur_s"]) for s in w) / max(len(w), 1)
+
+
+def _phase_section(steps: list[dict]) -> list[str]:
+    """One table of the host loop's phases (telemetry/phases.py): median
+    milliseconds over the steps that have the phase, and the share of the
+    loop's time (first span's start to last span's end) all its entries
+    took. The dispatch is the step record's own start_s / dur_s. Under the
+    table, the `ready` counter: was the prefetch pool ahead of the loop."""
+    from mgwfbp_tpu.telemetry.phases import PHASES
+
+    spans: dict[str, list[tuple[float, float]]] = {}
+    for s in steps:
+        if not s.get("phases"):
+            continue
+        spans.setdefault("dispatch", []).append(
+            (float(s["start_s"]), float(s["dur_s"])))
+        for name, (start_s, dur_s) in s["phases"].items():
+            spans.setdefault(name, []).append((float(start_s), float(dur_s)))
+    if not spans:
+        return []
+    every = [sp for rows in spans.values() for sp in rows]
+    loop_s = max(a + d for a, d in every) - min(a for a, _ in every)
+    order = [*PHASES[:3], "dispatch", *PHASES[3:]]
+    order += sorted(set(spans) - set(order))
+    lines = ["", f"host loop phases ({len(spans['dispatch'])} steps, "
+             f"{_fmt_s(loop_s)} s):",
+             f"  {'phase':>9} {'steps':>6} {'median_ms':>10} {'share':>7}"]
+    covered = 0.0
+    for name in order:
+        if name not in spans:
+            continue
+        durs = [d for _, d in spans[name]]
+        total = sum(durs)
+        covered += total
+        lines.append(
+            f"  {name:>9} {len(durs):>6} "
+            f"{statistics.median(durs) * 1e3:>10.3f} "
+            f"{100.0 * total / max(loop_s, 1e-12):>6.1f}%")
+    lines.append(
+        f"  {'(no span)':>9} {'':>6} {'':>10} "
+        f"{100.0 * max(loop_s - covered, 0.0) / max(loop_s, 1e-12):>6.1f}%")
+    ready = [int(s["ready"]) for s in steps if "ready" in s]
+    if ready:
+        lines.append(
+            f"  prefetch pool: {sum(ready) / len(ready):.2f} batches ready "
+            f"when the loop asked (none on {ready.count(0)} of {len(ready)} "
+            "steps)")
+    return lines
 
 
 def format_report(records: list[dict]) -> str:
@@ -85,6 +134,7 @@ def format_report(records: list[dict]) -> str:
                 f"trend: first-10 {_fmt_s(first)} s -> last-10 "
                 f"{_fmt_s(last)} s ({drift:+.1f}%)"
             )
+        lines.extend(_phase_section(steps))
     else:
         lines.append("steps: none recorded")
 
