@@ -25,9 +25,14 @@ jax array (whose serialization would force a device sync) raises
 host wall-clock around the *dispatch* of the async jitted step, and no
 block_until_ready / device_get is ever issued on their behalf (the zero-sync
 guard in tests/test_telemetry.py counts those two calls; lint rule JIT006
-covers the jitted side). What telemetry does pull from the device is the
-health drain (`Trainer._note_health_stats`, an ``__array__`` read the guard
-does not count); the `health` phase span times it (telemetry/phases.py).
+covers the jitted side). What telemetry does read from the device is the
+health drain (`Trainer._note_health_stats`): each step's statistics are
+copied to the host as the step's own output arrays, started at its dispatch
+(`copy_to_host_async`) and read one step late (an ``__array__`` read, which
+that guard does not count and which waits only for the step that made the
+array, never for the one in flight). The drain dispatches no device program
+(tests/test_health_drain.py); the `health` phase span times it and the
+`stats_ready` counter says whether it had to wait (telemetry/phases.py).
 """
 
 from __future__ import annotations
@@ -55,8 +60,8 @@ EVENT_TYPES: dict[str, tuple[str, ...]] = {
     # one optimizer step: host wall-clock span around the async dispatch,
     # start_s relative to the stream's epoch (header wall). The Trainer's
     # records also carry `phases` ({name: [start_s, dur_s]} of the rest of
-    # the iteration, same clock) and the counters ready / lowered
-    # (telemetry/phases.py); such a record is written after the NEXT
+    # the iteration, same clock) and the counters ready / lowered /
+    # stats_ready (telemetry/phases.py); such a record is written after the NEXT
     # dispatch, once its iteration is over, not at its own
     "step": ("step", "epoch", "start_s", "dur_s"),
     # one merge group's comm span within the step timeline (model-replayed

@@ -9,9 +9,9 @@ spans ride on the step's own record:
 
     {"event": "step", "step": 12, "epoch": 0, "start_s": 3.41, "dur_s": 0.004,
      "phases": {"wait": [3.400, 0.002], "place": [3.402, 0.008],
-                "guard": [3.414, 0.0001], "health": [3.4141, 0.041],
-                "tail": [3.4551, 0.0002], "log": [3.4553, 0.002]},
-     "ready": 3, "lowered": 0}
+                "guard": [3.414, 0.041], "health": [3.455, 0.0004],
+                "tail": [3.4554, 0.0002], "log": [3.4556, 0.002]},
+     "ready": 3, "lowered": 0, "stats_ready": 1}
 
 `phases` maps a name to `[start_s, dur_s]` on the clock `start_s` is on; a
 phase entered more than once in an iteration (`wait` and `place` with
@@ -37,7 +37,21 @@ loader cannot tell), read by `benchmarks/layer_metrics/pool_ready.py` and
 `tools/telemetry_report.py`; `lowered`, programs lowered between the previous
 step's dispatch and this one's (any new program, cache hit or not; an
 epoch's last record also counts what followed it), read by
-`window_lowerings`.
+`window_lowerings`; `stats_ready`, 1 when every statistics array the health
+drain was about to read had finished on the device (`is_ready()` of the
+replica the read takes), else 0 (left out on a step that drained nothing:
+the first of a run or an epoch, and all but every N-th with
+`MGWFBP_GUARD_CHECK_INTERVAL=N`; the lesser where an epoch's last step
+drains twice), read by `tools/telemetry_report.py`. Where the guard ran
+first it has waited for that step and this reads 1; with `--no-grad-guard`
+the `health` span is where the host waits for the chip, and it reads 0.
+
+The host runs about one step ahead of the chip: no span after the dispatch
+of step k needs step k itself. What stops it is the first read of step
+k-1's outputs, the `guard` span (or `health`, see above), which is a wait
+with the chip busy. `log` reads the step just dispatched, every
+`MGWFBP_LOG_INTERVAL`-th step, and the chip idles through the next `place`
+and dispatch.
 """
 
 from __future__ import annotations
@@ -55,7 +69,8 @@ PHASES = (
     "place",     # _to_model_batch, _stack_micro, _globalize
     # (the dispatch itself is the record's start_s / dur_s)
     "guard",     # _note_guard_flag: the previous step's non-finite flag
-    "health",    # _note_health_stats: the previous step's statistics
+    "health",    # _note_health_stats: starts this step's statistics on
+                 # their way to the host, reads the previous step's there
     "tail",      # step checkpoint, async-save poll, fault hooks, preemption
                  # agreement, straggler / drift / profile probes
     "log",       # the metrics pull every MGWFBP_LOG_INTERVAL-th step
@@ -157,6 +172,15 @@ class PhaseRecorder:
             phases[name] = [start_s, dur_s]
         else:
             have[1] += dur_s
+
+    def stats_ready(self, ready: bool) -> None:
+        """The health drain found every array it is about to read finished
+        (or not). Goes on the record in whose aftermath the drain runs; an
+        epoch's last step drains twice and keeps the lesser."""
+        record = self._record
+        if record is not None:
+            record["stats_ready"] = min(
+                record.get("stats_ready", 1), int(ready))
 
     def batches(self, loader, entered_s: float) -> Iterator:
         """The loader's batches, each `next` inside a `wait` span; `restart`

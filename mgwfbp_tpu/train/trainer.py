@@ -328,10 +328,11 @@ class Trainer:
         self._drift_reautotune_pending = False
         # training-health telemetry (ISSUE 12): the jitted step packs
         # per-group grad norms / update ratio into its metrics psum
-        # (config.health_stats); the trainer strips them one step LATE
-        # through this deque (the PR-5 guard idiom — one stacked
-        # device->host pull per drain, zero device_get on the dispatch
-        # path), streams `health` records, and feeds the online detector
+        # (config.health_stats); the trainer strips them, starts their
+        # copies to the host, and reads them one step LATE through this
+        # deque (the PR-5 guard idiom — finished arrays read on the host,
+        # no device program and no device_get on the dispatch path),
+        # streams `health` records, and feeds the online detector
         # (telemetry/health.py), whose alarm edges trip the flight
         # recorder (telemetry/recorder.py, wired in _build_run_sinks).
         from mgwfbp_tpu.telemetry.health import (
@@ -347,8 +348,9 @@ class Trainer:
             else None
         )
         # the running epoch's span recorder (telemetry/phases.py), here for
-        # the watchdog's abort alone, which writes the record it holds back;
-        # None between epochs and with telemetry off
+        # the watchdog's abort, which writes the record it holds back, and
+        # the health drain's `stats_ready`; None between epochs and with
+        # telemetry off
         self._phase_rec: Optional[PhaseRecorder] = None
         self._pending_health: deque = deque()  # graft: group-uniform -- fills at the deterministic step cadence; identical length everywhere
         # straggler probe bookkeeping: synchronous SGD equalizes
@@ -402,8 +404,10 @@ class Trainer:
         # Cadence: every step by default; MGWFBP_GUARD_CHECK_INTERVAL=N
         # batches N steps' flags into ONE stacked pull (detection lags by
         # at most N steps; the in-jit skip protects the params either
-        # way). What a per-step pull costs on the attached chip is not
-        # measured yet (ROADMAP S1)
+        # way). On the chip a read of a finished flag takes 0.5 to 0.9 ms;
+        # it is the loop's first read of step k-1's outputs, so it is
+        # where the host, one step ahead, waits for the busy chip
+        # (PERF.md, PR 24 and PR 25; ROADMAP S1 for the N > 1 stack)
         self._pending_guard: deque = deque()  # graft: group-uniform -- fills at the deterministic step cadence; identical length everywhere
         self._guard_interval = max(
             int(os.environ.get("MGWFBP_GUARD_CHECK_INTERVAL", "1")), 1
@@ -1318,19 +1322,25 @@ class Trainer:
 
     # ------------------------------------------------------------------
     # Training-health telemetry (ISSUE 12): the jitted step's health/*
-    # metrics drain one step LATE (the PR-5 deque idiom) into `health`
-    # events + the online detector; alarm edges become `health_alarm`
-    # events, which the flight recorder tee turns into postmortem
-    # bundles. Everything below is host arithmetic over already-host
-    # data — zero device_get/block_until_ready on the dispatch path
-    # (pinned by tests/test_health.py's zero-sync guard).
+    # metrics leave the chip as copies of the step's own output arrays,
+    # started when the step is dispatched and read one step LATE (the
+    # PR-5 deque idiom) into `health` events + the online detector; alarm
+    # edges become `health_alarm` events, which the flight recorder tee
+    # turns into postmortem bundles. The drain dispatches no device
+    # program (a program would queue behind the step in flight, and the
+    # host would wait that step out: PERF.md, PR 25) and calls no
+    # device_get/block_until_ready (tests/test_health.py's zero-sync
+    # guard counts those two; tests/test_health_drain.py pins "no
+    # program"). Reading an array does wait for the step that made it:
+    # where the guard ran first it has already waited, `stats_ready` on
+    # the step record says so.
     # ------------------------------------------------------------------
 
     def _note_health_stats(self, epoch: int, metrics) -> None:
         """Strip this step's health/* statistics from the metrics dict
-        (they are telemetry plumbing, not log-line metrics) and queue
-        them; drain all but the newest step's values — already computed
-        by now, so the stacked pull stalls nothing."""
+        (they are telemetry plumbing, not log-line metrics), start their
+        copies to the host and queue them; drain all but the newest
+        step's."""
         if not isinstance(metrics, dict):
             return
         from mgwfbp_tpu.train.step import HEALTH_PREFIX
@@ -1341,66 +1351,52 @@ class Trainer:
         vals = {k: metrics.pop(k) for k in keys}
         if self.telemetry is None:
             return
-        vals["loss"] = metrics.get("loss", float("nan"))
+        vals["loss"] = metrics["loss"]
+        for v in vals.values():
+            v.copy_to_host_async()
         self._pending_health.append((self.iteration, epoch, vals))
         if len(self._pending_health) <= self._guard_interval:
             return
-        items = [
+        self._drain_health([
             self._pending_health.popleft()
             for _ in range(len(self._pending_health) - 1)
-        ]
-        self._drain_health_batch(items)
+        ])
 
     def _drain_health_flags(self) -> None:
         items = list(self._pending_health)
         self._pending_health.clear()
-        self._drain_health_batch(items)
+        self._drain_health(items)
 
-    def _drain_health_batch(self, items: list) -> None:
+    def _drain_health(self, items: list) -> None:
+        """One `health` event per queued step, in step order. Each item
+        decodes with its own keys (an autotune commit or a resize changes
+        the per-group key set between two steps), on the host."""
         if not items:
             return
-        # a mid-run schedule rebind (autotune commit, resize) changes the
-        # per-group key set; queued items straddling it must decode with
-        # THEIR OWN keys, not the first item's — split into contiguous
-        # same-key runs (one stacked pull each; rebinds are rare, so this
-        # is one pull per drain in steady state)
-        run: list = []
-        run_keys: Optional[frozenset] = None
-        for item in items:
-            keys = frozenset(item[2])
-            if run and keys != run_keys:
-                self._drain_health_run(run)
-                run = []
-            run.append(item)
-            run_keys = keys
-        self._drain_health_run(run)
-
-    def _drain_health_run(self, items: list) -> None:
-        if not items:
-            return
-        # ONE stacked device->host pull for the whole run (key-major
-        # stack, like the guard batch) — N steps' statistics cost one RTT
-        keys = sorted(items[0][2])
-        mat = np.asarray(jnp.stack([
-            jnp.stack([
-                jnp.asarray(d[k], jnp.float32) for k in keys
-            ])
-            for _, _, d in items
-        ]))
         from mgwfbp_tpu.train.step import HEALTH_PREFIX
 
+        if self._phase_rec is not None:
+            # of a replicated array the read below takes the first
+            # addressable replica; the others may finish a moment later
+            self._phase_rec.stats_ready(all(
+                v.addressable_data(0).is_ready()
+                for _, _, d in items for v in d.values()
+            ))
         g_prefix = f"{HEALTH_PREFIX}gnorm_g"
         c_prefix = f"{HEALTH_PREFIX}comp_err_g"
-        for (it, ep, _), row in zip(items, mat):
-            vals = dict(zip(keys, (float(v) for v in row)))
+        for it, ep, d in items:
+            vals = {
+                k: float(np.asarray(d[k], dtype=np.float32))
+                for k in sorted(d)
+            }
             group_norms = [
-                vals[k] for k in keys if k.startswith(g_prefix)
+                v for k, v in vals.items() if k.startswith(g_prefix)
             ]
-            comp = [vals[k] for k in keys if k.startswith(c_prefix)]
+            comp = [v for k, v in vals.items() if k.startswith(c_prefix)]
             fields = {
                 "step": int(it),
                 "epoch": int(ep),
-                "loss": vals.get("loss", float("nan")),
+                "loss": vals["loss"],
                 "grad_norm": vals.get(
                     f"{HEALTH_PREFIX}grad_norm", float("nan")
                 ),

@@ -531,7 +531,16 @@ def test_zero_sync_guard_with_health_stats_and_recorder(
     health statistics, their deque drain, the health detector, and the
     flight recorder tee must add ZERO device syncs to the step loop —
     device_get/block_until_ready counts identical with everything on vs
-    everything off."""
+    everything off.
+
+    What this counts: calls of `jax.device_get` and `jax.block_until_ready`,
+    nothing else. It does not count `__float__` / `__array__` reads of
+    device arrays (the guard, the log line and the health drain make them)
+    and it does not count device programs: the drain of PRs 12 to 24
+    stacked the statistics with `jnp.stack`, whose programs queued behind
+    the step in flight, so it synchronised every step on the chip while
+    this pin held (PERF.md, PR 24 and PR 25). That the drain dispatches no
+    program is pinned by tests/test_health_drain.py."""
     from mgwfbp_tpu.train.trainer import Trainer
 
     monkeypatch.setenv("MGWFBP_LOG_INTERVAL", "1000")

@@ -270,6 +270,13 @@ def test_the_pool_says_how_many_batches_it_held_ready(stream):
         assert 0 <= s.get("ready", 0) <= 4  # workers + depth
 
 
+def test_stats_ready_is_on_the_records_whose_health_span_drained(stream):
+    """An epoch's first step queues its statistics and drains nothing; with
+    the guard on, every later drain finds its arrays finished."""
+    for s in events_of(stream, "step"):
+        assert s.get("stats_ready") == (None if s["step"] % 5 == 1 else 1)
+
+
 def test_health_records_still_follow_their_steps_record(stream):
     """A step's `health` record is drained one step late, so it follows the
     step's own record; an epoch's last step is drained inside its `drain`
@@ -311,8 +318,12 @@ def test_report_prints_one_table_of_the_phases(stream):
     assert "host loop phases (10 steps" in report
     table = report.split("host loop phases")[1].split("\n\n")[0]
     rows = [line.split()[0] for line in table.splitlines()[2:]]
-    assert rows == [*PHASES[:3], "dispatch", *PHASES[3:], "(no", "prefetch"]
+    assert rows == [
+        *PHASES[:3], "dispatch", *PHASES[3:], "(no", "prefetch", "health"]
     assert "when the loop asked (none on" in table and "of 8 steps)" in table
+    # an epoch's first step queues its statistics and drains nothing
+    assert ("health statistics: finished when the drain asked on 8 of 8 "
+            "steps (100.0%)") in table
 
 
 def test_streams_without_phases_render_as_before():

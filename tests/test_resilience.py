@@ -135,7 +135,12 @@ def test_consecutive_bad_steps_roll_back_to_checkpoint(
     finishes the epoch from its exact position."""
     from mgwfbp_tpu.train.trainer import Trainer
 
-    monkeypatch.setenv("MGWFBP_FAULT_PLAN", "nan@step=4,count=2")
+    # the stall gives the async writer time to finish step 4's save before
+    # step 5's `tail` polls for it: the loop no longer waits a device step
+    # out in every health drain (ISSUE 25), and a save still in flight at
+    # the rollback is abandoned for the one before it
+    monkeypatch.setenv(
+        "MGWFBP_FAULT_PLAN", "nan@step=4,count=2;stall@secs=1,step=5")
     cfg = _cfg(
         logdir=str(tmp_path), telemetry=True,
         checkpoint_dir=str(tmp_path / "ckpt"),

@@ -63,7 +63,9 @@ def _phase_section(steps: list[dict]) -> list[str]:
     milliseconds over the steps that have the phase, and the share of the
     loop's time (first span's start to last span's end) all its entries
     took. The dispatch is the step record's own start_s / dur_s. Under the
-    table, the `ready` counter: was the prefetch pool ahead of the loop."""
+    table, the counters: `ready` (was the prefetch pool ahead of the loop)
+    and `stats_ready` (had the health drain's arrays finished when it asked;
+    where not, the drain is where the host waited for the chip)."""
     from mgwfbp_tpu.telemetry.phases import PHASES
 
     spans: dict[str, list[tuple[float, float]]] = {}
@@ -103,6 +105,12 @@ def _phase_section(steps: list[dict]) -> list[str]:
             f"  prefetch pool: {sum(ready) / len(ready):.2f} batches ready "
             f"when the loop asked (none on {ready.count(0)} of {len(ready)} "
             "steps)")
+    stats = [int(s["stats_ready"]) for s in steps if "stats_ready" in s]
+    if stats:
+        lines.append(
+            f"  health statistics: finished when the drain asked on "
+            f"{sum(stats)} of {len(stats)} steps "
+            f"({100.0 * sum(stats) / len(stats):.1f}%)")
     return lines
 
 
