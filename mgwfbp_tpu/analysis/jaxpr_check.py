@@ -809,8 +809,8 @@ def trace_train_step(
     # abstract state: full init math traced, nothing executed
     state = jax.eval_shape(
         lambda: create_train_state(
-            jax.random.PRNGKey(0), model, jnp.zeros((1,) + meta.input_shape),
-            tx,
+            jax.random.PRNGKey(0), model,
+            jnp.zeros((1,) + meta.input_shape, meta.input_dtype), tx,
         )
     )
     full_params = state.params  # canonical tree (pre any sharded carry)
@@ -849,9 +849,13 @@ def trace_train_step(
     )
     batch = {
         "x": jax.ShapeDtypeStruct(
-            (1, batch_size) + meta.input_shape, jnp.float32
+            (1, batch_size) + meta.input_shape, meta.input_dtype
         ),
-        "y": jax.ShapeDtypeStruct((1, batch_size), jnp.int32),
+        # a carry-free lm model predicts a token at every position
+        "y": jax.ShapeDtypeStruct(
+            (1, batch_size)
+            + (meta.input_shape if meta.task == "lm" else ()), jnp.int32
+        ),
     }
     if steps == 1:
         closed = jax.make_jaxpr(step)(state, batch)

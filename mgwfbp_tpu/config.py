@@ -34,6 +34,12 @@ class TrainConfig:
     # --comm-op hier lowers the hierarchy explicitly)
     num_steps: Optional[int] = None  # LM window length override (default 35;
     # seq-parallel transformers need num_steps % seq_parallel == 0)
+    # the part of a model this chip holds (models that can be held in part:
+    # the mellum2 family). None = all of it
+    layers_held: Optional[int] = None  # the first n layers
+    experts_held: Optional[str] = None  # "first:count" of each layer's experts
+    vocab_size: Optional[int] = None  # `tokens` dataset: ids 0..n-1, and so
+    # the rows of the embedding and the head (the dataset's num_classes)
 
     # MG-WFBP scheduler
     policy: str = "auto"  # auto | mgwfbp | threshold | single | wfbp | none
@@ -78,6 +84,9 @@ class TrainConfig:
     weight_decay: float = 1e-4
     momentum: float = 0.9
     norm_clip: Optional[float] = None  # lstm 0.25 / lstman4 400 (dist_trainer.py:56-60)
+    optimizer: str = "sgd"  # sgd | adamw (decoupled `weight_decay` on
+    # matrices, b1 0.9, eps 1e-8; OptimSpec kind 'adam')
+    adam_b2: float = 0.999
 
     # schedule
     lr_schedule: str = "auto"  # auto | step | cosine | ptb | anneal | vgg | const
@@ -185,6 +194,16 @@ PRESETS: dict[str, dict] = {
     # out, dl_trainer.py:219-222: wd stays 1e-4, momentum 0.9)
     "lstman4": dict(dataset="an4", batch_size=4, lr=2e-4, max_epochs=100,
                     lr_schedule="anneal", norm_clip=400.0),
+    # sparse decoder LM (models/mellum.py). Nothing of the recipe is
+    # published: AdamW as sparse language models are usually trained, 2 x
+    # 8,192 tokens a device and step, cosine after the program's warm-up
+    "mellum2": dict(dataset="tokens", batch_size=2, num_steps=8192, lr=3e-4,
+                    max_epochs=40, lr_schedule="cosine", optimizer="adamw",
+                    adam_b2=0.95, weight_decay=0.1, norm_clip=1.0),
+    "mellum2_tiny": dict(dataset="tokens", batch_size=2, num_steps=64,
+                         lr=3e-3, max_epochs=40, lr_schedule="cosine",
+                         optimizer="adamw", adam_b2=0.95, weight_decay=0.1,
+                         norm_clip=1.0, vocab_size=256),
     "fcn5net": dict(dataset="mnist", batch_size=64, lr=0.05, max_epochs=10),
     "lr": dict(dataset="mnist", batch_size=64, lr=0.01, max_epochs=10),
 }

@@ -62,8 +62,10 @@ def _wrap_prefetch(train_loader):
 # MGWFBP_SYNTH_MODE=hard swaps the trivial twin for the held-out
 # generalization generator (datasets.synthetic_images_hard) — the honest
 # convergence substitute in this no-egress container.
-_SYNTH_TRAIN = {"mnist": 4096, "cifar10": 4096, "imagenet": 512, "ptb": 512}
-_SYNTH_VAL = {"mnist": 512, "cifar10": 512, "imagenet": 128, "ptb": 64}
+_SYNTH_TRAIN = {"mnist": 4096, "cifar10": 4096, "imagenet": 512, "ptb": 512,
+                "tokens": 64}  # tokens: sequences
+_SYNTH_VAL = {"mnist": 512, "cifar10": 512, "imagenet": 128, "ptb": 64,
+              "tokens": 8}
 
 
 def _synth_size(split: str, name: str) -> int:
@@ -95,6 +97,7 @@ def data_prepare(
     synthetic: Optional[bool] = None,
     augment: bool = True,
     num_steps: Optional[int] = None,
+    vocab_size: Optional[int] = None,
 ) -> DataBundle:
     """Build sharded train/val loaders for a dataset name.
 
@@ -104,7 +107,8 @@ def data_prepare(
     `augment=False` disables training-time augmentation (benchmarking).
     `num_steps` overrides the LM window length (default: the reference's
     35-token BPTT window; seq-parallel transformers need a length divisible
-    by the seq mesh extent).
+    by the seq mesh extent); for `tokens` it is the sequence length.
+    `vocab_size` states the `tokens` vocabulary (data/tokens.py).
     """
     name = dataset.lower()
     if name in ("mnist", "cifar10", "imagenet"):
@@ -225,6 +229,44 @@ def data_prepare(
             train=_wrap_prefetch(train_loader),
             val=val_loader,
             num_classes=vocab_size,
+            synthetic=is_synth,
+            num_batches_per_epoch=len(train_loader),
+        )
+    if name == "tokens":
+        from mgwfbp_tpu.data import tokens as tok
+        from mgwfbp_tpu.models import DATASET_CLASSES
+
+        seq_len = num_steps or tok.SEQ_LEN
+        streams = None
+        if not synthetic:
+            streams = (tok.load_token_stream(data_dir, "train"),
+                       tok.load_token_stream(data_dir, "valid"))
+            if streams[0] is None or streams[1] is None:
+                streams = None
+        is_synth = streams is None
+        if is_synth:
+            if synthetic is False:
+                raise FileNotFoundError(
+                    f"tokens/train.npy and tokens/valid.npy not found under "
+                    f"{data_dir!r}")
+            vocab = vocab_size or DATASET_CLASSES["tokens"]
+            streams = tuple(
+                tok.synthetic_token_stream(n, seq_len, vocab, seed + i)
+                for i, n in enumerate((
+                    _synth_size("train", "tokens"),
+                    _synth_size("val", "tokens"))))
+        else:
+            vocab = vocab_size or int(max(s.max() for s in streams)) + 1
+        train, val = (
+            tok.sequence_dataset(s, seq_len, vocab) for s in streams)
+        train_loader = ShardedLoader(
+            train, batch_size, shard, shuffle=True, seed=seed)
+        val_loader = ShardedLoader(
+            val, batch_size, shard, shuffle=False, seed=seed, drop_last=False)
+        return DataBundle(
+            train=_wrap_prefetch(train_loader),
+            val=val_loader,
+            num_classes=vocab,
             synthetic=is_synth,
             num_batches_per_epoch=len(train_loader),
         )

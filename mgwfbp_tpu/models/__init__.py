@@ -10,6 +10,7 @@ the module plus a `ModelMeta` describing the canonical input so callers
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, Optional
 
 import jax.numpy as jnp
@@ -21,6 +22,7 @@ DATASET_CLASSES = {
     "imagenet": 1000,
     "ptb": 10000,
     "an4": 29,  # CTC label alphabet, reference labels.json (29 chars)
+    "tokens": 98304,  # data/tokens.py default; --vocab-size names a share
 }
 
 
@@ -36,9 +38,13 @@ class ModelMeta:
     task: str = "classify"  # classify | lm | ctc
     has_aux_logits: bool = False  # googlenet/inceptionv3 style aux heads
     has_carry: bool = False  # recurrent models with BPTT carry state
+    # lm models whose `model(x, targets=y)` returns (per-token loss (B, T),
+    # statistics for the step's metrics) and never the whole batch's logits
+    fused_loss: bool = False
 
 
 _REGISTRY: dict[str, Callable[[int], tuple[Any, ModelMeta]]] = {}
+_TAKES_SHARE: set[str] = set()  # factories with layers_held / experts_held
 
 
 def register(name: str):
@@ -62,14 +68,25 @@ DATASET_INPUT_HWC = {
 }
 
 
-def create_model(name: str, dataset: Optional[str] = None, num_classes: Optional[int] = None):
+def create_model(
+    name: str, dataset: Optional[str] = None,
+    num_classes: Optional[int] = None, **share,
+):
     """Build (module, meta) for a model name (reference create_net,
     dl_trainer.py:87-135). dataset/num_classes override the model's default;
     for image models a dataset override also retargets meta.input_shape so
-    callers building batches from meta stay consistent."""
+    callers building batches from meta stay consistent. `share` (the part of
+    the model one chip holds: `layers_held`, `experts_held`) goes to the
+    factories that take it (the mellum2 family); for any other model it is
+    an error."""
     if name not in _REGISTRY:
         raise ValueError(f"unknown model {name!r}; known: {model_names()}")
-    factory = _REGISTRY[name]
+    share = {k: v for k, v in share.items() if v is not None}
+    if share and name not in _TAKES_SHARE:
+        raise ValueError(
+            f"model {name!r} cannot be held in part ({sorted(share)}); "
+            f"only {sorted(_TAKES_SHARE)} can")
+    factory = functools.partial(_REGISTRY[name], **share)
     module, meta = factory(num_classes)
     if dataset is not None and dataset != meta.dataset:
         nc = num_classes or DATASET_CLASSES.get(dataset, meta.num_classes)
@@ -320,3 +337,31 @@ def _lstman4(nc):
             input_shape=(201, 161), task="ctc",  # (time, freq=161)
         ),
     )
+
+
+def _register_mellum2(name: str, shape_name: str, window_len: int):
+    @register(name)
+    def _factory(nc, layers_held=None, experts_held=None):
+        from mgwfbp_tpu.models import mellum
+
+        shape = getattr(mellum, shape_name)
+        nc = nc or shape.vocab_size
+        return (
+            mellum.Mellum2LM(
+                vocab_size=nc, shape=shape, layers_held=layers_held,
+                experts_held=experts_held or (0, shape.num_experts),
+            ),
+            ModelMeta(
+                name=name, dataset="tokens", num_classes=nc,
+                input_shape=(window_len,), input_dtype=jnp.int32, task="lm",
+                has_carry=False, fused_loss=True,
+            ),
+        )
+
+    _TAKES_SHARE.add(name)
+
+
+# the published widths; and the same architecture at a size the CPU tests
+# hold (hidden 64, 2 key/value heads, 8 experts top 2, window 16)
+_register_mellum2("mellum2", "MELLUM2", 8192)
+_register_mellum2("mellum2_tiny", "MELLUM2_TINY", 64)

@@ -1,0 +1,393 @@
+"""Mellum 2 (JetBrains Mellum2-12B-A2.5B): pre-norm decoder with grouped-query
+attention, three sliding-window layers to one full layer, and a sparse block
+of 64 SwiGLU experts (top 8, renormalized, no shared expert) in every layer.
+
+Source: https://huggingface.co/JetBrains/Mellum2-12B-A2.5B-Instruct
+(config.json, `model_type` mellum). Per layer, with x the residual stream:
+
+    h  = x + W_o Attn(rope(W_q u), rope(W_k u), W_v u),   u = RMSNorm(x)
+    x' = h + sum_k w_k W_down,e_k (silu(W_gate,e_k v) * W_up,e_k v),
+                                                          v = RMSNorm(h)
+
+then a final RMSNorm and an untied head. No bias, no dropout. Rotary
+embedding over the whole head dimension in the half-split layout: plain
+(theta 500,000) on window layers, YaRN (factor 16 over 8,192 positions) on
+full layers, as `transformers` computes it.
+
+**A chip's share.** One chip cannot hold a whole layer's 64 experts with
+their optimizer state (6.7 GB), so the module is told what it holds:
+`layers_held` (the first n of `layer_types`), `experts_held = (first, count)`
+and `vocab_size` (the rows of the embedding and the head that live here). The
+router keeps its 64 outputs and its 8 experts a token; the weights are
+renormalized over all 8 chosen; only the terms whose expert is held are
+computed and added. A token none of whose experts live here gets zero from
+the block. Nothing stands in for the absent chips or their exchange.
+
+**Dropless.** No capacity: the step's (token, expert) assignments are sorted
+by expert and go through ONE grouped product per weight
+(`jax.lax.ragged_dot`), sized for the worst case that every assignment lands
+here (tokens x 8 rows, of which a quarter are expected to be in a group; the
+TPU's grouped product skips the rest). Taking the tokens a chunk at a time
+saved no memory and made the grouped products a half slower (224 against 150
+ms a step, my chip runs, PR 26), so there is one group per expert and step.
+
+**Memory.** The attention core recomputes its blocks (ops/blockattn.py), the
+experts recompute their sorted rows and products, and with `targets` the loss is taken over
+`loss_block` tokens at a time, each block's logits recomputed in the backward
+pass: no (tokens, vocabulary) array outlives a block. What is saved per layer
+is the residual stream and the projections' inputs.
+
+Assumed (the config names none of them): no query/key normalization, no
+router bias, no load-balancing loss, no multi-token-prediction head; initial
+weights normal(0, 0.02), norms at one.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Optional
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from mgwfbp_tpu.ops.blockattn import blockwise_attention
+
+SLIDING, FULL = "sliding_attention", "full_attention"
+# the step's metrics carry the routing counts under these keys (HEALTH_PREFIX
+# of train/step.py, so they leave the chip by the health statistics' road)
+MOE_TOKENS_KEY = "health/moe_tokens"
+MOE_DROPPED_KEY = "health/moe_dropped"
+
+
+@dataclasses.dataclass(frozen=True)
+class MellumShape:
+    """The published sizes (config.json); a test builds a smaller one."""
+
+    vocab_size: int = 98304
+    hidden_size: int = 2304
+    num_heads: int = 32
+    num_kv_heads: int = 4
+    head_dim: int = 128
+    num_experts: int = 64
+    experts_per_token: int = 8
+    expert_width: int = 896
+    sliding_window: int = 1024
+    layer_types: tuple[str, ...] = (SLIDING, SLIDING, SLIDING, FULL) * 7
+    rms_norm_eps: float = 1e-6
+    rope_theta: float = 500000.0
+    yarn_factor: float = 16.0
+    yarn_original_len: int = 8192
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_attention_factor: float = 1.2772588722239782
+
+
+MELLUM2 = MellumShape()
+# the architecture at a size the CPU tests hold (benchmarks/references/
+# mellum2_share_tiny.py states the same numbers independently)
+MELLUM2_TINY = MellumShape(
+    vocab_size=256, hidden_size=64, num_heads=4, num_kv_heads=2, head_dim=16,
+    num_experts=8, experts_per_token=2, expert_width=32, sliding_window=16,
+    layer_types=(SLIDING, SLIDING, SLIDING, FULL),
+)
+
+
+def rope_inv_freq(shape: MellumShape, kind: str) -> tuple[jax.Array, float]:
+    """(inverse frequencies (head_dim / 2,), factor on cos and sin) of a layer
+    of `kind`. YaRN as `transformers._compute_yarn_parameters`: interpolate
+    (divide by the factor) the low frequencies, keep the high ones, blend
+    linearly between the two correction dimensions."""
+    dim = shape.head_dim
+    base = shape.rope_theta ** (
+        -jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    if kind == SLIDING:
+        return base, 1.0
+
+    def correction_dim(rotations: float) -> float:
+        return dim * math.log(
+            shape.yarn_original_len / (rotations * 2 * math.pi)
+        ) / (2 * math.log(shape.rope_theta))
+
+    low = max(math.floor(correction_dim(shape.yarn_beta_fast)), 0)
+    high = min(math.ceil(correction_dim(shape.yarn_beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip(
+        (jnp.arange(dim // 2, dtype=jnp.float32) - low) / (high - low), 0, 1)
+    inv_freq = (1 - ramp) * base + ramp * base / shape.yarn_factor
+    return inv_freq, shape.yarn_attention_factor
+
+
+def apply_rope(x: jax.Array, inv_freq: jax.Array, factor: float) -> jax.Array:
+    """x (B, T, H, D) rotated by position in the half-split ("rotate_half")
+    layout, float32 inside, x's dtype out."""
+    t = x.shape[1]
+    angles = jnp.arange(t, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    angles = jnp.concatenate([angles, angles], axis=-1)[None, :, None, :]
+    cos, sin = jnp.cos(angles) * factor, jnp.sin(angles) * factor
+    x32 = x.astype(jnp.float32)
+    half = x.shape[-1] // 2
+    rotated = jnp.concatenate([-x32[..., half:], x32[..., :half]], axis=-1)
+    return (x32 * cos + rotated * sin).astype(x.dtype)
+
+
+def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
+    x32 = x.astype(jnp.float32)
+    y = x32 * lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + eps)
+    return (y * scale.astype(jnp.float32)).astype(x.dtype)
+
+
+def attention(p: dict, x: jax.Array, shape: MellumShape, kind: str,
+              block: int) -> jax.Array:
+    """The attention sublayer on the normed input x (B, T, hidden)."""
+    b, t, _ = x.shape
+    hd = shape.head_dim
+    with jax.named_scope("attn_proj"):
+        q = (x @ p["wq"]).reshape(b, t, shape.num_heads, hd)
+        k = (x @ p["wk"]).reshape(b, t, shape.num_kv_heads, hd)
+        v = (x @ p["wv"]).reshape(b, t, shape.num_kv_heads, hd)
+        inv_freq, factor = rope_inv_freq(shape, kind)
+        q = apply_rope(q, inv_freq, factor)
+        k = apply_rope(k, inv_freq, factor)
+    window = shape.sliding_window if kind == SLIDING else None
+    if window is not None:
+        # a block of the band computes block + window (+ alignment) keys
+        # for `window` useful ones: a quarter of the window a block wastes
+        # half as much as a half (16.4 against 33.2 ms a layer, forward and
+        # backward, at 256 and 512 of 1,024; my chip run, PR 26)
+        block = min(block, max(window // 4, 1))
+    with jax.named_scope("attn_window" if kind == SLIDING else "attn_full"):
+        a = blockwise_attention(q, k, v, window=window, block=block)
+    with jax.named_scope("attn_proj"):
+        return a.reshape(b, t, shape.num_heads * hd) @ p["wo"]
+
+
+def route(u: jax.Array, router: jax.Array, top_k: int):
+    """Softmax router over ALL experts in float32 (operands as they are
+    stored, product at `highest`): (indices (N, k), weights (N, k) summing to
+    one over the k chosen)."""
+    logits = jnp.dot(
+        u.astype(jnp.float32), router.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST,
+    )
+    probs = jax.nn.softmax(logits, axis=-1)
+    top, idx = lax.top_k(probs, top_k)
+    return idx, top / jnp.sum(top, axis=-1, keepdims=True)
+
+
+@jax.custom_vjp
+def _dispatch(u, order, inverse, valid):
+    """Row r of the result is token `order[r] // k` of u (N, D), for the N * k
+    assignments sorted by expert; `valid` rows of them lie in a group. The
+    transpose as XLA derives it is a scatter-add of N * k rows; written here
+    as what it equals, a gather by the inverse permutation and a sum over a
+    token's k assignments. Cotangent rows past `valid` are no expert's: the
+    grouped product leaves them unwritten on the chip, so they are zeroed
+    here and never summed."""
+    return u[order // (order.shape[0] // u.shape[0])]
+
+
+def _dispatch_fwd(u, order, inverse, valid):
+    return _dispatch(u, order, inverse, valid), (inverse, valid, u.shape[0])
+
+
+def _dispatch_bwd(res, g):
+    inverse, valid, n = res
+    g = jnp.where(jnp.arange(g.shape[0])[:, None] < valid, g, 0)
+    return g[inverse].reshape(n, -1, g.shape[-1]).sum(axis=1), None, None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _unsort(rows, order, inverse):
+    """rows[inverse]: the sorted assignments back in (token, k) order. Its
+    transpose is the gather by `order`."""
+    return rows[inverse]
+
+
+def _unsort_fwd(rows, order, inverse):
+    return rows[inverse], order
+
+
+def _unsort_bwd(order, g):
+    return g[order], None, None
+
+
+_unsort.defvjp(_unsort_fwd, _unsort_bwd)
+
+
+def held_experts(u, idx, weights, w_gate, w_up, w_down, first: int):
+    """The held experts' part of the sparse block for tokens u.
+
+    u (N, D); idx, weights (N, k) from `route`; w_gate, w_up (E, D, F) and
+    w_down (E, F, D) the E held experts, expert `first` of the model first.
+    Returns (y (N, D), tokens per held expert (E,), assignments to a held
+    expert that no group took (a count; 0 by construction))."""
+    n, k = idx.shape
+    count = w_gate.shape[0]
+    local = idx - first
+    held = (local >= 0) & (local < count)
+    # unheld assignments sort behind every held expert, into no group
+    keys = jnp.where(held, local, count).reshape(-1)
+    order = jnp.argsort(keys, stable=True)
+    inverse = jnp.argsort(order)
+    sizes = jnp.sum(
+        keys[:, None] == jnp.arange(count)[None, :], axis=0, dtype=jnp.int32)
+    # (N * k, D), grouped by expert; the rows past the last group belong to
+    # no expert: a grouped product leaves them UNWRITTEN on the chip (zero
+    # only on the CPU), forward and backward, so they are masked below and
+    # in _dispatch's transpose, never trusted
+    rows = _dispatch(u, order, inverse, jnp.sum(sizes))
+    gate = lax.ragged_dot(rows, w_gate, sizes)
+    up = lax.ragged_dot(rows, w_up, sizes)
+    mid = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32))
+    out = lax.ragged_dot(mid.astype(u.dtype), w_down, sizes)
+    out = _unsort(out, order, inverse).reshape(n, k, -1)
+    out = jnp.where(held[..., None], out.astype(jnp.float32), 0.0)
+    y = jnp.sum(out * weights[..., None], axis=1)
+    dropped = jnp.sum(held) - jnp.sum(sizes)
+    return y.astype(u.dtype), sizes, dropped
+
+
+def sparse_block(p: dict, x: jax.Array, shape: MellumShape, first: int):
+    """The sparse block on the normed input x (B, T, hidden): (y, tokens per
+    held expert (E,) float32, dropped float32). The experts' part keeps
+    nothing but its inputs for the backward pass (`jax.checkpoint`)."""
+    b, t, d = x.shape
+    u = x.reshape(b * t, d)
+    with jax.named_scope("moe_route"):
+        idx, weights = route(u, p["router"], shape.experts_per_token)
+    with jax.named_scope("moe_experts"):
+        y, sizes, dropped = jax.checkpoint(held_experts, static_argnums=6)(
+            u, idx, weights, p["w_gate"], p["w_up"], p["w_down"], first)
+    return (
+        y.reshape(b, t, d), sizes.astype(jnp.float32),
+        dropped.astype(jnp.float32),
+    )
+
+
+def token_losses(h: jax.Array, head: jax.Array, targets: jax.Array,
+                 block: int) -> jax.Array:
+    """-log softmax(h @ head)[target] per token, float32, `block` tokens at a
+    time: a block's (block, vocabulary) logits live only inside its own
+    forward and (recomputed) backward."""
+    n = h.shape[0]
+    block = block if n % block == 0 else n
+
+    def one(args):
+        hb, yb = args
+        with jax.named_scope("lm_head"):
+            logits = jnp.dot(hb, head, preferred_element_type=jnp.float32)
+        with jax.named_scope("loss"):
+            lse = jax.nn.logsumexp(logits, axis=-1)
+            picked = jnp.take_along_axis(logits, yb[:, None], axis=-1)[:, 0]
+            return lse - picked
+
+    return lax.map(jax.checkpoint(one), (
+        h.reshape(n // block, block, -1), targets.reshape(n // block, block),
+    )).reshape(n)
+
+
+class _Leaves(nn.Module):
+    """Declares a group of parameters and hands them back as a dict."""
+
+    shapes: tuple  # ((name, shape, is_norm), ...)
+
+    @nn.compact
+    def __call__(self) -> dict:
+        return {
+            name: self.param(
+                name,
+                nn.initializers.ones if is_norm
+                else nn.initializers.normal(0.02),
+                shape,
+            )
+            for name, shape, is_norm in self.shapes
+        }
+
+
+class Mellum2LM(nn.Module):
+    """Causal LM over integer tokens, task `lm` without carry.
+
+    `model(x)` returns logits (B, T, vocab_size). `model(x, targets=y)`
+    returns (per-token loss (B, T) float32, routing counts) without ever
+    holding the logits of more than `loss_block` tokens: the path the train
+    and eval steps take (`ModelMeta.fused_loss`)."""
+
+    vocab_size: int = MELLUM2.vocab_size
+    shape: MellumShape = MELLUM2
+    layers_held: Optional[int] = None  # the first n of shape.layer_types
+    experts_held: tuple[int, int] = (0, MELLUM2.num_experts)  # (first, count)
+    attn_block: int = 512  # queries a block; a window layer takes fewer
+    loss_block: int = 2048
+
+    def layer_kinds(self) -> tuple[str, ...]:
+        kinds = self.shape.layer_types
+        return kinds if self.layers_held is None else kinds[: self.layers_held]
+
+    @nn.compact
+    def __call__(self, x: jax.Array, targets: Optional[jax.Array] = None,
+                 train: bool = False):
+        s = self.shape
+        first, count = self.experts_held
+        if not (0 <= first and count >= 1 and first + count <= s.num_experts):
+            raise ValueError(
+                f"experts held {first}:{count} are not among the model's "
+                f"{s.num_experts}")
+        d, f = s.hidden_size, s.expert_width
+        dq, dkv = s.num_heads * s.head_dim, s.num_kv_heads * s.head_dim
+        layer_shapes = (
+            ("attn_norm", (d,), True), ("wq", (d, dq), False),
+            ("wk", (d, dkv), False), ("wv", (d, dkv), False),
+            ("wo", (dq, d), False), ("moe_norm", (d,), True),
+            ("router", (d, s.num_experts), False),
+            ("w_gate", (count, d, f), False), ("w_up", (count, d, f), False),
+            ("w_down", (count, f, d), False),
+        )
+        embed = _Leaves(
+            (("embedding", (self.vocab_size, d), False),), name="embed",
+        )()["embedding"]
+        kinds = self.layer_kinds()
+        layers = [
+            _Leaves(layer_shapes, name=f"layer_{i}")()
+            for i in range(len(kinds))
+        ]
+        out = _Leaves(
+            (("norm", (d,), True), ("head", (d, self.vocab_size), False)),
+            name="out",
+        )()
+        if self.is_initializing():
+            # init wants the declarations above and no forward pass (run
+            # eagerly at the real size it would compile op by op)
+            return jnp.zeros((*x.shape, self.vocab_size), embed.dtype)
+
+        h = embed[x]
+        tokens, dropped = [], []
+        for p, kind in zip(layers, kinds):
+            h = h + attention(
+                p, rms_norm(h, p["attn_norm"], s.rms_norm_eps), s, kind,
+                self.attn_block)
+            y, layer_tokens, layer_dropped = sparse_block(
+                p, rms_norm(h, p["moe_norm"], s.rms_norm_eps), s, first)
+            h = h + y
+            tokens.append(layer_tokens)
+            dropped.append(layer_dropped)
+        h = rms_norm(h, out["norm"], s.rms_norm_eps)
+        if targets is None:
+            with jax.named_scope("lm_head"):
+                return jnp.dot(h, out["head"])
+        b, t = x.shape
+        losses = token_losses(
+            h.reshape(b * t, d), out["head"], targets.reshape(b * t),
+            self.loss_block)
+        stats = {
+            # (layers held, experts held): tokens each held expert took
+            MOE_TOKENS_KEY: jnp.stack(tokens),
+            MOE_DROPPED_KEY: jnp.sum(jnp.stack(dropped)),
+        }
+        return losses.reshape(b, t), stats

@@ -174,8 +174,16 @@ def make_optimizer(
     epoch_offset: float = 0.0,
     world_size: int = 1,
     return_spec: bool = False,
+    optimizer: str = "sgd",
+    b1: float = 0.9,
+    b2: float = 0.999,
+    eps: float = 1e-8,
 ):
     """Build the full optimizer chain + its epoch schedule (for logging).
+
+    optimizer: 'sgd' (momentum + coupled masked decay, the reference's) or
+    'adamw' (optax.adamw with b1/b2/eps and `weight_decay` DECOUPLED, on
+    parameters of more than one dimension only; `momentum` is unused).
 
     step_offset/epoch_offset anchor the step->epoch conversion so an elastic
     resize continues the schedule from its current position (as_step_fn).
@@ -194,20 +202,35 @@ def make_optimizer(
         epoch_schedule, num_batches_per_epoch,
         step_offset=step_offset, epoch_offset=epoch_offset,
     )
-    tx = sgd(step_fn, momentum=momentum, weight_decay=weight_decay)
+    if optimizer == "sgd":
+        tx = sgd(step_fn, momentum=momentum, weight_decay=weight_decay)
+    elif optimizer == "adamw":
+        tx = optax.adamw(
+            step_fn, b1=b1, b2=b2, eps=eps, weight_decay=weight_decay,
+            mask=decay_mask,
+        )
+    else:
+        raise ValueError(f"unknown optimizer {optimizer!r} (sgd | adamw)")
     scaled_clip = None
     if norm_clip is not None:
         scaled_clip = scaled_clip_threshold(norm_clip, world_size)
         tx = optax.chain(optax.clip_by_global_norm(scaled_clip), tx)
     if not return_spec:
         return tx, epoch_schedule
-    spec = OptimSpec(
-        lr=step_fn,
-        kind="sgd",
-        momentum=momentum,
-        weight_decay=weight_decay,
-        norm_clip=scaled_clip,
-    )
+    if optimizer == "sgd":
+        spec = OptimSpec(
+            lr=step_fn,
+            kind="sgd",
+            momentum=momentum,
+            weight_decay=weight_decay,
+            norm_clip=scaled_clip,
+        )
+    else:
+        spec = OptimSpec(
+            lr=step_fn, kind="adam", b1=b1, b2=b2, eps=eps,
+            weight_decay=weight_decay, decoupled_wd=True,
+            norm_clip=scaled_clip,
+        )
     return tx, epoch_schedule, spec
 
 

@@ -122,6 +122,15 @@ def _leaf_sumsq(tree: Any) -> list[jax.Array]:
     ]
 
 
+def _pop_model_stats(metrics: dict) -> dict:
+    """Take the HEALTH_PREFIX entries a fused-loss model put into its
+    metrics out of the dict (none for any other model)."""
+    return {
+        k: metrics.pop(k) for k in list(metrics)
+        if k.startswith(HEALTH_PREFIX)
+    }
+
+
 def _tree_norm_sq(tree: Any) -> jax.Array:
     sq = _leaf_sumsq(tree)
     return sum(sq) if sq else jnp.zeros((), jnp.float32)
@@ -281,6 +290,22 @@ def make_loss_fn(
                 loss = cross_entropy(logits, batch["y"])
             correct = (jnp.argmax(logits, -1) == batch["y"]).mean()
             metrics = {"loss": loss, "accuracy": correct}
+            bstats_out, carry_out = restate(
+                updates.get("batch_stats", master_bstats), carry
+            )
+            return loss, (bstats_out, carry_out, metrics)
+        if meta.task == "lm" and meta.fused_loss:
+            # the model takes the loss itself, a block of tokens at a
+            # time: the whole batch's (tokens, vocabulary) logits and
+            # their gradient never exist (models/mellum.py). Its
+            # statistics (routing counts, HEALTH_PREFIX keys) ride in the
+            # metrics; the step strips them where nothing streams them
+            (per_token, stats), updates = model.apply(
+                variables, batch["x"], targets=batch["y"], train=True,
+                mutable=["batch_stats"], rngs=rngs,
+            )
+            loss = per_token.mean()
+            metrics = {"loss": loss, "perplexity": jnp.exp(loss), **stats}
             bstats_out, carry_out = restate(
                 updates.get("batch_stats", master_bstats), carry
             )
@@ -487,6 +512,7 @@ def make_train_step(
             grads, (bstats, mcarry, metrics) = micro_grads(
                 bstats, mcarry, micro_batch, micro_idx
             )
+            _pop_model_stats(metrics)  # the final micro-step's are kept
             grads_sum = jax.tree_util.tree_map(jnp.add, grads_sum, grads)
             metrics_sum = jax.tree_util.tree_map(jnp.add, metrics_sum, metrics)
             return (grads_sum, bstats, mcarry, metrics_sum), None
@@ -525,9 +551,15 @@ def make_train_step(
             )
             grads = jax.tree_util.tree_map(jnp.add, grads_sum, grads)
             metrics = jax.tree_util.tree_map(jnp.add, metrics_sum, metrics)
+        # a fused-loss model's own statistics (counts, of the final
+        # micro-step) are no means over micro-steps; without the health
+        # stream nothing reads them and they are dropped here
+        model_stats = _pop_model_stats(metrics)
         inv = 1.0 / float(nsteps_update)
         grads = jax.tree_util.tree_map(lambda g: g * inv, grads)
         metrics = jax.tree_util.tree_map(lambda m: m * inv, metrics)
+        if health_stats:
+            metrics.update(model_stats)
         # ---- the communication step: merged groups or one flat pmean ----
         # Named scopes classify every collective for analysis.jaxpr_check:
         # grad reductions live under the reducer's per-group scopes (or
@@ -735,6 +767,13 @@ def make_eval_step(
                 "top5": (top5 * valid).sum(),
                 "count": count,
             }
+            return lax.psum(sums, red_axes), carry
+        if meta.task == "lm" and meta.fused_loss:
+            per_tok, _ = model.apply(
+                variables, batch["x"], targets=batch["y"], train=False
+            )
+            per = per_tok.mean(axis=-1)  # per-sample mean token loss
+            sums = {"loss": (per * valid).sum(), "count": count}
             return lax.psum(sums, red_axes), carry
         if meta.task == "lm":
             if meta.has_carry:

@@ -160,7 +160,7 @@ class Trainer:
             else None
         )
         # graft: group-uniform -- model + metadata derive from config alone
-        self.model, self.meta = zoo.create_model(config.dnn, dataset=config.dataset)
+        self.model, self.meta = self._create_model()
         self._apply_lm_window()
         # sequence parallelism (ring attention): shard the lm time dim over
         # the mesh's seq axis. Only carry-free lm models expose a seq_axis
@@ -191,9 +191,8 @@ class Trainer:
         self.bundle = self._build_loaders()
         if self.bundle.num_classes != self.meta.num_classes:
             # graft: group-uniform -- model + metadata derive from config alone
-            self.model, self.meta = zoo.create_model(
-                config.dnn, dataset=config.dataset,
-                num_classes=self.bundle.num_classes,
+            self.model, self.meta = self._create_model(
+                self.bundle.num_classes
             )
             # the rebuild reset meta/model to registry defaults; re-apply
             # the window-length override
@@ -547,6 +546,25 @@ class Trainer:
         return optim.gather_params(shards, self._params_template)
 
     # ------------------------------------------------------------------
+    def _create_model(self, num_classes: Optional[int] = None):
+        """(module, meta) for the configuration: the model, and the part of
+        it this chip holds (--layers-held, --experts-held)."""
+        config = self.config
+        experts = None
+        if config.experts_held:
+            try:
+                first, count = (int(v) for v in config.experts_held.split(":"))
+            except ValueError:
+                raise ValueError(
+                    f"--experts-held {config.experts_held!r} is not "
+                    "FIRST:COUNT (two integers)"
+                ) from None
+            experts = (first, count)
+        return zoo.create_model(
+            config.dnn, dataset=config.dataset, num_classes=num_classes,
+            layers_held=config.layers_held, experts_held=experts,
+        )
+
     def _build_loaders(self):
         """Sharded data loaders at the current process batch (shared by
         __init__ and update_nworker so the two can never drift)."""
@@ -560,6 +578,7 @@ class Trainer:
             synthetic=self._synthetic_data,
             augment=self.config.augment,
             num_steps=self.config.num_steps,
+            vocab_size=self.config.vocab_size,
         )
         # eval batch is decoupled from the train batch (MGWFBP_EVAL_BATCH):
         # eval cost is per-batch dispatch + transfer, and carry-free eval
@@ -601,6 +620,8 @@ class Trainer:
             # reference distributed clip rule: threshold scales by sqrt(1/P)
             # (re-baked on elastic resize since _build_optimizer reruns)
             world_size=self.data_size,
+            optimizer=config.optimizer,
+            b2=config.adam_b2,
         )
 
     def _build_steps(self) -> None:
@@ -836,9 +857,7 @@ class Trainer:
         from mgwfbp_tpu.serving.model import ServingModel
         from mgwfbp_tpu.serving.plane import ServePlane
 
-        module, meta = zoo.create_model(
-            self.config.dnn, dataset=self.config.dataset
-        )
+        module, meta = self._create_model()
         try:
             serving_model = ServingModel(module, meta, mesh=self.mesh)
         except ValueError as e:
@@ -1362,6 +1381,15 @@ class Trainer:
             for _ in range(len(self._pending_health) - 1)
         ])
 
+    @property
+    def _moe_assignments(self) -> int:
+        """(token, expert) assignments of one layer in one device's
+        (micro-)step of a sparse-expert model."""
+        return (
+            self.config.batch_size * self.meta.input_shape[0]
+            * self.model.shape.experts_per_token
+        )
+
     def _drain_health_flags(self) -> None:
         items = list(self._pending_health)
         self._pending_health.clear()
@@ -1373,6 +1401,7 @@ class Trainer:
         the per-group key set between two steps), on the host."""
         if not items:
             return
+        from mgwfbp_tpu.models.mellum import MOE_DROPPED_KEY, MOE_TOKENS_KEY
         from mgwfbp_tpu.train.step import HEALTH_PREFIX
 
         if self._phase_rec is not None:
@@ -1385,6 +1414,16 @@ class Trainer:
         g_prefix = f"{HEALTH_PREFIX}gnorm_g"
         c_prefix = f"{HEALTH_PREFIX}comp_err_g"
         for it, ep, d in items:
+            if MOE_TOKENS_KEY in d:
+                # a sparse-expert model's routing counts: arrays, and no
+                # part of the `health` record; counters on the step record
+                d = dict(d)
+                tokens = np.asarray(d.pop(MOE_TOKENS_KEY), dtype=np.float64)
+                dropped = float(np.asarray(d.pop(MOE_DROPPED_KEY)))
+                if self._phase_rec is not None:
+                    self._phase_rec.routing(
+                        tokens, dropped, self._moe_assignments
+                    )
             vals = {
                 k: float(np.asarray(d[k], dtype=np.float32))
                 for k in sorted(d)

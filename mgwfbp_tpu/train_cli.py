@@ -100,6 +100,20 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--seq-parallel", dest="seq_parallel", type=int, default=None)
     p.add_argument("--num-steps", dest="num_steps", type=int, default=None,
                    help="LM window length (must divide by --seq-parallel)")
+    p.add_argument("--layers-held", dest="layers_held", type=int, default=None,
+                   help="hold only the first N layers of a model that can be "
+                        "held in part (mellum2): one pipeline stage's share")
+    p.add_argument("--experts-held", dest="experts_held", default=None,
+                   metavar="FIRST:COUNT",
+                   help="hold only COUNT experts of every sparse layer, "
+                        "starting at expert FIRST (mellum2): one chip's "
+                        "share of an expert-parallel group. The router "
+                        "still scores all experts; what the absent ones "
+                        "would add is left out")
+    p.add_argument("--vocab-size", dest="vocab_size", type=int, default=None,
+                   help="--dataset tokens: ids 0..N-1, which are also the "
+                        "rows of the embedding and the head held here")
+    p.add_argument("--optimizer", default=None, choices=["sgd", "adamw"])
     p.add_argument("--num-batches-per-epoch", dest="num_batches_per_epoch",
                    type=int, default=None,
                    help="cap optimizer steps per epoch (smoke runs)")
@@ -205,7 +219,8 @@ def config_from_args(args: argparse.Namespace) -> TrainConfig:
             "num_steps", "num_batches_per_epoch", "compressor", "density",
             "comm_op", "dcn_slices", "autotune_steps", "schedule_cache",
             "telemetry_dir", "ckpt_every_steps", "bad_step_limit",
-            "metrics_port", "ckpt_format",
+            "metrics_port", "ckpt_format", "layers_held", "experts_held",
+            "vocab_size", "optimizer",
         )
         if getattr(args, k, None) is not None
     }

@@ -21,7 +21,8 @@ def _example_input(meta, batch=2):
 
 
 ALL_IMAGE_MODELS = [
-    n for n in zoo.model_names() if n not in ("lstm", "lstman4", "transformer")
+    n for n in zoo.model_names()
+    if n not in ("lstm", "lstman4", "transformer", "mellum2", "mellum2_tiny")
 ]
 
 
@@ -36,6 +37,35 @@ def test_image_model_traces(name):
         lambda v: model.apply(v, x, train=False), variables
     )
     assert out.shape == (2, meta.num_classes)
+
+
+@pytest.mark.parametrize("name,share,want", [
+    # the whole model: 28 x 417.8 M + 2 x 98,304 x 2,304, "12B" by name
+    ("mellum2", {}, 12_149_915_904),
+    # one chip's share: 4 layers, 16 of 64 experts, a quarter of the ids
+    ("mellum2", dict(num_classes=24576, layers_held=4,
+                     experts_held=(0, 16)), 595_153_152),
+    ("mellum2_tiny", dict(experts_held=(2, 2)), None),
+])
+def test_mellum2_traces_and_counts_its_parameters(name, share, want):
+    model, meta = zoo.create_model(name, **share)
+    assert meta.task == "lm" and not meta.has_carry and meta.fused_loss
+    x = _example_input(meta)
+    variables = jax.eval_shape(
+        lambda: model.init({"params": jax.random.PRNGKey(0)}, x, train=False))
+    n = sum(int(np.prod(leaf.shape))
+            for leaf in jax.tree_util.tree_leaves(variables["params"]))
+    if want is not None:
+        assert n == want
+    layers = [k for k in variables["params"] if k.startswith("layer_")]
+    assert len(layers) == (share.get("layers_held") or 28 if want else 4)
+    if name == "mellum2_tiny":
+        logits = jax.eval_shape(lambda v: model.apply(v, x), variables)
+        assert logits.shape == (2, 64, meta.num_classes)
+        per_token, stats = jax.eval_shape(
+            lambda v: model.apply(v, x, targets=x, train=True), variables)
+        assert per_token.shape == (2, 64)
+        assert stats["health/moe_tokens"].shape == (4, 2)
 
 
 @pytest.mark.parametrize(

@@ -401,3 +401,38 @@ def test_a_loop_stopped_inside_tail_leaves_the_step_in_flight_on_the_stream(
         assert order.index("watchdog_stall") > max(
             i for i, r in enumerate(stream)
             if r["event"] == "step" and r["step"] == 3)
+
+
+def test_routing_counters_ride_the_record_and_the_report_prints_them(stream):
+    """PhaseRecorder.routing puts a sparse-expert model's counters on the
+    record in whose aftermath the drain ran; a stream without them (every
+    other model's) prints no routing line."""
+    import numpy as np
+    import telemetry_report
+
+    from mgwfbp_tpu.telemetry.phases import PhaseRecorder
+
+    assert "expert routing" not in telemetry_report.format_report(stream)
+    written = []
+    clock = iter(float(i) for i in range(100))
+    rec = PhaseRecorder(lambda: next(clock), lambda **f: written.append(f))
+    rec.routing(np.ones((2, 2)), 0.0, 8)  # before any dispatch: dropped
+    rec.dispatched(1, 0, 0.0, 0.1)
+    rec.add("guard", 0.2, 0.1)
+    # two layers, four held experts; 16 tokens x top 2 = 32 assignments
+    rec.routing(np.array([[2.0, 2, 2, 2], [1.0, 9, 1, 1]]), 0.0, 32)
+    rec.dispatched(2, 0, 1.0, 0.1)
+    rec.flush()
+    first, second = written
+    assert first["moe_here"] == pytest.approx((8 + 12) / 2 / 32)
+    # the layer whose fullest expert is the fullest: the second
+    assert (first["moe_load_max"], first["moe_load_mean"]) == (9.0, 3.0)
+    assert first["moe_dropped"] == 0.0 and "moe_here" not in second
+    records = [
+        {"event": "header", "schema_version": 2, "wall": 0.0},
+        *({"event": "step", "epoch": 0, **w} for w in written),
+    ]
+    report = telemetry_report.format_report(records)
+    assert ("expert routing (1 steps): 31.25% of the assignments landed on "
+            "experts held here; fullest held expert 3.000x") in report
+    assert "0 dropped" in report

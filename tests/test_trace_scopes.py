@@ -1,0 +1,60 @@
+"""tools/trace_scopes.py on a hand-made HLO text and event list: the join of
+instruction names to `op_name` scopes, forward against backward, loops not
+counted twice, a custom call without a name stack taken by its prefix."""
+
+import pytest
+
+import trace_scopes
+
+HLO = """
+HloModule jit_step
+%fused_computation.1 (p: f32[8]) -> f32[8] {
+  ROOT %exp.1 = f32[8]{0} exponential(%p), metadata={op_name="jit(step)/jvp(M)/attn_full/exp"}
+}
+ENTRY %main {
+  %fusion.7 = f32[8]{0} fusion(%a), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(step)/jvp(M)/attn_full/exp" stack_frame_id=2}
+  %fusion.8 = f32[8]{0} fusion(%b), kind=kLoop, calls=%fc, metadata={op_name="jit(step)/transpose(jvp(M))/attn_full/mul"}
+  %fusion.9 = f32[8]{0} fusion(%c), kind=kLoop, calls=%fc, metadata={op_name="jit(step)/jvp(M)/moe_experts/while/body/gather"}
+  %while.3 = (f32[8]) while(%t), condition=%c, body=%b, metadata={op_name="jit(step)/jvp(M)/moe_experts/while"}
+  %ragged-dot-none.4 = bf16[8,8] custom-call(%x), custom_call_target="tpu_custom_call", metadata={op_name="ragged-dot-none"}
+  %fusion.10 = f32[8]{0} fusion(%d), kind=kLoop, calls=%fc, metadata={op_name="jit(step)/jvp(M)/mul"}
+  ROOT %fusion.11 = f32[8]{0} fusion(%e), kind=kLoop, calls=%fc, metadata={op_name="jit(step)/add"}
+}
+"""
+EVENTS = [
+    ("%fusion.7 = f32[8]{0} fusion(%a)", 0, 100), ("fusion.7", 500, 100),
+    ("fusion.8", 100, 300), ("fusion.9", 1000, 40), ("while.3", 990, 70),
+    ("ragged-dot-none.4", 1040, 20), ("fusion.10", 1100, 5),
+    ("fusion.11", 1200, 7), ("copy.99", 1300, 1),
+]
+SCOPES = ["attn_window", "attn_full", "moe_experts"]
+
+
+def test_join_and_split():
+    names = trace_scopes.hlo_op_names(HLO)
+    assert names["fusion.7"].endswith("attn_full/exp")
+    assert names["fusion.11"] == "jit(step)/add"
+    totals, top = trace_scopes.split(
+        EVENTS, names, SCOPES, {"ragged-dot": "moe_experts"})
+    assert totals[("attn_full", "forward")] == 200
+    assert totals[("attn_full", "backward")] == 300
+    # the loop spans its body's events: only the body's are counted
+    assert totals[("moe_experts", "forward")] == 40
+    assert totals[("moe_experts", "-")] == 20  # the custom call, by prefix
+    assert totals[("(model, no scope)", "forward")] == 5
+    assert totals[("(outside the model)", "-")] == 7
+    assert totals[("(no metadata)", "-")] == 1
+    assert sum(totals.values()) == 573
+    assert top[0] == (300, "fusion.8", "attn_full")
+
+
+@pytest.mark.parametrize("op_name,want", [
+    ("jit(step)/jvp(M)/attn_window/dot_general", ("attn_window", "forward")),
+    ("jit(step)/transpose(jvp(M))/attn_window/dot_general",
+     ("attn_window", "backward")),
+    ("jit(step)/jvp(M)/checkpoint/moe_experts/x", ("moe_experts", "forward")),
+    ("jit(step)/jvp(M)/not_attn_full_really/x", ("(model, no scope)", "forward")),
+    (None, ("(no metadata)", "-")),
+])
+def test_classify(op_name, want):
+    assert trace_scopes.classify(op_name, SCOPES) == want

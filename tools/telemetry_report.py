@@ -65,7 +65,9 @@ def _phase_section(steps: list[dict]) -> list[str]:
     took. The dispatch is the step record's own start_s / dur_s. Under the
     table, the counters: `ready` (was the prefetch pool ahead of the loop)
     and `stats_ready` (had the health drain's arrays finished when it asked;
-    where not, the drain is where the host waited for the chip)."""
+    where not, the drain is where the host waited for the chip), and a
+    sparse-expert model's routing counters (`moe_here`, `moe_load_max` over
+    `moe_load_mean`, `moe_dropped`)."""
     from mgwfbp_tpu.telemetry.phases import PHASES
 
     spans: dict[str, list[tuple[float, float]]] = {}
@@ -111,6 +113,19 @@ def _phase_section(steps: list[dict]) -> list[str]:
             f"  health statistics: finished when the drain asked on "
             f"{sum(stats)} of {len(stats)} steps "
             f"({100.0 * sum(stats) / len(stats):.1f}%)")
+    routed = [s for s in steps if "moe_here" in s]
+    if routed:
+        # a sparse-expert model's routing counters (telemetry/phases.py)
+        here = sum(s["moe_here"] for s in routed) / len(routed)
+        imbalance = [
+            s["moe_load_max"] / s["moe_load_mean"] for s in routed
+            if s["moe_load_mean"]]
+        lines.append(
+            f"  expert routing ({len(routed)} steps): {100.0 * here:.2f}% of "
+            "the assignments landed on experts held here; fullest held "
+            f"expert {sum(imbalance) / max(len(imbalance), 1):.3f}x the "
+            f"average (worst step {max(imbalance, default=0.0):.3f}x); "
+            f"{sum(s['moe_dropped'] for s in routed):g} dropped")
     return lines
 
 
