@@ -158,18 +158,16 @@ def data_prepare(
         # 381-385: RandomCrop+flip for CIFAR, RandomResizedCrop+flip for
         # ImageNet; eval uses only normalize)
         train_tf = normalize
+        # fused crop + flip + normalize: one pass over the uint8 batch via
+        # the native C++ kernels (NumPy fallbacks are bit-identical)
         if augment and name == "cifar10":
-            # fused crop+flip+normalize: one pass over the uint8 batch via
-            # the native C++ kernel (NumPy fallback is bit-identical)
             from mgwfbp_tpu.data.augment import FusedCropFlipNormalize
 
             train_tf = FusedCropFlipNormalize(mean, std, pad=4)
-        elif augment:
-            from mgwfbp_tpu.data.augment import chain, train_augment
+        elif augment and name == "imagenet":
+            from mgwfbp_tpu.data.augment import FusedResizedCropFlipNormalize
 
-            aug = train_augment(name)
-            if aug is not None:
-                train_tf = chain(aug, normalize)
+            train_tf = FusedResizedCropFlipNormalize(mean, std)
         train_loader = ShardedLoader(
             train, batch_size, shard, shuffle=True, seed=seed,
             transform=train_tf,

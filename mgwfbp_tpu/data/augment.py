@@ -10,7 +10,11 @@ handed in by `ShardedLoader` (seeded by (seed, epoch, rank, batch)), so
 epochs reshuffle augmentation deterministically and ranks decorrelate.
 
 Everything is vectorized or O(B) NumPy — no PIL/torchvision; the bilinear
-resize for RandomResizedCrop is implemented directly.
+resize for RandomResizedCrop is implemented directly. What `data_prepare`
+hands the loader for `cifar10` and `imagenet` is the fused form of these
+(`FusedCropFlipNormalize`, `FusedResizedCropFlipNormalize`): one native pass
+over the uint8 batch (mgwfbp_tpu/native), with the NumPy operations below as
+the bit-identical fallback and the tests' plain reference.
 """
 
 from __future__ import annotations
@@ -56,23 +60,20 @@ def random_crop(
     return crop_at_offsets(x, ys, xs, pad)
 
 
-def random_resized_crop(
-    x: np.ndarray,
+def sample_crop_rects(
     rng: np.random.Generator,
+    b: int,
+    h: int,
+    w: int,
     scale: tuple[float, float] = (0.08, 1.0),
     ratio: tuple[float, float] = (3.0 / 4.0, 4.0 / 3.0),
     attempts: int = 10,
-) -> np.ndarray:
-    """torchvision RandomResizedCrop: sample an area fraction and aspect
-    ratio per sample, crop, bilinear-resize back to the input size.
-
-    Fully vectorized over the batch (the loader is synchronous, so a
-    per-sample Python resize loop would stall every train step): crop
-    rectangles are sampled as (B,) arrays, then one batched gather computes
-    the bilinear interpolation for all samples at once. Output is float32.
-    """
-    b, h, w, c = x.shape
-    # --- sample crop rectangles: (attempts, B) candidates, first valid wins
+) -> tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
+    """torchvision RandomResizedCrop's rectangles for b images of (h, w):
+    (top, left, ch, cw), each (B,) int64, every rectangle inside the image.
+    An area fraction and an aspect ratio per sample, `attempts` candidates,
+    the first that fits wins; the centre square where none does. The order
+    of these draws is part of what a seed names: every caller shares it."""
     area = h * w * rng.uniform(scale[0], scale[1], size=(attempts, b))
     ar = np.exp(
         rng.uniform(np.log(ratio[0]), np.log(ratio[1]), size=(attempts, b))
@@ -90,8 +91,22 @@ def random_resized_crop(
     # center-crop fallback where nothing was valid (torchvision semantics)
     top = np.where(any_valid, top, (h - ch) // 2)
     left = np.where(any_valid, left, (w - cw) // 2)
+    return top, left, ch, cw
 
-    # --- batched bilinear gather back to (h, w), half-pixel centers
+
+def resized_crop_at(
+    x: np.ndarray,
+    top: np.ndarray,
+    left: np.ndarray,
+    ch: np.ndarray,
+    cw: np.ndarray,
+) -> np.ndarray:
+    """Crop each sample of x (B, H, W, C) at its rectangle and resize it
+    back to (H, W): bilinear, half-pixel centres, indices clipped to the
+    rectangle, one batched gather. Output is float32. The native kernel
+    (`native.fused_rrc_flip_normalize`) repeats these operations in this
+    order, so the two agree to the bit."""
+    b, h, w, c = x.shape
     yy = top[:, None] + (np.arange(h)[None, :] + 0.5) * ch[:, None] / h - 0.5
     xx = left[:, None] + (np.arange(w)[None, :] + 0.5) * cw[:, None] / w - 0.5
     y0f = np.floor(yy)
@@ -113,6 +128,24 @@ def random_resized_crop(
     top_row = f[bi, y0e, x0e] * (1 - wx) + f[bi, y0e, x1e] * wx
     bot_row = f[bi, y1e, x0e] * (1 - wx) + f[bi, y1e, x1e] * wx
     return top_row * (1 - wy) + bot_row * wy
+
+
+def random_resized_crop(
+    x: np.ndarray,
+    rng: np.random.Generator,
+    scale: tuple[float, float] = (0.08, 1.0),
+    ratio: tuple[float, float] = (3.0 / 4.0, 4.0 / 3.0),
+    attempts: int = 10,
+) -> np.ndarray:
+    """torchvision RandomResizedCrop: sample an area fraction and aspect
+    ratio per sample, crop, bilinear-resize back to the input size.
+
+    Vectorized over the batch in NumPy: the plain reference of
+    `FusedResizedCropFlipNormalize` and its fallback. Output is float32.
+    """
+    b, h, w, _ = x.shape
+    rects = sample_crop_rects(rng, b, h, w, scale, ratio, attempts)
+    return resized_crop_at(x, *rects)
 
 
 class FusedCropFlipNormalize:
@@ -154,6 +187,50 @@ class FusedCropFlipNormalize:
         scale = (1.0 / (255.0 * self.std)).astype(np.float32)
         shift = (self.mean / self.std).astype(np.float32)
         return x.astype(np.float32) * scale - shift
+
+
+class FusedResizedCropFlipNormalize:
+    """ImageNet-style RandomResizedCrop + flip + normalize as ONE pass over
+    the batch: the twin of `FusedCropFlipNormalize` for
+    `chain(train_augment("imagenet"), normalize_images(mean, std))`.
+
+    Rectangles and flips are drawn here, by the calls and in the order
+    `random_resized_crop` and `random_hflip` make, so a seed names the same
+    crops on every path. The native kernel reads the uint8 batch and writes
+    normalized float32 once, without the GIL, into `out` where the caller
+    brings one (`PrefetchLoader`'s recycled arrays); the NumPy fallback (no
+    library, or a batch that is not uint8) is bit-identical and returns an
+    array of its own."""
+
+    wants_rng = True
+    takes_out = True
+
+    def __init__(self, mean, std, p_flip: float = 0.5):
+        self.mean = np.asarray(mean, np.float32)
+        self.std = np.asarray(std, np.float32)
+        # the fallback's affine: the SAME factorization (px*scale - shift)
+        # as the C++ kernel and `normalize_images`
+        self._scale = (1.0 / (255.0 * self.std)).astype(np.float32)
+        self._shift = (self.mean / self.std).astype(np.float32)
+        self.p_flip = p_flip
+
+    def __call__(
+        self, x: np.ndarray, rng: np.random.Generator, out=None
+    ) -> np.ndarray:
+        b, h, w, _ = x.shape
+        rects = sample_crop_rects(rng, b, h, w)
+        flips = rng.random(b) < self.p_flip
+        if x.dtype == np.uint8:
+            from mgwfbp_tpu import native
+
+            out = native.fused_rrc_flip_normalize(
+                x, *rects, flips, self.mean, self.std, out=out)
+            if out is not None:
+                return out
+        # fallback: the composition's own operations
+        x = resized_crop_at(x, *rects)
+        x[flips] = x[flips, :, ::-1]
+        return x * self._scale - self._shift
 
 
 class Augment:

@@ -6,18 +6,27 @@ indexable arrays; the loader owns the epoch permutation (sharded via
 `sharding.shard_indices`), batching, and normalization, and yields host
 numpy batches ready for device put (the trainer lays them out on the mesh).
 
-Double-buffered prefetch happens at the trainer level via
-`jax.device_put` overlap; the loader itself stays synchronous and
-deterministic (same seed -> same batches, rank-disjoint).
+`ShardedLoader` is synchronous and deterministic (same seed -> same
+batches, rank-disjoint): `load_batch(epoch, b)` gathers a batch's uint8
+images and runs the transform over them, for images one native pass that
+writes the normalized float32 the step consumes (mgwfbp_tpu/native; NumPy
+where the library did not build, to the same bits). The train path wraps it
+in `PrefetchLoader` (`data_prepare`), whose thread pool assembles batches
+ahead of the loop; the native pass runs without the GIL, so the workers
+run side by side. Batches go to the device in the trainer's `place`.
 """
 
 from __future__ import annotations
 
+import collections
 import dataclasses
+import math
+import weakref
 from typing import Callable, Iterator, Optional
 
 import numpy as np
 
+from mgwfbp_tpu import native
 from mgwfbp_tpu.data.sharding import ShardInfo, shard_indices
 
 
@@ -108,25 +117,34 @@ class ShardedLoader:
         never race to build the cache)."""
         self._epoch_indices(epoch)
 
-    def load_batch(self, epoch: int, b: int) -> tuple[np.ndarray, np.ndarray]:
+    def load_batch(
+        self, epoch: int, b: int, alloc=None
+    ) -> tuple[np.ndarray, np.ndarray]:
         """Assemble batch `b` of `epoch` (gather + transform), independently
         of iterator state — the unit of work `PrefetchLoader` farms out to a
         thread pool. Deterministic: (seed, epoch, rank, batch) fully name
-        the batch, so prefetched and inline assembly are bit-identical."""
+        the batch, so prefetched and inline assembly are bit-identical.
+
+        `alloc(shape)` hands a transform that `takes_out` the float32 array
+        to write the batch into (`PrefetchLoader`'s recycled arrays);
+        without it every batch is a fresh array."""
         idx = self._epoch_indices(epoch)
         sel = idx[b * self.batch_size : (b + 1) * self.batch_size]
         x = _gather(self.dataset.data, sel)
         y = self.dataset.labels[sel]
         if self.transform is not None:
+            kw = {}
+            if alloc is not None and getattr(self.transform, "takes_out", False):
+                kw["out"] = alloc(x.shape)
             if getattr(self.transform, "wants_rng", False):
                 # per-(seed, epoch, rank, batch) stream: augmentation is
                 # deterministic per epoch and decorrelated across ranks
                 rng = np.random.default_rng(
                     [self.seed, epoch, self.shard.rank, b]
                 )
-                x = self.transform(x, rng)
+                x = self.transform(x, rng, **kw)
             else:
-                x = self.transform(x)
+                x = self.transform(x, **kw)
         return x, y
 
     def __iter__(self) -> Iterator[tuple[np.ndarray, np.ndarray]]:
@@ -152,6 +170,53 @@ def _gather(data, sel: np.ndarray) -> np.ndarray:
     return np.asarray(data[usel.tolist()])[inverse]
 
 
+class _Lease:
+    """One hand-out of a recycled block: what every array made from it, and
+    every view, slice or device transfer of those, keeps alive through
+    `.base`. NumPy stops collapsing `.base` chains at an object that is not
+    an ndarray, so nothing can refer to the block's memory and not to this."""
+
+    def __init__(self, block: np.ndarray, shape: tuple):
+        self._block = block
+        self.__array_interface__ = {
+            "data": (block.ctypes.data, False), "shape": tuple(shape),
+            "typestr": block.dtype.str, "version": 3,
+        }
+
+
+class _OutputRing:
+    """Float32 blocks the pool's workers write their batches into, handed
+    out again once nothing refers to the last hand-out.
+
+    A fresh 77 MB array is 18,816 pages the kernel has to fault in and
+    zero: on the chip machine 80 to 100 ms a batch against 4.5 ms for the
+    normalize kernel itself (PERF.md, PR 27). The proof that a block is
+    free is the allocator's own: a batch goes out as an array whose `.base`
+    is a `_Lease`; the trainer, its views and `jax.device_put` (which keeps
+    the host array referenced until the copy to the device has finished,
+    and for good where the CPU backend aliases it) all hold the lease, and
+    the lease's finalizer puts the block back. So a block returns exactly
+    when a fresh array's memory would have been freed. With every block out
+    (a consumer that keeps batches) `take` hands out a fresh array."""
+
+    def __init__(self, size: int):
+        # None: a block not allocated yet. deque operations are atomic:
+        # workers take, whichever thread drops a lease last gives back.
+        self._free: collections.deque = collections.deque([None] * size)
+
+    def take(self, shape: tuple) -> np.ndarray:
+        n = math.prod(shape)
+        try:
+            block = self._free.popleft()
+        except IndexError:
+            return np.empty(shape, np.float32)
+        if block is None or block.size != n:  # first use, or a new batch size
+            block = np.empty(n, np.float32)
+        lease = _Lease(block, shape)
+        weakref.finalize(lease, self._free.append, block)
+        return np.asarray(lease)
+
+
 class PrefetchLoader:
     """Background-prefetching wrapper around an epoch loader.
 
@@ -162,8 +227,9 @@ class PrefetchLoader:
     consumption, and each ready batch is optionally `jax.device_put` early
     so the host->device transfer overlaps the previous step's compute
     (double buffering; the put is async, the jitted step just consumes the
-    committed arrays). NumPy transforms release the GIL, so threads give
-    real parallelism without pickling costs.
+    committed arrays). The native transforms (mgwfbp_tpu/native) run
+    without the GIL, so threads give real parallelism without pickling
+    costs; the NumPy fallbacks' fancy indexing holds it.
 
     Two modes:
       * inner exposes `load_batch(epoch, b)` (ShardedLoader): `workers`
@@ -186,6 +252,13 @@ class PrefetchLoader:
         self.device_put = device_put
         # the running pool's deque of outstanding batches (`ready_batches`)
         self._futs = None
+        # whether the pool's batch handed out last came from the native
+        # pass (`native_batch`)
+        self._native: Optional[int] = None
+        # output arrays for the transforms that take one: all the pool can
+        # hold (`workers + depth`), the batch in the loop's hands and the
+        # one still on its way to the device
+        self._ring = _OutputRing(self.workers + self.depth + 2)
 
     # epoch/batch-size/len plumbing passes through to the inner loader
     def set_epoch(self, epoch: int) -> None:
@@ -219,6 +292,13 @@ class PrefetchLoader:
         futs = self._futs
         return None if futs is None else sum(f.done() for f in futs)
 
+    def native_batch(self) -> Optional[int]:
+        """1 when the batch the pool handed out last was transformed by a
+        native kernel (mgwfbp_tpu/native), 0 when it took NumPy or has no
+        transform (telemetry's `native` counter asks after each `next`);
+        None where no pool runs."""
+        return self._native
+
     def _finalize(self, batch):
         if not self.device_put:
             return batch
@@ -242,7 +322,6 @@ class PrefetchLoader:
             yield from self._iter_thread()
 
     def _iter_pool(self):
-        import collections
         from concurrent.futures import ThreadPoolExecutor
 
         nb = len(self.inner)
@@ -254,7 +333,10 @@ class PrefetchLoader:
         with ThreadPoolExecutor(max_workers=self.workers) as ex:
 
             def job(b):
-                return self._finalize(self.inner.load_batch(epoch, b))
+                # a native pass counts itself on the thread that made it
+                before = native.passes()
+                batch = self.inner.load_batch(epoch, b, alloc=self._ring.take)
+                return self._finalize(batch), int(native.passes() > before)
 
             ahead = self.workers + self.depth
             futs = collections.deque(
@@ -264,13 +346,14 @@ class PrefetchLoader:
             self._futs = futs
             try:
                 while futs:
-                    out = futs.popleft().result()  # in-order consumption
+                    # in-order consumption
+                    out, self._native = futs.popleft().result()
                     if next_b < nb:
                         futs.append(ex.submit(job, next_b))
                         next_b += 1
                     yield out
             finally:
-                self._futs = None
+                self._futs = self._native = None
 
     def _iter_thread(self):
         import queue
@@ -342,13 +425,12 @@ def normalize_images(
     scale = (1.0 / (255.0 * std_a)).astype(np.float32)
     shift = (mean_a / std_a).astype(np.float32)
 
-    def _t(x: np.ndarray) -> np.ndarray:
+    def _t(x: np.ndarray, out: Optional[np.ndarray] = None) -> np.ndarray:
         if x.dtype == np.uint8 and x.ndim >= 1:
-            from mgwfbp_tpu import native
-
-            out = native.normalize_u8(x, mean_a, std_a)
+            out = native.normalize_u8(x, mean_a, std_a, out=out)
             if out is not None:
                 return out
         return x.astype(np.float32) * scale - shift
 
+    _t.takes_out = True  # the native pass writes where it is told to
     return _t

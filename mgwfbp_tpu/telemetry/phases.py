@@ -11,7 +11,7 @@ spans ride on the step's own record:
      "phases": {"wait": [3.400, 0.002], "place": [3.402, 0.008],
                 "guard": [3.414, 0.041], "health": [3.455, 0.0004],
                 "tail": [3.4554, 0.0002], "log": [3.4556, 0.002]},
-     "ready": 3, "lowered": 0, "stats_ready": 1}
+     "ready": 3, "native": 1, "lowered": 0, "stats_ready": 1}
 
 `phases` maps a name to `[start_s, dur_s]` on the clock `start_s` is on; a
 phase entered more than once in an iteration (`wait` and `place` with
@@ -34,7 +34,12 @@ the step in flight with it. The watchdog's abort writes it first
 Counters: `ready`, batches the prefetch pool held finished when the loop
 asked for one (the fewest over a step's micro-batches; left out where the
 loader cannot tell), read by `benchmarks/layer_metrics/pool_ready.py` and
-`tools/telemetry_report.py`; `lowered`, programs lowered between the previous
+`tools/telemetry_report.py`; `native`, 1 when every batch of the step came
+out of the pool's native transform pass (mgwfbp_tpu/native: one kernel call
+over the uint8 source), 0 when one took the NumPy fallback or the loader has
+no image transform (asked of the loader after each `next`; left out where no
+pool runs), read by `tools/telemetry_report.py` and by no benchmark metric
+yet; `lowered`, programs lowered between the previous
 step's dispatch and this one's (any new program, cache hit or not; an
 epoch's last record also counts what followed it), read by
 `window_lowerings`; `stats_ready`, 1 when every statistics array the health
@@ -142,6 +147,11 @@ class _Span:
 _BEFORE_DISPATCH = ("restart", "wait", "place")
 
 
+def _least(have: Optional[int], new: int) -> int:
+    """A counter over a step's micro-batches keeps its least reading."""
+    return new if have is None else min(have, new)
+
+
 class PhaseRecorder:
     """Collects each iteration's spans and counters and writes them with the
     step's record. `now` is the stream's clock; `emit(**fields)` writes one
@@ -157,6 +167,7 @@ class PhaseRecorder:
         # of the step not yet dispatched
         self._ahead: dict[str, list[float]] = {}
         self._ready: Optional[int] = None
+        self._native: Optional[int] = None
         # the step dispatched last: its aftermath is running
         self._record: Optional[dict] = None
         self._handed_s: Optional[float] = None
@@ -216,6 +227,7 @@ class PhaseRecorder:
         (The `next` that ends the epoch is the pool's shutdown: no iteration
         owns it.)"""
         ready_batches = getattr(loader, "ready_batches", None)
+        native_batch = getattr(loader, "native_batch", None)
         it = iter(loader)
         first = True
         while True:
@@ -232,9 +244,10 @@ class PhaseRecorder:
                 first = False
             self.add("wait", t0, self._handed_s - t0)
             if ready is not None:
-                self._ready = (
-                    ready if self._ready is None else min(self._ready, ready)
-                )
+                self._ready = _least(self._ready, ready)
+            native = None if native_batch is None else native_batch()
+            if native is not None:
+                self._native = _least(self._native, native)
             yield raw
 
     def dispatched(
@@ -250,8 +263,11 @@ class PhaseRecorder:
         }
         if self._ready is not None:
             self._record["ready"] = self._ready
+        if self._native is not None:
+            self._record["native"] = self._native
         self._record["lowered"] = self._lowerings()
-        self._ahead, self._ready, self._handed_s = {}, None, None
+        self._ahead, self._handed_s = {}, None
+        self._ready = self._native = None
         if done is not None:
             self._write(done)
 

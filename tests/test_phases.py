@@ -165,6 +165,63 @@ def test_loader_without_a_pool_leaves_ready_out():
     assert all("ready" not in r for r in out)
 
 
+class NativeLoader(FakeLoader):
+    """A pool that also says whether the batch it handed out last came from
+    the native pass; `native` lists the answers, None for "no pool"."""
+
+    def __init__(self, n, native):
+        super().__init__(n)
+        self.native = list(native)
+        self.handed = -1
+
+    def native_batch(self):
+        return self.native[self.handed]
+
+    def __iter__(self):
+        for i in range(self.n):
+            self.handed = i
+            yield i
+
+
+class Proxy:
+    """benchmarks/run.py's `TimedLoader`: `__getattr__` passes every name
+    but the iteration through to the program's loader."""
+
+    def __init__(self, inner):
+        self._inner = inner
+
+    def __getattr__(self, name):
+        return getattr(self._inner, name)
+
+    def __iter__(self):
+        yield from self._inner
+
+
+@pytest.mark.parametrize("wrap", [lambda x: x, Proxy], ids=["bare", "proxy"])
+@pytest.mark.parametrize("native,micro,want", [
+    ([1, 1, 1], 1, [1, 1, 1]),
+    ([1, 0, 1], 1, [1, 0, 1]),          # one batch took the fallback
+    ([0, 0], 1, [0, 0]),                # a pool with nothing native to run
+    ([1, 1, 1, 0], 2, [1, 0]),          # the lesser of a step's batches
+    ([None, None], 1, [None, None]),    # a loader whose pool is not running
+])
+def test_native_counter_rides_on_the_steps_record(native, micro, want, wrap):
+    out = []
+    rec = PhaseRecorder(FakeClock(), lambda **f: out.append(f))
+    _drive(rec, wrap(NativeLoader(len(native), native)), micro=micro)
+    assert [r.get("native") for r in out] == want
+    for r in out:  # beside `ready`, which the same loader answers
+        assert r["ready"] == 3
+
+
+def test_loader_without_a_pool_leaves_native_out():
+    out = []
+    rec = PhaseRecorder(FakeClock(), lambda **f: out.append(f))
+    _drive(rec, [0, 1])
+    _drive(rec, FakeLoader(2))  # answers `ready_batches` alone
+    assert len(out) == 4 and all("native" not in r for r in out)
+
+
 def test_one_lowering_listener_for_the_process():
     from jax._src import monitoring
 
@@ -270,6 +327,15 @@ def test_the_pool_says_how_many_batches_it_held_ready(stream):
         assert 0 <= s.get("ready", 0) <= 4  # workers + depth
 
 
+def test_the_pool_says_its_batches_came_from_the_native_pass(stream):
+    """LeNet's MNIST batches are normalized by `normalize_u8`: asked after
+    each `next`, so an epoch's first step answers too."""
+    from mgwfbp_tpu import native
+
+    for s in events_of(stream, "step"):
+        assert s["native"] == int(native.available())
+
+
 def test_stats_ready_is_on_the_records_whose_health_span_drained(stream):
     """An epoch's first step queues its statistics and drains nothing; with
     the guard on, every later drain finds its arrays finished."""
@@ -319,8 +385,15 @@ def test_report_prints_one_table_of_the_phases(stream):
     table = report.split("host loop phases")[1].split("\n\n")[0]
     rows = [line.split()[0] for line in table.splitlines()[2:]]
     assert rows == [
-        *PHASES[:3], "dispatch", *PHASES[3:], "(no", "prefetch", "health"]
+        *PHASES[:3], "dispatch", *PHASES[3:], "(no", "prefetch", "native",
+        "health"]
     assert "when the loop asked (none on" in table and "of 8 steps)" in table
+    # asked after each `next`, so all 10 steps answer (1 where g++ built)
+    from mgwfbp_tpu import native
+
+    share = 1.0 if native.available() else 0.0
+    assert (f"native pass on {int(10 * share)} of 10 steps "
+            f"(share {share:.3f})") in table
     # an epoch's first step queues its statistics and drains nothing
     assert ("health statistics: finished when the drain asked on 8 of 8 "
             "steps (100.0%)") in table

@@ -63,7 +63,8 @@ def _phase_section(steps: list[dict]) -> list[str]:
     milliseconds over the steps that have the phase, and the share of the
     loop's time (first span's start to last span's end) all its entries
     took. The dispatch is the step record's own start_s / dur_s. Under the
-    table, the counters: `ready` (was the prefetch pool ahead of the loop)
+    table, the counters: `ready` (was the prefetch pool ahead of the loop),
+    `native` (did the pool's batches come from the native transform pass)
     and `stats_ready` (had the health drain's arrays finished when it asked;
     where not, the drain is where the host waited for the chip), and a
     sparse-expert model's routing counters (`moe_here`, `moe_load_max` over
@@ -107,6 +108,12 @@ def _phase_section(steps: list[dict]) -> list[str]:
             f"  prefetch pool: {sum(ready) / len(ready):.2f} batches ready "
             f"when the loop asked (none on {ready.count(0)} of {len(ready)} "
             "steps)")
+    native = [int(s["native"]) for s in steps if "native" in s]
+    if native:
+        lines.append(
+            f"  native transform: the pool's batches came from the native "
+            f"pass on {sum(native)} of {len(native)} steps "
+            f"(share {sum(native) / len(native):.3f})")
     stats = [int(s["stats_ready"]) for s in steps if "stats_ready" in s]
     if stats:
         lines.append(
@@ -830,7 +837,14 @@ def _synthetic_stream(path: str) -> None:
     rows = attribute_overlap(groups, tb, comm, nbytes)
     step_s = 0.045
     for i in range(24):
-        w.emit("step", step=i, epoch=0, start_s=i * step_s, dur_s=step_s)
+        # phase spans and the loader's counters as telemetry/phases.py
+        # writes them: the pool behind on every fourth step, one batch of
+        # the 24 through the NumPy fallback
+        t = i * step_s
+        w.emit("step", step=i, epoch=0, start_s=t, dur_s=0.004,
+               phases={"wait": [t - 0.003, 0.002], "place": [t - 0.001, 0.001],
+                       "guard": [t + 0.004, 0.040]},
+               ready=0 if i % 4 == 0 else 2, native=int(i != 5), lowered=0)
     hidden = sum(r.hidden_s for r in rows)
     total = sum(r.comm_s for r in rows)
     w.emit(
@@ -921,6 +935,13 @@ def selftest() -> int:
         assert "postmortem bundles (1):" in report, report
         assert "/tmp/run/postmortems/0000" in report, report
         assert "gnorm_first" in report, report
+        # ISSUE 27: the phase table's counter lines, `native` among them
+        assert "host loop phases (24 steps" in report, report
+        assert "batches ready when the loop asked (none on 6 of 24" in report
+        assert (
+            "native transform: the pool's batches came from the native "
+            "pass on 23 of 24 steps (share 0.958)" in report
+        ), report
         # ISSUE 16: the save-duration trend section renders, async saves
         # are marked in the lifecycle, and the save whose payload write
         # spanned >1 step is flagged with its commit iteration
