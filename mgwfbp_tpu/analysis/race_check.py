@@ -78,7 +78,6 @@ from mgwfbp_tpu.analysis.spmd_check import (
 # the host-concurrency surfaces (package-relative)
 DEFAULT_THR_TARGETS = (
     "runtime",
-    "serving",
     os.path.join("train", "trainer.py"),
     "checkpoint.py",
     os.path.join("telemetry", "serve.py"),

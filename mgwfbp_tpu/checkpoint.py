@@ -1465,6 +1465,10 @@ class Checkpointer:
             md = self._mgr.item_metadata(step)
         except Exception:  # noqa: BLE001 — undecidable: treat as legacy
             return None
+        # the installed orbax hands the stored tree back wrapped in a
+        # TreeMetadata object (the keys sit under `.tree`); older ones
+        # returned the dict itself
+        md = getattr(md, "tree", md)
         if not isinstance(md, dict) or not isinstance(md.get("meta"), dict):
             return None
         if "epoch_step" not in md["meta"]:

@@ -143,13 +143,6 @@ class TrainConfig:
     seed: int = 0
     num_batches_per_epoch: Optional[int] = None
     eval_every_epochs: int = 1
-    serve_shadow: bool = False  # in-process serving plane (ISSUE 19):
-    # hot-reload each committed shard-native checkpoint into a ServingModel
-    # riding the trainer's HTTP plane, score a held-out shadow stream
-    # against it (shadow_eval events + served-vs-training loss gauge), and
-    # answer batched /predict — all off the step-loop thread. Single
-    # process only (the reload path must not interleave device work with
-    # the step loop's collectives); needs telemetry + checkpoint_dir.
 
     def tag(self) -> str:
         from mgwfbp_tpu.utils.logging import run_tag

@@ -542,7 +542,7 @@ def committed_profile_or_prior(path, connection: str, nworkers: int):
 
     Returns (cost_model, source): source is the profile path that was
     loaded, or None when the prior was used. Driver entry points
-    (bench.py, __graft_entry__.py) route through this so the round
+    (chip_smoke.py, __graft_entry__.py) route through this so the round
     artifacts exercise the calibrated path whenever the matching profile
     is committed (VERDICT r4 #5 — driver tails should not carry the
     UNCALIBRATED warning once a calibration exists)."""

@@ -20,15 +20,10 @@ group is drained and relaunched — all under per-class budgets;
 from __future__ import annotations
 
 import argparse
-import shlex
 import sys
 from typing import Optional
 
-from mgwfbp_tpu.runtime.supervisor import (
-    Supervisor,
-    default_serve_cmd,
-    default_train_cmd,
-)
+from mgwfbp_tpu.runtime.supervisor import Supervisor, default_train_cmd
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -82,18 +77,6 @@ def build_parser() -> argparse.ArgumentParser:
                         "completed step); the relaunched incarnation "
                         "resumes from the exact step — shard-native "
                         "checkpoints re-shard onto the new world size")
-    p.add_argument("--serve-replicas", dest="serve_replicas", type=int,
-                   default=0,
-                   help="spawn this many hot-reload serving replicas "
-                        "(python -m mgwfbp_tpu.serving) alongside the "
-                        "training group; replicas live for the whole "
-                        "supervisor run (resubmits/resizes do not churn "
-                        "them) and join the fleet under the serve role "
-                        "on role-offset metrics ports")
-    p.add_argument("--serve-args", dest="serve_args", default=None,
-                   help="arguments for the serving CLI, one shell-quoted "
-                        "string (e.g. --serve-args '--dnn lenet "
-                        "--checkpoint-dir ckpts --shadow')")
     p.add_argument("--no-heal", dest="heal", action="store_false",
                    default=True,
                    help="disable self-healing: any hard child failure "
@@ -110,11 +93,6 @@ def build_parser() -> argparse.ArgumentParser:
                         "(or its endpoint unreachable) before it is "
                         "declared wedged and the group is healed "
                         "(default: MGWFBP_LIVENESS_GRACE_S or 120)")
-    p.add_argument("--serve-max-restarts", dest="serve_max_restarts",
-                   type=int, default=3,
-                   help="per-replica respawn budget for crashed serve "
-                        "replicas (backoff-spaced; budget spent = the "
-                        "replica stays down)")
     p.add_argument("train_args", nargs=argparse.REMAINDER,
                    help="arguments for mgwfbp_tpu.train_cli (prefix "
                         "with --)")
@@ -139,15 +117,9 @@ def main(argv: Optional[list[str]] = None) -> int:
         fleet_port=args.fleet_port,
         fleet_file=args.fleet_file,
         resize_to=args.resize_to,
-        serve_replicas=args.serve_replicas,
-        serve_cmd=(
-            default_serve_cmd(shlex.split(args.serve_args or ""))
-            if args.serve_replicas else None
-        ),
         heal=args.heal,
         heal_max_restarts=args.heal_max_restarts,
         liveness_grace_s=args.liveness_grace,
-        serve_max_restarts=args.serve_max_restarts,
     )
     return sup.run()
 

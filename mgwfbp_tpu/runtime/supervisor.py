@@ -258,25 +258,16 @@ class Supervisor:
         fleet_port: Optional[int] = None,
         fleet_file: Optional[str] = None,
         resize_to: Optional[int] = None,
-        serve_replicas: int = 0,
-        serve_cmd: Optional[Sequence[str]] = None,
         heal: bool = True,
         heal_max_restarts: int = 2,
         heal_same_step_limit: int = 3,
         liveness_grace_s: Optional[float] = None,
-        serve_max_restarts: int = 3,
         sleep: Callable[[float], None] = time.sleep,
     ):
         if processes < 1:
             raise ValueError(f"processes must be >= 1, got {processes}")
         if resize_to is not None and resize_to < 1:
             raise ValueError(f"resize_to must be >= 1, got {resize_to}")
-        if serve_replicas < 0:
-            raise ValueError(
-                f"serve_replicas must be >= 0, got {serve_replicas}"
-            )
-        if serve_replicas and not serve_cmd:
-            raise ValueError("serve_replicas > 0 needs a serve_cmd")
         self.base_cmd = list(base_cmd)
         self.processes = int(processes)
         self.max_restarts = int(max_restarts)
@@ -316,16 +307,6 @@ class Supervisor:
         self._resize_signaled = False
         self._resize_poll_t = 0.0
         self._resize_no_metrics_warned = False
-        # serving replicas (ISSUE 19): spawned ONCE for the supervisor's
-        # lifetime — they hot-reload checkpoints across incarnations, so
-        # a training-group resubmit/resize must not churn them. Excluded
-        # from the rc policy (a dead replica degrades serving, never the
-        # training job); folded into the fleet under the `serve` role.
-        self.serve_replicas = int(serve_replicas)
-        self.serve_cmd = list(serve_cmd) if serve_cmd else None
-        self._serve_procs: list = []
-        self._serve_logs: list = []
-        self._serve_exit_warned: set = set()
         # self-healing (ISSUE 20): hard failures (crash/oom/wedge/
         # unreachable) heal the group instead of tearing it down —
         # relaunch at the same world when the slot looks recoverable,
@@ -360,11 +341,6 @@ class Supervisor:
         # max observed step per healed incarnation (crash-loop detection)
         self._crash_steps: list[int] = []
         self._postmortem_paths: list[str] = []
-        # serve-replica restart policy (satellite): respawn with backoff
-        # under an own budget instead of the old spawn-once
-        self.serve_max_restarts = int(serve_max_restarts)
-        self._serve_restarts: list[int] = []
-        self._serve_respawn_at: dict[int, float] = {}
         self._incarnation = 0
         self._events = None  # lazy supervisor-stream EventWriter
 
@@ -394,11 +370,9 @@ class Supervisor:
         except ValueError:
             return False
 
-    def _port_file(self, idx: int, role: str = "train") -> str:
+    def _port_file(self, idx: int) -> str:
         """Per-child metrics port-file sidecar path (the child's
-        telemetry/serve writes its ACTUAL bound port there). Role-aware:
-        serve replicas get their own `metrics_port.serve{i}.json`
-        namespace so replica i never clobbers training child i's file."""
+        telemetry/serve writes its ACTUAL bound port there)."""
         if self._ports_dir is None:
             if self.log_dir:
                 self._ports_dir = self.log_dir
@@ -409,8 +383,7 @@ class Supervisor:
                 self._ports_dir = tempfile.mkdtemp(
                     prefix="mgwfbp_fleet_ports_"
                 )
-        stem = f"serve{idx}" if role == "serve" else f"p{idx}"
-        return os.path.join(self._ports_dir, f"metrics_port.{stem}.json")
+        return os.path.join(self._ports_dir, f"metrics_port.p{idx}.json")
 
     def _child_targets(self) -> dict:
         """process index -> (host, port) of every currently-resolvable
@@ -438,34 +411,7 @@ class Supervisor:
                 pass
             if base is not None:
                 targets[i] = ("127.0.0.1", base + i)
-        if self.serve_replicas:
-            from mgwfbp_tpu.telemetry.serve import resolve_metrics_port
-
-            for i in range(self.serve_replicas):
-                key = f"serve{i}"
-                path = self._port_file(i, role="serve")
-                try:
-                    with open(path) as f:
-                        doc = _json.load(f)
-                    targets[key] = (
-                        str(doc.get("host") or "127.0.0.1"),
-                        int(doc["port"]),
-                    )
-                    continue
-                except (OSError, ValueError, KeyError, TypeError):
-                    pass
-                if base is not None:
-                    # the serve offset keeps replica ports disjoint from
-                    # the training children's base+index band
-                    targets[key] = (
-                        "127.0.0.1",
-                        resolve_metrics_port(base, i, role="serve"),
-                    )
         return targets
-
-    @staticmethod
-    def _target_role(key) -> str:
-        return "serve" if isinstance(key, str) else "train"
 
     def _refresh_fleet(self) -> None:
         """Re-resolve the child target map; persist `fleet.json`
@@ -481,10 +427,7 @@ class Supervisor:
             from mgwfbp_tpu.telemetry.fleet import write_fleet_sd
 
             try:
-                write_fleet_sd(
-                    self.fleet_file, targets,
-                    roles={k: self._target_role(k) for k in targets},
-                )
+                write_fleet_sd(self.fleet_file, targets)
             except OSError as e:
                 # do NOT record the targets: the sidecar is stale, and a
                 # stable group would otherwise never retry the write
@@ -496,10 +439,7 @@ class Supervisor:
             self.log.info(
                 "fleet targets -> %s (%s)", self.fleet_file,
                 ", ".join(
-                    f"{'' if isinstance(i, str) else 'p'}{i}={h}:{p}"
-                    for i, (h, p) in sorted(
-                        targets.items(), key=lambda kv: str(kv[0])
-                    )
+                    f"p{i}={h}:{p}" for i, (h, p) in sorted(targets.items())
                 ),
             )
         self._last_fleet_targets = dict(targets)
@@ -544,15 +484,6 @@ class Supervisor:
         }
         if self._pending_failure is not None:
             meta["heal"]["pending_failure"] = dict(self._pending_failure)
-        if self.serve_replicas:
-            meta["serving"] = {
-                "replicas": self.serve_replicas,
-                "alive": sum(
-                    1 for p in self._serve_procs if p.poll() is None
-                ),
-                "restarts": list(self._serve_restarts),
-                "restart_budget": self.serve_max_restarts,
-            }
         if self.resize_to is not None:
             # the transition is fleet-visible: pending while the group
             # still runs at the old size, done once an incarnation
@@ -703,143 +634,6 @@ class Supervisor:
             stderr=stderr,
         ), stdout
 
-    # -- serving replicas (ISSUE 19) ---------------------------------------
-    def _serve_env(self, idx: int) -> dict:
-        """A serve replica is NOT a member of the training group: it gets
-        no coordinator contract (and any inherited one is stripped so a
-        replica never tries to join jax.distributed), just its replica
-        index and the role-aware port file."""
-        env = dict(self.env)
-        for k in (
-            "MGWFBP_COORDINATOR",
-            "MGWFBP_NUM_PROCESSES",
-            "MGWFBP_PROCESS_ID",
-        ):
-            env.pop(k, None)
-        env["MGWFBP_SERVE_REPLICA"] = str(idx)
-        if self._metrics_enabled():
-            env["MGWFBP_METRICS_PORT_FILE"] = self._port_file(
-                idx, role="serve"
-            )
-            if self.fleet_port is not None or self._fleet_file_explicit:
-                env.setdefault("MGWFBP_METRICS_HOST", "0.0.0.0")
-        return env
-
-    def _spawn_serve(self, i: int) -> None:
-        """(Re)spawn serve replica `i` into slot `i`. The log file is
-        opened append so a respawned replica's output lands after its
-        previous life's instead of erasing the evidence."""
-        if self._metrics_enabled():
-            try:
-                os.unlink(self._port_file(i, role="serve"))
-            except OSError:
-                pass
-        stdout = stderr = None
-        if self.log_dir:
-            os.makedirs(self.log_dir, exist_ok=True)
-            stdout = open(
-                os.path.join(self.log_dir, f"serve{i}.log"),
-                "a", buffering=1,
-            )
-            stderr = subprocess.STDOUT
-        proc = subprocess.Popen(
-            self.serve_cmd,
-            env=self._serve_env(i),
-            stdout=stdout,
-            stderr=stderr,
-        )
-        if i < len(self._serve_procs):
-            old = self._serve_logs[i]
-            if old is not None:
-                old.close()
-            self._serve_procs[i] = proc
-            self._serve_logs[i] = stdout
-        else:
-            self._serve_procs.append(proc)
-            self._serve_logs.append(stdout)
-
-    def _start_serve_replicas(self) -> None:
-        """Spawn the serve replicas for the supervisor's lifetime
-        (training-group resubmits and resizes must not churn them — each
-        replica hot-reloads committed checkpoints on its own)."""
-        if not self.serve_replicas or self._serve_procs:
-            return
-        self._serve_restarts = [0] * self.serve_replicas
-        base = self._metrics_base_port()
-        for i in range(self.serve_replicas):
-            self._spawn_serve(i)
-            if base is not None:
-                from mgwfbp_tpu.telemetry.serve import resolve_metrics_port
-
-                self.log.info(
-                    "serve replica %d metrics at http://127.0.0.1:%d "
-                    "(/metrics /status, POST /predict)",
-                    i, resolve_metrics_port(base, i, role="serve"),
-                )
-
-    def _reap_serve_replicas(self, now: Optional[float] = None) -> None:
-        """Serve-replica restart policy (ISSUE 20 satellite): a dead
-        replica degrades serving capacity but never the training job —
-        respawn it after bounded exponential backoff, under the
-        replicas' OWN restart budget. Budget spent -> warn once and
-        leave the slot dead (the old spawn-once behavior, now the
-        endpoint of a policy instead of the whole policy)."""
-        if now is None:
-            now = time.monotonic()
-        for i, p in enumerate(self._serve_procs):
-            if p.poll() is None:
-                self._serve_respawn_at.pop(i, None)
-                continue
-            used = self._serve_restarts[i]
-            if used >= self.serve_max_restarts:
-                if i not in self._serve_exit_warned:
-                    self._serve_exit_warned.add(i)
-                    self.log.warning(
-                        "serve replica %d exited rc %d and its restart "
-                        "budget (%d) is spent; replica stays down "
-                        "(training continues%s)",
-                        i, p.returncode, self.serve_max_restarts,
-                        f" — see {self.log_dir}/serve{i}.log"
-                        if self.log_dir else "",
-                    )
-                continue
-            due = self._serve_respawn_at.get(i)
-            if due is None:
-                self._emit(
-                    "failure",
-                    **{"class": classify_rc(p.returncode)},
-                    target=f"serve{i}", rc=int(p.returncode),
-                )
-                delay = self.backoff_s(used + 1)
-                self._serve_respawn_at[i] = now + delay
-                self.log.warning(
-                    "serve replica %d exited rc %d; respawning in %.1fs "
-                    "(restart %d/%d)", i, p.returncode, delay,
-                    used + 1, self.serve_max_restarts,
-                )
-                continue
-            if now >= due:
-                self._serve_respawn_at.pop(i, None)
-                self._serve_restarts[i] += 1
-                self._spawn_serve(i)
-                self._emit(
-                    "heal", action="respawn_serve", target=f"serve{i}",
-                    restarts=self._serve_restarts[i],
-                )
-                self.log.info(
-                    "serve replica %d respawned (restart %d/%d)",
-                    i, self._serve_restarts[i], self.serve_max_restarts,
-                )
-
-    def _stop_serve_replicas(self) -> None:
-        if self._serve_procs:
-            self._teardown(self._serve_procs)
-        for f in self._serve_logs:
-            if f is not None:
-                f.close()
-        self._serve_procs = []
-        self._serve_logs = []
-
     def _run_group(self, incarnation: int) -> GroupResult:
         self._status_snapshots = None  # fresh capture per incarnation
         # fresh failure/liveness state per incarnation (the PREVIOUS
@@ -982,7 +776,6 @@ class Supervisor:
             # keep the fleet.json sidecar current (no-op when the live
             # plane is off or nothing changed)
             self._refresh_fleet()
-            self._reap_serve_replicas()
             # --resize-to: drain a healthy group once it is stepping
             self._maybe_trigger_resize(procs)
             # wedge/unreachable detection (no-op once a failure is known)
@@ -1081,10 +874,8 @@ class Supervisor:
 
     def run(self) -> int:
         try:
-            self._start_serve_replicas()
             return self._run_policy()
         finally:
-            self._stop_serve_replicas()
             if self.fleet_server is not None:
                 self.fleet_server.close()
                 self.fleet_server = None
@@ -1316,8 +1107,3 @@ def default_train_cmd(train_args: Sequence[str]) -> list[str]:
     this repo's launcher, the user's args verbatim."""
     return [sys.executable, "-m", "mgwfbp_tpu.train_cli", *train_args]
 
-
-def default_serve_cmd(serve_args: Sequence[str]) -> list[str]:
-    """The per-replica command for `--serve-replicas`: the standalone
-    serving CLI; the replica index rides in MGWFBP_SERVE_REPLICA."""
-    return [sys.executable, "-m", "mgwfbp_tpu.serving", *serve_args]

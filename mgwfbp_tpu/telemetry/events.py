@@ -97,8 +97,6 @@ EVENT_TYPES: dict[str, tuple[str, ...]] = {
     # watchdog stall/abort (also CRITICAL-logged; this makes it greppable
     # from the same file as the step records)
     "watchdog_stall": ("phase", "idle_s", "timeout_s", "abort"),
-    # bench.py structured skip (chip unavailable)
-    "bench_skip": ("detail",),
     # --- resilience layer (ISSUE 5) ------------------------------------
     # graceful preemption drain: the in-flight step finished, a
     # step-indexed checkpoint was written, the process exits rc 75
@@ -151,31 +149,15 @@ EVENT_TYPES: dict[str, tuple[str, ...]] = {
     # recorder.py): `trigger` names the alarm event that tripped it,
     # `step` the trigger's step, `path` the bundle directory
     "postmortem": ("trigger", "step", "path"),
-    # --- serving plane (ISSUE 19) ---------------------------------------
-    # the serving model hot-reloaded a newly committed shard-native
-    # checkpoint: `step` the served train step after the swap, `lag_s`
-    # commit-to-served latency (manifest mtime -> swap), `duration_s` the
-    # load+install time itself
-    "reload": ("step", "lag_s", "duration_s"),
-    # shadow-eval scored the held-out stream against a freshly served
-    # checkpoint; `train_loss` rides as an extra when the emitter knows it
-    # so the report can plot served-vs-training loss from the stream alone
-    "shadow_eval": ("step", "loss"),
-    # periodic request-plane snapshot from the dispatcher: `requests` is
-    # the CUMULATIVE served-request count, queue_depth the bounded queue's
-    # instantaneous depth, batch_fill the mean fill ratio of flushed batch
-    # slots since the last snapshot; latency quantiles ride as extras
-    # (latency_p50_s/p95_s/p99_s over the recent-request window)
-    "serve_stats": ("requests", "queue_depth", "batch_fill"),
     # --- self-healing supervisor (ISSUE 20) -----------------------------
     # the supervisor (or trainer) observed one HARD failure: `class` is
     # 'crash' | 'oom_kill' | 'wedge' | 'unreachable' | 'coordination',
-    # `target` names the failed member ('p1', 'serve0', ...). rc/signal/
-    # step ride as extras when known
+    # `target` names the failed member ('p1', ...). rc/signal/step ride
+    # as extras when known
     "failure": ("class", "target"),
     # the supervisor's healing policy acted on a failure: `action` is
     # 'relaunch' (same world) | 'shrink' (elastic resume at survivor
-    # count) | 'respawn_serve' | 'stop' (budget exhausted / crash loop).
+    # count) | 'stop' (budget exhausted / crash loop).
     # world/incarnation/restarts ride as extras
     "heal": ("action",),
 }

@@ -244,8 +244,6 @@ METRICS: tuple[tuple[str, str, str], ...] = (
      "autotune candidates raced"),
     ("mgwfbp_autotune_commits_total", "counter",
      "autotune schedule commits (race or cache)"),
-    ("mgwfbp_bench_skips_total", "counter",
-     "bench runs skipped (chip unavailable)"),
     ("mgwfbp_bad_steps_total", "counter",
      "steps dropped by the non-finite-gradient guard"),
     ("mgwfbp_rollbacks_total", "counter",
@@ -257,7 +255,7 @@ METRICS: tuple[tuple[str, str, str], ...] = (
      "hard failures observed (crash/oom_kill/wedged/unreachable/"
      "coordination)"),
     ("mgwfbp_heals_total", "counter",
-     "healing actions applied (relaunch/shrink/respawn_serve/stop)"),
+     "healing actions applied (relaunch/shrink/stop)"),
     ("mgwfbp_drift_alarms_total", "counter",
      "cost-model drift alarms raised (telemetry.drift)"),
     ("mgwfbp_drift_residual", "gauge",
@@ -285,31 +283,6 @@ METRICS: tuple[tuple[str, str, str], ...] = (
      "training-health alarms raised (telemetry.health)"),
     ("mgwfbp_postmortems_total", "counter",
      "flight-recorder postmortem bundles written"),
-    # serving plane (ISSUE 19): request plane + hot-reload + shadow-eval
-    ("mgwfbp_serve_requests_total", "counter",
-     "predict requests served (cumulative, from serve_stats snapshots)"),
-    ("mgwfbp_serve_reloads_total", "counter",
-     "serving hot-reloads of a committed checkpoint"),
-    ("mgwfbp_shadow_evals_total", "counter",
-     "shadow-eval scores against freshly served checkpoints"),
-    ("mgwfbp_serve_step", "gauge",
-     "train step of the currently served checkpoint"),
-    ("mgwfbp_serve_reload_lag_seconds", "gauge",
-     "latest commit-to-served hot-reload lag"),
-    ("mgwfbp_serve_queue_depth", "gauge",
-     "predict request queue depth (latest dispatcher snapshot)"),
-    ("mgwfbp_serve_batch_fill", "gauge",
-     "mean fill ratio of flushed predict batch slots (latest snapshot)"),
-    ("mgwfbp_serve_latency_p50_seconds", "gauge",
-     "predict request latency p50 over the recent-request window"),
-    ("mgwfbp_serve_latency_p95_seconds", "gauge",
-     "predict request latency p95 over the recent-request window"),
-    ("mgwfbp_serve_latency_p99_seconds", "gauge",
-     "predict request latency p99 over the recent-request window"),
-    ("mgwfbp_shadow_eval_loss", "gauge",
-     "latest shadow-eval loss on the held-out stream"),
-    ("mgwfbp_shadow_eval_delta", "gauge",
-     "latest shadow-eval loss minus training loss (served-vs-training)"),
     # fleet fan-in synthesis (rendered only by telemetry/fleet.py's
     # /fleet/metrics, never by a per-process endpoint — registered here
     # so the fleet exposition flows through the same single registry)
@@ -330,7 +303,6 @@ EVENT_COUNTERS: dict[str, str] = {
     "watchdog_stall": "mgwfbp_watchdog_stalls_total",
     "autotune_race": "mgwfbp_autotune_races_total",
     "autotune_commit": "mgwfbp_autotune_commits_total",
-    "bench_skip": "mgwfbp_bench_skips_total",
     "bad_step": "mgwfbp_bad_steps_total",
     "rollback": "mgwfbp_rollbacks_total",
     "preempt": "mgwfbp_preempts_total",
@@ -339,8 +311,6 @@ EVENT_COUNTERS: dict[str, str] = {
     "heal": "mgwfbp_heals_total",
     "profile": "mgwfbp_profile_windows_total",
     "postmortem": "mgwfbp_postmortems_total",
-    "reload": "mgwfbp_serve_reloads_total",
-    "shadow_eval": "mgwfbp_shadow_evals_total",
 }
 
 

@@ -58,10 +58,8 @@ from mgwfbp_tpu.utils.logging import get_logger
 # costs one timeout, not a hang
 SCRAPE_TIMEOUT_S = 2.0
 
-# targets map: process key -> (host, port). Training children are keyed
-# by int process index; serving replicas (ISSUE 19) ride under str keys
-# ("serve0", "serve1", ...) so the same map carries both roles.
-TargetMap = Dict[object, Tuple[str, int]]
+# targets map: process index -> (host, port)
+TargetMap = Dict[int, Tuple[str, int]]
 
 
 @dataclass
@@ -69,7 +67,7 @@ class ChildScrape:
     """One child's scraped live state (best-effort: `error` records a
     failed/timed-out scrape; a child with `status` answered)."""
 
-    process: object  # int training index or "serve<i>" replica key
+    process: int
     host: str
     port: int
     status: Optional[dict] = None
@@ -120,9 +118,7 @@ def scrape_fleet(
     — the hard-timeout contract the check.sh smoke pins."""
     if not targets:
         return []
-    # mixed int/str keys (training children + serve replicas) sort by
-    # their string form — a plain sorted() would TypeError on int vs str
-    items = sorted(targets.items(), key=lambda kv: str(kv[0]))
+    items = sorted(targets.items())
     with ThreadPoolExecutor(max_workers=min(len(items), 16)) as pool:
         futs = [
             pool.submit(scrape_child, idx, host, port, timeout_s)
@@ -256,9 +252,7 @@ def arm_fleet_profile(
             return idx, {"armed": False, "error": str(e)}
 
     out: dict = {"steps": steps, "processes": {}}
-    # serve replicas carry str keys; they answer the arm with their own
-    # /profile document ("supported": false) like any other child
-    items = sorted(targets.items(), key=lambda kv: str(kv[0]))
+    items = sorted(targets.items())
     if not items:
         return out
     with ThreadPoolExecutor(max_workers=min(len(items), 16)) as pool:
@@ -346,27 +340,19 @@ def render_fleet_metrics(children: list[ChildScrape]) -> str:
 
 def write_fleet_sd(
     path: str, targets: TargetMap, labels: Optional[dict] = None,
-    roles: Optional[dict] = None,
 ) -> list[dict]:
     """Persist the scrape targets in Prometheus HTTP-SD / file-SD format
-    (one target group per process, ``process`` + ``role`` labels each),
-    atomically. A Prometheus `http_sd_configs`/`file_sd_configs` entry
-    pointed at this file scrapes every child without guessing ports
-    (README). ``roles`` maps a target key to its role label; targets not
-    listed default to ``train``."""
+    (one target group per process, a ``process`` label each), atomically.
+    A Prometheus `http_sd_configs`/`file_sd_configs` entry pointed at this
+    file scrapes every child without guessing ports (README)."""
     doc = [
         {
             "targets": [f"{host}:{port}"],
             "labels": {
-                "job": "mgwfbp",
-                "process": str(idx),
-                "role": str((roles or {}).get(idx, "train")),
-                **(labels or {}),
+                "job": "mgwfbp", "process": str(idx), **(labels or {}),
             },
         }
-        for idx, (host, port) in sorted(
-            targets.items(), key=lambda kv: str(kv[0])
-        )
+        for idx, (host, port) in sorted(targets.items())
     ]
     os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
     tmp = f"{path}.tmp.{os.getpid()}"

@@ -66,14 +66,6 @@ METRICS_HOST_ENV = "MGWFBP_METRICS_HOST"
 # never have to guess ports — the base+index convention cannot cover the
 # ephemeral (base == 0) case at all
 METRICS_PORT_FILE_ENV = "MGWFBP_METRICS_PORT_FILE"
-# role-aware port namespace (ISSUE 19 satellite): serving replicas ride
-# the SAME base-port convention as training children, displaced by this
-# offset so `supervise --serve-replicas N` can never collide a serve
-# replica's listen port with a training child's (train: base + index;
-# serve: base + offset + index). 100 leaves room for any plausible
-# training world below the serve band.
-SERVE_PORT_OFFSET_ENV = "MGWFBP_SERVE_PORT_OFFSET"
-DEFAULT_SERVE_PORT_OFFSET = 100
 
 # hard ceiling on one /profile window: the endpoint is unauthenticated on
 # loopback and the window syncs the device, so a request may never arm an
@@ -123,45 +115,28 @@ def advertised_host(bound_host: str) -> str:
     return bound_host
 
 
-def serve_port_offset() -> int:
-    """The serving role's displacement above the training port band."""
-    raw = (os.environ.get(SERVE_PORT_OFFSET_ENV) or "").strip()
-    if not raw:
-        return DEFAULT_SERVE_PORT_OFFSET
-    try:
-        off = int(raw)
-    except ValueError:
-        return DEFAULT_SERVE_PORT_OFFSET
-    return off if off > 0 else DEFAULT_SERVE_PORT_OFFSET
-
-
 def resolve_metrics_port(
-    base_port: Optional[int], process_index: int = 0, role: str = "train",
+    base_port: Optional[int], process_index: int = 0
 ) -> Optional[int]:
     """Concrete listen port for one process of a run: ``base + index`` so
     a multi-host group's processes serve distinct ports from ONE
     configured value (the supervisor exports a single environment).
-    ``role='serve'`` displaces the whole band by ``serve_port_offset()``
-    so serving replicas sharing a supervisor's base can never collide
-    with its training children. ``base == 0`` asks the OS for an
-    ephemeral port per process (the bound port is logged and available
-    as ``TelemetryServer.port``); None disables the plane."""
+    ``base == 0`` asks the OS for an ephemeral port per process (the
+    bound port is logged and available as ``TelemetryServer.port``);
+    None disables the plane."""
     if base_port is None:
         return None
     base = int(base_port)
     if base < 0:
         raise ValueError(f"metrics port must be >= 0, got {base}")
-    if role not in ("train", "serve"):
-        raise ValueError(f"unknown metrics role {role!r}")
-    offset = serve_port_offset() if role == "serve" else 0
-    port = 0 if base == 0 else base + offset + int(process_index)
+    port = 0 if base == 0 else base + int(process_index)
     if port > 65535:
         # base + index walked off the end of the port space; an
         # observability knob must degrade (the caller warns), not kill
         # the training process with an OverflowError out of socket.bind
         raise ValueError(
-            f"metrics port {base} + role offset {offset} + "
-            f"process_index {process_index} exceeds 65535"
+            f"metrics port {base} + process_index {process_index} "
+            "exceeds 65535"
         )
     return port
 
@@ -202,13 +177,6 @@ class MetricsAggregator:
         # manifests (fed by `postmortem` events — live tee or replay)
         self._health: Optional[dict] = None
         self._postmortems: collections.deque = collections.deque(maxlen=20)
-        # serving plane (ISSUE 19): latest hot-reload / dispatcher
-        # snapshot / shadow-eval facts, fed by the same validated stream
-        # (`reload`, `serve_stats`, `shadow_eval` events)
-        self._serving_step: Optional[int] = None
-        self._reload_lag_s: Optional[float] = None
-        self._serve_stats: Optional[dict] = None
-        self._shadow: Optional[dict] = None
         # (kind, group/slow_process) -> alarm fields, kept while active
         self._active_alarms: dict = {}
         # health: None = healthy; else the reason string. Sticky once an
@@ -307,13 +275,6 @@ class MetricsAggregator:
                 self._active_alarms.pop(key, None)
         elif event == "postmortem":
             self._postmortems.append(dict(fields))
-        elif event == "reload":
-            self._serving_step = int(fields.get("step", 0))
-            self._reload_lag_s = float(fields.get("lag_s", 0.0))
-        elif event == "serve_stats":
-            self._serve_stats = dict(fields)
-        elif event == "shadow_eval":
-            self._shadow = dict(fields)
 
     def set_schedule(
         self, comm_op: str, num_groups: int, policy_detail: str = "",
@@ -468,45 +429,6 @@ class MetricsAggregator:
                     out["mgwfbp_health_compression_error"] = max(
                         float(e) for e in comp
                     )
-            if self._serving_step is not None:
-                out["mgwfbp_serve_step"] = int(self._serving_step)
-            if self._reload_lag_s is not None:
-                out["mgwfbp_serve_reload_lag_seconds"] = float(
-                    self._reload_lag_s
-                )
-            if self._serve_stats is not None:
-                s = self._serve_stats
-                out["mgwfbp_serve_requests_total"] = int(
-                    s.get("requests", 0)
-                )
-                out["mgwfbp_serve_queue_depth"] = int(
-                    s.get("queue_depth", 0)
-                )
-                out["mgwfbp_serve_batch_fill"] = float(
-                    s.get("batch_fill", 0.0)
-                )
-                for key, name in (
-                    ("latency_p50_s", "mgwfbp_serve_latency_p50_seconds"),
-                    ("latency_p95_s", "mgwfbp_serve_latency_p95_seconds"),
-                    ("latency_p99_s", "mgwfbp_serve_latency_p99_seconds"),
-                ):
-                    v = s.get(key)
-                    if v is not None:
-                        out[name] = float(v)
-            if self._shadow is not None:
-                out["mgwfbp_shadow_eval_loss"] = float(
-                    self._shadow.get("loss", 0.0)
-                )
-                # served-vs-training loss gauge: the shadow event carries
-                # train_loss when the emitter knows it (in-process mode);
-                # a standalone replica falls back to the health stream
-                train_loss = self._shadow.get("train_loss")
-                if train_loss is None and self._health is not None:
-                    train_loss = self._health.get("loss")
-                if train_loss is not None:
-                    out["mgwfbp_shadow_eval_delta"] = float(
-                        self._shadow.get("loss", 0.0)
-                    ) - float(train_loss)
             out["mgwfbp_active_alarms"] = len(self._active_alarms)
             return out
 
@@ -560,27 +482,7 @@ class MetricsAggregator:
                     dict(a) for a in self._active_alarms.values()
                 ],
                 "profile": self._profile_status_locked(),
-                "serving": self._serving_locked(),
             }
-
-    def _serving_locked(self) -> Optional[dict]:
-        if (self._serving_step is None and self._serve_stats is None
-                and self._shadow is None):
-            return None
-        return {
-            "step": self._serving_step,
-            "reload_lag_s": self._reload_lag_s,
-            "reloads": int(
-                self._counts.get("mgwfbp_serve_reloads_total", 0)
-            ),
-            "stats": (
-                dict(self._serve_stats)
-                if self._serve_stats is not None else None
-            ),
-            "shadow": (
-                dict(self._shadow) if self._shadow is not None else None
-            ),
-        }
 
     def _postmortems_locked(self) -> dict:
         return {
@@ -644,55 +546,10 @@ class _Handler(BaseHTTPRequestHandler):
         else:
             body = (
                 b"not found: serve /metrics, /healthz, /status, /profile, "
-                b"/postmortems (POST /predict)\n"
+                b"/postmortems\n"
             )
             ctype = "text/plain; charset=utf-8"
             code = 404
-        self._respond(code, ctype, body)
-
-    def do_POST(self):  # noqa: N802 — BaseHTTPRequestHandler contract
-        from urllib.parse import urlsplit
-
-        path = urlsplit(self.path).path.rstrip("/") or "/"
-        if path != "/predict":
-            self._respond(
-                404, "text/plain; charset=utf-8",
-                b"not found: POST serves /predict only\n",
-            )
-            return
-        # the serving plane attaches its PredictService here
-        # (TelemetryServer.attach_predict); without one the route exists
-        # but answers 503 — a prober can tell "no serving on this
-        # process" from "route missing"
-        service = getattr(self.server, "predict_service", None)
-        if service is None:
-            self._respond_json(
-                503, {"error": "no serving model attached to this process"}
-            )
-            return
-        try:
-            n = int(self.headers.get("Content-Length") or 0)
-            doc = json.loads(self.rfile.read(n) if n else b"")
-            if not isinstance(doc, dict) or "inputs" not in doc:
-                raise ValueError(
-                    'body must be a JSON object with an "inputs" list'
-                )
-            inputs = doc["inputs"]
-        except (ValueError, KeyError) as e:
-            self._respond_json(400, {"error": f"bad request: {e}"})
-            return
-        # handle() blocks THIS handler thread until the dispatcher
-        # flushes the batch (deadline-or-full); ThreadingHTTPServer keeps
-        # other requests flowing meanwhile
-        code, out = service.handle(inputs)
-        self._respond_json(code, out)
-
-    def _respond_json(self, code: int, doc: dict) -> None:
-        self._respond(
-            code, "application/json", (json.dumps(doc) + "\n").encode()
-        )
-
-    def _respond(self, code: int, ctype: str, body: bytes) -> None:
         self.send_response(code)
         self.send_header("Content-Type", ctype)
         self.send_header("Content-Length", str(len(body)))
@@ -726,7 +583,6 @@ class TelemetryServer:
         self._httpd = ThreadingHTTPServer((host, int(port)), _Handler)
         self._httpd.daemon_threads = True
         self._httpd.aggregator = aggregator  # type: ignore[attr-defined]
-        self._httpd.predict_service = None  # type: ignore[attr-defined]
         self.host = host
         self.port = int(self._httpd.server_address[1])
         self._thread = threading.Thread(
@@ -735,15 +591,6 @@ class TelemetryServer:
             daemon=True,
         )
         self._thread.start()
-
-    def attach_predict(self, service) -> None:
-        """Open the POST /predict route over a serving plane's
-        PredictService (None detaches — the route answers 503 again).
-        Handler threads read the attribute per request; attach/detach is
-        a single reference store, safe against in-flight requests."""
-        httpd = self._httpd
-        if httpd is not None:
-            httpd.predict_service = service  # type: ignore[attr-defined]
 
     def close(self) -> None:
         httpd, self._httpd = self._httpd, None
@@ -761,17 +608,13 @@ class TelemetryServer:
 
 def write_port_file(
     path: str, server: TelemetryServer, process_index: int,
-    role: str = "train",
 ) -> None:
     """Persist the ACTUAL bound endpoint (atomic JSON sidecar) so the
     supervisor's fleet fan-in and the `fleet.json` scrape targets read
     real ports instead of assuming the base+index convention — which is
-    simply wrong when the base is 0 (per-process ephemeral ports). The
-    ``role`` field namespaces the sidecar: a serving replica's doc can
-    never be mistaken for (or clobbered into) a training child's."""
+    simply wrong when the base is 0 (per-process ephemeral ports)."""
     doc = {
         "process": int(process_index),
-        "role": str(role),
         # a 0.0.0.0 bind advertises the ROUTABLE address (cross-host
         # seam): fleet.json targets must be dialable from other hosts
         "host": advertised_host(server.host),
@@ -790,13 +633,12 @@ def start_metrics_server(
     aggregator: MetricsAggregator,
     base_port: Optional[int],
     process_index: int = 0,
-    role: str = "train",
 ) -> Optional[TelemetryServer]:
     """Start the per-process metrics server, or None when disabled or the
     bind fails (logged — the plane is observability, not a dependency)."""
     log = get_logger("mgwfbp.telemetry.serve")
     try:
-        port = resolve_metrics_port(base_port, process_index, role)
+        port = resolve_metrics_port(base_port, process_index)
     except ValueError as e:
         log.warning("metrics server disabled: %s", e)
         return None
@@ -813,7 +655,7 @@ def start_metrics_server(
     port_file = (os.environ.get(METRICS_PORT_FILE_ENV) or "").strip()
     if port_file:
         try:
-            write_port_file(port_file, server, process_index, role)
+            write_port_file(port_file, server, process_index)
         except OSError as e:  # the sidecar is a convenience, not a gate
             log.warning("could not write metrics port file %s: %s",
                         port_file, e)

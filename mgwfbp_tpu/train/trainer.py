@@ -828,66 +828,6 @@ class Trainer:
             self.log.warning("telemetry write failed (%s); disabling", e)
             self.telemetry = None
 
-    def _start_serve_plane(self) -> None:
-        """In-process serving plane (``--serve-shadow``, ISSUE 19): a
-        ServingModel + reload watcher + /predict dispatcher riding THIS
-        process's metrics server, hot-reloading every checkpoint the run
-        commits — without touching the step loop (the reload path is
-        device_put + jit only, no collectives, so its threads coexist
-        with the step loop's owning-thread discipline). Single-process
-        only; a multi-host group serves from standalone replicas
-        (``supervise --serve-replicas``) instead."""
-        if (
-            not self.config.serve_shadow
-            or getattr(self, "_serve_plane", None) is not None
-        ):
-            return
-        if coord.process_count() != 1:
-            self.log.warning(
-                "--serve-shadow is single-process only (standalone "
-                "replicas serve multi-host runs); serving disabled"
-            )
-            return
-        if self.checkpointer is None or self.telemetry is None:
-            self.log.warning(
-                "--serve-shadow needs --checkpoint-dir and telemetry; "
-                "serving disabled"
-            )
-            return
-        from mgwfbp_tpu.serving.model import ServingModel
-        from mgwfbp_tpu.serving.plane import ServePlane
-
-        module, meta = self._create_model()
-        try:
-            serving_model = ServingModel(module, meta, mesh=self.mesh)
-        except ValueError as e:
-            self.log.warning("--serve-shadow: %s; serving disabled", e)
-            return
-        agg = getattr(self, "_metrics_agg", None)
-        train_loss_fn = None
-        if agg is not None:
-            def train_loss_fn():
-                v = agg.values().get("mgwfbp_health_loss")
-                return float(v) if v is not None else None
-        self._serve_plane = ServePlane(
-            serving_model,
-            os.path.join(
-                self.config.checkpoint_dir, self.config.tag()
-            ),
-            emit=lambda ev, f: self._emit_event(ev, **f),
-            server=getattr(self, "_metrics_server", None),
-            shadow=True,
-            train_loss_fn=train_loss_fn,
-        )
-        self._serve_plane.start()
-        self.log.info(
-            "serving plane up: hot-reloading committed checkpoints, "
-            "shadow-eval on, /predict %s (slot %d)",
-            "attached" if getattr(self, "_metrics_server", None)
-            is not None else "unattached (no metrics port)",
-            serving_model.max_batch,
-        )
-
     def _layer_specs(self) -> list:
         """Arrival-ordered LayerSpecs of the live reducer's layer set
         (shared by the autotuner's frontier and the overlap tb prior).
@@ -3630,7 +3570,7 @@ class Trainer:
         already committed a shard-native checkpoint, the gathered view is
         sitting on disk — read it off the manifest instead of issuing the
         collective (ROADMAP shard-native follow-up (b); pinned bitwise
-        against the gathered path in tests/test_serving.py)."""
+        against the gathered path in tests/test_shard_ckpt.py)."""
         if not self._cross_step:
             return self.state.params
         params = self._manifest_eval_params()
@@ -4278,12 +4218,6 @@ class Trainer:
         return manifest, files
 
     def close(self) -> None:
-        plane = getattr(self, "_serve_plane", None)
-        if plane is not None:
-            # first: its watcher/dispatcher threads emit telemetry and
-            # read the checkpoint dir, both of which close below
-            plane.close()
-            self._serve_plane = None
         if self.checkpointer is not None:
             if coord.process_count() == 1:
                 # land the in-flight async save's commit AND its
@@ -4865,9 +4799,6 @@ class Trainer:
                 self._watchdog = wd if wd.enabled else None
                 # SIGTERM/SIGINT -> graceful drain for the whole fit
                 self._arm_signals()
-                # --serve-shadow: the in-process serving plane rides the
-                # whole fit (hot-reloads land as checkpoints commit)
-                self._start_serve_plane()
                 if cfg.autotune and self.autotune_report is None:
                     # closed-loop tuning phase: the first few real steps
                     # race candidate schedules (cache hit skips the race)

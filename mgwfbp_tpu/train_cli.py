@@ -145,15 +145,6 @@ def build_parser() -> argparse.ArgumentParser:
                         "ports persist via MGWFBP_METRICS_PORT_FILE for "
                         "the supervisor's /fleet fan-in); implies "
                         "--telemetry (MGWFBP_METRICS_PORT)")
-    p.add_argument("--serve-shadow", action="store_true",
-                   help="in-process serving plane (mgwfbp_tpu/serving/): "
-                        "hot-reload every committed checkpoint into a "
-                        "ServingModel, score a held-out shadow stream "
-                        "against it (shadow_eval events + served-vs-"
-                        "training loss gauge), and answer batched POST "
-                        "/predict on the --metrics-port server; needs "
-                        "--checkpoint-dir, implies --telemetry, single "
-                        "process only (README 'Serving')")
     p.add_argument("--compressor", default=None,
                    choices=["none", "topk"],
                    help="gradient compressor (reference --compressor)")
@@ -237,11 +228,6 @@ def config_from_args(args: argparse.Namespace) -> TrainConfig:
     if args.telemetry or args.telemetry_dir or args.metrics_port is not None:
         # the live plane's aggregator is fed by the event stream, so
         # --metrics-port implies the stream (same as --telemetry-dir)
-        overrides["telemetry"] = True
-    if args.serve_shadow:
-        # the plane's reload/shadow_eval/serve_stats events ride the
-        # telemetry stream, so serving implies it too
-        overrides["serve_shadow"] = True
         overrides["telemetry"] = True
     if args.autotune:
         overrides["autotune"] = True

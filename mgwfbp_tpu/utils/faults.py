@@ -2,19 +2,18 @@
 
 MG-WFBP is synchronous data-parallel SGD: every merge-group collective is a
 barrier, so the interesting failure modes — a non-finite gradient, a wedged
-dispatch, a preempted host, a chip that never answers — are all *rare* in CI
-and *routine* in production. This module makes each of them a first-class,
-reproducible test input: a fault plan names exactly which fault fires at
-which optimizer step (or phase), so every handling path (skip-step guard,
-watchdog escalation, graceful preemption drain, bench chip-unavailable
-skip) runs in tier-1 on the CPU mesh instead of being dead code until the
-first real outage.
+dispatch, a preempted host — are all *rare* in CI and *routine* in
+production. This module makes each of them a first-class, reproducible
+test input: a fault plan names exactly which fault fires at which
+optimizer step (or phase), so every handling path (skip-step guard,
+watchdog escalation, graceful preemption drain) runs in tier-1 on the CPU
+mesh instead of being dead code until the first real outage.
 
 Plan grammar (``MGWFBP_FAULT_PLAN``)::
 
     plan  := spec (';' spec)*
     spec  := kind ('@' kv (',' kv)*)?
-    kind  := 'nan' | 'stall' | 'preempt' | 'chip_unavailable'
+    kind  := 'nan' | 'stall' | 'preempt' | 'kill' | 'wedge'
     kv    := key '=' value
 
     nan@step=N[,count=C]        poison the batch of optimizer steps
@@ -28,9 +27,6 @@ Plan grammar (``MGWFBP_FAULT_PLAN``)::
     preempt@step=N[,signal=SIGTERM|SIGINT]
                                 deliver the preemption signal after step N
                                 completes (the graceful-drain path); ONCE
-    chip_unavailable            backend init reports the chip as
-                                unavailable (bench.py's ChipUnavailable
-                                structured-skip path)
     kill@step=N                 SIGKILL self after step N completes — a
                                 HARD crash, no drain, no checkpoint
                                 barrier (the supervisor's healer is what
@@ -98,12 +94,11 @@ class Preempted(RuntimeError):
         self.epoch = epoch
         self.iteration = iteration
 
-KINDS = ("nan", "stall", "preempt", "chip_unavailable", "kill", "wedge")
+KINDS = ("nan", "stall", "preempt", "kill", "wedge")
 _ALLOWED_KEYS = {
     "nan": {"step", "count", "proc"},
     "stall": {"secs", "phase", "step", "proc"},
     "preempt": {"step", "signal", "proc"},
-    "chip_unavailable": {"proc"},
     "kill": {"step", "proc", "inc"},
     "wedge": {"step", "secs", "proc", "inc"},
 }
@@ -111,7 +106,6 @@ _REQUIRED_KEYS = {
     "nan": {"step"},
     "stall": {"secs"},
     "preempt": {"step"},
-    "chip_unavailable": set(),
     "kill": {"step"},
     "wedge": {"step", "secs"},
 }
@@ -245,7 +239,7 @@ def parse_plan(text: str) -> "FaultPlan":
 
 
 class FaultPlan:
-    """Parsed fault plan; the trainer/bench query it at phase boundaries."""
+    """Parsed fault plan; the trainer queries it at phase boundaries."""
 
     def __init__(self, specs: Optional[list[FaultSpec]] = None):
         self.specs = list(specs or [])
@@ -339,9 +333,6 @@ class FaultPlan:
             if s.observed_below or step == s.step:
                 return _SIGNALS[s.signal]
         return None
-
-    def chip_unavailable(self) -> bool:
-        return any(s.kind == "chip_unavailable" for s in self.specs)
 
     def kill_after(self, step: int) -> bool:
         """True when the process must SIGKILL ITSELF after step `step`

@@ -73,8 +73,8 @@ def enable_compile_cache() -> str:
 
 # Peak dense-matmul FLOP/s per chip by device-kind substring (bf16; for
 # fp32 runs an upper bound, making MFU conservative). A device that is not
-# listed has no peak: MFU is then not reported, never invented. Shared by
-# bench.py and tools/mfu_ablation.py so the table cannot drift.
+# listed has no peak: MFU is then not reported, never invented. Read by
+# tools/mfu_ablation.py.
 PEAK_FLOPS_BY_DEVICE_KIND = [
     ("v5 lite", 197e12),  # TPU v5e
     ("v5e", 197e12),

@@ -11,8 +11,8 @@ import sys
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, _ROOT)
-# tools/ scripts (policy_grid, an4_report) are imported by artifact-pinning
-# tests; one insert here replaces per-test sys.path mutation
+# tools/ scripts (telemetry_report, an4_report) are imported by tests;
+# one insert here replaces per-test sys.path mutation
 sys.path.insert(0, os.path.join(_ROOT, "tools"))
 
 os.environ["JAX_PLATFORMS"] = "cpu"  # tests never touch an attached chip

@@ -196,12 +196,11 @@ def test_write_fleet_sd_http_sd_format(tmp_path):
         path, {0: ("127.0.0.1", 9100), 1: ("127.0.0.1", 45001)},
     )
     assert json.load(open(path)) == doc
-    # targets not named in `roles` default to the training role
     assert doc == [
         {"targets": ["127.0.0.1:9100"],
-         "labels": {"job": "mgwfbp", "process": "0", "role": "train"}},
+         "labels": {"job": "mgwfbp", "process": "0"}},
         {"targets": ["127.0.0.1:45001"],
-         "labels": {"job": "mgwfbp", "process": "1", "role": "train"}},
+         "labels": {"job": "mgwfbp", "process": "1"}},
     ]
 
 
@@ -380,6 +379,28 @@ def test_supervisor_base_port_fallback_without_port_files(tmp_path):
         0: ("127.0.0.1", 9100), 1: ("127.0.0.1", 9101),
     }
     assert Supervisor(["true"], 1, env={})._child_targets() == {}
+
+
+@pytest.mark.parametrize("case", ["base_plus_index", "ephemeral", "port_file"])
+def test_one_port_rule_for_every_process(case, tmp_path):
+    """With one kind of process under the supervisor the listen port is
+    `base + process_index` (0 stays 0: ephemeral) and child i's sidecar
+    is `metrics_port.p<i>.json` — the path its environment carries."""
+    from mgwfbp_tpu.runtime.supervisor import Supervisor
+    from mgwfbp_tpu.telemetry.serve import resolve_metrics_port
+
+    if case == "base_plus_index":
+        assert resolve_metrics_port(9100, 3) == 9103
+    elif case == "ephemeral":
+        assert resolve_metrics_port(0, 5) == 0
+    else:
+        sup = Supervisor(
+            ["true"], 4, env={"MGWFBP_METRICS_PORT": "0"},
+            log_dir=str(tmp_path),
+        )
+        path = sup._port_file(3)
+        assert path == os.path.join(str(tmp_path), "metrics_port.p3.json")
+        assert sup._child_env(3, 1234)["MGWFBP_METRICS_PORT_FILE"] == path
 
 
 # ---------------------------------------------------------------------------

@@ -481,15 +481,6 @@ def test_shipped_contexts_include_the_async_writer():
     assert any(lbl.startswith("signal:") for lbl in labels)
 
 
-def test_shipped_contexts_include_the_serving_plane():
-    # the serving package is a default THR target: its dispatcher and
-    # hot-reload watcher threads must be visible to the checker, so any
-    # new unsynchronized write in the request/reload planes is caught
-    labels = {c[0] for c in discover_contexts()}
-    assert "thread:PredictService._run" in labels
-    assert "thread:ReloadWatcher._run" in labels
-
-
 # --------------------------------------------------------------------------
 # exit codes + CLI
 # --------------------------------------------------------------------------

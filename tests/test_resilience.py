@@ -2,8 +2,7 @@
 plan grammar, the non-finite-gradient guard (skip + rollback), graceful
 preemption with bitwise-exact mid-epoch resume, watchdog escalation
 (all-thread stack dump before abort), the structured checkpoint-drift
-error, telemetry stream rotation, and bench.py's injected
-chip-unavailable skip."""
+error, and telemetry stream rotation."""
 
 import json
 import os
@@ -38,7 +37,7 @@ def _cfg(dnn="lenet", **kw):
 def test_fault_plan_parses_and_queries():
     plan = parse_plan(
         "nan@step=3,count=2; stall@secs=0.5,phase=eval ;"
-        "preempt@step=6,signal=SIGINT;chip_unavailable"
+        "preempt@step=6,signal=SIGINT;kill@step=9"
     )
     assert plan and len(plan.specs) == 4
     assert not plan.nan_at(2)
@@ -51,7 +50,8 @@ def test_fault_plan_parses_and_queries():
     assert plan.preempt_signal_after(5) is None
     assert plan.preempt_signal_after(7) == signal.SIGINT  # >= step fires
     assert plan.preempt_signal_after(8) is None  # consumed
-    assert plan.chip_unavailable()
+    assert not plan.kill_after(8)
+    assert plan.kill_after(9)
 
 
 def test_preempt_spec_consumed_by_resumed_counter():
@@ -303,6 +303,37 @@ def test_lost_sidecar_index_does_not_misread_new_format(tmp_path):
     assert snap.mid_epoch and snap.epoch == 2 and snap.epoch_step == 5
     # the sidecar was healed from the payload's own bookkeeping
     assert ck2._index["17"]["mid_epoch"] is True
+    ck2.close()
+
+
+def test_lost_sidecar_index_still_reads_a_legacy_payload(tmp_path):
+    """The other side of the probe: an unindexed step whose stored meta
+    has no `epoch_step` IS the legacy epoch-keyed format (the orbax step
+    is the epoch) and restores as an epoch boundary."""
+    import jax.numpy as jnp
+    import optax
+    import orbax.checkpoint as ocp
+
+    from mgwfbp_tpu.checkpoint import Checkpointer
+    from mgwfbp_tpu.train.step import TrainState
+
+    params = {"w": jnp.arange(4, dtype=jnp.float32)}
+    tx = optax.sgd(0.1)
+    state = TrainState(
+        step=jnp.zeros((), jnp.int32), params=params, batch_stats={},
+        opt_state=tx.init(params), rng=jax.random.PRNGKey(0),
+    )
+    ck = Checkpointer(str(tmp_path))
+    ck._mgr.save(3, args=ocp.args.StandardSave(
+        {"state": state, "meta": {"epoch": 3, "iteration": 42}}
+    ))
+    ck._mgr.wait_until_finished()
+    ck.close()
+    ck2 = Checkpointer(str(tmp_path))
+    assert ck2._probe_format(3) is None
+    snap = ck2.restore(state, step=3)
+    assert snap is not None
+    assert (snap.epoch, snap.iteration, snap.mid_epoch) == (3, 42, False)
     ck2.close()
 
 
@@ -701,27 +732,3 @@ def test_rotation_env_var_and_report(tmp_path, monkeypatch):
     assert len(events_of(recs, "step")) == 81
     report = telemetry_report.format_report(recs)
     assert "81 spans" in report
-
-
-# --------------------------------------------------------------------------
-# Chip-unavailable injection through bench.py
-# --------------------------------------------------------------------------
-
-
-def test_bench_chip_unavailable_injection(tmp_path, monkeypatch, capsys):
-    import bench
-
-    monkeypatch.setenv("MGWFBP_FAULT_PLAN", "chip_unavailable")
-    monkeypatch.setenv("MGWFBP_TELEMETRY_DIR", str(tmp_path))
-    with pytest.raises(bench.ChipUnavailable):
-        bench._require_chip()
-    rc = bench.main()
-    assert rc != 0  # structured record, but never a success
-    out = capsys.readouterr().out.strip().splitlines()
-    payload = json.loads(out[-1])
-    assert payload["skipped"] == "chip unavailable"
-    assert payload["value"] is None
-    assert "injected" in payload["detail"]
-    recs = read_events(str(tmp_path / "telemetry.jsonl"))
-    (ev,) = events_of(recs, "bench_skip")
-    assert "chip_unavailable" in ev["detail"] or "unavailable" in ev["detail"]

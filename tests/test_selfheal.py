@@ -1,6 +1,6 @@
 """Self-healing supervisor (ISSUE 20): failure classification, liveness
 tracking, the healing policy (relaunch / shrink / budgets / crash-loop),
-serve-replica respawn, the chaos fault grammar (kill/wedge + inc), and
+the chaos fault grammar (kill/wedge + inc), and
 the bounded-coordination surface (CoordinationTimeout, env hardening).
 
 Everything here is fast and jax-free on the supervisor side (stub child
@@ -440,37 +440,3 @@ def test_fleet_meta_reports_heal_state():
     assert meta["heal"]["restarts"] == {"crash": 2}
     assert meta["heal"]["budget"] == 4
     assert meta["heal"]["pending_failure"]["target"] == "p1"
-
-
-# ---------------------------------------------------------------------------
-# serve-replica restart policy (satellite)
-# ---------------------------------------------------------------------------
-
-def test_serve_replica_respawns_under_budget(tmp_path):
-    """A crashed serve replica respawns (backoff-spaced) under its own
-    budget; the restart counts are fleet-visible. The training child
-    just outlives a few respawn cycles."""
-    sup = _stub(
-        "import time; time.sleep(2.5)", n=1,
-        serve_replicas=1,
-        serve_cmd=[sys.executable, "-c", "import sys; sys.exit(1)"],
-        serve_max_restarts=2,
-        backoff_base_s=0.05, backoff_max_s=0.1,
-        log_dir=str(tmp_path / "logs"),
-    )
-    assert sup.run() == 0
-    assert sup._serve_restarts == [2]  # budget fully consumed
-    assert 0 in sup._serve_exit_warned  # then warned, left down
-    meta_serving = {
-        "replicas": 1, "alive": 0, "restarts": [2], "restart_budget": 2,
-    }
-    # respawn decisions landed in the supervisor stream
-    events = _read_events(tmp_path / "logs" / "telemetry.supervisor.jsonl")
-    respawns = [e for e in events if e["event"] == "heal"
-                and e["action"] == "respawn_serve"]
-    assert len(respawns) == 2
-    assert respawns[0]["target"] == "serve0"
-    fails = [e for e in events if e["event"] == "failure"
-             and e["target"] == "serve0"]
-    assert fails and fails[0]["class"] == "crash"
-    assert sup._fleet_meta()["serving"] == meta_serving

@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import glob
 import os
+import time
 
 import jax
 import numpy as np
@@ -391,3 +392,37 @@ def test_checkpoint_event_carries_save_cost(tmp_path):
         assert row["format"] == "sharded"
         assert row["duration_s"] >= 0.0
         assert row["bytes"] > 0  # this process's payload, not the world's
+
+
+# ---------------------------------------------------------------------------
+# manifest-addressed partial eval, bitwise vs the gather path
+# ---------------------------------------------------------------------------
+
+
+def test_manifest_eval_params_bitwise(tmp_path, monkeypatch):
+    t = _mk(4, "rs_fwd_ag", tmp_path, ckpt_every_steps=2)
+    try:
+        t.fit(1)
+        # wait out the async writer: the manifest path only engages once
+        # the CURRENT iteration's commit is visible (a pending commit
+        # must fall back to the gather, never read a torn directory)
+        deadline = time.time() + 30
+        while (
+            time.time() < deadline
+            and t.checkpointer.entry_format(int(t.iteration)) != "sharded"
+        ):
+            time.sleep(0.05)
+        assert t.checkpointer.entry_format(int(t.iteration)) == "sharded"
+
+        p_manifest = t._eval_params()
+        assert t._eval_params_source == "manifest"
+        monkeypatch.setattr(t, "_manifest_eval_params", lambda: None)
+        p_gather = t._eval_params()
+        assert t._eval_params_source == "gather"
+        lm = jax.tree_util.tree_leaves(p_manifest)
+        lg = jax.tree_util.tree_leaves(p_gather)
+        assert len(lm) == len(lg) and lm
+        for a, b in zip(lm, lg):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    finally:
+        t.close()

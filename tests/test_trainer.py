@@ -2,8 +2,10 @@
 mesh — the reference's "multi-node without a cluster" strategy (SURVEY.md §4)
 with real assertions instead of oracle A/B runs."""
 
+import glob
 import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -578,3 +580,94 @@ def test_fused_wer_matches_second_pass_decode(monkeypatch):
     ev = t.evaluate()  # fused path (single process)
     two_pass = t._evaluate_wer()  # the old re-forward decode
     assert ev["wer"] == pytest.approx(two_pass["wer"], abs=1e-9)
+
+
+# --------------------------------------------------------------------------
+# README.md against the programs: every flag and variable, both ways
+# --------------------------------------------------------------------------
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+# flags the README quotes from programs that are not this repo's
+_FOREIGN_FLAGS = {
+    "--xla_force_host_platform_device_count",  # XLA_FLAGS
+    "--oversubscribe",  # the reference's mpirun
+}
+
+
+def _read(*parts):
+    with open(os.path.join(_REPO, *parts)) as f:
+        return f.read()
+
+
+def _sources(*roots):
+    """The text of every .py file under the named directories and files."""
+    out = []
+    for root in roots:
+        path = os.path.join(_REPO, root)
+        files = (
+            sorted(glob.glob(os.path.join(path, "**", "*.py"), recursive=True))
+            if os.path.isdir(path) else [path]
+        )
+        out.extend(_read(f) for f in files)
+    return "\n".join(out)
+
+
+def _declared_flags(src):
+    out = set()
+    for m in re.finditer(r'add_argument\(\s*((?:"-{1,2}[\w-]+",?\s*)+)', src):
+        out.update(re.findall(r'"(--[\w-]+)"', m.group(1)))
+    return out
+
+
+def _readme_flags(readme):
+    return set(re.findall(r"(?<![\w-])--[a-z][a-z0-9_-]*", readme))
+
+
+def test_readme_names_every_flag_and_variable():
+    """A flag of the three launchers, or a variable the package reads as
+    a quoted name, that README.md does not name is undocumented."""
+    readme = _read("README.md")
+    flags = _declared_flags(_sources(
+        "mgwfbp_tpu/train_cli.py", "mgwfbp_tpu/runtime/supervise.py",
+        "mgwfbp_tpu/calibrate.py",
+    ))
+    assert len(flags) > 60  # the extraction itself still finds them
+    assert sorted(flags - _readme_flags(readme)) == []
+    # a name built at run time ends in "_": any README name with that
+    # prefix documents it
+    names = set(re.findall(
+        r"""["'](MGWFBP_[A-Z0-9_]+)""", _sources("mgwfbp_tpu")
+    ))
+    assert len(names) > 40
+    assert sorted(n for n in names if n not in readme) == []
+
+
+def test_readme_names_nothing_the_programs_do_not_read():
+    """A flag or MGWFBP_* name in README.md that no program of the repo
+    declares or mentions was removed, renamed or never existed."""
+    readme = _read("README.md")
+    src = _sources(
+        "mgwfbp_tpu", "tools", "benchmarks", "chip_smoke.py",
+        "__graft_entry__.py",
+    )
+    known = _declared_flags(src) | _FOREIGN_FLAGS
+    assert sorted(_readme_flags(readme) - known) == []
+    readme_names = set(re.findall(r"MGWFBP_[A-Z0-9_]+", readme))
+    assert sorted(n for n in readme_names if n not in src) == []
+
+
+@pytest.mark.parametrize("module,argv", [
+    ("mgwfbp_tpu.train_cli", ["--dnn", "lenet", "--serve" "-shadow"]),
+    ("mgwfbp_tpu.runtime.supervise",
+     ["--processes", "1", "--serve" "-replicas", "1"]),
+])
+def test_retired_flags_are_unknown_arguments(module, argv, capsys):
+    """The serving plane's flags left with it (PR 28): argparse refuses
+    them by name instead of a launcher accepting and ignoring them."""
+    import importlib
+
+    parser = importlib.import_module(module).build_parser()
+    with pytest.raises(SystemExit) as exc:
+        parser.parse_args(argv)
+    assert exc.value.code == 2
+    assert "unrecognized arguments" in capsys.readouterr().err

@@ -86,25 +86,7 @@ def _probe(port: int, path: str, timeout_s: float = 1.0):
         return None, str(e)
 
 
-def _probe_post(port: int, path: str, doc: dict, timeout_s: float = 2.0):
-    """(http status, body) of one POST probe, or (None, reason)."""
-    req = urllib.request.Request(
-        f"http://127.0.0.1:{port}{path}",
-        data=json.dumps(doc).encode(),
-        headers={"Content-Type": "application/json"},
-    )
-    try:
-        with urllib.request.urlopen(req, timeout=timeout_s) as resp:
-            return resp.status, resp.read().decode()
-    except urllib.error.HTTPError as e:  # 4xx/5xx is an answer
-        return e.code, e.read().decode()
-    except Exception as e:  # noqa: BLE001 — not up yet
-        return None, str(e)
-
-
-def _cli(
-    logdir: str, ckpt: bool = True, extra: tuple = (),
-) -> list[str]:
+def _cli(logdir: str, ckpt: bool = True) -> list[str]:
     cmd = [
         sys.executable, "-m", "mgwfbp_tpu.train_cli",
         "--dnn", "lenet", "--synthetic", "--no-profile-backward",
@@ -117,12 +99,12 @@ def _cli(
             "--checkpoint-dir", os.path.join(logdir, "ckpt"),
             "--ckpt-every-steps", "2",
         ]
-    return cmd + ["--telemetry", *extra]
+    return cmd + ["--telemetry"]
 
 
 def _run(
     logdir: str, fault_plan: str, metrics_port: int = 0,
-    ckpt: bool = True, extra: tuple = (),
+    ckpt: bool = True,
 ) -> tuple[int, dict]:
     """One real-launcher run; with metrics_port > 0 the live plane is
     probed WHILE the run is up (mid-run, not post-hoc — that is the whole
@@ -142,7 +124,7 @@ def _run(
     out_path = os.path.join(logdir, "fault_smoke_child.log")
     with open(out_path, "w") as sink:
         proc = subprocess.Popen(
-            _cli(logdir, ckpt=ckpt, extra=extra), env=env, cwd=_ROOT,
+            _cli(logdir, ckpt=ckpt), env=env, cwd=_ROOT,
             stdout=sink, stderr=subprocess.STDOUT,
         )
         probes: dict = {}
@@ -645,129 +627,6 @@ def async_ckpt_smoke() -> dict:
     }
 
 
-def serve_smoke() -> dict:
-    """ISSUE 19: the in-process serving plane riding a real training run.
-    A --serve-shadow run must answer POST /predict MID-RUN from the
-    training process's own metrics port, the served step must ADVANCE as
-    later mid-epoch commits hot-reload (reload + shadow_eval events in
-    the stream, serving section in /status), and post-warmup median step
-    time must stay within noise of an identical serve-off run — the
-    serving plane lives entirely off the step path."""
-    from mgwfbp_tpu.telemetry import events_of
-
-    def _post_warmup_median_step_s(d: str) -> float:
-        steps = sorted(
-            events_of(_events(d), "step"), key=lambda r: r["step"]
-        )
-        assert len(steps) >= 8, f"run too short: {len(steps)} steps"
-        durs = sorted(float(r["dur_s"]) for r in steps[2:])
-        return durs[len(durs) // 2]
-
-    with tempfile.TemporaryDirectory(prefix="mgwfbp_serve_off_") as d:
-        rc, _ = _run(d, "")
-        assert rc == 0, f"serve-off baseline exited rc {rc}"
-        off_median = _post_warmup_median_step_s(d)
-
-    from mgwfbp_tpu import models
-
-    _, meta = models.create_model("lenet")
-    inputs = [
-        [[[0.5] * meta.input_shape[-1]] * meta.input_shape[1]]
-        * meta.input_shape[0]
-    ] * 2  # a batch of 2 constant images
-    with tempfile.TemporaryDirectory(prefix="mgwfbp_serve_on_") as d:
-        port = _free_port()
-        env = dict(os.environ)
-        env["JAX_PLATFORMS"] = "cpu"
-        env.setdefault(
-            "XLA_FLAGS", "--xla_force_host_platform_device_count=8"
-        )
-        env["MGWFBP_METRICS_PORT"] = str(port)
-        # two stalls hold the run open: the first while an EARLY commit
-        # is being served, the second after later mid-epoch commits land
-        # — the served step observed across them must advance
-        env["MGWFBP_FAULT_PLAN"] = "stall@secs=4,step=3;stall@secs=4,step=9"
-        out_path = os.path.join(d, "serve_smoke_child.log")
-        first = advanced = serving_status = None
-        with open(out_path, "w") as sink:
-            proc = subprocess.Popen(
-                _cli(d, extra=("--serve-shadow",)), env=env, cwd=_ROOT,
-                stdout=sink, stderr=subprocess.STDOUT,
-            )
-            deadline = time.monotonic() + 600
-            while proc.poll() is None:
-                if time.monotonic() > deadline:
-                    proc.kill()
-                    proc.wait()
-                    raise AssertionError("serve smoke run timed out")
-                code, body = _probe_post(port, "/predict",
-                                         {"inputs": inputs})
-                if code == 200:
-                    doc = json.loads(body)
-                    if first is None:
-                        first = doc
-                    elif (advanced is None and int(doc["served_step"])
-                          > int(first["served_step"])):
-                        advanced = doc
-                if advanced is not None and serving_status is None:
-                    code, body = _probe(port, "/status")
-                    if code == 200:
-                        st = json.loads(body).get("serving")
-                        if st and st.get("stats"):
-                            serving_status = st
-                time.sleep(0.1)
-        with open(out_path) as f:
-            tail = f.read()[-4000:]
-        if proc.returncode != 0:
-            sys.stderr.write(tail)
-        assert proc.returncode == 0, f"serve-on run exited {proc.returncode}"
-        assert first is not None, (
-            "POST /predict never answered 200 mid-run; child tail:\n"
-            + tail
-        )
-        assert advanced is not None, (
-            "served step never advanced past the first served commit "
-            f"(stuck at {first['served_step']})"
-        )
-        assert len(advanced["outputs"]) == 2, advanced
-        assert len(advanced["outputs"][0]) == meta.num_classes, advanced
-        assert serving_status is not None, (
-            "/status never carried a serving section with request stats"
-        )
-        # the serve_stats emit is throttled (~1 s), so the snapshot may
-        # trail the live request count — presence with >=1 is the pin
-        assert serving_status["stats"]["requests"] >= 1, serving_status
-        on_median = _post_warmup_median_step_s(d)
-        recs = _events(d)
-        reloads = events_of(recs, "reload")
-        assert len(reloads) >= 2, f"fewer than 2 hot-reloads: {reloads}"
-        rsteps = [int(r["step"]) for r in reloads]
-        assert rsteps == sorted(rsteps), reloads
-        # at least one reload served a MID-EPOCH commit (6 steps/epoch)
-        assert any(s % 6 != 0 for s in rsteps), rsteps
-        shadows = events_of(recs, "shadow_eval")
-        assert shadows, "no shadow_eval events in the stream"
-        assert all(
-            float(s["loss"]) == float(s["loss"]) for s in shadows
-        ), shadows  # NaN check
-    # the plane is off the step path: a generous CPU-jitter envelope a
-    # synchronous reload or an on-loop dispatcher would still trip
-    assert on_median <= off_median * 3.0 + 0.05, (
-        f"serve-on median step {on_median * 1e3:.2f} ms vs serve-off "
-        f"{off_median * 1e3:.2f} ms — serving is back on the step path"
-    )
-    return {
-        "serve_smoke": "ok",
-        "first_served_step": int(first["served_step"]),
-        "advanced_served_step": int(advanced["served_step"]),
-        "reload_steps": rsteps,
-        "shadow_evals": len(shadows),
-        "requests_served": serving_status["stats"]["requests"],
-        "serve_off_median_step_ms": round(off_median * 1e3, 3),
-        "serve_on_median_step_ms": round(on_median * 1e3, 3),
-    }
-
-
 def chaos_smoke() -> dict:
     """ISSUE 20: the self-healing supervisor, end to end, against DRAIN-
     LESS faults — failures that never say goodbye, which the agreed-
@@ -1028,17 +887,9 @@ def main() -> int:
                          "survivors off the last committed shard-native "
                          "step) and wedge one (liveness monitor heals "
                          "the group in bounded time)")
-    ap.add_argument("--serve", action="store_true",
-                    help="serving-plane lifecycle (ISSUE 19): "
-                         "--serve-shadow run answering POST /predict "
-                         "mid-run, served step advancing across "
-                         "mid-epoch commits, step-time envelope vs a "
-                         "serve-off run")
     args = ap.parse_args()
     if args.chaos:
         out = chaos_smoke()
-    elif args.serve:
-        out = serve_smoke()
     elif args.async_ckpt:
         out = async_ckpt_smoke()
     elif args.resize:

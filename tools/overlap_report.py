@@ -113,9 +113,7 @@ def summarize_overlap(logdir: str) -> dict:
 
 
 def measure_tb(model, meta, params, batch_stats, batch):
-    """One arrival-order backward profile for a model (shared by the
-    _build_setup fallback and tools/policy_grid.py, which measures ONCE and
-    feeds every policy's solve from the same numbers)."""
+    """One arrival-order backward profile for a model."""
     import jax
     import jax.numpy as jnp
 
@@ -135,15 +133,9 @@ def measure_tb(model, meta, params, batch_stats, batch):
     )
 
 
-def _build_setup(model_name, batch, policy, nsteps, comm_profile=None,
-                 tb=None):
-    """Shared setup: model/state/reducer (measured-tb schedule) + step fn.
-
-    `tb`: pass a precomputed arrival-order backward profile so every policy
-    of an A/B grid is solved AND simulated from the same measurement
-    (tools/policy_grid.py measures once, reuses five times); by default tb
-    is measured here for the policies that need it (mgwfbp/auto).
-    """
+def _build_setup(model_name, batch, policy, nsteps, comm_profile=None):
+    """Shared setup: model/state/reducer (measured-tb schedule) + step fn;
+    tb is measured here for the policies that need it (mgwfbp/auto)."""
     import jax
     import jax.numpy as jnp
 
@@ -180,7 +172,8 @@ def _build_setup(model_name, batch, policy, nsteps, comm_profile=None,
             if comm_profile
             else lookup_alpha_beta("ici", max(n_dev, 2))
         )
-        if tb is None and policy in ("mgwfbp", "auto"):
+        tb = None
+        if policy in ("mgwfbp", "auto"):
             tb = measure_tb(model, meta, state.params, state.batch_stats, batch)
         reducer = make_merged_allreduce(
             state.params, axis_name=DATA_AXIS, policy=policy,

@@ -238,7 +238,6 @@ def format_report(records: list[dict]) -> str:
                      "policy 'none', or telemetry off during fit)")
 
     lines.extend(_health_section(records))
-    lines.extend(_serving_section(records))
 
     alarms = events_of(records, "drift_alarm", "straggler", "health_alarm")
     if alarms:
@@ -295,7 +294,6 @@ def format_report(records: list[dict]) -> str:
             f"WATCHDOG STALL in {r.get('phase')!r} after "
             f"{_fmt_s(r.get('idle_s'))} s"
             + (" (aborted)" if r.get("abort") else ""))),
-        ("bench_skip", lambda r: f"bench skipped: {r.get('detail')}"),
         ("bad_step", lambda r: (
             f"BAD STEP {r.get('step')} (epoch {r.get('epoch')}): "
             f"{_fmt_s(r.get('nonfinite'))} non-finite gradient element(s), "
@@ -467,59 +465,6 @@ def _health_section(records: list[dict]) -> list[str]:
     return lines
 
 
-def _serving_section(records: list[dict]) -> list[str]:
-    """Serving-plane section (ISSUE 19): hot-reload count + lag trend,
-    request latency quantiles, queue depth trend, batch fill, and the
-    shadow-eval loss against the training loss it shadows."""
-    from mgwfbp_tpu.telemetry import events_of
-
-    lines: list[str] = []
-    reloads = events_of(records, "reload")
-    stats = events_of(records, "serve_stats")
-    shadows = events_of(records, "shadow_eval")
-    if not (reloads or stats or shadows):
-        return lines
-    lines.append("")
-    lines.append("serving:")
-    if reloads:
-        lags = [float(r.get("lag_s", 0.0)) for r in reloads]
-        lines.append(
-            f"  hot-reloads: {len(reloads)} (step "
-            f"{reloads[0].get('step')} -> {reloads[-1].get('step')}), "
-            f"reload lag mean {_fmt_s(sum(lags) / len(lags))} s, "
-            f"max {_fmt_s(max(lags))} s"
-        )
-    if stats:
-        last = stats[-1]
-        lines.append(
-            f"  requests: {last.get('requests')} total, latency p50 "
-            f"{_fmt_s(last.get('latency_p50_s'))} s / p95 "
-            f"{_fmt_s(last.get('latency_p95_s'))} s / p99 "
-            f"{_fmt_s(last.get('latency_p99_s'))} s, batch fill "
-            f"{_fmt_s(last.get('batch_fill'))}"
-        )
-        depths = [float(s.get("queue_depth", 0)) for s in stats]
-        lines.append(
-            f"  queue depth: first {_fmt_s(depths[0])} -> last "
-            f"{_fmt_s(depths[-1])} (max {_fmt_s(max(depths))})"
-        )
-    if shadows:
-        first, last = shadows[0], shadows[-1]
-        line = (
-            f"  shadow eval: {len(shadows)} scores, loss "
-            f"{_fmt_s(first.get('loss'))} (step {first.get('step')}) -> "
-            f"{_fmt_s(last.get('loss'))} (step {last.get('step')})"
-        )
-        if last.get("train_loss") is not None:
-            delta = float(last["loss"]) - float(last["train_loss"])
-            line += (
-                f"; vs training loss {_fmt_s(last.get('train_loss'))} "
-                f"(delta {delta:+.4g})"
-            )
-        lines.append(line)
-    return lines
-
-
 def _alarm_lines(alarms: list[dict]) -> list[str]:
     """Active-alarm table rows (live /status and /fleet/status share the
     same alarm dicts the aggregator keeps)."""
@@ -608,33 +553,6 @@ def format_live_report(status: dict, values: dict) -> str:
             lines.append(
                 f"  compression error (worst group): {_fmt_s(max(comp))}"
             )
-    serving = status.get("serving")
-    if serving:
-        lines.append("")
-        lines.append(
-            f"serving: step {serving.get('step')}, "
-            f"{serving.get('reloads', 0)} hot-reload(s), reload lag "
-            f"{_fmt_s(serving.get('reload_lag_s'))} s"
-        )
-        st = serving.get("stats") or {}
-        if st:
-            lines.append(
-                f"  requests {st.get('requests', 0)}, queue depth "
-                f"{st.get('queue_depth', 0)}, batch fill "
-                f"{_fmt_s(st.get('batch_fill'))}, latency p50 "
-                f"{_fmt_s(st.get('latency_p50_s'))} s / p95 "
-                f"{_fmt_s(st.get('latency_p95_s'))} s / p99 "
-                f"{_fmt_s(st.get('latency_p99_s'))} s"
-            )
-        sh = serving.get("shadow") or {}
-        if sh:
-            line = (
-                f"  shadow eval (step {sh.get('step')}): loss "
-                f"{_fmt_s(sh.get('loss'))}"
-            )
-            if sh.get("train_loss") is not None:
-                line += f" vs training {_fmt_s(sh.get('train_loss'))}"
-            lines.append(line)
     pm = status.get("postmortems") or {}
     if pm.get("total"):
         lines.append(
@@ -670,9 +588,6 @@ def format_live_report(status: dict, values: dict) -> str:
         ("mgwfbp_health_alarms_total", "health alarms"),
         ("mgwfbp_postmortems_total", "postmortem bundles"),
         ("mgwfbp_profile_windows_total", "profile windows"),
-        ("mgwfbp_serve_requests_total", "predict requests"),
-        ("mgwfbp_serve_reloads_total", "hot-reloads"),
-        ("mgwfbp_shadow_evals_total", "shadow evals"),
     ):
         v = values.get(key, 0)
         if v:
@@ -749,15 +664,6 @@ def format_fleet_report(doc: dict) -> str:
                 f"{pending.get('target')} (step {pending.get('step')}) "
                 "— draining to heal"
             )
-    serving = doc.get("serving")
-    if serving:
-        lines.append("")
-        lines.append(
-            f"serve replicas: {serving.get('alive', 0)}/"
-            f"{serving.get('replicas', 0)} alive, restarts "
-            f"{serving.get('restarts')} (budget "
-            f"{serving.get('restart_budget')}/replica)"
-        )
     alarms = doc.get("active_alarms") or []
     lines.append("")
     if alarms:
@@ -893,15 +799,6 @@ def _synthetic_stream(path: str) -> None:
            band=2.0, active=False, group=-1)
     w.emit("postmortem", trigger="health_alarm", step=20,
            path="/tmp/run/postmortems/0000")
-    # serving plane (ISSUE 19): hot-reloads, request stats, shadow evals
-    w.emit("reload", step=8, lag_s=0.4, duration_s=0.05)
-    w.emit("reload", step=16, lag_s=0.6, duration_s=0.04)
-    w.emit("serve_stats", requests=10, queue_depth=1, batch_fill=0.5,
-           latency_p50_s=0.02, latency_p95_s=0.04, latency_p99_s=0.05)
-    w.emit("serve_stats", requests=24, queue_depth=0, batch_fill=0.75,
-           latency_p50_s=0.018, latency_p95_s=0.035, latency_p99_s=0.04)
-    w.emit("shadow_eval", step=8, loss=1.9, train_loss=1.8)
-    w.emit("shadow_eval", step=16, loss=1.4, train_loss=1.35)
     # self-healing supervisor (ISSUE 20): a hard-failure verdict and the
     # healing action taken, as the supervisor's own stream records them
     w.emit("failure", **{"class": "oom_kill"}, target="p1", rc=-9,
@@ -952,14 +849,6 @@ def selftest() -> int:
             in report
         ), report
         assert "save at iter 8 overlapped" not in report, report
-        # ISSUE 19: the serving section renders latency quantiles, queue
-        # depth trend, batch fill, reload lag, shadow-vs-training loss
-        assert "serving:" in report, report
-        assert "hot-reloads: 2 (step 8 -> 16)" in report, report
-        assert "latency p50 0.018 s / p95 0.035 s / p99 0.04 s" in report
-        assert "queue depth: first 1 -> last 0" in report, report
-        assert "shadow eval: 2 scores" in report, report
-        assert "vs training loss 1.35 (delta +0.05)" in report, report
         # ISSUE 20: failure verdicts and healing actions render in the
         # lifecycle section
         assert "FAILURE [oom_kill] on p1 rc -9 at step 20" in report
@@ -992,11 +881,6 @@ def selftest() -> int:
         assert "mgwfbp_health_alarms_total 1" in prom, prom
         assert "mgwfbp_postmortems_total 1" in prom, prom
         assert "mgwfbp_health_grad_norm" in prom, prom
-        assert "mgwfbp_serve_reloads_total 2" in prom, prom
-        assert "mgwfbp_shadow_evals_total 2" in prom, prom
-        assert "mgwfbp_serve_step 16" in prom, prom
-        assert "mgwfbp_serve_latency_p95_seconds 0.035" in prom, prom
-        assert "mgwfbp_shadow_eval_delta 0.05" in prom, prom
         assert "mgwfbp_failures_total 2" in prom, prom
         assert "mgwfbp_heals_total 2" in prom, prom
         # --live round trip: serve the replayed aggregator over HTTP and
@@ -1011,16 +895,12 @@ def selftest() -> int:
             lambda: {0: ("127.0.0.1", srv.port),
                      1: ("127.0.0.1", srv.port)},
             port=0,
-            # the supervisor's heal/serving state flows through the
-            # fan-in meta verbatim (ISSUE 20)
+            # the supervisor's heal state flows through the fan-in meta
+            # verbatim (ISSUE 20)
             meta_provider=lambda: {
                 "heal": {
                     "enabled": True, "restarts": {"oom_kill": 1},
                     "budget": 2, "liveness_grace_s": 120.0,
-                },
-                "serving": {
-                    "replicas": 2, "alive": 1, "restarts": [0, 2],
-                    "restart_budget": 3,
                 },
             },
         )
@@ -1032,10 +912,6 @@ def selftest() -> int:
             assert code == 200 and parse_metrics_text(mtext), mtext
             live = format_live_report(status, parse_metrics_text(mtext))
             assert "steps: 24 recorded" in live, live
-            # the --live view carries the same serving section, sourced
-            # from /status's `serving` document
-            assert "serving: step 16, 2 hot-reload(s)" in live, live
-            assert "shadow eval (step 16)" in live, live
             # ISSUE 20: failure/heal lifecycle counters in the live view
             assert "hard failures: 2" in live, live
             assert "heals: 2" in live, live
@@ -1056,15 +932,11 @@ def selftest() -> int:
             )
             assert 'mgwfbp_steps_total{process="0"} 24' in fmet, fmet
             assert 'mgwfbp_steps_total{process="1"} 24' in fmet, fmet
-            # ISSUE 20: the supervisor's heal + serve-replica state
-            # renders in the fleet view
+            # ISSUE 20: the supervisor's heal state renders in the fleet
+            # view
             freport = format_fleet_report(fdoc)
             assert "self-healing: enabled" in freport, freport
             assert "heals so far: oom_kill=1" in freport, freport
-            assert (
-                "serve replicas: 1/2 alive, restarts [0, 2] "
-                "(budget 3/replica)" in freport
-            ), freport
             print(format_fleet_report(fdoc))
             print()
         finally:
