@@ -153,7 +153,14 @@ def main(argv: Optional[list[str]] = None) -> int:
             )
         overlap = 1.0
         if not args.no_overlap:
-            overlap = profile_overlap_capability(mesh)
+            # measured the way the train step is compiled, or the solver's
+            # `overlap` would describe a chip the step never runs on
+            from mgwfbp_tpu.train.step import async_collective_options
+
+            overlap = profile_overlap_capability(
+                mesh, compiler_options=async_collective_options(
+                    mesh, mesh.axis_names),
+            )
         pack_beta = 0.0
         update_beta = 0.0
         if not args.no_gamma:  # same bucket-path microbench family

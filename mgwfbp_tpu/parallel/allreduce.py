@@ -694,6 +694,28 @@ def _chain_token(buf: jax.Array, token) -> jax.Array:
     return buf + jnp.zeros((), buf.dtype) * clean.astype(buf.dtype)
 
 
+def _order_after(buf: jax.Array, token) -> jax.Array:
+    """`buf` with ONE element made to depend on `token`: merged_psum's
+    ordering edge (its docstring has the why).
+
+    `_chain_token` adds the token to the whole bucket, and XLA fuses that
+    add into the kernel that PRODUCES the bucket's gradient, so the kernel
+    takes the previous group's reduced value as an operand. Here the
+    bucket's first element is rewritten in place (slice, add, update: the
+    bucket is a fresh value, so no copy and no pass over it), which the
+    TPU compiler keeps as a one-element update between the producer and
+    the collective: only the collective's operand waits for the token.
+    """
+    if (
+        token is None
+        or buf.shape[0] == 0
+        or not jnp.issubdtype(buf.dtype, jnp.inexact)
+    ):
+        return buf
+    head = _chain_token(lax.slice_in_dim(buf, 0, 1), token)
+    return lax.dynamic_update_slice_in_dim(buf, head, 0, axis=0)
+
+
 def _rs_phase(
     g_arr, layout, optim, axes, world, mean, comm_dtype, sequential, token
 ):
@@ -1143,11 +1165,30 @@ def merged_psum(
     `0*x` (IEEE: 0*x is not 0 for NaN/inf) and has no finiteness range
     analysis to see through the `where`, so the dependency survives every
     simplifier pass — while the `where` guarantees a NaN/inf in one bucket
-    never leaks into later buckets' gradients. The add fuses into the
-    bucket pack — one fused elementwise pass, no extra HBM round-trip.
-    (`lax.optimization_barrier` would be cleaner but is dropped by the SPMD
-    partitioner on at least the CPU backend — verified empirically; the
-    combiner then re-merges everything.)
+    never leaks into later buckets' gradients.
+
+    WHAT the token hangs on (`_order_after`, PR 29): ONE element of the
+    bucket, rewritten in place between the kernel that produces the bucket
+    and the collective. Only the collective's operand depends on the
+    previous group's reduced value; the gradient's producer does not.
+    Until PR 29 the token was added to the whole bucket, XLA fused that
+    add into the kernel that computes the weight gradient
+    (`convert_add_fusion`, 14.4 ms a step on four-chip VGG-16), and that
+    kernel took the previous group's reduced bucket as an operand:
+    harmless while every all-reduce is synchronous, but the train step is
+    now compiled with asynchronous all-reduces on a multi-chip TPU mesh
+    (train/step.py `async_collective_options`), and a producer that waits
+    for the previous collective's `-done` puts the backward pass behind
+    the exchange instead of beside it. It also pinned the order in which
+    the weight gradients are computed, which cost 1.75 GiB of temporaries
+    on that step (5.569 against 3.814 GiB compiled without the options;
+    PERF.md, PR 29). tests/test_exchange_order.py
+    states the property on the traced program. The other lowerings
+    (`_chain_token`) keep the whole-bucket token: no cell runs them.
+    (`lax.optimization_barrier((buf, token))` would say the same thing
+    more plainly, but inside `shard_map` both the CPU's and the TPU's
+    compiler drop it before the all-reduce combiner runs: the 32 buckets
+    of four-chip VGG-16 came out as 3 combined all-reduces, PERF.md, PR 29.)
     """
     if comm_op not in ("all_reduce", "rs_ag", "hier"):
         raise ValueError(
@@ -1188,13 +1229,8 @@ def merged_psum(
             orig_dtype = buf.dtype
             if comm_dtype is not None and buf.dtype != comm_dtype:
                 buf = buf.astype(comm_dtype)
-            if sequential and token is not None and jnp.issubdtype(
-                buf.dtype, jnp.inexact
-            ):
-                clean = jnp.where(
-                    jnp.isfinite(token), token, jnp.zeros_like(token)
-                )
-                buf = buf + jnp.zeros((), buf.dtype) * clean.astype(buf.dtype)
+            if sequential:
+                buf = _order_after(buf, token)
             if compressor is not None and jnp.issubdtype(
                 buf.dtype, jnp.floating
             ):

@@ -414,6 +414,7 @@ def profile_overlap_capability(
     warmup: int = 3,
     iters: int = 10,
     axis_name: str = DATA_AXIS,
+    compiler_options: Optional[dict] = None,
 ) -> float:
     """Measure how much collective time the platform hides behind compute.
 
@@ -426,7 +427,10 @@ def profile_overlap_capability(
     same cores as compute). The solver's simulation blends its overlapped
     and serialized timelines by this factor (simulate_groups); the
     reference assumes 1.0 unconditionally (NCCL streams), which mispredicts
-    any platform that cannot overlap.
+    any platform that cannot overlap. `compiler_options` go to each of the
+    three programs' `jax.jit` (train/step.py's `async_collective_options`
+    are what the train step is built with: with and without them is how to
+    learn what a chip needs before it overlaps).
     """
     w = jnp.ones((512, 512), jnp.float32) * 1e-3
     payload = jnp.ones((payload_elems,), jnp.float32)
@@ -447,7 +451,8 @@ def profile_overlap_capability(
             shard_map(
                 body, mesh=mesh, in_specs=(P(), P()), out_specs=out_spec,
                 check_vma=False,
-            )
+            ),
+            compiler_options=compiler_options or None,
         )
         x = jnp.ones((512, 512), jnp.float32)
         for _ in range(warmup):
@@ -933,6 +938,51 @@ def hlo_collective_scope_map(
         if s is not None:
             out[m.group(1)] = s.group(1)
     return out
+
+
+_HLO_COLLECTIVES = (
+    "all-reduce|all-gather|reduce-scatter|collective-permute|all-to-all"
+    "|collective-broadcast"
+)
+
+
+def hlo_collective_counts(hlo_text: str) -> dict[str, int]:
+    """How many collectives a COMPILED program issues, and how many of them
+    asynchronously: `{"collectives": n, "async_collectives": k}`.
+
+    Asynchronous is a `<collective>-start` instruction or, on a TPU, an
+    async collective fusion (`%async-collective-start.N = ... fusion(...)`:
+    the compiler wraps the collective and the compute steps that drive it
+    in one kernel; its `-done` twin is where the core waits). Synchronous
+    is the bare opcode on the computation's own instruction stream. The
+    bare opcode INSIDE a fusion's called computation is that fusion's body
+    and is not counted a second time."""
+    import re as _re
+
+    fused = set(_re.findall(r"\bfusion\(.*?calls=%([\w.\-]+)", hlo_text))
+    header = _re.compile(r"^(?:ENTRY\s+)?%([\w.\-]+)\s*\(.*\{\s*$")
+    sync = _re.compile(
+        r"^\s*(?:ROOT\s+)?%[\w.\-]+\s*=\s.*?[\s)](?:"
+        + _HLO_COLLECTIVES + r")\("
+    )
+    started = _re.compile(
+        r"^\s*(?:ROOT\s+)?%(?:async-collective-start[.\d]*\s*=\s.*?\bfusion\("
+        r"|[\w.\-]+\s*=\s.*?[\s)](?:" + _HLO_COLLECTIVES + r")-start\()"
+    )
+    n_sync = n_async = 0
+    inside = None
+    for line in hlo_text.splitlines():
+        h = header.match(line)
+        if h is not None:
+            inside = h.group(1)
+            continue
+        if inside in fused:
+            continue
+        if started.match(line):
+            n_async += 1
+        elif sync.match(line):
+            n_sync += 1
+    return {"collectives": n_sync + n_async, "async_collectives": n_async}
 
 
 def _group_times_from_scopes(

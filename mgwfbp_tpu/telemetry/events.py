@@ -79,6 +79,11 @@ EVENT_TYPES: dict[str, tuple[str, ...]] = {
     "scalar": ("tag", "value", "step"),
     # epoch boundary (throughput trend anchor for the report CLI)
     "epoch": ("epoch", "steps", "dur_s"),
+    # one built step program with gradient collectives, read from its
+    # compiled text after its first dispatch (Trainer._note_step_program):
+    # how many collectives it issues and how many of them the compiler
+    # made asynchronous; `compiler_options` (names) rides along
+    "step_program": ("step", "collectives", "async_collectives"),
     # autotune: one raced candidate / the committed winner
     "autotune_race": ("label", "comm_op", "num_groups", "verified",
                       "measured_step_s"),

@@ -108,6 +108,18 @@ def _poison_batch(batch: Any) -> tuple[Any, bool]:
     return (out if poisoned else batch), poisoned
 
 
+def _describe_args(args: Any) -> Any:
+    """`args` as ShapeDtypeStructs with the arrays' own shardings: what a
+    dispatch was traced and lowered for, still there after it has donated
+    the arrays themselves."""
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=getattr(a, "sharding", None)
+        ),
+        args,
+    )
+
+
 class Trainer:
     def __init__(
         self,
@@ -655,8 +667,12 @@ class Trainer:
         self._train_step_compiled = False
         self._eval_step_compiled = False
         # compiled-HLO text of the live step (the /profile window's
-        # trace-event join key) describes the OLD program
+        # trace-event join key) describes the OLD program, and so does the
+        # count of its collectives (_note_step_program)
         self._step_hlo_cache = None
+        self._step_program = None
+        # a one-device step has no gradient collective to count
+        self._step_program_noted = self.data_size * self.seq_size <= 1
 
     def _build_run_sinks(self) -> None:
         """(Re)bind every tag-addressed output — log file, checkpoint dir,
@@ -1248,6 +1264,57 @@ class Trainer:
                 float(reducer.schedule.predicted_nonoverlap_time),
             )
 
+    def _note_step_program(self, step_args) -> None:
+        """Once per step-program build, after its first dispatch: count the
+        compiled program's collectives and how many of them the compiler
+        made asynchronous (profiling.hlo_collective_counts), for the log,
+        the `step_program` telemetry record and _schedule_state_doc. Only
+        a program with gradient collectives is read (_build_steps marks a
+        one-device step as noted: it has none).
+
+        No second compilation: `step_args` describe the dispatch that just
+        built the program, so the lowering is jax's cached one, and the
+        executable is the one in memory, or, for a step built with
+        compile options (train/step.py: jax keeps no executable in memory
+        for those), a read of the persistent compile cache that the
+        dispatch just wrote."""
+        self._step_program_noted = True
+        from mgwfbp_tpu.profiling import hlo_collective_counts
+        from mgwfbp_tpu.train.step import async_collective_options
+
+        red_axes = tuple(self.data_axes) + (
+            (self.seq_axis,) if self.seq_axis else ()
+        )
+        options = sorted(async_collective_options(self.mesh, red_axes))
+        if options and not jax.config.jax_compilation_cache_dir:
+            # nothing to read back: compiling again is all that is left
+            self.log.info(
+                "merge schedule: compiled step not read (compile options "
+                "%s and no persistent compile cache)", ", ".join(options),
+            )
+            return
+        t0 = time.perf_counter()
+        try:
+            text = self.train_step.lower(*step_args).compile().as_text()
+        except Exception as e:  # noqa: BLE001 — a description of the
+            # program, never a reason to stop training it
+            self.log.info("step program: compiled text unavailable (%s)", e)
+            return
+        self._step_hlo_cache = text
+        self._step_program = {
+            **hlo_collective_counts(text), "compiler_options": options,
+        }
+        self.log.info(
+            "merge schedule: the compiled step issues %d collectives, %d of "
+            "them asynchronous (compile options: %s; read in %.2f s)",
+            self._step_program["collectives"],
+            self._step_program["async_collectives"],
+            ", ".join(options) or "none", time.perf_counter() - t0,
+        )
+        self._emit_event(
+            "step_program", step=int(self.iteration), **self._step_program
+        )
+
     def _schedule_state_doc(self) -> dict:
         """The committed schedule + cost-model state, JSON-able — the
         flight recorder snapshots this into every postmortem bundle so
@@ -1277,6 +1344,9 @@ class Trainer:
         measured = getattr(self, "_measured_group_times", None)
         if measured is not None:
             doc["measured_group_times"] = [float(t) for t in measured]
+        program = getattr(self, "_step_program", None)
+        if program is not None:
+            doc["step_program"] = dict(program)
         return doc
 
     # ------------------------------------------------------------------
@@ -3070,6 +3140,16 @@ class Trainer:
                 wd.beat(f"compile train step (epoch {epoch})",
                         allow_s=COMPILE_ALLOW_S)
             self._local_busy_s += time.perf_counter() - t_anchor
+            # a fresh step program's arguments, described before the
+            # dispatch donates them (_note_step_program reads the compiled
+            # program once this dispatch has built it)
+            step_args = (
+                None if self._step_program_noted
+                else _describe_args(
+                    (self.state, batch, self.carry) if self.meta.has_carry
+                    else (self.state, batch)
+                )
+            )
             # step span: host wall-clock around the ASYNC dispatch, taken
             # outside jit — no block_until_ready, no device_get (the span
             # itself syncs nothing; once the dispatch pipeline fills, span
@@ -3097,6 +3177,8 @@ class Trainer:
                 rec.dispatched(
                     self.iteration, epoch, span0, rec.now() - span0
                 )
+            if step_args is not None:
+                self._note_step_program(step_args)
             window_iters += 1
             epoch_steps += 1
             # non-finite guard bookkeeping (one step LATE via the deque, so

@@ -312,6 +312,40 @@ def test_trainer_smoke_emits_step_and_group_events(tmp_path):
     assert any(e.get("ph") == "X" for e in doc["traceEvents"])
 
 
+def test_trainer_records_its_step_programs_collectives(tmp_path):
+    """ISSUE 29: once per built step program, after its first dispatch, the
+    Trainer counts the compiled program's collectives and how many of them
+    are asynchronous: one `step_program` record, the same numbers in
+    `_schedule_state_doc`, and a line in the report. On the CPU mesh the
+    step gets no compile option and every collective is synchronous. A
+    second epoch runs the same program and adds no record."""
+    from mgwfbp_tpu.train.trainer import Trainer
+
+    cfg = _cfg(logdir=str(tmp_path), telemetry=True, policy="wfbp")
+    t = Trainer(cfg, synthetic_data=True, profile_backward=False)
+    assert t._schedule_state_doc().get("step_program") is None  # not built
+    t.train_epoch(0)
+    t.train_epoch(1)
+    t.close()
+    recs = read_events(
+        os.path.join(str(tmp_path), cfg.tag(), "telemetry.jsonl"))
+    (prog,) = events_of(recs, "step_program")
+    groups = t.reducer.layout.num_groups
+    # one all-reduce a merge group and the metrics' own
+    assert prog["collectives"] == groups + 1
+    assert prog["async_collectives"] == 0
+    assert prog["compiler_options"] == []
+    assert prog["step"] == 1
+    doc = t._schedule_state_doc()["step_program"]
+    assert doc == {k: prog[k] for k in doc}
+    import telemetry_report
+
+    assert (
+        f"step program (built by step 1): {groups + 1} collectives, "
+        "0 asynchronous; compile options: none"
+    ) in telemetry_report.format_report(recs)
+
+
 def test_zero_sync_guard(tmp_path, monkeypatch):
     """Telemetry must add ZERO device syncs to the step loop: the number
     of jax.device_get / jax.block_until_ready calls during a training

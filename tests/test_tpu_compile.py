@@ -166,3 +166,133 @@ def test_resnet50_bf16_b128_step_fits_one_v5e_chip(topo):
     mem = step.lower(state, batch).compile().memory_analysis()
     need = mem.temp_size_in_bytes + mem.argument_size_in_bytes
     assert need < HBM_BYTES, f"{need / 1e9:.1f} GB does not fit one chip"
+
+
+def _four_chip_mlp_step_text(topo):
+    """Compiled text of a data-parallel step over four described chips:
+    three dense layers wide enough that a gradient is worth hiding, `wfbp`
+    (one all-reduce a leaf), bf16 compute. Compiles in seconds."""
+    import flax.linen as nn
+
+    from mgwfbp_tpu.models import ModelMeta
+
+    width = 2048
+
+    class MLP(nn.Module):
+        @nn.compact
+        def __call__(self, x, train=False):
+            for _ in range(3):
+                x = nn.relu(nn.Dense(width)(x))
+            return nn.Dense(10)(x)
+
+    mesh = Mesh(np.asarray(topo.devices).reshape(4), (DATA_AXIS,))
+    model, meta = MLP(), ModelMeta("mlp", "synthetic", 10, (width,))
+    tx = _imagenet_sgd()
+    state, batch = _abstract_step_args(model, meta, tx, mesh, 64)
+    n_leaves = len(jax.tree_util.tree_leaves(state.params))
+    reducer = make_merged_allreduce(
+        state.params, axis_name=DATA_AXIS, policy="wfbp",
+        tb=[1e-3] * n_leaves, cost_model=lookup_alpha_beta("ici", 4),
+    )
+    step = make_train_step(
+        model, meta, tx, mesh, reducer, compute_dtype=jnp.bfloat16,
+        donate=True, health_stats=True,
+    )
+    assert reducer.schedule.num_groups == 8
+    return step.lower(state, batch).compile().as_text()
+
+
+@pytest.mark.parametrize("with_options", [True, False])
+def test_four_chip_step_gets_asynchronous_allreduces(
+    topo, monkeypatch, with_options
+):
+    """ISSUE 29, asked of the chip's compiler: a data-parallel step over
+    four described chips is built with `ASYNC_COLLECTIVE_OPTIONS` (the
+    mesh's reduction axis spans four TPU devices) and the compiled program
+    then holds asynchronous all-reduces; built without them, as before
+    PR 29, every all-reduce is synchronous. Either way there is one
+    all-reduce per merge group and the metrics' own: the ordering token
+    keeps the compiler's combiner off the buckets."""
+    import mgwfbp_tpu.train.step as stepmod
+    from mgwfbp_tpu.profiling import hlo_collective_counts
+
+    mesh = Mesh(np.asarray(topo.devices).reshape(4), (DATA_AXIS,))
+    assert stepmod.async_collective_options(mesh, (DATA_AXIS,)) == dict(
+        stepmod.ASYNC_COLLECTIVE_OPTIONS
+    )
+    assert stepmod.async_collective_options(
+        Mesh(np.asarray(topo.devices[:1]), (DATA_AXIS,)), (DATA_AXIS,)
+    ) == {}
+    if not with_options:
+        monkeypatch.setattr(
+            stepmod, "async_collective_options", lambda mesh, axes: {}
+        )
+    counts = hlo_collective_counts(_four_chip_mlp_step_text(topo))
+    assert counts["collectives"] == 8 + 1
+    if with_options:
+        # the three 16 MB kernels' all-reduces at least; the biases' and the
+        # metrics' scalar are too short to be worth a start/done pair
+        assert counts["async_collectives"] >= 3
+    else:
+        assert counts["async_collectives"] == 0
+
+
+def _gradient_kernels_fed_by_a_reduced_bucket(text):
+    """(n, fed): of the compiled entry computation's `n` kernels that
+    compute a gradient (a fusion under `transpose(jvp(...))/.../dot_general`)
+    the names of those with an operand that depends on a reduced bucket (a
+    gradient all-reduce's result or an async collective's `-done`). The
+    state an `async-collective-start` hands to the kernels that carry its
+    steps is the collective running, not a reduced value, and is not
+    followed."""
+    import re
+
+    lines = text.split("\n")
+    at = next(i for i, line in enumerate(lines) if line.startswith("ENTRY"))
+    fed: dict[str, bool] = {}
+    kernels, hit = 0, []
+    for line in lines[at + 1:]:
+        if line.startswith("}"):
+            break
+        m = re.match(r"^\s*(?:ROOT )?%([\w.\-]+) = .*? ([\w\-]+)\((.*)$", line)
+        if m is None:
+            continue
+        name, opcode, rest = m.groups()
+        op_name = re.search(r'op_name="([^"]*)"', line)
+        op_name = op_name.group(1) if op_name else ""
+        if name.startswith("async-collective-done") or (
+            opcode in ("all-reduce", "all-reduce-done")
+            and "mgwfbp_group" in op_name
+        ):
+            fed[name] = True
+            continue
+        if name.startswith("async-collective-start"):
+            fed[name] = False
+            continue
+        operands = re.findall(r"%([\w.\-]+)", rest.split("), ")[0])
+        fed[name] = any(fed.get(o, False) for o in operands)
+        if (opcode == "fusion" and "transpose(jvp" in op_name
+                and "dot_general" in op_name):
+            kernels += 1
+            if fed[name]:
+                hit.append(name)
+    return kernels, hit
+
+
+def test_no_gradient_kernel_waits_for_a_reduced_bucket(topo, monkeypatch):
+    """What the one-element token is for, read off the chip compiler's
+    program: no kernel of the backward pass has an operand that depends on
+    a reduced bucket, so an asynchronous all-reduce holds up nothing but
+    the next all-reduce. Under the whole-bucket token (the parent's) the
+    compiler fuses the token's add into the kernels that compute the
+    weight gradients and they do wait (four-chip VGG-16: 15 of 31 gradient
+    kernels, PERF.md, PR 29)."""
+    from mgwfbp_tpu.parallel import allreduce
+
+    kernels, hit = _gradient_kernels_fed_by_a_reduced_bucket(
+        _four_chip_mlp_step_text(topo))
+    assert kernels >= 6 and hit == []
+    monkeypatch.setattr(allreduce, "_order_after", allreduce._chain_token)
+    kernels, hit = _gradient_kernels_fed_by_a_reduced_bucket(
+        _four_chip_mlp_step_text(topo))
+    assert kernels >= 6 and len(hit) >= 2

@@ -149,6 +149,13 @@ def format_report(records: list[dict]) -> str:
     )
 
     steps = events_of(records, "step")
+    for prog in events_of(records, "step_program"):
+        lines.append(
+            f"step program (built by step {prog.get('step')}): "
+            f"{prog.get('collectives')} collectives, "
+            f"{prog.get('async_collectives')} asynchronous; compile options: "
+            + (", ".join(prog.get("compiler_options") or ()) or "none")
+        )
     if steps:
         durs = [float(s["dur_s"]) for s in steps]
         lines.append("")
@@ -751,6 +758,10 @@ def _synthetic_stream(path: str) -> None:
                phases={"wait": [t - 0.003, 0.002], "place": [t - 0.001, 0.001],
                        "guard": [t + 0.004, 0.040]},
                ready=0 if i % 4 == 0 else 2, native=int(i != 5), lowered=0)
+    # the built step program's collectives, as Trainer._note_step_program
+    # records them after the first dispatch
+    w.emit("step_program", step=1, collectives=33, async_collectives=5,
+           compiler_options=["xla_enable_async_all_reduce"])
     hidden = sum(r.hidden_s for r in rows)
     total = sum(r.comm_s for r in rows)
     w.emit(
@@ -838,6 +849,11 @@ def selftest() -> int:
         assert (
             "native transform: the pool's batches came from the native "
             "pass on 23 of 24 steps (share 0.958)" in report
+        ), report
+        # ISSUE 29: how many of the step's collectives are asynchronous
+        assert (
+            "step program (built by step 1): 33 collectives, 5 asynchronous; "
+            "compile options: xla_enable_async_all_reduce" in report
         ), report
         # ISSUE 16: the save-duration trend section renders, async saves
         # are marked in the lifecycle, and the save whose payload write
