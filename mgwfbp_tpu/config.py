@@ -35,7 +35,7 @@ class TrainConfig:
     num_steps: Optional[int] = None  # LM window length override (default 35;
     # seq-parallel transformers need num_steps % seq_parallel == 0)
     # the part of a model this chip holds (models that can be held in part:
-    # the mellum2 family). None = all of it
+    # the mellum2 and granite4h families). None = all of it
     layers_held: Optional[int] = None  # the first n layers
     experts_held: Optional[str] = None  # "first:count" of each layer's experts
     vocab_size: Optional[int] = None  # `tokens` dataset: ids 0..n-1, and so
@@ -197,6 +197,15 @@ PRESETS: dict[str, dict] = {
                          lr=3e-3, max_epochs=40, lr_schedule="cosine",
                          optimizer="adamw", adam_b2=0.95, weight_decay=0.1,
                          norm_clip=1.0, vocab_size=256),
+    # hybrid state-space decoder LM (models/granite.py): mellum2's recipe
+    # (none of it published) at one sequence of 8,192 tokens a device
+    "granite4h": dict(dataset="tokens", batch_size=1, num_steps=8192, lr=3e-4,
+                      max_epochs=40, lr_schedule="cosine", optimizer="adamw",
+                      adam_b2=0.95, weight_decay=0.1, norm_clip=1.0),
+    "granite4h_tiny": dict(dataset="tokens", batch_size=2, num_steps=64,
+                           lr=3e-3, max_epochs=40, lr_schedule="cosine",
+                           optimizer="adamw", adam_b2=0.95, weight_decay=0.1,
+                           norm_clip=1.0, vocab_size=256),
     "fcn5net": dict(dataset="mnist", batch_size=64, lr=0.05, max_epochs=10),
     "lr": dict(dataset="mnist", batch_size=64, lr=0.01, max_epochs=10),
 }

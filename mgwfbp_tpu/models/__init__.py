@@ -339,6 +339,16 @@ def _lstman4(nc):
     )
 
 
+def _held_lm_meta(name: str, nc: int, window_len: int) -> ModelMeta:
+    """A decoder LM over the `tokens` dataset that takes its loss itself and
+    can be held in part (the mellum2 and granite4h families)."""
+    return ModelMeta(
+        name=name, dataset="tokens", num_classes=nc,
+        input_shape=(window_len,), input_dtype=jnp.int32, task="lm",
+        has_carry=False, fused_loss=True,
+    )
+
+
 def _register_mellum2(name: str, shape_name: str, window_len: int):
     @register(name)
     def _factory(nc, layers_held=None, experts_held=None):
@@ -351,11 +361,7 @@ def _register_mellum2(name: str, shape_name: str, window_len: int):
                 vocab_size=nc, shape=shape, layers_held=layers_held,
                 experts_held=experts_held or (0, shape.num_experts),
             ),
-            ModelMeta(
-                name=name, dataset="tokens", num_classes=nc,
-                input_shape=(window_len,), input_dtype=jnp.int32, task="lm",
-                has_carry=False, fused_loss=True,
-            ),
+            _held_lm_meta(name, nc, window_len),
         )
 
     _TAKES_SHARE.add(name)
@@ -365,3 +371,28 @@ def _register_mellum2(name: str, shape_name: str, window_len: int):
 # hold (hidden 64, 2 key/value heads, 8 experts top 2, window 16)
 _register_mellum2("mellum2", "MELLUM2", 8192)
 _register_mellum2("mellum2_tiny", "MELLUM2_TINY", 64)
+
+
+def _register_granite4h(name: str, shape_name: str, window_len: int):
+    @register(name)
+    def _factory(nc, layers_held=None, experts_held=None):
+        from mgwfbp_tpu.models import granite
+
+        if experts_held is not None:
+            raise ValueError(
+                f"model {name!r} is dense: it has no experts to hold in part")
+        shape = getattr(granite, shape_name)
+        nc = nc or shape.vocab_size
+        return (
+            granite.Granite4HLM(
+                vocab_size=nc, shape=shape, layers_held=layers_held),
+            _held_lm_meta(name, nc, window_len),
+        )
+
+    _TAKES_SHARE.add(name)
+
+
+# granite-4.0-h-micro at its published widths; and at a size the CPU tests
+# hold (hidden 32, 4 Mamba heads of 16, state 8, chunk 16, four layers)
+_register_granite4h("granite4h", "GRANITE4H", 8192)
+_register_granite4h("granite4h_tiny", "GRANITE4H_TINY", 64)

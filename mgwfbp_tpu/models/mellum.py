@@ -296,18 +296,21 @@ def token_losses(h: jax.Array, head: jax.Array, targets: jax.Array,
 class _Leaves(nn.Module):
     """Declares a group of parameters and hands them back as a dict."""
 
-    shapes: tuple  # ((name, shape, is_norm), ...)
+    # ((name, shape, init), ...): True a norm's scale (ones), False a weight
+    # (normal 0.02), or an initializer of the leaf's own
+    shapes: tuple
 
     @nn.compact
     def __call__(self) -> dict:
         return {
             name: self.param(
                 name,
-                nn.initializers.ones if is_norm
+                init if callable(init)
+                else nn.initializers.ones if init
                 else nn.initializers.normal(0.02),
                 shape,
             )
-            for name, shape, is_norm in self.shapes
+            for name, shape, init in self.shapes
         }
 
 
@@ -326,9 +329,28 @@ class Mellum2LM(nn.Module):
     attn_block: int = 512  # queries a block; a window layer takes fewer
     loss_block: int = 2048
 
+    # what `__call__` puts among the step's metrics, and `step_counters`
+    # takes back on the host (Trainer._drain_health)
+    health_keys = (MOE_TOKENS_KEY, MOE_DROPPED_KEY)
+
     def layer_kinds(self) -> tuple[str, ...]:
         kinds = self.shape.layer_types
         return kinds if self.layers_held is None else kinds[: self.layers_held]
+
+    def step_counters(self, stats: dict, *, tokens: int) -> dict:
+        """The `step` record's routing counters from one step's statistics
+        as host arrays; `tokens` one device's tokens a (micro-)step, so
+        tokens x experts a token are a layer's assignments."""
+        held = stats[MOE_TOKENS_KEY]  # (layers held, experts held)
+        worst = int(held.max(axis=1).argmax())  # the layer of the fullest
+        return {
+            "moe_here": float(
+                held.sum(axis=1).mean()
+                / (tokens * self.shape.experts_per_token)),
+            "moe_load_max": float(held[worst].max()),
+            "moe_load_mean": float(held[worst].mean()),
+            "moe_dropped": float(stats[MOE_DROPPED_KEY]),
+        }
 
     @nn.compact
     def __call__(self, x: jax.Array, targets: Optional[jax.Array] = None,

@@ -48,9 +48,11 @@ def key_range(start: int, stop: int, window: Optional[int]) -> tuple[int, int]:
     return lo - lo % _ALIGN, stop
 
 
-def _one_block(q, k, v, q_start: int, k_start: int, window: Optional[int]):
+def _one_block(q, k, v, q_start: int, k_start: int, window: Optional[int],
+               scale: float):
     """q (B, Hkv, G, Tq, D) against k, v (B, Hkv, Tk, D): (B, Hkv, G, Tq, D).
-    `q_start`, `k_start`: the global positions of the first query and key.
+    `q_start`, `k_start`: the global positions of the first query and key;
+    `scale` multiplies the scores.
     A key head's G query heads are rows of ONE (G * Tq, D) x (D, Tk) product
     per (batch, key head): as a five-dimensional einsum with the group as an
     output dimension of its own, the TPU compiler lays the scores out with
@@ -59,7 +61,7 @@ def _one_block(q, k, v, q_start: int, k_start: int, window: Optional[int]):
     s = jnp.einsum(
         "bhmd,bhkd->bhmk", q.reshape(b, hkv, g * tq, d), k,
         preferred_element_type=jnp.float32,
-    ) * (1.0 / math.sqrt(d))
+    ) * scale
     qi = q_start + jnp.arange(tq)[:, None]
     kj = k_start + jnp.arange(k.shape[2])[None, :]
     mask = kj <= qi
@@ -88,11 +90,13 @@ def _one_block(q, k, v, q_start: int, k_start: int, window: Optional[int]):
 def blockwise_attention(
     q: jax.Array, k: jax.Array, v: jax.Array, *,
     window: Optional[int] = None, block: int = 512,
+    scale: Optional[float] = None,
 ) -> jax.Array:
     """Causal (optionally windowed) attention. q: (B, T, H, D); k, v:
     (B, T, Hkv, D) with H a multiple of Hkv, query head i served by key head
     i // (H / Hkv). Returns (B, T, H, D) in q's dtype. T need not be a
-    multiple of `block`: the last block is shorter."""
+    multiple of `block`: the last block is shorter. `scale` multiplies the
+    scores before the softmax: 1 / sqrt(D) unless a model publishes its own."""
     b, t, h, d = q.shape
     hkv = k.shape[2]
     if h % hkv:
@@ -100,12 +104,13 @@ def blockwise_attention(
     # heads before positions, once for all blocks
     q = q.reshape(b, t, hkv, h // hkv, d).transpose(0, 2, 3, 1, 4)
     k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
-    one = jax.checkpoint(_one_block, static_argnums=(3, 4, 5))
+    scale = 1.0 / math.sqrt(d) if scale is None else float(scale)
+    one = jax.checkpoint(_one_block, static_argnums=(3, 4, 5, 6))
     out = []
     for start in range(0, t, block):
         stop = min(start + block, t)
         lo, hi = key_range(start, stop, window)
         out.append(one(q[:, :, :, start:stop], k[:, :, lo:hi], v[:, :, lo:hi],
-                       start, lo, window))
+                       start, lo, window, scale))
     out = jnp.concatenate(out, axis=3)  # (B, Hkv, G, T, D)
     return out.transpose(0, 3, 1, 2, 4).reshape(b, t, h, d)

@@ -50,15 +50,20 @@ the first of a run or an epoch, and all but every N-th with
 drains twice), read by `tools/telemetry_report.py`. Where the guard ran
 first it has waited for that step and this reads 1; with `--no-grad-guard`
 the `health` span is where the host waits for the chip, and it reads 0.
-A sparse-expert model (models/mellum.py) adds, by the same road and for the
-same step (the one BEFORE the record's own): `moe_here`, the share of the
+A model that declares statistics of its own (`health_keys` and
+`step_counters`) adds counters by the same road and for the same step (the
+one BEFORE the record's own). models/mellum.py: `moe_here`, the share of the
 step's (token, expert) assignments that landed on experts held here, mean
 over the held layers; `moe_load_max` and `moe_load_mean`, the tokens on the
 fullest held expert and on the average one, in the layer whose fullest is
 the fullest; `moe_dropped`, assignments to a held expert that were not
 computed (0: the layer is dropless). Read by `moe_here_share`,
 `moe_load_imbalance`, `moe_dropped` under benchmarks/layer_metrics and by
-`tools/telemetry_report.py`.
+`tools/telemetry_report.py`. models/granite.py: `ssm_state_rms`, the root
+mean square of the scan's state after a sequence's last position, mean over
+the Mamba layers held, and `ssm_log_decay_min`, the most negative sum of
+log-decays over one chunk of the scan (any head, any layer); read by the
+reader files of the same names.
 
 The host runs about one step ahead of the chip: no span after the dispatch
 of step k needs step k itself. What stops it is the first read of step
@@ -202,24 +207,16 @@ class PhaseRecorder:
             record["stats_ready"] = min(
                 record.get("stats_ready", 1), int(ready))
 
-    def routing(self, tokens, dropped: float, assignments: int) -> None:
-        """Routing counters of a sparse-expert model's step, from the arrays
-        the health drain just read: `tokens` (layers, experts held) the
-        tokens each held expert took, `dropped` the assignments to a held
-        expert that were not computed, `assignments` tokens x experts a
-        token of one layer. They go on the record in whose aftermath the
-        drain runs, as `stats_ready` does (so they describe the step before
-        it); a second drain of the same aftermath (an epoch's last step)
-        overwrites the first's."""
+    def counters(self, fields: dict) -> None:
+        """A model's own counters for a step (`step_counters` of
+        models/mellum.py, models/granite.py), from the arrays the health
+        drain just read. They go on the record in whose aftermath the drain
+        runs, as `stats_ready` does (so they describe the step before it); a
+        second drain of the same aftermath (an epoch's last step) overwrites
+        the first's."""
         record = self._record
-        if record is None:
-            return
-        per_layer = tokens.sum(axis=1)
-        worst = int(tokens.max(axis=1).argmax())  # the layer of the fullest
-        record["moe_here"] = float(per_layer.mean() / assignments)
-        record["moe_load_max"] = float(tokens[worst].max())
-        record["moe_load_mean"] = float(tokens[worst].mean())
-        record["moe_dropped"] = float(dropped)
+        if record is not None:
+            record.update(fields)
 
     def batches(self, loader, entered_s: float) -> Iterator:
         """The loader's batches, each `next` inside a `wait` span; `restart`

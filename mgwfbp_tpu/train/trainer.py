@@ -1391,15 +1391,6 @@ class Trainer:
             for _ in range(len(self._pending_health) - 1)
         ])
 
-    @property
-    def _moe_assignments(self) -> int:
-        """(token, expert) assignments of one layer in one device's
-        (micro-)step of a sparse-expert model."""
-        return (
-            self.config.batch_size * self.meta.input_shape[0]
-            * self.model.shape.experts_per_token
-        )
-
     def _drain_health_flags(self) -> None:
         items = list(self._pending_health)
         self._pending_health.clear()
@@ -1411,7 +1402,6 @@ class Trainer:
         the per-group key set between two steps), on the host."""
         if not items:
             return
-        from mgwfbp_tpu.models.mellum import MOE_DROPPED_KEY, MOE_TOKENS_KEY
         from mgwfbp_tpu.train.step import HEALTH_PREFIX
 
         if self._phase_rec is not None:
@@ -1423,17 +1413,23 @@ class Trainer:
             ))
         g_prefix = f"{HEALTH_PREFIX}gnorm_g"
         c_prefix = f"{HEALTH_PREFIX}comp_err_g"
+        # a model's own statistics (routing counts, a scan's state): the
+        # keys it declares, arrays among them, and no part of the `health`
+        # record; the model turns them into counters on the step record
+        own_keys = getattr(self.model, "health_keys", ())
         for it, ep, d in items:
-            if MOE_TOKENS_KEY in d:
-                # a sparse-expert model's routing counts: arrays, and no
-                # part of the `health` record; counters on the step record
-                d = dict(d)
-                tokens = np.asarray(d.pop(MOE_TOKENS_KEY), dtype=np.float64)
-                dropped = float(np.asarray(d.pop(MOE_DROPPED_KEY)))
+            own = {
+                k: np.asarray(d[k], dtype=np.float64)
+                for k in own_keys if k in d
+            }
+            if own:
+                d = {k: v for k, v in d.items() if k not in own}
                 if self._phase_rec is not None:
-                    self._phase_rec.routing(
-                        tokens, dropped, self._moe_assignments
-                    )
+                    self._phase_rec.counters(self.model.step_counters(
+                        own,
+                        tokens=self.config.batch_size
+                        * self.meta.input_shape[0],
+                    ))
             vals = {
                 k: float(np.asarray(d[k], dtype=np.float32))
                 for k in sorted(d)

@@ -102,7 +102,8 @@ def build_parser() -> argparse.ArgumentParser:
                    help="LM window length (must divide by --seq-parallel)")
     p.add_argument("--layers-held", dest="layers_held", type=int, default=None,
                    help="hold only the first N layers of a model that can be "
-                        "held in part (mellum2): one pipeline stage's share")
+                        "held in part (mellum2, granite4h): one pipeline "
+                        "stage's share")
     p.add_argument("--experts-held", dest="experts_held", default=None,
                    metavar="FIRST:COUNT",
                    help="hold only COUNT experts of every sparse layer, "

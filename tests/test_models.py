@@ -22,7 +22,8 @@ def _example_input(meta, batch=2):
 
 ALL_IMAGE_MODELS = [
     n for n in zoo.model_names()
-    if n not in ("lstm", "lstman4", "transformer", "mellum2", "mellum2_tiny")
+    if n not in ("lstm", "lstman4", "transformer", "mellum2", "mellum2_tiny",
+                 "granite4h", "granite4h_tiny")
 ]
 
 
@@ -66,6 +67,35 @@ def test_mellum2_traces_and_counts_its_parameters(name, share, want):
             lambda v: model.apply(v, x, targets=x, train=True), variables)
         assert per_token.shape == (2, 64)
         assert stats["health/moe_tokens"].shape == (4, 2)
+
+
+@pytest.mark.parametrize("name,share,want,layers", [
+    # the whole model: 36 Mamba and 4 attention layers, the tied embedding
+    ("granite4h", {}, 3_191_396_096, 40),
+    # one chip's share: one period of the pattern, an eighth of the ids
+    ("granite4h", dict(num_classes=12544, layers_held=10), 772_160_448, 10),
+    ("granite4h_tiny", {}, None, 4),
+])
+def test_granite4h_traces_and_counts_its_parameters(name, share, want, layers):
+    model, meta = zoo.create_model(name, **share)
+    assert meta.task == "lm" and not meta.has_carry and meta.fused_loss
+    x = _example_input(meta)
+    variables = jax.eval_shape(
+        lambda: model.init({"params": jax.random.PRNGKey(0)}, x, train=False))
+    params = variables["params"]
+    if want is not None:
+        assert sum(int(np.prod(leaf.shape))
+                   for leaf in jax.tree_util.tree_leaves(params)) == want
+    assert len([k for k in params if k.startswith("layer_")]) == layers
+    assert set(params["out"]) == {"norm"}  # no head: the embedding is tied
+    if name == "granite4h_tiny":
+        logits = jax.eval_shape(lambda v: model.apply(v, x), variables)
+        assert logits.shape == (2, 64, meta.num_classes)
+        per_token, stats = jax.eval_shape(
+            lambda v: model.apply(v, x, targets=x, train=True), variables)
+        assert per_token.shape == (2, 64)
+        assert stats["health/ssm_state"].shape == (3,)  # the Mamba layers
+        assert stats["health/ssm_log_decay_min"].shape == (3,)
 
 
 @pytest.mark.parametrize(

@@ -477,23 +477,33 @@ def test_a_loop_stopped_inside_tail_leaves_the_step_in_flight_on_the_stream(
 
 
 def test_routing_counters_ride_the_record_and_the_report_prints_them(stream):
-    """PhaseRecorder.routing puts a sparse-expert model's counters on the
-    record in whose aftermath the drain ran; a stream without them (every
-    other model's) prints no routing line."""
+    """PhaseRecorder.counters puts a model's own counters (here the
+    sparse-expert model's, from its `step_counters`) on the record in whose
+    aftermath the drain ran; a stream without them (every other model's)
+    prints no routing line."""
     import numpy as np
     import telemetry_report
 
+    from mgwfbp_tpu.models import mellum
     from mgwfbp_tpu.telemetry.phases import PhaseRecorder
+
+    model = mellum.Mellum2LM(shape=mellum.MELLUM2_TINY)  # top 2 a token
+
+    def routing(tokens, dropped, assignments):
+        return model.step_counters(
+            {mellum.MOE_TOKENS_KEY: tokens,
+             mellum.MOE_DROPPED_KEY: np.asarray(dropped)},
+            tokens=assignments // 2)
 
     assert "expert routing" not in telemetry_report.format_report(stream)
     written = []
     clock = iter(float(i) for i in range(100))
     rec = PhaseRecorder(lambda: next(clock), lambda **f: written.append(f))
-    rec.routing(np.ones((2, 2)), 0.0, 8)  # before any dispatch: dropped
+    rec.counters(routing(np.ones((2, 2)), 0.0, 8))  # before any dispatch: dropped
     rec.dispatched(1, 0, 0.0, 0.1)
     rec.add("guard", 0.2, 0.1)
     # two layers, four held experts; 16 tokens x top 2 = 32 assignments
-    rec.routing(np.array([[2.0, 2, 2, 2], [1.0, 9, 1, 1]]), 0.0, 32)
+    rec.counters(routing(np.array([[2.0, 2, 2, 2], [1.0, 9, 1, 1]]), 0.0, 32))
     rec.dispatched(2, 0, 1.0, 0.1)
     rec.flush()
     first, second = written

@@ -58,3 +58,36 @@ def test_join_and_split():
 ])
 def test_classify(op_name, want):
     assert trace_scopes.classify(op_name, SCOPES) == want
+
+
+GRANITE_SCOPES = [
+    "ssm_in_proj", "ssm_conv", "ssm_scan", "ssm_gate_norm", "ssm_out_proj",
+    "mlp", "attn_full", "attn_proj", "lm_head", "loss",
+]
+
+
+@pytest.mark.parametrize("op_name,want", [
+    # the hybrid state-space decoder's scopes (models/granite.py): each layer
+    # is under jax.checkpoint, the scan's chunk blocks in a checkpointed loop
+    ("jit(step)/jvp(Granite4HLM)/checkpoint/ssm_in_proj/dot_general",
+     ("ssm_in_proj", "forward")),
+    ("jit(step)/transpose(jvp(Granite4HLM))/checkpoint/rematted_computation/"
+     "ssm_scan/while/body/checkpoint/rematted_computation/exp",
+     ("ssm_scan", "backward")),
+    ("jit(step)/jvp(Granite4HLM)/checkpoint/ssm_conv/mul", ("ssm_conv", "forward")),
+    ("jit(step)/transpose(jvp(Granite4HLM))/checkpoint/ssm_gate_norm/rsqrt",
+     ("ssm_gate_norm", "backward")),
+    ("jit(step)/jvp(Granite4HLM)/checkpoint/ssm_out_proj/dot_general",
+     ("ssm_out_proj", "forward")),
+    ("jit(step)/transpose(jvp(Granite4HLM))/checkpoint/mlp/dot_general",
+     ("mlp", "backward")),
+    ("jit(step)/jvp(Granite4HLM)/checkpoint/attn_full/checkpoint/exp",
+     ("attn_full", "forward")),
+    ("jit(step)/jvp(Granite4HLM)/while/body/checkpoint/lm_head/dot_general",
+     ("lm_head", "forward")),
+    # the scan's name inside another word is no scope
+    ("jit(step)/jvp(Granite4HLM)/not_ssm_scan_really/x",
+     ("(model, no scope)", "forward")),
+])
+def test_classify_the_state_space_scopes(op_name, want):
+    assert trace_scopes.classify(op_name, GRANITE_SCOPES) == want

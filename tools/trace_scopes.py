@@ -5,6 +5,10 @@
         --hlo <compiled HLO text of the step> --steps N \
         --scopes attn_window,attn_full,attn_proj,moe_route,moe_experts,lm_head,loss
 
+(models/mellum.py's scopes; models/granite.py's are ssm_in_proj, ssm_conv,
+ssm_scan, ssm_gate_norm, ssm_out_proj, mlp, attn_full, attn_proj, lm_head,
+loss.)
+
 A TPU trace names each event of the `XLA Ops` line after the HLO instruction
 it ran; the compiled module's per-instruction `op_name` metadata still carries
 the name stack (`jit(step)/jvp(Model)/attn_window/...`, with `transpose(` on
