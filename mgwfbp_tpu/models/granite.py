@@ -33,7 +33,7 @@ share out.
 **Memory.** Every layer is under `jax.checkpoint`: what the forward pass
 keeps per layer is the residual stream, and the backward pass recomputes a
 layer before it differentiates it. Inside, the scan recomputes its blocks of
-chunks, the attention core its query blocks, and the loss its token blocks
+chunks, the attention core keeps no scores (ops/blockattn.py), and the loss recomputes its token blocks
 (`mellum.token_losses`), so neither a (chunk, chunk) decay matrix per head
 and chunk, nor a (T, T) score matrix, nor (tokens, vocabulary) logits
 outlive their block.

@@ -31,7 +31,8 @@ TPU's grouped product skips the rest). Taking the tokens a chunk at a time
 saved no memory and made the grouped products a half slower (224 against 150
 ms a step, my chip runs, PR 26), so there is one group per expert and step.
 
-**Memory.** The attention core recomputes its blocks (ops/blockattn.py), the
+**Memory.** The attention core keeps no scores (ops/blockattn.py: the fused
+kernel saves its output and a row log-sum-exp, the plain blocks recompute), the
 experts recompute their sorted rows and products, and with `targets` the loss is taken over
 `loss_block` tokens at a time, each block's logits recomputed in the backward
 pass: no (tokens, vocabulary) array outlives a block. What is saved per layer
@@ -150,7 +151,12 @@ def attention(p: dict, x: jax.Array, shape: MellumShape, kind: str,
         k = (x @ p["wk"]).reshape(b, t, shape.num_kv_heads, hd)
         v = (x @ p["wv"]).reshape(b, t, shape.num_kv_heads, hd)
         inv_freq, factor = rope_inv_freq(shape, kind)
-        q = apply_rope(q, inv_freq, factor)
+        # the scores' 1 / sqrt(D) rides on q's rotation, which is float32
+        # inside, so q is rounded to the compute dtype once: the fused
+        # kernel takes its scale folded into q, and 2 ** -3.5 applied to a
+        # rounded q rounds it again (first_loss_rel 2.1e-5 against 1.4e-5
+        # on one seed, the plain blocks 1.5e-5; my chip runs, PR 31)
+        q = apply_rope(q, inv_freq, factor * hd ** -0.5)
         k = apply_rope(k, inv_freq, factor)
     window = shape.sliding_window if kind == SLIDING else None
     if window is not None:
@@ -160,7 +166,8 @@ def attention(p: dict, x: jax.Array, shape: MellumShape, kind: str,
         # backward, at 256 and 512 of 1,024; my chip run, PR 26)
         block = min(block, max(window // 4, 1))
     with jax.named_scope("attn_window" if kind == SLIDING else "attn_full"):
-        a = blockwise_attention(q, k, v, window=window, block=block)
+        a = blockwise_attention(
+            q, k, v, window=window, block=block, scale=1.0)
     with jax.named_scope("attn_proj"):
         return a.reshape(b, t, shape.num_heads * hd) @ p["wo"]
 

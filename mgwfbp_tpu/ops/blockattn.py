@@ -1,10 +1,24 @@
-"""Causal attention in query blocks against static key ranges: trainable at
-sequence lengths whose (T, T) score matrix does not fit.
+"""The attention core (scores, mask, softmax, values) of the language models:
+causal, optionally a sliding window, grouped-query heads, trainable at sequence
+lengths whose (T, T) score matrix does not fit. One entry point,
+`blockwise_attention`, and two ways down from it.
 
-`models/transformer.py`'s dense path holds a (B, H, T, T) float32 array (8.6
-GB a sequence at T 8,192 with 32 heads) and `ops/flashattn.py`'s Pallas kernel
-has no backward. Here the queries are cut into blocks of `block` positions and
-each block is scored against the one static slice of keys it can see:
+**The fused kernel.** Where the step is traced for a TPU and the shape fits
+(`_kernel_tiles`), the core is the installed
+`jax.experimental.pallas.ops.tpu.splash_attention`: one Pallas kernel forward
+and its backward kernels under a `custom_vjp`, whose score tiles live in VMEM
+and never reach HBM. The backward recomputes the probabilities from the saved
+row log-sum-exp. `scale` is folded into `q` (in float32, then back to q's
+dtype), the batch into the heads (query head b * H + h is served by key head
+b * Hkv + h // G, which is the kernel's own grouping), and the kernel object,
+whose mask information is host work, is built once per (T, heads, window,
+tiles) and cached. The tiles are constants chosen from the shape, fitted on a
+v5e from the trace by scope (PERF.md section 6, PR 31).
+
+**The plain blocks.** Everywhere else (the CPU, a T the tiles do not divide,
+a head size other than 64 or 128), and as the kernel's reference: the queries
+are cut into blocks of `block` positions and each block is scored against the
+one static slice of keys it can see:
 
   * full causal attention: keys [0, end of the block), so the blocks above
     the diagonal are never computed;
@@ -15,20 +29,23 @@ each block is scored against the one static slice of keys it can see:
 
 Every block is plain `jax.numpy` under `jax.checkpoint`: the forward keeps a
 block's output and nothing of its scores, the backward recomputes the block's
-probabilities. No (T, T) array exists in either pass; the largest temporary is
-one block's (B, H, block, range) float32 scores. Grouped-query attention is
-native: `k`/`v` carry fewer heads than `q` and each serves a group of
-consecutive query heads. Scores and the softmax are float32 whatever the
-operands' dtype; the exponentials are cast to the values' dtype for the
-second product (as the other attention paths of this repo cast their
-probabilities) and the row sums divide its float32 result.
+probabilities. The largest temporary is one block's (B, H, block, range)
+float32 scores, in HBM.
 
-Written for one device's whole sequence. No Pallas: giving the kernel a
-backward and a window is a later PR's, measured against this.
+Both: scores and the softmax are float32 whatever the operands' dtype, every
+causal pair is computed, and no (T, T) array exists in either pass. The blocks
+cast the exponentials to the values' dtype for the second product; the
+kernel's forward keeps them float32 there (its backward casts them).
+
+`LOWERED` counts, as programs are traced, how many calls went each way:
+`make_train_step` reads it round the trace of its step, for the Trainer's
+`attention_program` telemetry record. Written for one device's whole sequence.
 """
 
 from __future__ import annotations
 
+import collections
+import functools
 import math
 from typing import Optional
 
@@ -38,6 +55,20 @@ from jax import lax
 
 _NEG_INF = -1e30  # finite mask value, as parallel/ringattn.py
 _ALIGN = 128  # key ranges start on a lane-tile boundary
+
+# calls of `blockwise_attention` traced so far, by the way they went down
+LOWERED = collections.Counter(kernel=0, blocks=0)
+
+# The fused kernel's tiles as (tile, keys of a tile taken at a time, fused
+# backward), fitted on a v5e at T 8,192 (PERF.md section 6, PR 31). One tile
+# serves all the kernels. Fused backward: dq comes out of the dk/dv kernel, a
+# partial sum per key tile added up outside it, instead of a kernel of its own
+# that computes the probabilities again. A window layer's tiles overhang its
+# band, so it takes smaller ones (fitted at a window of 1,024), and its
+# partial sums cost more than the second kernel.
+_TILES_FULL = (1024, 512, True)
+_TILES_WINDOW = (512, 512, False)
+_KERNEL_HEAD_DIMS = (64, 128)  # half a lane tile, and a whole one
 
 
 def key_range(start: int, stop: int, window: Optional[int]) -> tuple[int, int]:
@@ -87,24 +118,13 @@ def _one_block(q, k, v, q_start: int, k_start: int, window: Optional[int],
     return out.reshape(b, hkv, g, tq, d)
 
 
-def blockwise_attention(
-    q: jax.Array, k: jax.Array, v: jax.Array, *,
-    window: Optional[int] = None, block: int = 512,
-    scale: Optional[float] = None,
-) -> jax.Array:
-    """Causal (optionally windowed) attention. q: (B, T, H, D); k, v:
-    (B, T, Hkv, D) with H a multiple of Hkv, query head i served by key head
-    i // (H / Hkv). Returns (B, T, H, D) in q's dtype. T need not be a
-    multiple of `block`: the last block is shorter. `scale` multiplies the
-    scores before the softmax: 1 / sqrt(D) unless a model publishes its own."""
+def _blocks(q, k, v, window: Optional[int], block: int, scale: float):
+    """The plain blocks: q (B, T, H, D), k and v (B, T, Hkv, D)."""
     b, t, h, d = q.shape
     hkv = k.shape[2]
-    if h % hkv:
-        raise ValueError(f"{h} query heads do not divide over {hkv} key heads")
     # heads before positions, once for all blocks
     q = q.reshape(b, t, hkv, h // hkv, d).transpose(0, 2, 3, 1, 4)
     k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
-    scale = 1.0 / math.sqrt(d) if scale is None else float(scale)
     one = jax.checkpoint(_one_block, static_argnums=(3, 4, 5, 6))
     out = []
     for start in range(0, t, block):
@@ -114,3 +134,95 @@ def blockwise_attention(
                        start, lo, window, scale))
     out = jnp.concatenate(out, axis=3)  # (B, Hkv, G, T, D)
     return out.transpose(0, 3, 1, 2, 4).reshape(b, t, h, d)
+
+
+def _traced_for_tpu() -> bool:
+    """Whether what is traced now will be lowered for a TPU: this package
+    builds its meshes from the default backend's devices."""
+    return jax.default_backend() == "tpu"
+
+
+def _splash():
+    """The library, imported where a kernel is wanted: a second of imports
+    that the CPU and the models without attention never pay."""
+    from jax.experimental.pallas.ops.tpu import splash_attention
+
+    return splash_attention
+
+
+def _kernel_tiles(t: int, d: int, window: Optional[int]):
+    """The fused kernel's tiles (splash_attention's BlockSizes) for a call of
+    this shape, each cut to T where T is shorter, or None where the plain
+    blocks stay: a head size the kernel has no tile for, or a T that its
+    tiles do not divide."""
+    block, compute, fused = _TILES_FULL if window is None else _TILES_WINDOW
+    block, compute = min(block, t), min(compute, t)
+    if d not in _KERNEL_HEAD_DIMS or t % _ALIGN or t % block or block % compute:
+        return None
+    return _splash().BlockSizes(
+        block_q=block, block_kv=block, block_kv_compute=compute,
+        block_q_dkv=block, block_kv_dkv=block, block_kv_dkv_compute=compute,
+        **(dict(use_fused_bwd_kernel=True) if fused
+           else dict(block_q_dq=block, block_kv_dq=block)),
+    )
+
+
+@functools.lru_cache(maxsize=None)
+def _splash_kernel(t: int, heads: int, window: Optional[int], tiles,
+                   interpret: bool):
+    """The kernel object for `heads` query heads of T positions: its mask
+    information is host work over (T / tile)^2 entries, so it is built once
+    per shape and never under a trace (its arrays are constants of whichever
+    program closes over it)."""
+    sa = _splash()
+    if window is None:
+        mask = sa.CausalMask((t, t))
+    else:
+        mask = sa.LocalMask((t, t), (window - 1, 0), 0)
+    with jax.ensure_compile_time_eval():
+        return sa.make_splash_mha(
+            sa.MultiHeadMask((mask,) * heads),  # one mask: deduplicated there
+            block_sizes=tiles, head_shards=1, q_seq_shards=1,
+            interpret=interpret,
+        )
+
+
+def _fused(q, k, v, window: Optional[int], scale: float, tiles,
+           interpret: bool = False):
+    """The fused kernel: q (B, T, H, D), k and v (B, T, Hkv, D), `tiles` a
+    BlockSizes. `interpret` runs it without a TPU (the tests' way in)."""
+    b, t, h, d = q.shape
+    hkv = k.shape[2]
+    kernel = _splash_kernel(t, b * h, window, tiles, interpret)
+    # the kernel has no scale of its own: the scores' factor goes into q
+    q = (q.astype(jnp.float32) * scale).astype(q.dtype)
+    out = kernel(
+        q.transpose(0, 2, 1, 3).reshape(b * h, t, d),
+        k.transpose(0, 2, 1, 3).reshape(b * hkv, t, d),
+        v.transpose(0, 2, 1, 3).reshape(b * hkv, t, d),
+    )
+    return out.reshape(b, h, t, d).transpose(0, 2, 1, 3)
+
+
+def blockwise_attention(
+    q: jax.Array, k: jax.Array, v: jax.Array, *,
+    window: Optional[int] = None, block: int = 512,
+    scale: Optional[float] = None,
+) -> jax.Array:
+    """Causal (optionally windowed) attention. q: (B, T, H, D); k, v:
+    (B, T, Hkv, D) with H a multiple of Hkv, query head i served by key head
+    i // (H / Hkv). Returns (B, T, H, D) in q's dtype. `scale` multiplies the
+    scores before the softmax: 1 / sqrt(D) unless a model publishes its own.
+    `block` is the plain blocks' query block (T need not be a multiple of it:
+    the last block is shorter); the fused kernel has its own tiles."""
+    b, t, h, d = q.shape
+    if h % k.shape[2]:
+        raise ValueError(
+            f"{h} query heads do not divide over {k.shape[2]} key heads")
+    scale = 1.0 / math.sqrt(d) if scale is None else float(scale)
+    tiles = _kernel_tiles(t, d, window) if _traced_for_tpu() else None
+    if tiles is None:
+        LOWERED["blocks"] += 1
+        return _blocks(q, k, v, window, block, scale)
+    LOWERED["kernel"] += 1
+    return _fused(q, k, v, window, scale, tiles)

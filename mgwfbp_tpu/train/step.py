@@ -37,6 +37,7 @@ from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from mgwfbp_tpu.models import ModelMeta
+from mgwfbp_tpu.ops.blockattn import LOWERED as ATTENTION_LOWERED
 from mgwfbp_tpu.parallel.allreduce import MergedAllreduce
 from mgwfbp_tpu.parallel.mesh import DATA_AXIS
 
@@ -715,14 +716,30 @@ def make_train_step(
     else:
         # (nsteps, batch, time): batch over data, time over seq
         batch_spec = P(None, data_axes, seq_axis)
+    # filled when the step is traced: how many of the program's attention
+    # cores went through the fused kernel and how many through the plain
+    # blocks (ops/blockattn.py); Trainer records it as `attention_program`
+    attention_calls: dict[str, int] = {}
+
+    def counting_attention(fn):
+        def traced(*args):
+            before = dict(ATTENTION_LOWERED)
+            out = fn(*args)
+            attention_calls.update(
+                (way, n - before[way]) for way, n in ATTENTION_LOWERED.items()
+            )
+            return out
+
+        return traced
+
     if has_carry:
-        fn = shard_map(
+        fn = counting_attention(shard_map(
             per_device,
             mesh=mesh,
             in_specs=(state_spec, batch_spec, P(data_axes)),
             out_specs=(state_spec, P(), P(data_axes)),
             check_vma=False,
-        )
+        ))
 
         @partial(
             jax.jit, donate_argnums=(0, 2) if donate else (),
@@ -731,19 +748,20 @@ def make_train_step(
         def step_lm(state, batch, carry):
             return fn(state, batch, carry)
 
+        step_lm.attention_calls = attention_calls
         return step_lm
 
     def per_device_nocarry(state, batch):
         s, m, _ = per_device(state, batch, None)
         return s, m
 
-    fn = shard_map(
+    fn = counting_attention(shard_map(
         per_device_nocarry,
         mesh=mesh,
         in_specs=(state_spec, batch_spec),
         out_specs=(state_spec, P()),
         check_vma=False,
-    )
+    ))
 
     @partial(
         jax.jit, donate_argnums=(0,) if donate else (),
@@ -752,6 +770,7 @@ def make_train_step(
     def step(state, batch):
         return fn(state, batch)
 
+    step.attention_calls = attention_calls
     return step
 
 

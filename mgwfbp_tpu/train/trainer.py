@@ -673,6 +673,7 @@ class Trainer:
         self._step_program = None
         # a one-device step has no gradient collective to count
         self._step_program_noted = self.data_size * self.seq_size <= 1
+        self._attention_program_noted = False
 
     def _build_run_sinks(self) -> None:
         """(Re)bind every tag-addressed output — log file, checkpoint dir,
@@ -1313,6 +1314,25 @@ class Trainer:
         )
         self._emit_event(
             "step_program", step=int(self.iteration), **self._step_program
+        )
+
+    def _note_attention_program(self) -> None:
+        """Once per step-program build, after its first dispatch: how many
+        of the program's attention cores went through the fused kernel and
+        how many through the plain blocks (ops/blockattn.py chooses by
+        platform and shape), as make_train_step counted them while the
+        step was traced. Nothing is compiled or read from the device."""
+        self._attention_program_noted = True
+        calls = getattr(self.train_step, "attention_calls", None)
+        if not calls:  # not traced through make_train_step's own wrapper
+            return
+        self.log.info(
+            "attention: %d core(s) of the step through the fused kernel, "
+            "%d through the plain blocks", calls["kernel"], calls["blocks"],
+        )
+        self._emit_event(
+            "attention_program", step=int(self.iteration),
+            kernel=int(calls["kernel"]), blocks=int(calls["blocks"]),
         )
 
     def _schedule_state_doc(self) -> dict:
@@ -3175,6 +3195,8 @@ class Trainer:
                 )
             if step_args is not None:
                 self._note_step_program(step_args)
+            if not self._attention_program_noted:
+                self._note_attention_program()
             window_iters += 1
             epoch_steps += 1
             # non-finite guard bookkeeping (one step LATE via the deque, so

@@ -1,11 +1,19 @@
-"""ops/blockattn.py's `scale`: the default is 1 / sqrt(D), and a model's own
-multiplier (granite-4.0-h: 1 / 64 at head size 64, a group of 4 query heads
-a key head) replaces it, value and gradients against dense attention."""
+"""ops/blockattn.py. Its `scale`: the default is 1 / sqrt(D), and a model's
+own multiplier (granite-4.0-h: 1 / 64 at head size 64, a group of 4 query
+heads a key head) replaces it, value and gradients against dense attention.
+Its fused kernel (the installed splash_attention, here in `interpret` mode on
+the CPU) against its plain blocks at the call shapes of both language cells;
+the test of platform and shape that chooses between the two; and the count of
+both that a step program leaves on the telemetry."""
+
+import dataclasses
+import os
 
 import jax
 import jax.numpy as jnp
 import pytest
 
+from mgwfbp_tpu.ops import blockattn
 from mgwfbp_tpu.ops.blockattn import blockwise_attention
 
 
@@ -43,3 +51,178 @@ def test_scale_value_and_gradient_against_dense(scale, want_scale):
     other, _ = through(lambda q, k, v: dense_attention(
         q, k, v, 0.125 if scale else 1.0 / 64))(q, k, v)
     assert abs(float(other) - float(want)) > 1e-3 * abs(float(want))
+
+
+def tiles_of(size, **more):
+    return blockattn._splash().BlockSizes(
+        block_q=size, block_kv=size, block_kv_compute=size, block_q_dkv=size,
+        block_kv_dkv=size, block_kv_dkv_compute=size, **(more or dict(
+            block_q_dq=size, block_kv_dq=size)))
+
+
+@pytest.mark.parametrize("b,h,hkv,d,window,scale,tiles", [
+    (1, 8, 2, 64, None, 1.0 / 64, tiles_of(128)),
+    (1, 8, 1, 128, None, None, tiles_of(128)),
+    (1, 8, 1, 128, 128, None, tiles_of(128)),
+    (2, 4, 2, 128, None, None, tiles_of(256)),
+    (2, 4, 2, 64, 128, None, tiles_of(128, use_fused_bwd_kernel=True)),
+], ids=["d64-full-scale-1/64-groups-of-4", "d128-full", "d128-window-4-long",
+        "batch-2", "batch-2-window-fused-backward"])
+def test_fused_kernel_value_and_gradients_against_the_plain_blocks(
+        b, h, hkv, d, window, scale, tiles):
+    """Float32, so the two differ by rounding order alone; T 512 is four
+    windows and four tiles long. The batch rides in the kernel's heads."""
+    t = 512
+    keys = jax.random.split(jax.random.PRNGKey(11), 4)
+    q = 2.0 * jax.random.normal(keys[0], (b, t, h, d))
+    k = jax.random.normal(keys[1], (b, t, hkv, d))
+    v = jax.random.normal(keys[2], (b, t, hkv, d))
+    w = jax.random.normal(keys[3], (b, t, h, d))
+    scale = d ** -0.5 if scale is None else scale
+
+    def through(fn):
+        return jax.jit(jax.value_and_grad(
+            lambda q, k, v: jnp.sum(jnp.square(fn(q, k, v)) * w),
+            argnums=(0, 1, 2)))
+
+    got, got_grads = through(lambda q, k, v: blockattn._fused(
+        q, k, v, window, scale, tiles, interpret=True))(q, k, v)
+    want, want_grads = through(lambda q, k, v: blockattn._blocks(
+        q, k, v, window, 128, scale))(q, k, v)
+    assert abs(float(got) - float(want)) < 2e-5 * abs(float(want))
+    for g, wg in zip(got_grads, want_grads):
+        assert float(jnp.linalg.norm(g - wg) / jnp.linalg.norm(wg)) < 5e-6
+
+
+def test_the_kernel_object_is_built_once_a_shape_and_outside_the_trace():
+    """Its mask information is host work: the second trace of a shape finds
+    the first one's object, whose arrays are no tracers of the first."""
+    tiles = tiles_of(128)
+    blockattn._splash_kernel.cache_clear()
+    q = jnp.ones((1, 256, 2, 64))
+    for _ in range(2):
+        jax.make_jaxpr(lambda q: blockattn._fused(
+            q, q[:, :, :1], q[:, :, :1], None, 1.0, tiles, interpret=True))(q)
+    info = blockattn._splash_kernel.cache_info()
+    assert (info.misses, info.hits) == (1, 1)
+    kernel = blockattn._splash_kernel(256, 2, None, tiles, True)
+    assert all(isinstance(x, jax.Array) and not isinstance(x, jax.core.Tracer)
+               for x in jax.tree_util.tree_leaves(kernel))
+
+
+@pytest.mark.parametrize("t,d,window,fits", [
+    (8192, 128, None, True), (8192, 64, None, True), (8192, 128, 1024, True),
+    (512, 64, None, True), (8192 + 512, 128, None, False),
+    (640, 64, None, False), (40, 64, None, False), (8192, 96, None, False),
+    (8192, 256, None, False),
+], ids=["mellum2-full", "granite", "mellum2-window", "short", "tiles-overhang",
+        "five-lane-tiles", "no-lane-tile", "head-96", "head-256"])
+def test_shape_test_of_the_kernel(t, d, window, fits):
+    tiles = blockattn._kernel_tiles(t, d, window)
+    assert (tiles is not None) == fits
+    if fits:
+        sizes = [size for name, size in dataclasses.asdict(tiles).items()
+                 if name.startswith("block_") and size is not None]
+        assert all(t % size == 0 and size <= t for size in sizes)
+        # a window layer keeps the dq kernel of its own
+        assert (tiles.block_q_dq is None) == (window is None)
+        assert tiles.use_fused_bwd_kernel == (window is None)
+
+
+def traced_ways(fn, *args):
+    before = dict(blockattn.LOWERED)
+    # a fresh function each time: a cached trace calls nothing and counts none
+    jaxpr = jax.make_jaxpr(lambda *a: fn(*a))(*args)
+    ways = {way: n - before[way] for way, n in blockattn.LOWERED.items()}
+    return ways, "pallas_call" in str(jaxpr)
+
+
+def test_falls_back_to_the_blocks_off_the_tpu_and_on_a_shape_that_misfits(
+        monkeypatch):
+    """The choice is read off the platform traced for and the shape: on this
+    CPU every call takes the blocks; traced as for a TPU, a shape the tiles
+    divide takes the kernel and one they do not divide the blocks."""
+    fits, misfits = jnp.ones((1, 256, 2, 64)), jnp.ones((1, 200, 2, 64))
+
+    def attend(q):
+        return blockwise_attention(q, q[:, :, :1], q[:, :, :1])
+
+    assert not blockattn._traced_for_tpu()
+    assert traced_ways(attend, fits) == ({"kernel": 0, "blocks": 1}, False)
+    monkeypatch.setattr(blockattn, "_traced_for_tpu", lambda: True)
+    assert traced_ways(attend, fits) == ({"kernel": 1, "blocks": 0}, True)
+    assert traced_ways(attend, misfits) == ({"kernel": 0, "blocks": 1}, False)
+
+
+def trainer_of(tmp_path, *flags):
+    from mgwfbp_tpu import train_cli
+    from mgwfbp_tpu.train.trainer import Trainer
+
+    args = train_cli.build_parser().parse_args([
+        *flags, "--dataset", "tokens", "--vocab-size", "256", "--num-steps",
+        "64", "--batch-size", "2", "--lr", "0.01", "--lr-schedule", "const",
+        "--synthetic", "--telemetry", "--no-profile-backward",
+        "--num-batches-per-epoch", "2", "--max-epochs", "1", "--seed", "5",
+        "--logdir", str(tmp_path)])
+    cfg = train_cli.config_from_args(args)
+    return cfg, Trainer(cfg, profile_backward=False, synthetic_data=True)
+
+
+@pytest.mark.parametrize("flags,blocks", [
+    (["--dnn", "mellum2_tiny", "--experts-held", "2:2", "--layers-held", "2"],
+     2),
+    (["--dnn", "granite4h_tiny", "--layers-held", "3"], 1),
+], ids=["mellum2_tiny", "granite4h_tiny"])
+def test_a_step_program_leaves_its_attention_count_on_the_telemetry(
+        tmp_path, monkeypatch, flags, blocks):
+    """One `attention_program` record a built step program, counted while the
+    step was traced: on the CPU every core of the tiny models goes through
+    the blocks. A second epoch runs the same program and adds no record; the
+    report prints the line."""
+    from mgwfbp_tpu.telemetry.events import events_of, read_events
+
+    monkeypatch.setenv("MGWFBP_SYNTH_TRAIN_N", "16")
+    cfg, trainer = trainer_of(tmp_path, *flags)
+    try:
+        trainer.train_epoch(0)
+        trainer.train_epoch(1)
+        assert trainer.train_step.attention_calls == {
+            "kernel": 0, "blocks": blocks}
+    finally:
+        trainer.close()
+    records = read_events(
+        os.path.join(str(tmp_path), cfg.tag(), "telemetry.jsonl"))
+    (program,) = events_of(records, "attention_program")
+    assert (program["step"], program["kernel"], program["blocks"]) \
+        == (1, 0, blocks)
+    import telemetry_report
+
+    assert (f"0 core(s) through the fused kernel, {blocks} through the plain "
+            "blocks") in telemetry_report.format_report(records)
+
+
+@pytest.mark.parametrize("model,want", [
+    ("mellum2", {"kernel": 4, "blocks": 0}),
+    ("granite4h", {"kernel": 1, "blocks": 0}),
+])
+def test_traced_as_for_a_tpu_the_models_cores_go_where_the_shape_test_sends_them(
+        monkeypatch, model, want):
+    """The tiny models at a head size the kernel has tiles for, T 256: the
+    count a step program would record on the chip."""
+    from mgwfbp_tpu.models import granite, mellum
+
+    monkeypatch.setattr(blockattn, "_traced_for_tpu", lambda: True)
+    if model == "mellum2":
+        module = mellum.Mellum2LM(
+            vocab_size=256, experts_held=(0, 2), shape=dataclasses.replace(
+                mellum.MELLUM2_TINY, head_dim=64, sliding_window=128))
+    else:
+        module = granite.Granite4HLM(
+            vocab_size=256, layers_held=3, shape=dataclasses.replace(
+                granite.GRANITE4H_TINY, head_dim=64))
+    x = jnp.zeros((1, 256), jnp.int32)
+    params = jax.eval_shape(module.init, jax.random.PRNGKey(0), x)
+    ways, kernel = traced_ways(
+        lambda p: jax.grad(lambda p: jnp.sum(
+            module.apply(p, x, targets=x)[0]))(p), params)
+    assert ways == want and kernel

@@ -98,6 +98,38 @@ def test_flash_backward_is_not_compilable_yet(topo):
         jax.jit(jax.grad(loss)).lower(x, x, x).compile()
 
 
+@pytest.mark.parametrize("b,h,hkv,d,window,scale", [
+    (2, 32, 4, 128, None, 1.0),
+    (2, 32, 4, 128, 1024, 1.0),
+    (1, 32, 8, 64, None, 1.0 / 64),
+], ids=["mellum2-full", "mellum2-window-1024", "granite4h"])
+def test_attention_core_compiles_for_v5e_at_the_cells_shapes(
+        topo, b, h, hkv, d, window, scale):
+    """ops/blockattn.py's fused kernel, forward and backward, at the three
+    call shapes of the language cells (T 8,192, bf16) and the tiles the shape
+    test gives them: the tiles fit VMEM and the backward compiles. The kernel
+    path is called outright: this process traces for the CPU."""
+    from mgwfbp_tpu.ops import blockattn
+
+    t = 8192
+    one = SingleDeviceSharding(topo.devices[0])
+    q = jax.ShapeDtypeStruct((b, t, h, d), jnp.bfloat16, sharding=one)
+    kv = jax.ShapeDtypeStruct((b, t, hkv, d), jnp.bfloat16, sharding=one)
+    tiles = blockattn._kernel_tiles(t, d, window)
+    assert tiles is not None
+
+    def loss(q, k, v):
+        out = blockattn._fused(q, k, v, window, scale, tiles)
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile().as_text()
+    # forward, dk/dv and dq (or the two of a fused backward)
+    assert text.count("tpu_custom_call") >= 2
+    # no float32 array of a whole head's scores leaves the kernel
+    assert f"f32[{t},{t}]" not in text and f",{t},{t}]" not in text
+
+
 def _abstract_step_args(model, meta, tx, mesh, per_device_batch):
     """(state, batch) as ShapeDtypeStructs sharded the way the Trainer
     places them: state replicated, batch split over the data axis. A

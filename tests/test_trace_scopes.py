@@ -48,6 +48,32 @@ def test_join_and_split():
     assert top[0] == (300, "fusion.8", "attn_full")
 
 
+def test_a_kernels_custom_call_printed_over_several_lines_keeps_its_scope():
+    """As the TPU compiler prints a Pallas kernel (ops/blockattn.py's): the
+    `kernel_metadata` JSON breaks the instruction over three lines and the
+    op_name stands on the last; the next instruction has none of its own and
+    must not inherit it."""
+    hlo = (
+        '  %splash_mha_dkv_no_residuals.7 = (f32[8]{0}, bf16[8]{0}) '
+        'custom-call(%q, %k), custom_call_target="tpu_custom_call", '
+        'frontend_attributes={kernel_metadata={\n'
+        '"xprof_metadata":"{\\"block_q_dkv\\": 1024}"\n'
+        '}}, metadata={op_name="jit(step)/transpose(jvp(M))/attn_full/'
+        'jit(_splash_attention)/splash_mha_dkv_no_residuals/pallas_call" '
+        'stack_frame_id=20}, backend_config={"custom_call_config":{}}\n'
+        '  %bare.1 = f32[8]{0} copy(%x)\n'
+        '  %fusion.3 = f32[8]{0} fusion(%y), kind=kLoop, calls=%fc, '
+        'metadata={op_name="jit(step)/jvp(M)/attn_window/mul"}\n')
+    names = trace_scopes.hlo_op_names(hlo)
+    assert set(names) == {"splash_mha_dkv_no_residuals.7", "fusion.3"}
+    totals, _ = trace_scopes.split(
+        [("splash_mha_dkv_no_residuals.7", 0, 17), ("bare.1", 20, 1),
+         ("fusion.3", 30, 2)], names, SCOPES)
+    assert totals == {("attn_full", "backward"): 17,
+                      ("(no metadata)", "-"): 1,
+                      ("attn_window", "forward"): 2}
+
+
 @pytest.mark.parametrize("op_name,want", [
     ("jit(step)/jvp(M)/attn_window/dot_general", ("attn_window", "forward")),
     ("jit(step)/transpose(jvp(M))/attn_window/dot_general",

@@ -156,6 +156,12 @@ def format_report(records: list[dict]) -> str:
             f"{prog.get('async_collectives')} asynchronous; compile options: "
             + (", ".join(prog.get("compiler_options") or ()) or "none")
         )
+    for prog in events_of(records, "attention_program"):
+        lines.append(
+            f"attention (step program built by step {prog.get('step')}): "
+            f"{prog.get('kernel')} core(s) through the fused kernel, "
+            f"{prog.get('blocks')} through the plain blocks"
+        )
     if steps:
         durs = [float(s["dur_s"]) for s in steps]
         lines.append("")
@@ -762,6 +768,7 @@ def _synthetic_stream(path: str) -> None:
     # records them after the first dispatch
     w.emit("step_program", step=1, collectives=33, async_collectives=5,
            compiler_options=["xla_enable_async_all_reduce"])
+    w.emit("attention_program", step=1, kernel=1, blocks=3)
     hidden = sum(r.hidden_s for r in rows)
     total = sum(r.comm_s for r in rows)
     w.emit(
@@ -854,6 +861,11 @@ def selftest() -> int:
         assert (
             "step program (built by step 1): 33 collectives, 5 asynchronous; "
             "compile options: xla_enable_async_all_reduce" in report
+        ), report
+        # ISSUE 31: which way the step's attention cores went down
+        assert (
+            "attention (step program built by step 1): 1 core(s) through "
+            "the fused kernel, 3 through the plain blocks" in report
         ), report
         # ISSUE 16: the save-duration trend section renders, async saves
         # are marked in the lifecycle, and the save whose payload write

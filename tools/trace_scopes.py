@@ -36,15 +36,21 @@ _OP_NAME = re.compile(r'op_name="([^"]*)"')
 
 
 def hlo_op_names(hlo_text: str) -> dict[str, str]:
-    """{instruction name: its op_name metadata} over every computation."""
+    """{instruction name: its op_name metadata} over every computation. A
+    Pallas kernel's custom call is printed over several lines (its
+    `kernel_metadata` holds a JSON string with line breaks in it) and its
+    `metadata={op_name=...}` stands on the last of them: lines that start no
+    instruction belong to the one before."""
     out = {}
+    waiting = None  # the instruction whose op_name has not been seen yet
     for line in hlo_text.splitlines():
         m = _INSTRUCTION.match(line)
-        if m is None:
-            continue
+        if m is not None:
+            waiting = m.group(1)
         meta = _OP_NAME.search(line)
-        if meta is not None:
-            out[m.group(1)] = meta.group(1)
+        if meta is not None and waiting is not None:
+            out[waiting] = meta.group(1)
+            waiting = None
     return out
 
 
