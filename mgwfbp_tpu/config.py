@@ -206,6 +206,16 @@ PRESETS: dict[str, dict] = {
                            lr=3e-3, max_epochs=40, lr_schedule="cosine",
                            optimizer="adamw", adam_b2=0.95, weight_decay=0.1,
                            norm_clip=1.0, vocab_size=256),
+    # sparse decoder LM with a dense first layer, a shared expert and a head
+    # count by layer (models/laguna.py): mellum2's recipe (none of it
+    # published) at one sequence of 8,192 tokens a device
+    "laguna_xs2": dict(dataset="tokens", batch_size=1, num_steps=8192, lr=3e-4,
+                       max_epochs=40, lr_schedule="cosine", optimizer="adamw",
+                       adam_b2=0.95, weight_decay=0.1, norm_clip=1.0),
+    "laguna_xs2_tiny": dict(dataset="tokens", batch_size=2, num_steps=64,
+                            lr=3e-3, max_epochs=40, lr_schedule="cosine",
+                            optimizer="adamw", adam_b2=0.95, weight_decay=0.1,
+                            norm_clip=1.0, vocab_size=256),
     "fcn5net": dict(dataset="mnist", batch_size=64, lr=0.05, max_epochs=10),
     "lr": dict(dataset="mnist", batch_size=64, lr=0.01, max_epochs=10),
 }

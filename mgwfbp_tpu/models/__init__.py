@@ -341,7 +341,7 @@ def _lstman4(nc):
 
 def _held_lm_meta(name: str, nc: int, window_len: int) -> ModelMeta:
     """A decoder LM over the `tokens` dataset that takes its loss itself and
-    can be held in part (the mellum2 and granite4h families)."""
+    can be held in part (the mellum2, granite4h and laguna_xs2 families)."""
     return ModelMeta(
         name=name, dataset="tokens", num_classes=nc,
         input_shape=(window_len,), input_dtype=jnp.int32, task="lm",
@@ -349,50 +349,56 @@ def _held_lm_meta(name: str, nc: int, window_len: int) -> ModelMeta:
     )
 
 
-def _register_mellum2(name: str, shape_name: str, window_len: int):
+def _register_held_lm(name: str, load: Callable[[], tuple[Any, Any]],
+                      window_len: int, takes_experts: bool):
+    """A decoder held in part by layers and vocabulary and, with
+    `takes_experts`, by routed experts. `load` imports the family's module
+    when the model is first built and returns (class, shape)."""
     @register(name)
     def _factory(nc, layers_held=None, experts_held=None):
-        from mgwfbp_tpu.models import mellum
-
-        shape = getattr(mellum, shape_name)
+        cls, shape = load()
         nc = nc or shape.vocab_size
-        return (
-            mellum.Mellum2LM(
-                vocab_size=nc, shape=shape, layers_held=layers_held,
-                experts_held=experts_held or (0, shape.num_experts),
-            ),
-            _held_lm_meta(name, nc, window_len),
-        )
-
-    _TAKES_SHARE.add(name)
-
-
-# the published widths; and the same architecture at a size the CPU tests
-# hold (hidden 64, 2 key/value heads, 8 experts top 2, window 16)
-_register_mellum2("mellum2", "MELLUM2", 8192)
-_register_mellum2("mellum2_tiny", "MELLUM2_TINY", 64)
-
-
-def _register_granite4h(name: str, shape_name: str, window_len: int):
-    @register(name)
-    def _factory(nc, layers_held=None, experts_held=None):
-        from mgwfbp_tpu.models import granite
-
-        if experts_held is not None:
+        share = {"layers_held": layers_held}
+        if takes_experts:
+            share["experts_held"] = experts_held or (0, shape.num_experts)
+        elif experts_held is not None:
             raise ValueError(
                 f"model {name!r} is dense: it has no experts to hold in part")
-        shape = getattr(granite, shape_name)
-        nc = nc or shape.vocab_size
-        return (
-            granite.Granite4HLM(
-                vocab_size=nc, shape=shape, layers_held=layers_held),
-            _held_lm_meta(name, nc, window_len),
-        )
+        return (cls(vocab_size=nc, shape=shape, **share),
+                _held_lm_meta(name, nc, window_len))
 
     _TAKES_SHARE.add(name)
 
 
-# granite-4.0-h-micro at its published widths; and at a size the CPU tests
-# hold (hidden 32, 4 Mamba heads of 16, state 8, chunk 16, four layers)
-_register_granite4h("granite4h", "GRANITE4H", 8192)
-_register_granite4h("granite4h_tiny", "GRANITE4H_TINY", 64)
+def _mellum2(tiny: bool):
+    from mgwfbp_tpu.models import mellum
+
+    return mellum.Mellum2LM, mellum.MELLUM2_TINY if tiny else mellum.MELLUM2
+
+
+def _laguna_xs2(tiny: bool):
+    from mgwfbp_tpu.models import laguna
+
+    return laguna.LagunaLM, (
+        laguna.LAGUNA_XS2_TINY if tiny else laguna.LAGUNA_XS2)
+
+
+def _granite4h(tiny: bool):
+    from mgwfbp_tpu.models import granite
+
+    return granite.Granite4HLM, (
+        granite.GRANITE4H_TINY if tiny else granite.GRANITE4H)
+
+
+# each family at its published widths, and at a size the CPU tests hold:
+# mellum2 (hidden 64, 2 key/value heads, 8 experts top 2, window 16);
+# laguna_xs2 (hidden 64, 6 / 8 query heads over 2 key heads of 16, 16 experts
+# top 2 and a shared one, window 16, five layers: the dense one first);
+# granite4h (hidden 32, 4 Mamba heads of 16, state 8, chunk 16, four layers)
+for _name, _load, _experts in (
+        ("mellum2", _mellum2, True), ("laguna_xs2", _laguna_xs2, True),
+        ("granite4h", _granite4h, False)):
+    _register_held_lm(
+        _name, functools.partial(_load, False), 8192, _experts)
+    _register_held_lm(
+        _name + "_tiny", functools.partial(_load, True), 64, _experts)

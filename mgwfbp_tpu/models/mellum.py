@@ -96,30 +96,43 @@ MELLUM2_TINY = MellumShape(
 )
 
 
-def rope_inv_freq(shape: MellumShape, kind: str) -> tuple[jax.Array, float]:
-    """(inverse frequencies (head_dim / 2,), factor on cos and sin) of a layer
-    of `kind`. YaRN as `transformers._compute_yarn_parameters`: interpolate
-    (divide by the factor) the low frequencies, keep the high ones, blend
-    linearly between the two correction dimensions."""
-    dim = shape.head_dim
-    base = shape.rope_theta ** (
-        -jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
-    if kind == SLIDING:
-        return base, 1.0
+def plain_inv_freq(dim: int, theta: float) -> jax.Array:
+    """theta ** (-2i / dim) for the dim / 2 pairs of a rotation over `dim`
+    dimensions of a head."""
+    return theta ** (-jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+
+
+def yarn_inv_freq(dim: int, theta: float, factor: float, original_len: int,
+                  beta_fast: float, beta_slow: float) -> jax.Array:
+    """YaRN as `transformers._compute_yarn_parameters`, over the `dim`
+    dimensions of a head that rotate: interpolate (divide by the factor) the
+    low frequencies, keep the high ones, blend linearly between the two
+    correction dimensions."""
+    base = plain_inv_freq(dim, theta)
 
     def correction_dim(rotations: float) -> float:
         return dim * math.log(
-            shape.yarn_original_len / (rotations * 2 * math.pi)
-        ) / (2 * math.log(shape.rope_theta))
+            original_len / (rotations * 2 * math.pi)
+        ) / (2 * math.log(theta))
 
-    low = max(math.floor(correction_dim(shape.yarn_beta_fast)), 0)
-    high = min(math.ceil(correction_dim(shape.yarn_beta_slow)), dim - 1)
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
     if low == high:
         high += 0.001
     ramp = jnp.clip(
         (jnp.arange(dim // 2, dtype=jnp.float32) - low) / (high - low), 0, 1)
-    inv_freq = (1 - ramp) * base + ramp * base / shape.yarn_factor
-    return inv_freq, shape.yarn_attention_factor
+    return (1 - ramp) * base + ramp * base / factor
+
+
+def rope_inv_freq(shape: MellumShape, kind: str) -> tuple[jax.Array, float]:
+    """(inverse frequencies (head_dim / 2,), factor on cos and sin) of a layer
+    of `kind`: plain on window layers, YaRN on full ones."""
+    if kind == SLIDING:
+        return plain_inv_freq(shape.head_dim, shape.rope_theta), 1.0
+    return yarn_inv_freq(
+        shape.head_dim, shape.rope_theta, shape.yarn_factor,
+        shape.yarn_original_len, shape.yarn_beta_fast, shape.yarn_beta_slow,
+    ), shape.yarn_attention_factor
 
 
 def apply_rope(x: jax.Array, inv_freq: jax.Array, factor: float) -> jax.Array:
@@ -300,6 +313,21 @@ def token_losses(h: jax.Array, head: jax.Array, targets: jax.Array,
     )).reshape(n)
 
 
+def routing_counters(stats: dict, assignments: int) -> dict:
+    """The `step` record's routing counters from one step's statistics as
+    host arrays (MOE_TOKENS_KEY (sparse layers held, experts held),
+    MOE_DROPPED_KEY); `assignments` a layer's (token, expert) pairs on one
+    device. Shared by every model that routes through `held_experts`."""
+    held = stats[MOE_TOKENS_KEY]
+    worst = int(held.max(axis=1).argmax())  # the layer of the fullest
+    return {
+        "moe_here": float(held.sum(axis=1).mean() / assignments),
+        "moe_load_max": float(held[worst].max()),
+        "moe_load_mean": float(held[worst].mean()),
+        "moe_dropped": float(stats[MOE_DROPPED_KEY]),
+    }
+
+
 class _Leaves(nn.Module):
     """Declares a group of parameters and hands them back as a dict."""
 
@@ -348,16 +376,7 @@ class Mellum2LM(nn.Module):
         """The `step` record's routing counters from one step's statistics
         as host arrays; `tokens` one device's tokens a (micro-)step, so
         tokens x experts a token are a layer's assignments."""
-        held = stats[MOE_TOKENS_KEY]  # (layers held, experts held)
-        worst = int(held.max(axis=1).argmax())  # the layer of the fullest
-        return {
-            "moe_here": float(
-                held.sum(axis=1).mean()
-                / (tokens * self.shape.experts_per_token)),
-            "moe_load_max": float(held[worst].max()),
-            "moe_load_mean": float(held[worst].mean()),
-            "moe_dropped": float(stats[MOE_DROPPED_KEY]),
-        }
+        return routing_counters(stats, tokens * self.shape.experts_per_token)
 
     @nn.compact
     def __call__(self, x: jax.Array, targets: Optional[jax.Array] = None,

@@ -64,8 +64,21 @@ LOWERED = collections.Counter(kernel=0, blocks=0)
 # serves all the kernels. Fused backward: dq comes out of the dk/dv kernel, a
 # partial sum per key tile added up outside it, instead of a kernel of its own
 # that computes the probabilities again. A window layer's tiles overhang its
-# band, so it takes smaller ones (fitted at a window of 1,024), and its
-# partial sums cost more than the second kernel.
+# band, so it takes smaller ones, and its partial sums cost more than the
+# second kernel. Chosen by shape, that is by `window` alone: every call shape
+# of the cells has D 64 or 128 and T 8,192. Fitted at a window of 1,024
+# (Mellum 2, 2 x 32 query heads: 17.0 ms forward + backward alone on the chip;
+# 256 tiles 24.9, 1,024 tiles 18.8, fused backward 21.6; PR 31) and again at
+# a window of 512 (Laguna-XS.2, 64 query heads over 8 key heads, where a 512
+# tile overhangs the band by a whole tile: 512 x 512 with its own dq kernel
+# 11.18 ms; keys 256 at a time 11.82; 256 tiles 15.23; 128 tiles 30.01; 1,024
+# tiles 16.54; fused backward 16.97 at 512 and 34.56 at 256; the plain blocks
+# of 128 queries 13.2; my chip run, PR 32): the smaller tiles' worse rate
+# costs more than the overhang they save, so both windows keep 512. Laguna's
+# full layers (48 query heads, groups of SIX to a key head) keep the full
+# tiles: 1,024 x 1,024, keys 512 at a time, fused backward 23.33 ms; a dq
+# kernel of its own 28.08; keys 1,024 at a time 23.95; 512 tiles 29.26; the
+# plain blocks 74.65; 2,048 x 512 does not fit VMEM (PR 32).
 _TILES_FULL = (1024, 512, True)
 _TILES_WINDOW = (512, 512, False)
 _KERNEL_HEAD_DIMS = (64, 128)  # half a lane tile, and a whole one

@@ -102,12 +102,14 @@ def build_parser() -> argparse.ArgumentParser:
                    help="LM window length (must divide by --seq-parallel)")
     p.add_argument("--layers-held", dest="layers_held", type=int, default=None,
                    help="hold only the first N layers of a model that can be "
-                        "held in part (mellum2, granite4h): one pipeline "
+                        "held in part (mellum2, granite4h, laguna_xs2): one "
+                        "pipeline "
                         "stage's share")
     p.add_argument("--experts-held", dest="experts_held", default=None,
                    metavar="FIRST:COUNT",
                    help="hold only COUNT experts of every sparse layer, "
-                        "starting at expert FIRST (mellum2): one chip's "
+                        "starting at expert FIRST (mellum2, laguna_xs2): one "
+                        "chip's "
                         "share of an expert-parallel group. The router "
                         "still scores all experts; what the absent ones "
                         "would add is left out")

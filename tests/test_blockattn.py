@@ -66,12 +66,21 @@ def tiles_of(size, **more):
     (1, 8, 1, 128, 128, None, tiles_of(128)),
     (2, 4, 2, 128, None, None, tiles_of(256)),
     (2, 4, 2, 64, 128, None, tiles_of(128, use_fused_bwd_kernel=True)),
+    (1, 12, 2, 128, None, 1.0, tiles_of(256, use_fused_bwd_kernel=True)),
+    (1, 8, 1, 128, 512, None, tiles_of(512)),
+    (1, 6, 1, 64, 256, None, tiles_of(256)),
 ], ids=["d64-full-scale-1/64-groups-of-4", "d128-full", "d128-window-4-long",
-        "batch-2", "batch-2-window-fused-backward"])
+        "batch-2", "batch-2-window-fused-backward",
+        "laguna-full-groups-of-6-scale-in-q", "laguna-window-512-one-tile",
+        "groups-of-6-window-a-tile"])
 def test_fused_kernel_value_and_gradients_against_the_plain_blocks(
         b, h, hkv, d, window, scale, tiles):
     """Float32, so the two differ by rounding order alone; T 512 is four
-    windows and four tiles long. The batch rides in the kernel's heads."""
+    windows and four tiles long. The batch rides in the kernel's heads.
+    Laguna-XS.2's shapes: a group of SIX query heads a key head (the first
+    that is no power of two) and a window of 512 under the 512 tile the
+    chip's sweep kept for it (the band is one tile wide: at T 512 every tile
+    the kernel visits is on the diagonal)."""
     t = 512
     keys = jax.random.split(jax.random.PRNGKey(11), 4)
     q = 2.0 * jax.random.normal(keys[0], (b, t, h, d))
@@ -112,10 +121,12 @@ def test_the_kernel_object_is_built_once_a_shape_and_outside_the_trace():
 
 @pytest.mark.parametrize("t,d,window,fits", [
     (8192, 128, None, True), (8192, 64, None, True), (8192, 128, 1024, True),
+    (8192, 128, 512, True),
     (512, 64, None, True), (8192 + 512, 128, None, False),
     (640, 64, None, False), (40, 64, None, False), (8192, 96, None, False),
     (8192, 256, None, False),
-], ids=["mellum2-full", "granite", "mellum2-window", "short", "tiles-overhang",
+], ids=["mellum2-full", "granite", "mellum2-window", "laguna-window-512",
+        "short", "tiles-overhang",
         "five-lane-tiles", "no-lane-tile", "head-96", "head-256"])
 def test_shape_test_of_the_kernel(t, d, window, fits):
     tiles = blockattn._kernel_tiles(t, d, window)
@@ -127,6 +138,10 @@ def test_shape_test_of_the_kernel(t, d, window, fits):
         # a window layer keeps the dq kernel of its own
         assert (tiles.block_q_dq is None) == (window is None)
         assert tiles.use_fused_bwd_kernel == (window is None)
+        # the tiles the chip's sweeps kept (PR 31; PR 32 for a window of 512)
+        assert tiles.block_q == tiles.block_kv == min(
+            t, 1024 if window is None else 512)
+        assert tiles.block_kv_compute == min(t, 512)
 
 
 def traced_ways(fn, *args):
@@ -172,7 +187,8 @@ def trainer_of(tmp_path, *flags):
     (["--dnn", "mellum2_tiny", "--experts-held", "2:2", "--layers-held", "2"],
      2),
     (["--dnn", "granite4h_tiny", "--layers-held", "3"], 1),
-], ids=["mellum2_tiny", "granite4h_tiny"])
+    (["--dnn", "laguna_xs2_tiny", "--experts-held", "2:4"], 5),
+], ids=["mellum2_tiny", "granite4h_tiny", "laguna_xs2_tiny"])
 def test_a_step_program_leaves_its_attention_count_on_the_telemetry(
         tmp_path, monkeypatch, flags, blocks):
     """One `attention_program` record a built step program, counted while the
@@ -204,15 +220,22 @@ def test_a_step_program_leaves_its_attention_count_on_the_telemetry(
 @pytest.mark.parametrize("model,want", [
     ("mellum2", {"kernel": 4, "blocks": 0}),
     ("granite4h", {"kernel": 1, "blocks": 0}),
+    ("laguna_xs2", {"kernel": 5, "blocks": 0}),
 ])
 def test_traced_as_for_a_tpu_the_models_cores_go_where_the_shape_test_sends_them(
         monkeypatch, model, want):
     """The tiny models at a head size the kernel has tiles for, T 256: the
     count a step program would record on the chip."""
-    from mgwfbp_tpu.models import granite, mellum
+    from mgwfbp_tpu.models import granite, laguna, mellum
 
     monkeypatch.setattr(blockattn, "_traced_for_tpu", lambda: True)
-    if model == "mellum2":
+    if model == "laguna_xs2":
+        # 6 | 8 query heads over 2 key heads: every one of the five layers'
+        # cores is counted, the three window layers alike among them
+        module = laguna.LagunaLM(
+            vocab_size=256, experts_held=(0, 2), shape=dataclasses.replace(
+                laguna.LAGUNA_XS2_TINY, head_dim=64, sliding_window=128))
+    elif model == "mellum2":
         module = mellum.Mellum2LM(
             vocab_size=256, experts_held=(0, 2), shape=dataclasses.replace(
                 mellum.MELLUM2_TINY, head_dim=64, sliding_window=128))

@@ -23,7 +23,8 @@ def _example_input(meta, batch=2):
 ALL_IMAGE_MODELS = [
     n for n in zoo.model_names()
     if n not in ("lstm", "lstman4", "transformer", "mellum2", "mellum2_tiny",
-                 "granite4h", "granite4h_tiny")
+                 "granite4h", "granite4h_tiny", "laguna_xs2",
+                 "laguna_xs2_tiny")
 ]
 
 
@@ -67,6 +68,43 @@ def test_mellum2_traces_and_counts_its_parameters(name, share, want):
             lambda v: model.apply(v, x, targets=x, train=True), variables)
         assert per_token.shape == (2, 64)
         assert stats["health/moe_tokens"].shape == (4, 2)
+
+
+@pytest.mark.parametrize("name,share,want,layers", [
+    # the whole model: "33.4B" by name, one gate scalar a head
+    ("laguna_xs2", {}, 33_442_596_864, 40),
+    # one chip's share: the dense layer and four sparse ones, 32 of 256
+    # routed experts, an eighth of the ids
+    ("laguna_xs2", dict(num_classes=12544, layers_held=5,
+                        experts_held=(0, 32)), 691_623_936, 5),
+    ("laguna_xs2_tiny", dict(experts_held=(2, 4)), None, 5),
+])
+def test_laguna_xs2_traces_and_counts_its_parameters(
+        name, share, want, layers):
+    model, meta = zoo.create_model(name, **share)
+    assert meta.task == "lm" and not meta.has_carry and meta.fused_loss
+    assert meta.dataset == "tokens"
+    x = _example_input(meta)
+    variables = jax.eval_shape(
+        lambda: model.init({"params": jax.random.PRNGKey(0)}, x, train=False))
+    params = variables["params"]
+    if want is not None:
+        assert sum(int(np.prod(leaf.shape))
+                   for leaf in jax.tree_util.tree_leaves(params)) == want
+    assert len([k for k in params if k.startswith("layer_")]) == layers
+    assert set(params["out"]) == {"norm", "head"}
+    # the leading layer dense, every later one sparse beside a shared expert
+    assert "mlp_gate" in params["layer_0"] and "router" not in params["layer_0"]
+    assert {"router", "shared_gate", "w_gate"} <= set(params["layer_1"])
+    if name == "laguna_xs2_tiny":
+        logits = jax.eval_shape(lambda v: model.apply(v, x), variables)
+        assert logits.shape == (2, 64, meta.num_classes)
+        per_token, stats = jax.eval_shape(
+            lambda v: model.apply(v, x, targets=x, train=True), variables)
+        assert per_token.shape == (2, 64)
+        assert stats["health/moe_tokens"].shape == (4, 4)  # sparse layers
+        assert stats["health/attn_gate"].shape == (5,)
+        assert stats["health/moe_score_sum"].shape == (4,)
 
 
 @pytest.mark.parametrize("name,share,want,layers", [

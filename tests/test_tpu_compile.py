@@ -102,10 +102,13 @@ def test_flash_backward_is_not_compilable_yet(topo):
     (2, 32, 4, 128, None, 1.0),
     (2, 32, 4, 128, 1024, 1.0),
     (1, 32, 8, 64, None, 1.0 / 64),
-], ids=["mellum2-full", "mellum2-window-1024", "granite4h"])
+    (1, 64, 8, 128, 512, 1.0),
+    (1, 48, 8, 128, None, 1.0),
+], ids=["mellum2-full", "mellum2-window-1024", "granite4h",
+        "laguna-xs2-window-512-64-heads", "laguna-xs2-full-48-heads-groups-of-6"])
 def test_attention_core_compiles_for_v5e_at_the_cells_shapes(
         topo, b, h, hkv, d, window, scale):
-    """ops/blockattn.py's fused kernel, forward and backward, at the three
+    """ops/blockattn.py's fused kernel, forward and backward, at the five
     call shapes of the language cells (T 8,192, bf16) and the tiles the shape
     test gives them: the tiles fit VMEM and the backward compiles. The kernel
     path is called outright: this process traces for the CPU."""
