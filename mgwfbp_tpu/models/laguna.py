@@ -96,6 +96,7 @@ from mgwfbp_tpu.models.mellum import (
     yarn_inv_freq,
 )
 from mgwfbp_tpu.ops.blockattn import blockwise_attention
+from mgwfbp_tpu.ops.groupmm import counted
 
 DENSE, SPARSE = "dense", "sparse"
 # the step's metrics carry these under HEALTH_PREFIX of train/step.py
@@ -245,8 +246,9 @@ def sparse_block(p: dict, x: jax.Array, shape: LagunaShape, first: int):
     with jax.named_scope("moe_shared"):
         shared = swiglu(u, p["shared_gate"], p["shared_up"], p["shared_down"])
     with jax.named_scope("moe_experts"):
-        y, sizes, dropped = jax.checkpoint(held_experts, static_argnums=6)(
-            u, idx, weights, p["w_gate"], p["w_up"], p["w_down"], first)
+        y, sizes, dropped = counted(
+            jax.checkpoint(held_experts, static_argnums=6))(
+                u, idx, weights, p["w_gate"], p["w_up"], p["w_down"], first)
     return (
         (shared + y).reshape(b, t, d), sizes.astype(jnp.float32),
         dropped.astype(jnp.float32), lax.stop_gradient(jnp.mean(score_sum)),

@@ -25,11 +25,13 @@ the block. Nothing stands in for the absent chips or their exchange.
 
 **Dropless.** No capacity: the step's (token, expert) assignments are sorted
 by expert and go through ONE grouped product per weight
-(`jax.lax.ragged_dot`), sized for the worst case that every assignment lands
-here (tokens x 8 rows, of which a quarter are expected to be in a group; the
-TPU's grouped product skips the rest). Taking the tokens a chunk at a time
-saved no memory and made the grouped products a half slower (224 against 150
-ms a step, my chip runs, PR 26), so there is one group per expert and step.
+(`ops/groupmm.grouped_product`: a tiled kernel where the step is traced for a
+TPU, `jax.lax.ragged_dot` elsewhere), sized for the worst case that every
+assignment lands here (tokens x 8 rows, of which a quarter are expected to be
+in a group; either grouped product skips the rest). Taking the tokens a chunk
+at a time saved no memory and made the grouped products a half slower (224
+against 150 ms a step, my chip runs, PR 26), so there is one group per expert
+and step.
 
 **Memory.** The attention core keeps no scores (ops/blockattn.py: the fused
 kernel saves its output and a row log-sum-exp, the plain blocks recompute), the
@@ -55,6 +57,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from mgwfbp_tpu.ops.blockattn import blockwise_attention
+from mgwfbp_tpu.ops.groupmm import counted, grouped_product
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 # the step's metrics carry the routing counts under these keys (HEALTH_PREFIX
@@ -263,10 +266,10 @@ def held_experts(u, idx, weights, w_gate, w_up, w_down, first: int):
     # only on the CPU), forward and backward, so they are masked below and
     # in _dispatch's transpose, never trusted
     rows = _dispatch(u, order, inverse, jnp.sum(sizes))
-    gate = lax.ragged_dot(rows, w_gate, sizes)
-    up = lax.ragged_dot(rows, w_up, sizes)
+    gate = grouped_product(rows, w_gate, sizes)
+    up = grouped_product(rows, w_up, sizes)
     mid = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32))
-    out = lax.ragged_dot(mid.astype(u.dtype), w_down, sizes)
+    out = grouped_product(mid.astype(u.dtype), w_down, sizes)
     out = _unsort(out, order, inverse).reshape(n, k, -1)
     out = jnp.where(held[..., None], out.astype(jnp.float32), 0.0)
     y = jnp.sum(out * weights[..., None], axis=1)
@@ -283,8 +286,9 @@ def sparse_block(p: dict, x: jax.Array, shape: MellumShape, first: int):
     with jax.named_scope("moe_route"):
         idx, weights = route(u, p["router"], shape.experts_per_token)
     with jax.named_scope("moe_experts"):
-        y, sizes, dropped = jax.checkpoint(held_experts, static_argnums=6)(
-            u, idx, weights, p["w_gate"], p["w_up"], p["w_down"], first)
+        y, sizes, dropped = counted(
+            jax.checkpoint(held_experts, static_argnums=6))(
+                u, idx, weights, p["w_gate"], p["w_up"], p["w_down"], first)
     return (
         y.reshape(b, t, d), sizes.astype(jnp.float32),
         dropped.astype(jnp.float32),

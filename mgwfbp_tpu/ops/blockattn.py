@@ -149,7 +149,7 @@ def _blocks(q, k, v, window: Optional[int], block: int, scale: float):
     return out.transpose(0, 3, 1, 2, 4).reshape(b, t, h, d)
 
 
-def _traced_for_tpu() -> bool:
+def traced_for_tpu() -> bool:
     """Whether what is traced now will be lowered for a TPU: this package
     builds its meshes from the default backend's devices."""
     return jax.default_backend() == "tpu"
@@ -233,7 +233,7 @@ def blockwise_attention(
         raise ValueError(
             f"{h} query heads do not divide over {k.shape[2]} key heads")
     scale = 1.0 / math.sqrt(d) if scale is None else float(scale)
-    tiles = _kernel_tiles(t, d, window) if _traced_for_tpu() else None
+    tiles = _kernel_tiles(t, d, window) if traced_for_tpu() else None
     if tiles is None:
         LOWERED["blocks"] += 1
         return _blocks(q, k, v, window, block, scale)

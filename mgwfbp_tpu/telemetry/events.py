@@ -85,10 +85,15 @@ EVENT_TYPES: dict[str, tuple[str, ...]] = {
     # made asynchronous; `compiler_options` (names) rides along
     "step_program": ("step", "collectives", "async_collectives"),
     # one built step program, counted while it was traced
-    # (Trainer._note_attention_program): how many of its attention cores
+    # (Trainer._note_traced_programs): how many of its attention cores
     # went through the fused kernel and how many through the plain blocks
     # (ops/blockattn.py); 0 and 0 for a model without attention
     "attention_program": ("step", "kernel", "blocks"),
+    # the same for the experts' grouped products (ops/groupmm.py): how many
+    # went through the tiled kernel and how many through `lax.ragged_dot`
+    # (3 a sparse layer held), and the distinct kernel programs among the
+    # former with their transposes; 0, 0 and 0 for a model without experts
+    "experts_program": ("step", "kernel", "ragged", "programs"),
     # autotune: one raced candidate / the committed winner
     "autotune_race": ("label", "comm_op", "num_groups", "verified",
                       "measured_step_s"),

@@ -37,6 +37,7 @@ from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from mgwfbp_tpu.models import ModelMeta
+from mgwfbp_tpu.ops import groupmm
 from mgwfbp_tpu.ops.blockattn import LOWERED as ATTENTION_LOWERED
 from mgwfbp_tpu.parallel.allreduce import MergedAllreduce
 from mgwfbp_tpu.parallel.mesh import DATA_AXIS
@@ -718,22 +719,29 @@ def make_train_step(
         batch_spec = P(None, data_axes, seq_axis)
     # filled when the step is traced: how many of the program's attention
     # cores went through the fused kernel and how many through the plain
-    # blocks (ops/blockattn.py); Trainer records it as `attention_program`
+    # blocks (ops/blockattn.py), and how many of its experts' grouped
+    # products through the tiled kernel and through `lax.ragged_dot`, with
+    # the distinct kernel programs among them (ops/groupmm.py); Trainer
+    # records them as `attention_program` and `experts_program`
     attention_calls: dict[str, int] = {}
+    experts_calls: dict[str, int] = {}
 
-    def counting_attention(fn):
+    def counting_programs(fn):
         def traced(*args):
-            before = dict(ATTENTION_LOWERED)
+            attention = dict(ATTENTION_LOWERED)
+            experts = groupmm.LOWERED.copy()
             out = fn(*args)
             attention_calls.update(
-                (way, n - before[way]) for way, n in ATTENTION_LOWERED.items()
+                (way, n - attention[way])
+                for way, n in ATTENTION_LOWERED.items()
             )
+            experts_calls.update(groupmm.lowered_since(experts))
             return out
 
         return traced
 
     if has_carry:
-        fn = counting_attention(shard_map(
+        fn = counting_programs(shard_map(
             per_device,
             mesh=mesh,
             in_specs=(state_spec, batch_spec, P(data_axes)),
@@ -749,13 +757,14 @@ def make_train_step(
             return fn(state, batch, carry)
 
         step_lm.attention_calls = attention_calls
+        step_lm.experts_calls = experts_calls
         return step_lm
 
     def per_device_nocarry(state, batch):
         s, m, _ = per_device(state, batch, None)
         return s, m
 
-    fn = counting_attention(shard_map(
+    fn = counting_programs(shard_map(
         per_device_nocarry,
         mesh=mesh,
         in_specs=(state_spec, batch_spec),
@@ -771,6 +780,7 @@ def make_train_step(
         return fn(state, batch)
 
     step.attention_calls = attention_calls
+    step.experts_calls = experts_calls
     return step
 
 

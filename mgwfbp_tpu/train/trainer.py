@@ -673,7 +673,7 @@ class Trainer:
         self._step_program = None
         # a one-device step has no gradient collective to count
         self._step_program_noted = self.data_size * self.seq_size <= 1
-        self._attention_program_noted = False
+        self._traced_programs_noted = False
 
     def _build_run_sinks(self) -> None:
         """(Re)bind every tag-addressed output — log file, checkpoint dir,
@@ -1316,13 +1316,16 @@ class Trainer:
             "step_program", step=int(self.iteration), **self._step_program
         )
 
-    def _note_attention_program(self) -> None:
-        """Once per step-program build, after its first dispatch: how many
-        of the program's attention cores went through the fused kernel and
-        how many through the plain blocks (ops/blockattn.py chooses by
-        platform and shape), as make_train_step counted them while the
-        step was traced. Nothing is compiled or read from the device."""
-        self._attention_program_noted = True
+    def _note_traced_programs(self) -> None:
+        """Once per step-program build, after its first dispatch, what
+        make_train_step counted while the step was traced: how many of the
+        program's attention cores went through the fused kernel and how
+        many through the plain blocks (ops/blockattn.py), how many of its
+        experts' grouped products through the tiled kernel and how many
+        through `lax.ragged_dot`, and the distinct kernel programs among
+        them (ops/groupmm.py); both choose by platform and shape. Nothing
+        is compiled or read from the device."""
+        self._traced_programs_noted = True
         calls = getattr(self.train_step, "attention_calls", None)
         if not calls:  # not traced through make_train_step's own wrapper
             return
@@ -1333,6 +1336,17 @@ class Trainer:
         self._emit_event(
             "attention_program", step=int(self.iteration),
             kernel=int(calls["kernel"]), blocks=int(calls["blocks"]),
+        )
+        calls = self.train_step.experts_calls
+        self.log.info(
+            "experts: %d grouped product(s) of the step through the tiled "
+            "kernel (%d distinct kernel program(s)), %d through ragged_dot",
+            calls["kernel"], calls["programs"], calls["ragged"],
+        )
+        self._emit_event(
+            "experts_program", step=int(self.iteration),
+            kernel=int(calls["kernel"]), ragged=int(calls["ragged"]),
+            programs=int(calls["programs"]),
         )
 
     def _schedule_state_doc(self) -> dict:
@@ -3195,8 +3209,8 @@ class Trainer:
                 )
             if step_args is not None:
                 self._note_step_program(step_args)
-            if not self._attention_program_noted:
-                self._note_attention_program()
+            if not self._traced_programs_noted:
+                self._note_traced_programs()
             window_iters += 1
             epoch_steps += 1
             # non-finite guard bookkeeping (one step LATE via the deque, so

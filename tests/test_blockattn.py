@@ -162,9 +162,9 @@ def test_falls_back_to_the_blocks_off_the_tpu_and_on_a_shape_that_misfits(
     def attend(q):
         return blockwise_attention(q, q[:, :, :1], q[:, :, :1])
 
-    assert not blockattn._traced_for_tpu()
+    assert not blockattn.traced_for_tpu()
     assert traced_ways(attend, fits) == ({"kernel": 0, "blocks": 1}, False)
-    monkeypatch.setattr(blockattn, "_traced_for_tpu", lambda: True)
+    monkeypatch.setattr(blockattn, "traced_for_tpu", lambda: True)
     assert traced_ways(attend, fits) == ({"kernel": 1, "blocks": 0}, True)
     assert traced_ways(attend, misfits) == ({"kernel": 0, "blocks": 1}, False)
 
@@ -174,8 +174,7 @@ def trainer_of(tmp_path, *flags):
     from mgwfbp_tpu.train.trainer import Trainer
 
     args = train_cli.build_parser().parse_args([
-        *flags, "--dataset", "tokens", "--vocab-size", "256", "--num-steps",
-        "64", "--batch-size", "2", "--lr", "0.01", "--lr-schedule", "const",
+        *flags, "--batch-size", "2", "--lr", "0.01", "--lr-schedule", "const",
         "--synthetic", "--telemetry", "--no-profile-backward",
         "--num-batches-per-epoch", "2", "--max-epochs", "1", "--seed", "5",
         "--logdir", str(tmp_path)])
@@ -183,11 +182,14 @@ def trainer_of(tmp_path, *flags):
     return cfg, Trainer(cfg, profile_backward=False, synthetic_data=True)
 
 
+TOKENS = ["--dataset", "tokens", "--vocab-size", "256", "--num-steps", "64"]
+
+
 @pytest.mark.parametrize("flags,blocks", [
-    (["--dnn", "mellum2_tiny", "--experts-held", "2:2", "--layers-held", "2"],
-     2),
-    (["--dnn", "granite4h_tiny", "--layers-held", "3"], 1),
-    (["--dnn", "laguna_xs2_tiny", "--experts-held", "2:4"], 5),
+    (["--dnn", "mellum2_tiny", "--experts-held", "2:2", "--layers-held", "2",
+      *TOKENS], 2),
+    (["--dnn", "granite4h_tiny", "--layers-held", "3", *TOKENS], 1),
+    (["--dnn", "laguna_xs2_tiny", "--experts-held", "2:4", *TOKENS], 5),
 ], ids=["mellum2_tiny", "granite4h_tiny", "laguna_xs2_tiny"])
 def test_a_step_program_leaves_its_attention_count_on_the_telemetry(
         tmp_path, monkeypatch, flags, blocks):
@@ -228,7 +230,7 @@ def test_traced_as_for_a_tpu_the_models_cores_go_where_the_shape_test_sends_them
     count a step program would record on the chip."""
     from mgwfbp_tpu.models import granite, laguna, mellum
 
-    monkeypatch.setattr(blockattn, "_traced_for_tpu", lambda: True)
+    monkeypatch.setattr(blockattn, "traced_for_tpu", lambda: True)
     if model == "laguna_xs2":
         # 6 | 8 query heads over 2 key heads: every one of the five layers'
         # cores is counted, the three window layers alike among them

@@ -1,0 +1,242 @@
+"""ops/groupmm.py. Its tiled kernel (the installed megablox `gmm` / `tgmm`
+under this module's custom VJP, here in `interpret` mode on the CPU) against
+a per-group dense product in float32: forward, d lhs and d rhs, at group
+sizes no tile divides, with empty and one-row groups, with the rows past the
+last group poisoned, at both sparse cells' (K, N). The test of platform and
+shape that chooses between the kernel and `lax.ragged_dot`; the count of
+distinct kernel programs in a module lowered for a TPU; and the count a step
+program leaves on the telemetry."""
+
+import collections
+import functools
+import hashlib
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from mgwfbp_tpu.ops import blockattn, groupmm
+
+from test_blockattn import TOKENS, trainer_of  # the Trainer at a tiny size
+
+Case = collections.namedtuple(
+    "Case", "m k n sizes dtype tiles", defaults=(None,))
+CASES = {
+    # a group of one row, an empty group, and 303 rows past the last group
+    "mellum2_gate_up": Case(1024, 2304, 896, (300, 1, 0, 420), "bfloat16"),
+    # tiles the entry point does not choose: K 2,304 over a tile of 1,024
+    # leaves a remainder tile of 256 in the product and in d rhs, and d lhs's
+    # output has one; then the same with K and N changing places
+    "k_with_a_remainder_tile": Case(
+        1024, 2304, 896, (5, 600, 0, 100), "bfloat16", groupmm.Tiles(
+            product=(512, 1024, 896), d_lhs=(512, 896, 1024),
+            d_rhs=(512, 1024, 896))),
+    "n_with_a_remainder_tile": Case(
+        1024, 896, 2304, (5, 600, 0, 100), "bfloat16", groupmm.Tiles(
+            product=(512, 896, 1024), d_lhs=(512, 1024, 896),
+            d_rhs=(512, 896, 1024))),
+    "mellum2_down": Case(1024, 896, 2304, (0, 511, 2, 200), "bfloat16"),
+    "laguna_gate_up": Case(1024, 2048, 512, (130, 0, 0, 57, 1, 300, 7, 129),
+                           "bfloat16"),
+    "laguna_down": Case(1024, 512, 2048, (64, 64, 64, 64, 100, 1, 0, 3),
+                        "bfloat16"),
+    # float32 operands: the masks and the walk over the tiles, to rounding
+    "empty_groups_only_at_the_ends": Case(
+        1024, 256, 128, (0, 0, 513, 5, 0), "float32"),
+    "one_row_groups": Case(512, 128, 256, (1, 1, 1, 1, 1, 1), "float32"),
+    "no_tile_divides_a_group": Case(1536, 384, 128, (517, 3, 611, 299),
+                                    "float32"),
+    "every_row_in_a_group": Case(1024, 128, 128, (1, 510, 2, 511), "float32"),
+    "no_row_in_a_group": Case(512, 128, 128, (0, 0, 0), "float32"),
+}
+
+
+def dense_by_group(lhs, rhs, g, sizes):
+    """The product and both transposes, a group at a time, in float32; rows
+    past the last group are zero."""
+    lhs, rhs, g = (np.asarray(a, np.float32) for a in (lhs, rhs, g))
+    out = np.zeros((lhs.shape[0], rhs.shape[2]), np.float32)
+    d_lhs, d_rhs = np.zeros_like(lhs), np.zeros_like(rhs)
+    start = 0
+    for i, size in enumerate(sizes):
+        rows = slice(start, start + size)
+        out[rows] = lhs[rows] @ rhs[i]
+        d_lhs[rows] = g[rows] @ rhs[i].T
+        d_rhs[i] = lhs[rows].T @ g[rows]
+        start += size
+    return {"product": out, "d_lhs": d_lhs, "d_rhs": d_rhs}
+
+
+@functools.lru_cache(maxsize=None)
+def kernel_against_dense(name: str):
+    """{which: (got, want)} for one case, the kernel run once. The rows past
+    the last group hold NaN on the way in and in the cotangent."""
+    case = CASES[name]
+    dtype = jnp.dtype(case.dtype)
+    sizes = jnp.asarray(case.sizes, jnp.int32)
+    held = sum(case.sizes)
+    keys = jax.random.split(jax.random.PRNGKey(len(name)), 3)
+    lhs = jax.random.normal(keys[0], (case.m, case.k)).astype(dtype)
+    rhs = (jax.random.normal(keys[1], (len(case.sizes), case.k, case.n))
+           / np.sqrt(case.k)).astype(dtype)
+    g = jax.random.normal(keys[2], (case.m, case.n)).astype(dtype)
+    want = dense_by_group(lhs, rhs, g, case.sizes)
+    past = jnp.arange(case.m)[:, None] >= held
+    lhs, g = jnp.where(past, jnp.nan, lhs), jnp.where(past, jnp.nan, g)
+    tiles = case.tiles or groupmm._kernel_tiles(
+        case.m, case.k, case.n, dtype)
+    assert tiles is not None
+    out, vjp = jax.vjp(
+        lambda a, b: groupmm._kernel_product(a, b, sizes, tiles, True),
+        lhs, rhs)
+    d_lhs, d_rhs = vjp(g)
+    assert out.dtype == d_lhs.dtype == d_rhs.dtype == dtype
+    got = {"product": out, "d_lhs": d_lhs, "d_rhs": d_rhs}
+    # rows past the last group hold anything: never compared
+    return {
+        which: (np.asarray(a, np.float32)[:held] if which != "d_rhs"
+                else np.asarray(a, np.float32),
+                want[which][:held] if which != "d_rhs" else want[which])
+        for which, a in got.items()}
+
+
+@pytest.mark.parametrize("which", ["product", "d_lhs", "d_rhs"])
+@pytest.mark.parametrize("name", list(CASES))
+def test_kernel_against_a_dense_product_a_group(name, which):
+    got, want = kernel_against_dense(name)[which]
+    assert np.isfinite(got).all()  # no poisoned row reached a row in a group
+    # one rounding of a float32 sum to the operands' dtype
+    eps = 2.0 ** -8 if CASES[name].dtype == "bfloat16" else 1e-5
+    scale = np.abs(want).max(initial=0.0)
+    assert np.abs(got - want).max(initial=0.0) <= eps * scale
+    if which == "d_rhs":  # an empty group's block is zero, not left alone
+        for i, size in enumerate(CASES[name].sizes):
+            if size == 0:
+                assert not got[i].any()
+
+
+def traced_ways(fn, *args):
+    before = groupmm.LOWERED.copy()
+    # a fresh function each time: a cached trace calls nothing and counts none
+    jaxpr = jax.make_jaxpr(lambda *a: fn(*a))(*args)
+    return groupmm.lowered_since(before), "pallas_call" in str(jaxpr)
+
+
+def test_falls_back_to_ragged_dot_off_the_tpu_and_on_a_shape_that_misfits(
+        monkeypatch):
+    """The choice is read off the platform traced for and the shape: on this
+    CPU every call is `lax.ragged_dot`; traced as for a TPU, a shape the tiles
+    divide takes the kernel, and rows the row tile does not divide, a K or N
+    that is no whole number of lane tiles, or integer operands do not."""
+    sizes = jnp.asarray([5, 7], jnp.int32)
+
+    def product(lhs, rhs):
+        return groupmm.grouped_product(lhs, rhs, sizes)
+
+    def operands(m, k, n, dtype=jnp.bfloat16):
+        return jnp.ones((m, k), dtype), jnp.ones((2, k, n), dtype)
+
+    ragged = ({"kernel": 0, "ragged": 1, "programs": 0}, False)
+    assert not blockattn.traced_for_tpu()
+    assert traced_ways(product, *operands(512, 128, 128)) == ragged
+    monkeypatch.setattr(blockattn, "traced_for_tpu", lambda: True)
+    # K = N: the product and d lhs are one program, d rhs another
+    assert traced_ways(product, *operands(512, 128, 128)) == (
+        {"kernel": 1, "ragged": 0, "programs": 2}, True)
+    assert traced_ways(product, *operands(512, 256, 128)) == (
+        {"kernel": 1, "ragged": 0, "programs": 3}, True)
+    assert traced_ways(product, *operands(384, 128, 128)) == ragged
+    assert traced_ways(product, *operands(512, 96, 128)) == ragged
+    assert traced_ways(product, *operands(512, 128, 200)) == ragged
+    assert traced_ways(product, *operands(512, 128, 128, jnp.int8)) == ragged
+
+
+def expert_blocks(layers: int):
+    """Forward + gradient of `layers` checkpointed expert blocks as the
+    models call them, at a size the tiles divide: 128 tokens x 8 = 1,024
+    rows, 4 experts held of 8."""
+    from mgwfbp_tpu.models import mellum
+
+    tokens, d, f, experts, k = 128, 256, 128, 4, 8
+    bf = jnp.bfloat16
+    shape = jax.ShapeDtypeStruct
+
+    def loss(ws, u, idx, weights):
+        for w_gate, w_up, w_down in ws:
+            y, _, _ = groupmm.counted(jax.checkpoint(
+                mellum.held_experts, static_argnums=6))(
+                    u, idx, weights, w_gate, w_up, w_down, 0)
+            u = u + y.astype(u.dtype)
+        return jnp.sum(u.astype(jnp.float32))
+
+    ws = [(shape((experts, d, f), bf), shape((experts, d, f), bf),
+           shape((experts, f, d), bf)) for _ in range(layers)]
+    args = (ws, shape((tokens, d), bf), shape((tokens, k), jnp.int32),
+            shape((tokens, k), jnp.float32))
+    return jax.jit(jax.value_and_grad(loss, argnums=(0, 1))), args
+
+
+def test_four_layers_lower_no_more_kernel_programs_than_one(monkeypatch):
+    """Lowered for a TPU, here: the distinct `tpu_custom_call` programs of
+    four expert blocks, forward + gradient, are those of one block, and no
+    more than the entry point's docstring states; every product of every
+    layer is counted all the same."""
+    monkeypatch.setattr(blockattn, "traced_for_tpu", lambda: True)
+    # the docstring's own number, so that the two cannot part
+    assert "at most FOUR distinct programs" in groupmm.__doc__
+    stated = 4
+    seen = {}
+    for layers in (1, 4):
+        fn, args = expert_blocks(layers)
+        before = groupmm.LOWERED.copy()
+        text = fn.trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+        assert "ragged_dot" not in text
+        counted = groupmm.lowered_since(before)
+        assert (counted["kernel"], counted["ragged"]) == (3 * layers, 0)
+        programs = {
+            hashlib.sha256(config.encode()).hexdigest() for config in
+            re.findall(r'backend_config = "([^"]*)"', text)}
+        sites = text.count("stablehlo.custom_call @tpu_custom_call")
+        assert len(programs) == counted["programs"]
+        seen[layers] = (len(programs), sites)
+    assert seen[1] == seen[4]
+    assert 0 < seen[1][0] <= stated
+
+
+@pytest.mark.parametrize("flags,ragged", [
+    (["--dnn", "mellum2_tiny", "--experts-held", "2:2", "--layers-held", "2",
+      *TOKENS], 6),
+    (["--dnn", "laguna_xs2_tiny", "--experts-held", "2:4", *TOKENS], 12),
+    (["--dnn", "resnet20"], 0),
+], ids=["mellum2_tiny", "laguna_xs2_tiny", "resnet20"])
+def test_a_step_program_leaves_its_experts_count_on_the_telemetry(
+        tmp_path, monkeypatch, flags, ragged):
+    """One `experts_program` record a built step program, counted while the
+    step was traced: 3 grouped products a sparse layer held, all through
+    `lax.ragged_dot` on the CPU, layers that share a cached trace counted
+    each; none in a model without experts. A second epoch runs the same
+    program and adds no record; the report prints the line."""
+    from mgwfbp_tpu.telemetry.events import events_of, read_events
+
+    monkeypatch.setenv("MGWFBP_SYNTH_TRAIN_N", "16")
+    cfg, trainer = trainer_of(tmp_path, *flags)
+    try:
+        trainer.train_epoch(0)
+        trainer.train_epoch(1)
+        assert trainer.train_step.experts_calls == {
+            "kernel": 0, "ragged": ragged, "programs": 0}
+    finally:
+        trainer.close()
+    records = read_events(
+        os.path.join(str(tmp_path), cfg.tag(), "telemetry.jsonl"))
+    (program,) = events_of(records, "experts_program")
+    assert (program["step"], program["kernel"], program["ragged"],
+            program["programs"]) == (1, 0, ragged, 0)
+    import telemetry_report
+
+    assert (f"0 grouped product(s) through the tiled kernel (0 distinct "
+            f"kernel program(s)), {ragged} through ragged_dot"
+            ) in telemetry_report.format_report(records)

@@ -133,6 +133,36 @@ def test_attention_core_compiles_for_v5e_at_the_cells_shapes(
     assert f"f32[{t},{t}]" not in text and f",{t},{t}]" not in text
 
 
+@pytest.mark.parametrize("m,k,n,groups", [
+    (131072, 2304, 896, 16), (131072, 896, 2304, 16),
+    (65536, 2048, 512, 32), (65536, 512, 2048, 32),
+], ids=["mellum2-gate-up", "mellum2-down", "laguna-xs2-gate-up",
+        "laguna-xs2-down"])
+def test_grouped_product_compiles_for_v5e_at_the_cells_shapes(
+        topo, m, k, n, groups):
+    """ops/groupmm.py's tiled kernel with both transposes at the sparse
+    cells' call shapes (every assignment's row, bf16) and the tiles the shape
+    test gives them: each of the three fits VMEM. The kernel path is called
+    outright: this process traces for the CPU."""
+    from mgwfbp_tpu.ops import groupmm
+
+    one = SingleDeviceSharding(topo.devices[0])
+    lhs = jax.ShapeDtypeStruct((m, k), jnp.bfloat16, sharding=one)
+    rhs = jax.ShapeDtypeStruct((groups, k, n), jnp.bfloat16, sharding=one)
+    sizes = jax.ShapeDtypeStruct((groups,), jnp.int32, sharding=one)
+    tiles = groupmm._kernel_tiles(m, k, n, jnp.bfloat16)
+    assert tiles is not None
+
+    def loss(lhs, rhs, sizes):
+        out = groupmm._kernel_product(lhs, rhs, sizes, tiles)
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        lhs, rhs, sizes).compile().as_text()
+    assert text.count("tpu_custom_call") == 3  # the product, d lhs, d rhs
+    assert "ragged-dot" not in text
+
+
 def _abstract_step_args(model, meta, tx, mesh, per_device_batch):
     """(state, batch) as ShapeDtypeStructs sharded the way the Trainer
     places them: state replicated, batch split over the data axis. A

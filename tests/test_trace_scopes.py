@@ -74,6 +74,35 @@ def test_a_kernels_custom_call_printed_over_several_lines_keeps_its_scope():
                       ("attn_window", "forward"): 2}
 
 
+def test_the_grouped_products_kernels_land_in_the_experts_scope():
+    """As the TPU compiler prints ops/groupmm.py's kernels in a Mellum 2 step
+    (compiled here for a v5e, PR 35): the library's jitted `gmm` / `tgmm`
+    keep the model's name stack in front of their own, so the forward
+    product, the recomputed one, d lhs and d rhs are all `moe_experts`, where
+    `ragged-dot-none` carried no name stack and needed `--prefix`."""
+    back = ("jit(step)/transpose(jvp(Mellum2LM))/moe_experts/jvp(Mellum2LM)/"
+            "moe_experts/checkpoint/")
+    hlo = "".join(
+        f'  %{name} = bf16[131072,896]{{1,0:T(8,128)(2,1)}} custom-call(%a, '
+        '%b), custom_call_target="tpu_custom_call", operand_layout_constraints'
+        '={s32[], s32[17]{0}}, frontend_attributes={kernel_metadata={}}, '
+        f'metadata={{op_name="{op_name}/pallas_call" stack_frame_id=116}}, '
+        'backend_config={"custom_call_config":{"body":"TUzvUg"}}\n'
+        for name, op_name in [
+            ("gmm.1", "jit(step)/jvp(Mellum2LM)/moe_experts/jit(gmm)"),
+            ("gmm.18", back + "rematted_computation/jit(gmm)"),
+            ("gmm.14", back + "jit(gmm)"),
+            ("tgmm.6", back + "jit(tgmm)")])
+    names = trace_scopes.hlo_op_names(hlo)
+    assert set(names) == {"gmm.1", "gmm.18", "gmm.14", "tgmm.6"}
+    totals, top = trace_scopes.split(
+        [("gmm.1", 0, 10), ("gmm.18", 20, 11), ("gmm.14", 40, 12),
+         ("tgmm.6", 60, 13)], names, SCOPES)
+    assert totals == {("moe_experts", "forward"): 10,
+                      ("moe_experts", "backward"): 36}
+    assert top[0] == (13, "tgmm.6", "moe_experts")
+
+
 @pytest.mark.parametrize("op_name,want", [
     ("jit(step)/jvp(M)/attn_window/dot_general", ("attn_window", "forward")),
     ("jit(step)/transpose(jvp(M))/attn_window/dot_general",

@@ -162,6 +162,13 @@ def format_report(records: list[dict]) -> str:
             f"{prog.get('kernel')} core(s) through the fused kernel, "
             f"{prog.get('blocks')} through the plain blocks"
         )
+    for prog in events_of(records, "experts_program"):
+        lines.append(
+            f"experts (step program built by step {prog.get('step')}): "
+            f"{prog.get('kernel')} grouped product(s) through the tiled "
+            f"kernel ({prog.get('programs')} distinct kernel program(s)), "
+            f"{prog.get('ragged')} through ragged_dot"
+        )
     if steps:
         durs = [float(s["dur_s"]) for s in steps]
         lines.append("")
@@ -769,6 +776,7 @@ def _synthetic_stream(path: str) -> None:
     w.emit("step_program", step=1, collectives=33, async_collectives=5,
            compiler_options=["xla_enable_async_all_reduce"])
     w.emit("attention_program", step=1, kernel=1, blocks=3)
+    w.emit("experts_program", step=1, kernel=12, ragged=0, programs=4)
     hidden = sum(r.hidden_s for r in rows)
     total = sum(r.comm_s for r in rows)
     w.emit(
