@@ -22,6 +22,11 @@ Layer map (mirrors SURVEY.md §1):
                        (horovod.torch.mpi_ops / NCCL / OpenMPI)
 """
 
-from mgwfbp_tpu.version import __version__
+import time as _time
+
+# where the `import` span of the set-up record starts (telemetry/phases.py)
+_IMPORT_T0 = _time.perf_counter()
+
+from mgwfbp_tpu.version import __version__  # noqa: E402
 
 __all__ = ["__version__"]

@@ -34,6 +34,7 @@ from mgwfbp_tpu.data.loader import (
     normalize_images,
 )
 from mgwfbp_tpu.data.sharding import ShardInfo
+from mgwfbp_tpu.telemetry.phases import setup_span
 
 
 def _wrap_prefetch(train_loader):
@@ -109,6 +110,10 @@ def data_prepare(
     35-token BPTT window; seq-parallel transformers need a length divisible
     by the seq mesh extent); for `tokens` it is the sequence length.
     `vocab_size` states the `tokens` vocabulary (data/tokens.py).
+
+    The data sets' construction (files read, or the synthetic twin drawn)
+    is the `dataset` span of the set-up record (telemetry/phases.py), apart
+    from the loaders and the prefetch pool's start.
     """
     name = dataset.lower()
     if name in ("mnist", "cifar10", "imagenet"):
@@ -127,8 +132,10 @@ def data_prepare(
                 "cifar10": load_cifar10,
                 "imagenet": load_imagenet_hdf5,
             }[name]
-            train = loader_fn(data_dir, "train")
-            val = loader_fn(data_dir, "val" if name == "imagenet" else "test")
+            with setup_span("dataset"):
+                train = loader_fn(data_dir, "train")
+                val = loader_fn(
+                    data_dir, "val" if name == "imagenet" else "test")
         is_synth = train is None or val is None
         if is_synth:
             if synthetic is False:
@@ -143,8 +150,9 @@ def data_prepare(
                 from mgwfbp_tpu.data.datasets import synthetic_images_hard
 
                 gen = synthetic_images_hard
-            train = gen(_synth_size("train", name), (h, w, c), nc, seed)
-            val = gen(_synth_size("val", name), (h, w, c), nc, seed + 1)
+            with setup_span("dataset"):
+                train = gen(_synth_size("train", name), (h, w, c), nc, seed)
+                val = gen(_synth_size("val", name), (h, w, c), nc, seed + 1)
         else:
             real_hw = tuple(train.data.shape[1:3])
             if image_hw is not None and real_hw != tuple(image_hw):
@@ -195,32 +203,37 @@ def data_prepare(
         )
 
         nsteps = num_steps or NUM_STEPS
-        streams = None
-        if not synthetic:
-            streams = (load_ptb_stream(data_dir, "train"),
-                       load_ptb_stream(data_dir, "valid"))
-            if streams[0] is None or streams[1] is None:
-                streams = None
-        is_synth = streams is None
-        if is_synth:
-            if synthetic is False:
-                raise FileNotFoundError(f"PTB files not found under {data_dir!r}")
-            vocab_size = VOCAB_SIZE
-            train_stream = synthetic_ptb_stream(_SYNTH_TRAIN["ptb"], seed=seed)
-            val_stream = synthetic_ptb_stream(_SYNTH_VAL["ptb"], seed=seed + 1)
-        else:
-            (train_stream, vocab_size), (val_stream, _) = streams
-        # Stateful-BPTT layout: contiguous sub-streams per batch element and
-        # per rank (see ptb.carry_layout); NO shuffling, NO sample-sharding —
-        # the carry must see textually consecutive windows each step.
-        train = carry_layout(
-            train_stream, nsteps, batch_size, shard.rank, shard.nranks,
-            vocab_size,
-        )
-        val = carry_layout(
-            val_stream, nsteps, batch_size, shard.rank, shard.nranks,
-            vocab_size,
-        )
+        with setup_span("dataset"):
+            streams = None
+            if not synthetic:
+                streams = (load_ptb_stream(data_dir, "train"),
+                           load_ptb_stream(data_dir, "valid"))
+                if streams[0] is None or streams[1] is None:
+                    streams = None
+            is_synth = streams is None
+            if is_synth:
+                if synthetic is False:
+                    raise FileNotFoundError(
+                        f"PTB files not found under {data_dir!r}")
+                vocab_size = VOCAB_SIZE
+                train_stream = synthetic_ptb_stream(
+                    _SYNTH_TRAIN["ptb"], seed=seed)
+                val_stream = synthetic_ptb_stream(
+                    _SYNTH_VAL["ptb"], seed=seed + 1)
+            else:
+                (train_stream, vocab_size), (val_stream, _) = streams
+            # Stateful-BPTT layout: contiguous sub-streams per batch element
+            # and per rank (see ptb.carry_layout); NO shuffling, NO
+            # sample-sharding — the carry must see textually consecutive
+            # windows each step.
+            train = carry_layout(
+                train_stream, nsteps, batch_size, shard.rank, shard.nranks,
+                vocab_size,
+            )
+            val = carry_layout(
+                val_stream, nsteps, batch_size, shard.rank, shard.nranks,
+                vocab_size,
+            )
         train_loader = ShardedLoader(train, batch_size, shuffle=False, seed=seed)
         val_loader = ShardedLoader(val, batch_size, shuffle=False, seed=seed)
         return DataBundle(
@@ -235,28 +248,29 @@ def data_prepare(
         from mgwfbp_tpu.models import DATASET_CLASSES
 
         seq_len = num_steps or tok.SEQ_LEN
-        streams = None
-        if not synthetic:
-            streams = (tok.load_token_stream(data_dir, "train"),
-                       tok.load_token_stream(data_dir, "valid"))
-            if streams[0] is None or streams[1] is None:
-                streams = None
-        is_synth = streams is None
-        if is_synth:
-            if synthetic is False:
-                raise FileNotFoundError(
-                    f"tokens/train.npy and tokens/valid.npy not found under "
-                    f"{data_dir!r}")
-            vocab = vocab_size or DATASET_CLASSES["tokens"]
-            streams = tuple(
-                tok.synthetic_token_stream(n, seq_len, vocab, seed + i)
-                for i, n in enumerate((
-                    _synth_size("train", "tokens"),
-                    _synth_size("val", "tokens"))))
-        else:
-            vocab = vocab_size or int(max(s.max() for s in streams)) + 1
-        train, val = (
-            tok.sequence_dataset(s, seq_len, vocab) for s in streams)
+        with setup_span("dataset"):
+            streams = None
+            if not synthetic:
+                streams = (tok.load_token_stream(data_dir, "train"),
+                           tok.load_token_stream(data_dir, "valid"))
+                if streams[0] is None or streams[1] is None:
+                    streams = None
+            is_synth = streams is None
+            if is_synth:
+                if synthetic is False:
+                    raise FileNotFoundError(
+                        f"tokens/train.npy and tokens/valid.npy not found "
+                        f"under {data_dir!r}")
+                vocab = vocab_size or DATASET_CLASSES["tokens"]
+                streams = tuple(
+                    tok.synthetic_token_stream(n, seq_len, vocab, seed + i)
+                    for i, n in enumerate((
+                        _synth_size("train", "tokens"),
+                        _synth_size("val", "tokens"))))
+            else:
+                vocab = vocab_size or int(max(s.max() for s in streams)) + 1
+            train, val = (
+                tok.sequence_dataset(s, seq_len, vocab) for s in streams)
         train_loader = ShardedLoader(
             train, batch_size, shard, shuffle=True, seed=seed)
         val_loader = ShardedLoader(
@@ -271,7 +285,8 @@ def data_prepare(
     if name == "an4":
         from mgwfbp_tpu.data.audio import an4_prepare
 
-        bundle = an4_prepare(data_dir, batch_size, shard, seed, synthetic)
+        with setup_span("dataset"):  # the loaders too: made in one call
+            bundle = an4_prepare(data_dir, batch_size, shard, seed, synthetic)
         bundle.train = _wrap_prefetch(bundle.train)
         return bundle
     raise ValueError(f"unknown dataset {dataset!r}")

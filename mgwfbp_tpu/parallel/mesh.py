@@ -29,6 +29,8 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from mgwfbp_tpu.telemetry.phases import backend_span
+
 DATA_AXIS = "data"
 SEQ_AXIS = "seq"
 DCN_AXIS = "dcn"
@@ -152,7 +154,12 @@ def make_mesh(
     pod jax enumerates slice-by-slice, so the LEADING dcn dimension puts
     each slice's chips contiguously on the inner axes.
     """
-    devs = list(devices if devices is not None else jax.devices())
+    if devices is None:
+        # where this is the process's first touch of the backend, its start
+        # is a span of the set-up record (telemetry/phases.py)
+        with backend_span():
+            devices = jax.devices()
+    devs = list(devices)
     n = len(devs)
     seq = max(spec.seq, 1)
     dcn = max(spec.dcn, 1)

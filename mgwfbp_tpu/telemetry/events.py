@@ -94,6 +94,14 @@ EVENT_TYPES: dict[str, tuple[str, ...]] = {
     # (3 a sparse layer held), and the distinct kernel programs among the
     # former with their transposes; 0, 0 and 0 for a model without experts
     "experts_program": ("step", "kernel", "ragged", "programs"),
+    # what set-up was made of, once per process start, when the host has
+    # read the first step's results, and once more after a rebuild that
+    # recompiles the step (telemetry/phases.py): `spans` as
+    # {name: [start_s, dur_s, parent]} on the stream's clock (negative for
+    # what preceded the writer), `counters` (programs traced, lowered,
+    # compiled, loaded from the compile cache; slow_events), `origin_wall`
+    # the process's start as the OS knows it
+    "setup": ("spans", "counters", "origin_wall"),
     # autotune: one raced candidate / the committed winner
     "autotune_race": ("label", "comm_op", "num_groups", "verified",
                       "measured_step_s"),
@@ -351,6 +359,11 @@ class EventWriter:
     def now(self) -> float:
         """Seconds since this writer opened (span-timestamp base)."""
         return time.perf_counter() - self._t0
+
+    def clock_of(self, perf_counter_s: float) -> float:
+        """A `time.perf_counter` reading on `now`'s clock: negative for what
+        preceded the writer (the `setup` record's spans)."""
+        return perf_counter_s - self._t0
 
     def emit(self, event: str, **fields) -> None:
         """Append one typed record. Unknown event names and missing

@@ -71,11 +71,68 @@ k-1's outputs, the `guard` span (or `health`, see above), which is a wait
 with the chip busy. `log` reads the step just dispatched, every
 `MGWFBP_LOG_INTERVAL`-th step, and the chip idles through the next `place`
 and dispatch.
+
+Set-up is spanned by the same means (SetupRecorder below). What a (re)started
+job does before it trains again happens mostly before an `EventWriter`
+exists: the interpreter's start, the imports, the backend, the whole of
+`Trainer.__init__` (the writer is built near its end). So those spans are
+kept in memory on `time.perf_counter`, the clock `EventWriter.now` reads, and
+written once, as one `setup` record, when the host has read the first step's
+results (the `guard` read one step later, else `health`, else the epoch's
+`drain`), which is after that step's own `step` record and before the next:
+
+    {"event": "setup", "origin_wall": 1790736012.42,
+     "spans": {"setup": [-31.2, 105.8, null],
+               "before_init": [-31.2, 21.3, "setup"],
+               "import": [-30.9, 4.1, "before_init"],
+               "init": [-9.9, 53.2, "setup"], "data": [-9.1, 27.0, "init"],
+               "dataset": [-9.1, 26.6, "data"], ...,
+               "first_step": [44.1, 19.0, "setup"],
+               "trace": [44.2, 4.9, "first_step"], ...},
+     "counters": {"programs_traced": 812, "programs_lowered": 97, ...}}
+
+`spans` maps a name to `[start_s, dur_s, parent]`: `start_s` on the stream's
+clock (`EventWriter.clock_of`: negative for what preceded the writer, the
+clock `benchmarks/run.py align` ties to the device trace), `parent` the span
+that caused it, None for a root. A span's self time is its duration less what
+its children cover (`self_times`). Every span entered through `setup_span`
+also enters a `jax.profiler.TraceAnnotation` of its name. SETUP_SPANS lists
+the names; the origin is the process's start as the OS knows it
+(`_process_age_s`), so the interpreter's start and every import are inside
+`before_init`. `first_step` is step 1's dispatch, the `start_s` and `dur_s`
+of its `step` record; its children come from jax's own monitoring events,
+which the one listener below keeps while a set-up is open and never after:
+`trace` (the longest `jaxpr_trace_duration` inside the dispatch: the step
+program's, which holds every trace nested in it), `lower`
+(`jaxpr_to_mlir_module_duration`), `compile` (`backend_compile_duration`:
+cache key and load on a hit, the compile and the cache write on a miss) and
+inside it `cache_load` (`cache_retrieval_time_sec`); what the trace itself
+lowered or compiled is inside `trace` and not counted twice. Counters, over
+the whole set-up: `programs_traced`, `programs_lowered`, `cache_loads`;
+`programs_compiled`, backend compiles that the persistent cache did not hold
+and would (at least `jax_persistent_cache_min_compile_time_secs` long: a warm
+start reads 0); `small_compiles` and `small_compile_s`, the shorter ones,
+which no start finds cached; `kernel_trace_s`, the outermost traces nested in
+the step program's, summed; `slow_events`, every monitoring event of 0.1 s or
+more as `[event, function, seconds]`, at most 32, longest first. Readers:
+`benchmarks/setup_spans.py` (by `setup_record()`), `tools/telemetry_report.py`
+and the Trainer's own "set-up:" log line (`setup_line`).
+
+A later Trainer of the same process opens a set-up of its own at its
+constructor's entry (no `before_init`). A rebuild that recompiles the step
+(`update_nworker`, autotune's swap) writes one more record with `steps` and
+`first_step` (and the latter's children) alone, at that step's dispatch. With
+telemetry off the constructor enters NO_SPAN, reads no clock and drops the
+process's buffer, so the listener is back to its integer add. A name entered
+again under the same parent keeps its first start and sums its durations;
+under another parent it is kept as `<parent>.<name>`.
 """
 
 from __future__ import annotations
 
 import contextlib
+import os
+import time
 from typing import Callable, Iterator, Optional
 
 import jax
@@ -97,14 +154,56 @@ PHASES = (
     "snapshot",  # epoch end: `epoch` event, overlap snapshot
 )
 
+# the `setup` record's spans, each under its parent; benchmarks/setup_spans.py
+# and tools/telemetry_report.py go by these names
+SETUP_SPANS = (
+    "setup",             # origin -> the host has read step 1's results
+    "before_init",       #   origin -> Trainer.__init__'s entry
+    "import",            #     mgwfbp_tpu's import -> train/trainer.py's end
+    "backend",           #     the program's own first backend touch
+                         #     (train_cli.main; under `mesh` where make_mesh
+                         #     is first): absent where the caller made it
+    "init",              #   Trainer.__init__
+    "mesh",              #     make_mesh
+    "model",             #     _create_model, _apply_lm_window (both calls)
+    "data",              #     _build_loaders
+    "dataset",           #       the data sets' construction (data_prepare)
+    "optimizer",         #     _build_optimizer, the state made and placed
+    "reducer",           #     _build_reducer
+    "profile_backward",  #       _profile_backward
+    "profile_forward",   #       _profile_forward
+    "solve",             #       make_merged_allreduce: solver, bucket plan
+    "steps",             #     _build_steps
+    "sinks",             #     _build_run_sinks
+    "resume",            #     _maybe_resume
+    "first_step",        #   step 1's dispatch (its record's start_s, dur_s)
+    "trace",             #     the step program's trace
+    "lower",             #     jaxpr -> MLIR module
+    "compile",           #     cache key + load, or compile + write
+    "cache_load",        #       the persistent cache's read
+    "first_result",      #   step 1's dispatch returned -> its results read
+    "program_read",      #     _note_step_program, _note_traced_programs
+)
+
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
 _LOWERING_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_SLOW_EVENT_S = 0.1
+_SLOW_EVENTS_KEPT = 32
+# a set-up nobody closes (no Trainer is ever built) stops keeping events here
+_EVENTS_KEPT = 1 << 16
 _lowered = 0
+# the set-up that is open (SetupRecorder, below), None once it is written
+_setup: Optional["SetupRecorder"] = None
 
 
-def _on_duration_event(name: str, _secs: float, **_kw) -> None:
+def _on_duration_event(name: str, secs: float, **kw) -> None:
     global _lowered
     if name == _LOWERING_EVENT:
         _lowered += 1
+    if _setup is not None:
+        _setup.note(name, secs, kw.get("fun_name", ""))
 
 
 # one listener for the process, however many Trainers it builds
@@ -114,6 +213,343 @@ jax.monitoring.register_event_duration_secs_listener(_on_duration_event)
 def lowered_programs() -> int:
     """Programs lowered in this process since this module was imported."""
     return _lowered
+
+
+def _process_age_s() -> Optional[float]:
+    """Seconds since the OS started this process: its start time
+    (/proc/self/stat, field 22, clock ticks after boot) against the time
+    since boot. /proc/stat's `btime` would give the same difference in whole
+    seconds only, so the boot clock is read from /proc/uptime (10 ms). None
+    where /proc cannot be read."""
+    try:
+        with open("/proc/self/stat") as f:
+            # the command (field 2) may hold spaces: count from its ')'
+            started = float(f.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime") as f:
+            up_s = float(f.read().split()[0])
+        age_s = up_s - started / os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, IndexError):
+        return None
+    return age_s if age_s >= 0.0 else None
+
+
+def self_times(spans: dict) -> dict:
+    """name -> the span's duration less what its children cover."""
+    out = {name: span[1] for name, span in spans.items()}
+    for _, dur_s, parent in spans.values():
+        if parent in out:
+            out[parent] -= dur_s
+    return out
+
+
+class _SetupSpan:
+    """One entry of a set-up span: as `_Span`, the clock read lies inside
+    the profiler annotation; the parent is whatever span is open."""
+
+    __slots__ = ("_rec", "_name", "_annotation", "_t0")
+
+    def __init__(self, rec: "SetupRecorder", name: str):
+        self._rec = rec
+        self._name = name
+        self._annotation = jax.profiler.TraceAnnotation(name)
+
+    def __enter__(self):
+        self._annotation.__enter__()
+        self._name = self._rec.enter(self._name)
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self._rec.leave(self._name, self._t0, time.perf_counter() - self._t0)
+        self._annotation.__exit__(*exc)
+        return False
+
+
+class SetupRecorder:
+    """One set-up's spans and monitoring events, on `time.perf_counter`,
+    until `finish` puts them on a stream's clock. `begin_setup` makes it."""
+
+    def __init__(self, origin: float, roots: tuple = ("setup",)):
+        self.origin = origin
+        self.origin_wall = time.time() - (time.perf_counter() - origin)
+        # name -> [start, seconds, parent]
+        self.spans: dict[str, list] = {}
+        self._open = list(roots)
+        # a rebuild's record has no root: `steps` and `first_step` alone
+        self.rebuild = not roots
+        # (clock at the event's end, event, seconds, function)
+        self.events: list[tuple[float, str, float, str]] = []
+        # the first step dispatched after the build, its dispatch on the
+        # STREAM's clock, and when its results were on the host
+        self.first_step: Optional[int] = None
+        self._dispatch: Optional[tuple[float, float]] = None
+        self._read_at: Optional[float] = None
+
+    def span(self, name: str) -> _SetupSpan:
+        return _SetupSpan(self, name)
+
+    def enter(self, name: str) -> str:
+        """Opens `name` under the span that is open; returns the name it is
+        kept under (`<parent>.<name>` where the name has another parent)."""
+        parent = self._open[-1] if self._open else None
+        have = self.spans.get(name)
+        if have is not None and have[2] != parent:
+            name = f"{parent}.{name}"
+        self._open.append(name)
+        return name
+
+    def leave(self, name: str, start: float, seconds: float) -> None:
+        self._open.remove(name)
+        self.add(name, start, seconds, self._open[-1] if self._open else None)
+
+    def add(self, name: str, start: float, seconds: float, parent) -> None:
+        have = self.spans.get(name)
+        if have is None:
+            self.spans[name] = [start, seconds, parent]
+        else:
+            have[1] += seconds
+
+    def close_before_init(self, now: float) -> None:
+        """A constructor claims the process's set-up: `before_init` ends."""
+        self.add("before_init", self.origin, now - self.origin, "setup")
+        self._open = ["setup"]
+
+    def note(self, event: str, seconds: float, function: str) -> None:
+        if len(self.events) < _EVENTS_KEPT:
+            self.events.append(
+                (time.perf_counter(), event, seconds, str(function)))
+
+    def dispatched(self, step: int, start_s: float, dur_s: float) -> None:
+        """The first step after the build is on its way: `start_s`, `dur_s`
+        of its `step` record."""
+        self.first_step = int(step)
+        self._dispatch = (float(start_s), float(dur_s))
+
+    def results_read(self) -> None:
+        """The host holds the first step's results (the first call counts)."""
+        if self._read_at is None:
+            self._read_at = time.perf_counter()
+
+    def _first_step_spans(self, start: float, end: float) -> tuple:
+        """(spans, kernel_trace_s) of the events inside [start, end]: the
+        longest trace is the step program's; what it traced, lowered and
+        compiled on its own way is inside it."""
+        inside = [e for e in self.events if start <= e[0] <= end]
+        traces = [e for e in inside if e[1] == _TRACE_EVENT]
+        spans: dict[str, list] = {}
+        nested_s = 0.0
+        t_lo = t_hi = start
+        if traces:
+            t_hi, _, trace_s, _ = max(traces, key=lambda e: e[2])
+            t_lo = t_hi - trace_s
+            spans["trace"] = [t_lo, trace_s, "first_step"]
+            # outermost first: by start, the longer first among equals
+            covered = t_lo
+            for at, _, secs, _ in sorted(
+                    (e for e in traces if t_lo <= e[0] - e[2] and e[0] < t_hi),
+                    key=lambda e: (e[0] - e[2], -e[2])):
+                if at > covered:
+                    nested_s += secs
+                    covered = at
+        for name, event, parent in (
+                ("lower", _LOWERING_EVENT, "first_step"),
+                ("compile", _COMPILE_EVENT, "first_step"),
+                ("cache_load", _CACHE_LOAD_EVENT, "compile")):
+            for at, what, secs, _ in inside:
+                if what == event and not t_lo <= at < t_hi:
+                    have = spans.setdefault(name, [at - secs, 0.0, parent])
+                    have[1] += secs
+        if "compile" not in spans:
+            spans.pop("cache_load", None)
+        return spans, nested_s
+
+    def _counters(self) -> dict:
+        least_s = float(
+            jax.config.jax_persistent_cache_min_compile_time_secs)
+        counts = dict.fromkeys((
+            "programs_traced", "programs_lowered", "programs_compiled",
+            "small_compiles", "cache_loads"), 0)
+        small_s = 0.0
+        loaded = False
+        for _, event, secs, _ in self.events:
+            if event == _TRACE_EVENT:
+                counts["programs_traced"] += 1
+            elif event == _LOWERING_EVENT:
+                counts["programs_lowered"] += 1
+            elif event == _CACHE_LOAD_EVENT:
+                counts["cache_loads"] += 1
+                loaded = True  # inside the compile event that follows
+            elif event == _COMPILE_EVENT:
+                if loaded:
+                    loaded = False
+                elif secs >= least_s:
+                    counts["programs_compiled"] += 1
+                else:
+                    counts["small_compiles"] += 1
+                    small_s += secs
+        slow = sorted(
+            (e for e in self.events if e[2] >= _SLOW_EVENT_S),
+            key=lambda e: -e[2])[:_SLOW_EVENTS_KEPT]
+        return {
+            **counts, "small_compile_s": round(small_s, 6),
+            "slow_events": [
+                [event.rsplit("/", 1)[-1], function, round(secs, 3)]
+                for _, event, secs, function in slow],
+        }
+
+    def finish(self, clock_of: Callable[[float], float]) -> dict:
+        """The record's fields, spans on the clock `clock_of` maps
+        `perf_counter` to; the set-up is over for the listener too."""
+        global _setup, _last_record
+        if _setup is self:
+            _setup = None
+        end = self._read_at if self._read_at is not None \
+            else time.perf_counter()
+        spans = {name: list(span) for name, span in self.spans.items()}
+        counters = self._counters()
+        root = None if self.rebuild else "setup"
+        if self._dispatch is not None:
+            # the step record's own numbers, taken back to perf_counter
+            start = self._dispatch[0] - clock_of(0.0)
+            spans["first_step"] = [start, self._dispatch[1], root]
+            parts, nested_s = self._first_step_spans(
+                start, start + self._dispatch[1])
+            counters["kernel_trace_s"] = round(nested_s, 6)
+            spans.update(parts)
+            if root is not None:
+                done = start + self._dispatch[1]
+                spans["first_result"] = [done, max(end - done, 0.0), root]
+                if "program_read" in spans:  # entered while this was open
+                    spans["program_read"][2] = "first_result"
+        if root is not None:
+            spans[root] = [self.origin, end - self.origin, None]
+
+        def on_stream(start: float, dur: float, parent) -> list:
+            # whole microseconds, start and end rounded as `_write` does
+            start_s = round(clock_of(start), 6)
+            end_s = round(clock_of(start + dur), 6)
+            return [start_s, round(end_s - start_s, 6), parent]
+
+        record = {
+            "origin_wall": round(self.origin_wall, 3),
+            "spans": {name: on_stream(*span) for name, span in spans.items()},
+            "counters": counters,
+        }
+        if self._dispatch is not None:
+            # to the last digit what the step's own record says
+            record["spans"]["first_step"][:2] = self._dispatch
+        _last_record = record
+        return record
+
+
+def _process_setup() -> SetupRecorder:
+    """The set-up this process opened with: origin at the process's start,
+    `before_init` open until a Trainer's constructor claims it."""
+    now = time.perf_counter()
+    import mgwfbp_tpu
+
+    imported = getattr(mgwfbp_tpu, "_IMPORT_T0", now)
+    age_s = _process_age_s()
+    origin = now - age_s if age_s is not None else imported
+    setup = SetupRecorder(min(origin, imported), roots=("before_init",))
+    setup.add("import", imported, 0.0, "before_init")
+    return setup
+
+
+_setup = _process_setup()
+_claimed = False
+_last_record: Optional[dict] = None
+
+
+def note_imported() -> None:
+    """train/trainer.py's last line: the `import` span of the process's
+    set-up ends here (at the first call)."""
+    setup = _setup
+    if setup is not None and not _claimed and not setup.spans["import"][1]:
+        setup.spans["import"][1] = \
+            time.perf_counter() - setup.spans["import"][0]
+
+
+def begin_setup(rebuild: bool = False) -> SetupRecorder:
+    """A Trainer's constructor (or, `rebuild`, a rebuild of its step) opens
+    a set-up: the process's own where no Trainer has claimed it yet, with
+    `before_init` closed here; else a new one whose origin is now."""
+    global _setup, _claimed
+    now = time.perf_counter()
+    if rebuild:
+        _setup = SetupRecorder(now, roots=())
+    elif _setup is not None and not _claimed:
+        _setup.close_before_init(now)
+    else:
+        _setup = SetupRecorder(now)
+    _claimed = True
+    return _setup
+
+
+def drop_setup() -> None:
+    """Telemetry is off: whatever set-up is open is dropped unwritten, and
+    the listener keeps nothing from here on. Reads no clock."""
+    global _setup, _claimed
+    _setup, _claimed = None, True
+
+
+def setup_span(name: str):
+    """A span of the set-up that is open, for the layers under the Trainer
+    (data_prepare, make_mesh, train_cli); NO_SPAN where none is."""
+    return NO_SPAN if _setup is None else _setup.span(name)
+
+
+def backend_span():
+    """`backend`, round the program's first touch of the backend
+    (`jax.devices()`, `init_distributed`); NO_SPAN where a backend is up
+    already (the caller's own touch, as under benchmarks/run.py: the parent
+    span's self time holds it)."""
+    if _setup is None:
+        return NO_SPAN
+    from jax._src import xla_bridge
+
+    return NO_SPAN if xla_bridge.backends_are_initialized() \
+        else _setup.span("backend")
+
+
+def setup_record() -> Optional[dict]:
+    """The last `setup` record this process finished, or None."""
+    return _last_record
+
+
+def setup_line(record: dict) -> str:
+    """The record as one log line, in the constructor's own order."""
+    spans = record["spans"]
+
+    def of(parent: str, *extra: str) -> str:
+        parts = [f"{name} {span[1]:.1f}" for name, span in spans.items()
+                 if span[2] == parent and name not in extra]
+        return f" ({', '.join(parts)})" if parts else ""
+
+    def part(label: str, name: str, *extra: str) -> Optional[str]:
+        if name not in spans:
+            return None
+        return f"{label} {spans[name][1]:.1f}{of(name, *extra)}"
+
+    first = part("first step", "first_step")
+    if first and "cache_load" in spans:
+        first = first[:-1] + f" of which cache load " \
+            f"{spans['cache_load'][1]:.1f})"
+    parts = [
+        part("before the constructor", "before_init"),
+        part("constructor", "init"),
+        part("steps rebuilt in", "steps") if "setup" not in spans else None,
+        first, part("first result", "first_result"),
+    ]
+    total = f"{spans['setup'][1]:.1f} s to the first result" \
+        if "setup" in spans else "the step rebuilt"
+    counters = record["counters"]
+    return (
+        f"set-up: {total}: " + ", ".join(p for p in parts if p)
+        + f"; {counters['programs_compiled']} program(s) compiled, "
+        f"{counters['cache_loads']} loaded from the compile cache, "
+        f"{counters['small_compiles']} too small for it "
+        f"({counters['small_compile_s']:.1f} s)")
 
 
 # what the loop enters in a span's place with telemetry off: one shared
@@ -267,6 +703,14 @@ class PhaseRecorder:
         self._ready = self._native = None
         if done is not None:
             self._write(done)
+
+    def dispatch_span(self) -> tuple[float, float]:
+        """`start_s`, `dur_s` of the step dispatched last."""
+        return self._record["start_s"], self._record["dur_s"]
+
+    def holds(self, step: int) -> bool:
+        """The record of `step` is still held back, not yet written."""
+        return self._record is not None and self._record["step"] <= step
 
     def flush(self) -> None:
         """The loop is over (or unwinding, or about to be killed): write the

@@ -47,6 +47,7 @@ from mgwfbp_tpu.parallel.mesh import DATA_AXIS, SEQ_AXIS, MeshSpec, make_mesh
 from mgwfbp_tpu.profiling import benchmark_trainer_backward
 from mgwfbp_tpu.runtime import ResizeUnsupported
 from mgwfbp_tpu.runtime import coordination as coord
+from mgwfbp_tpu.telemetry import phases
 from mgwfbp_tpu.telemetry.phases import NO_SPAN, PhaseRecorder, no_span
 from mgwfbp_tpu.train.step import (
     create_train_state,
@@ -128,13 +129,40 @@ class Trainer:
         profile_backward: bool = True,
         synthetic_data: Optional[bool] = None,
     ):
+        # set-up as spans (telemetry/phases.py): the constructor and what
+        # preceded it, written as one `setup` record once the first step's
+        # results are read. With telemetry off nothing is opened, no clock
+        # read, and the buffer the process opened with is dropped
+        self._setup: Optional[phases.SetupRecorder] = None
+        self.telemetry = None  # _build_run_sinks opens it
+        if config.telemetry or config.metrics_port is not None:
+            self._setup = phases.begin_setup()
+        else:
+            self._drop_setup()
+        with self._setup_span("init"):
+            self._construct(config, mesh, profile_backward, synthetic_data)
+
+    def _setup_span(self, name: str):
+        return NO_SPAN if self._setup is None else self._setup.span(name)
+
+    def _drop_setup(self) -> None:
+        """No `setup` record will be written: nothing is kept from here on."""
+        self._setup = None
+        phases.drop_setup()
+
+    def _construct(
+        self, config: TrainConfig, mesh, profile_backward: bool,
+        synthetic_data: Optional[bool],
+    ) -> None:
+        span = self._setup_span
         self.config = config
-        # graft: group-uniform -- the mesh derives from config + the global device set, identical on every process
-        self.mesh = mesh if mesh is not None else make_mesh(
-            MeshSpec(
-                data=-1, seq=config.seq_parallel, dcn=config.dcn_slices,
+        with span("mesh"):
+            # graft: group-uniform -- the mesh derives from config + the global device set, identical on every process
+            self.mesh = mesh if mesh is not None else make_mesh(
+                MeshSpec(
+                    data=-1, seq=config.seq_parallel, dcn=config.dcn_slices,
+                )
             )
-        )
         from mgwfbp_tpu.parallel.mesh import DCN_AXIS
 
         self.dcn_size = self.mesh.shape.get(DCN_AXIS, 1)
@@ -171,9 +199,10 @@ class Trainer:
             if config.dtype not in (None, "", "float32", "f32")
             else None
         )
-        # graft: group-uniform -- model + metadata derive from config alone
-        self.model, self.meta = self._create_model()
-        self._apply_lm_window()
+        with span("model"):
+            # graft: group-uniform -- model + metadata derive from config alone
+            self.model, self.meta = self._create_model()
+            self._apply_lm_window()
         # sequence parallelism (ring attention): shard the lm time dim over
         # the mesh's seq axis. Only carry-free lm models expose a seq_axis
         # attribute (models/transformer.py). self.model stays axis-free
@@ -200,37 +229,40 @@ class Trainer:
             image_hw = self.meta.input_shape[:2]  # inception 299
         self._image_hw = image_hw
         self._synthetic_data = synthetic_data
-        self.bundle = self._build_loaders()
+        with span("data"):
+            self.bundle = self._build_loaders()
         if self.bundle.num_classes != self.meta.num_classes:
-            # graft: group-uniform -- model + metadata derive from config alone
-            self.model, self.meta = self._create_model(
-                self.bundle.num_classes
-            )
-            # the rebuild reset meta/model to registry defaults; re-apply
-            # the window-length override
-            self._apply_lm_window()
+            with span("model"):
+                # graft: group-uniform -- model + metadata derive from config alone
+                self.model, self.meta = self._create_model(
+                    self.bundle.num_classes
+                )
+                # the rebuild reset meta/model to registry defaults;
+                # re-apply the window-length override
+                self._apply_lm_window()
         # schedule anchor: epoch position the step->lr conversion continues
         # from (moves only on elastic resizes, see update_nworker)
         self._sched_step_offset = 0
         self._sched_epoch_offset = 0.0
-        self._build_optimizer()
-        # on the mesh from birth, like every state the step returns: an
-        # uncommitted initial state gives the second call other input
-        # shardings than the first, and the whole step compiles twice
-        self.state = self._replicate_onto_mesh(create_train_state(
-            jax.random.PRNGKey(config.seed),
-            self.model,
-            self._example_input(),
-            self.tx,
-        ))
-        # canonical param pytree shapes/dtypes: the shape source for layer
-        # specs, reducer builds, and checkpoint templates — on the
-        # cross-step (rs_fwd_ag) path the live state.params is the sharded
-        # carry and no longer LOOKS like the model's param tree
-        self._params_template = jax.tree_util.tree_map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
-            self.state.params,
-        )
+        with span("optimizer"):
+            self._build_optimizer()
+            # on the mesh from birth, like every state the step returns: an
+            # uncommitted initial state gives the second call other input
+            # shardings than the first, and the whole step compiles twice
+            self.state = self._replicate_onto_mesh(create_train_state(
+                jax.random.PRNGKey(config.seed),
+                self.model,
+                self._example_input(),
+                self.tx,
+            ))
+            # canonical param pytree shapes/dtypes: the shape source for
+            # layer specs, reducer builds, and checkpoint templates — on the
+            # cross-step (rs_fwd_ag) path the live state.params is the
+            # sharded carry and no longer LOOKS like the model's param tree
+            self._params_template = jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+                self.state.params,
+            )
         self._tb_cache = None  # measured backward profile, reused on resize
         self._tf_cache = None  # measured forward profile (rs_fwd_ag)
         # trace-attributed per-group comm seconds (layout order) for the
@@ -244,8 +276,9 @@ class Trainer:
         self._train_step_compiled = False
         self._eval_step_compiled = False
         self._profile_backward_enabled = profile_backward
-        # graft: group-uniform -- the merge schedule solves from broadcast-identical profiles; later swaps ride group-agreed commits
-        self.reducer = self._build_reducer(profile_backward)
+        with span("reducer"):
+            # graft: group-uniform -- the merge schedule solves from broadcast-identical profiles; later swaps ride group-agreed commits
+            self.reducer = self._build_reducer(profile_backward)
         if self._sharded_opt or self._cross_step:
             # rs_opt_ag / rs_fwd_ag: the optimizer state lives as 1/world
             # bucket shards on each device from here on; it only returns
@@ -290,7 +323,11 @@ class Trainer:
                 self.reducer.schedule.predicted_nonoverlap_time,
             )
         self._build_steps()
-        self._build_run_sinks()
+        with span("sinks"):
+            self._build_run_sinks()
+        if self.telemetry is None:
+            # asked for and not to be had (no directory): nothing to write to
+            self._drop_setup()
         self.start_epoch = 0
         self.iteration = 0  # graft: group-uniform -- the step counter advances in lockstep; resume/rollback targets are broadcast-agreed
         self.carry = None
@@ -428,7 +465,8 @@ class Trainer:
         # deterministic — abort instead of looping
         self._last_rollback_iteration: Optional[int] = None
         self._good_step_since_rollback = True
-        self._maybe_resume()
+        with span("resume"):
+            self._maybe_resume()
 
     # ------------------------------------------------------------------
     @property
@@ -639,29 +677,35 @@ class Trainer:
     def _build_steps(self) -> None:
         """(Re)build the jitted train/eval steps from the current
         model/tx/mesh/reducer (shared by __init__ and update_nworker)."""
+        if self._setup is None and self.telemetry is not None:
+            # a rebuild in a running job (update_nworker, autotune's swap):
+            # what the recompile costs goes out as a `setup` record of its
+            # own, at the new step's first dispatch
+            self._setup = phases.begin_setup(rebuild=True)
         step_model = (
             self.model.clone(seq_axis=self.seq_axis)
             if self.seq_axis
             else self.model
         )
-        self.train_step = make_train_step(
-            step_model, self.meta, self.tx, self.mesh, self.reducer,
-            nsteps_update=self.config.nsteps_update,
-            axis_name=self.data_axes, seq_axis=self.seq_axis,
-            compute_dtype=self.compute_dtype,
-            grad_guard=self.config.grad_guard,
-            # the statistics exist to be STREAMED: without the telemetry
-            # stream they would be computed, popped, and discarded every
-            # step — so the stream gates them (and every non-telemetry
-            # run compiles the plain step)
-            health_stats=(
-                self.config.health_stats and self.config.telemetry
-            ),
-        )
-        self.eval_step = make_eval_step(
-            step_model, self.meta, self.mesh, axis_name=self.data_axes,
-            seq_axis=self.seq_axis, compute_dtype=self.compute_dtype,
-        )
+        with self._setup_span("steps"):
+            self.train_step = make_train_step(
+                step_model, self.meta, self.tx, self.mesh, self.reducer,
+                nsteps_update=self.config.nsteps_update,
+                axis_name=self.data_axes, seq_axis=self.seq_axis,
+                compute_dtype=self.compute_dtype,
+                grad_guard=self.config.grad_guard,
+                # the statistics exist to be STREAMED: without the telemetry
+                # stream they would be computed, popped, and discarded every
+                # step — so the stream gates them (and every non-telemetry
+                # run compiles the plain step)
+                health_stats=(
+                    self.config.health_stats and self.config.telemetry
+                ),
+            )
+            self.eval_step = make_eval_step(
+                step_model, self.meta, self.mesh, axis_name=self.data_axes,
+                seq_axis=self.seq_axis, compute_dtype=self.compute_dtype,
+            )
         # fresh programs recompile on first dispatch (update_nworker
         # rebuilds mid-run) — restore the watchdog's compile allowance
         self._train_step_compiled = False
@@ -1265,6 +1309,45 @@ class Trainer:
                 float(reducer.schedule.predicted_nonoverlap_time),
             )
 
+    def _note_first_dispatch(
+        self, step_args, rec: Optional[PhaseRecorder]
+    ) -> None:
+        """The first dispatch of a newly built step program has returned:
+        the open set-up takes that step's span from its record (`rec` holds
+        it), a rebuild's set-up, which waits for no result, is written, and
+        the program is read (`program_read`)."""
+        setup = self._setup if rec is not None else None
+        if setup is not None and setup.first_step is None:
+            setup.dispatched(self.iteration, *rec.dispatch_span())
+            if setup.rebuild:
+                self._write_setup()
+        with self._setup_span("program_read"):
+            if step_args is not None:
+                self._note_step_program(step_args)
+            self._note_traced_programs()
+
+    def _setup_results_read(self, step: int) -> None:
+        """The host holds the results of `step`: where that is the open
+        set-up's first step (or a later one), set-up is over. The record
+        follows that step's own `step` record, which an epoch of one step
+        still holds back here: train_epoch writes both at its end."""
+        setup = self._setup
+        if setup.first_step is None or step < setup.first_step:
+            return
+        setup.results_read()
+        rec = self._phase_rec
+        if rec is None or not rec.holds(setup.first_step):
+            self._write_setup()
+
+    def _write_setup(self) -> None:
+        if self.telemetry is None:  # the stream failed meanwhile
+            self._drop_setup()
+            return
+        setup, self._setup = self._setup, None
+        record = setup.finish(self.telemetry.clock_of)
+        self._emit_event("setup", **record)
+        self.log.info("%s", phases.setup_line(record))
+
     def _note_step_program(self, step_args) -> None:
         """Once per step-program build, after its first dispatch: count the
         compiled program's collectives and how many of them the compiler
@@ -1294,7 +1377,6 @@ class Trainer:
                 "%s and no persistent compile cache)", ", ".join(options),
             )
             return
-        t0 = time.perf_counter()
         try:
             text = self.train_step.lower(*step_args).compile().as_text()
         except Exception as e:  # noqa: BLE001 — a description of the
@@ -1307,10 +1389,10 @@ class Trainer:
         }
         self.log.info(
             "merge schedule: the compiled step issues %d collectives, %d of "
-            "them asynchronous (compile options: %s; read in %.2f s)",
+            "them asynchronous (compile options: %s)",
             self._step_program["collectives"],
             self._step_program["async_collectives"],
-            ", ".join(options) or "none", time.perf_counter() - t0,
+            ", ".join(options) or "none",
         )
         self._emit_event(
             "step_program", step=int(self.iteration), **self._step_program
@@ -1507,6 +1589,8 @@ class Trainer:
                     value=float(a.value), band=float(a.band),
                     active=bool(a.active), group=int(a.group),
                 )
+        if self._setup is not None:
+            self._setup_results_read(items[-1][0])
 
     def _reset_health_detector(self) -> None:
         """Resolve raised health alarms and forget learned baselines —
@@ -2777,7 +2861,8 @@ class Trainer:
         tf = None
         if cfg.policy in ("mgwfbp", "auto") and profile_backward:
             if self._tb_cache is None:
-                self._tb_cache = self._profile_backward()
+                with self._setup_span("profile_backward"):
+                    self._tb_cache = self._profile_backward()
             # tb is per-device backward time at the per-device batch, which
             # weak scaling holds constant — reusable across worker resizes
             tb = self._tb_cache
@@ -2789,7 +2874,8 @@ class Trainer:
                 # other runs must not pay the extra benchmark (falls back
                 # to solver.forward_prior_tf when the benchmark fails)
                 if self._tf_cache is None:
-                    self._tf_cache = self._profile_forward()
+                    with self._setup_span("profile_forward"):
+                        self._tf_cache = self._profile_forward()
                 tf = self._tf_cache
         comm_dtype = (
             jnp.dtype(cfg.comm_dtype) if cfg.comm_dtype else None
@@ -2836,24 +2922,25 @@ class Trainer:
         axes = self.data_axes
         if self.seq_axis is not None:
             axes = axes + (self.seq_axis,)
-        return make_merged_allreduce(
-            self._params_template,
-            axis_name=axes,
-            policy=cfg.policy,
-            tb=tb,
-            tf=tf,
-            cost_model=cost_model,
-            threshold=cfg.threshold,
-            comm_dtype=comm_dtype,
-            compressor=compressor,
-            comm_op=cfg.comm_op,
-            optim_spec=(
-                self.optim_spec
-                if cfg.comm_op in ("rs_opt_ag", "rs_fwd_ag")
-                else None
-            ),
-            world_size=self.data_size * self.seq_size,
-        )
+        with self._setup_span("solve"):
+            return make_merged_allreduce(
+                self._params_template,
+                axis_name=axes,
+                policy=cfg.policy,
+                tb=tb,
+                tf=tf,
+                cost_model=cost_model,
+                threshold=cfg.threshold,
+                comm_dtype=comm_dtype,
+                compressor=compressor,
+                comm_op=cfg.comm_op,
+                optim_spec=(
+                    self.optim_spec
+                    if cfg.comm_op in ("rs_opt_ag", "rs_fwd_ag")
+                    else None
+                ),
+                world_size=self.data_size * self.seq_size,
+            )
 
     def _profile_backward(self) -> Optional[list[float]]:
         """Offline layer-wise backward benchmark (reference benchmark(trainer),
@@ -2882,7 +2969,6 @@ class Trainer:
         paths = jax.tree_util.tree_flatten_with_path(self.state.params)[0]
         names = [jax.tree_util.keystr(kp) for kp, _ in paths]
         perm = arrival_order(len(names), names=names)
-        t0 = time.perf_counter()
         tb = benchmark_trainer_backward(
             self.model, self.meta, self.state.params, self.state.batch_stats,
             batch, perm, warmup=2, iters=10, names=names,
@@ -2900,8 +2986,7 @@ class Trainer:
             tb = TbProfile((float(t) for t in tb_arr), source=source)
         self.log.info(
             "backward benchmark: %.3g s total over %d tensors, "
-            "per-layer source=%s (%.1f s)",
-            sum(tb), len(tb), source, time.perf_counter() - t0,
+            "per-layer source=%s", sum(tb), len(tb), source,
         )
         return tb
 
@@ -2927,7 +3012,6 @@ class Trainer:
         paths = jax.tree_util.tree_flatten_with_path(self._params_template)[0]
         names = [jax.tree_util.keystr(kp) for kp, _ in paths]
         perm = arrival_order(len(names), names=names)
-        t0 = time.perf_counter()
         params = self.state.params
         from mgwfbp_tpu.parallel.allreduce import ShardedParams
 
@@ -2963,8 +3047,7 @@ class Trainer:
         )
         self.log.info(
             "forward benchmark: %.3g s total over %d tensors, "
-            "per-layer source=%s (%.1f s)",
-            sum(tf), len(tf), source, time.perf_counter() - t0,
+            "per-layer source=%s", sum(tf), len(tf), source,
         )
         return tf
 
@@ -3067,6 +3150,9 @@ class Trainer:
             # last dispatched step's record behind
             self._phase_rec = None
             rec.flush()
+            if self._setup is not None and self._setup.first_step is not None:
+                # the epoch read every result it had (or unwound)
+                self._write_setup()
 
     def _run_epoch(self, epoch: int, rec: Optional[PhaseRecorder]) -> dict:
         entered_s = rec.now() if rec is not None else 0.0
@@ -3207,10 +3293,9 @@ class Trainer:
                 rec.dispatched(
                     self.iteration, epoch, span0, rec.now() - span0
                 )
-            if step_args is not None:
-                self._note_step_program(step_args)
             if not self._traced_programs_noted:
-                self._note_traced_programs()
+                # once per step-program build, after its first dispatch
+                self._note_first_dispatch(step_args, rec)
             window_iters += 1
             epoch_steps += 1
             # non-finite guard bookkeeping (one step LATE via the deque, so
@@ -3560,6 +3645,8 @@ class Trainer:
             values = [float(items[0][2])]
         else:
             values = np.asarray(jnp.stack([f for _, _, f in items]))
+        if self._setup is not None:
+            self._setup_results_read(items[-1][0])
         for (it, ep, _), v in zip(items, values):
             self._check_guard_value(it, ep, float(v))
 
@@ -4332,6 +4419,9 @@ class Trainer:
         return manifest, files
 
     def close(self) -> None:
+        if self._setup is not None:
+            # built and never stepped: no set-up to report
+            self._drop_setup()
         if self.checkpointer is not None:
             if coord.process_count() == 1:
                 # land the in-flight async save's commit AND its
@@ -4996,3 +5086,7 @@ class Trainer:
                 self._graceful_drain_boundary(epoch)
             epoch += 1
         return metrics
+
+
+# the `import` span of the process's set-up record ends with this module
+phases.note_imported()

@@ -316,21 +316,26 @@ def main(argv: Optional[list[str]] = None) -> int:
         or process_id is not None
         or (num_processes or 0) > 1
     )
-    if not multi_host:
-        # fail fast instead of hanging in PJRT init while another process
-        # holds the chip (MGWFBP_INIT_TIMEOUT_S tunes/disables). Single-process
-        # only: jax.distributed.initialize() must run before any backend
-        # touch, so a resolved multi-host launch skips the probe — there
-        # the coordinator barrier itself surfaces a dead host.
-        preflight_backend()
     from mgwfbp_tpu.parallel.mesh import init_distributed
+    from mgwfbp_tpu.telemetry.phases import backend_span
     from mgwfbp_tpu.train.trainer import Trainer
 
-    init_distributed(
-        coordinator_address=coordinator,
-        num_processes=num_processes,
-        process_id=process_id,
-    )
+    # the backend's start is a span of the set-up record (with telemetry
+    # off the Trainer drops the record, and this span with it)
+    with backend_span():
+        if not multi_host:
+            # fail fast instead of hanging in PJRT init while another
+            # process holds the chip (MGWFBP_INIT_TIMEOUT_S tunes/disables).
+            # Single-process only: jax.distributed.initialize() must run
+            # before any backend touch, so a resolved multi-host launch
+            # skips the probe — there the coordinator barrier itself
+            # surfaces a dead host.
+            preflight_backend()
+        init_distributed(
+            coordinator_address=coordinator,
+            num_processes=num_processes,
+            process_id=process_id,
+        )
     trainer = Trainer(
         cfg,
         profile_backward=not args.no_profile_backward,

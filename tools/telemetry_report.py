@@ -4,6 +4,9 @@ Reads a run's telemetry JSONL (written by `mgwfbp_tpu.telemetry`, enabled
 with ``--telemetry`` on the train CLI) and prints:
 
   * the run header (model/world/comm_op/policy);
+  * what set-up was made of, from the process's start to the first step's
+    results (the `setup` record of telemetry/phases.py): each span under its
+    parent with its seconds and its self time, and the compile counters;
   * the step-time trend — span count, mean/min/max seconds per step, first
     vs last 10-span window (throughput drift over the run);
   * the per-merge-group exposed/hidden comm table from the latest overlap
@@ -136,6 +139,51 @@ def _phase_section(steps: list[dict]) -> list[str]:
     return lines
 
 
+def _setup_section(records: list[dict]) -> list[str]:
+    """One table a `setup` record (telemetry/phases.py): its spans in the
+    order they started, each under the span that caused it, with its seconds
+    and its self time (its seconds less what its children cover); under the
+    table, the record's counters and its slowest monitoring events."""
+    from mgwfbp_tpu.telemetry.phases import self_times
+
+    lines: list[str] = []
+    for record in records:
+        spans = record["spans"]
+        own = self_times(spans)
+        children: dict = {}
+        for name, (start_s, _, parent) in sorted(
+                spans.items(), key=lambda kv: kv[1][0]):
+            children.setdefault(parent if parent in spans else None,
+                                []).append(name)
+        whole = "set-up" if "setup" in spans else "step rebuilt"
+        lines += ["", f"{whole} (process started at wall "
+                  f"{record['origin_wall']}):",
+                  f"  {'span':<28} {'start_s':>10} {'seconds':>9} "
+                  f"{'self_s':>9}"]
+
+        def rows(parent, depth: int) -> None:
+            for name in children.get(parent, ()):
+                start_s, dur_s, _ = spans[name]
+                lines.append(
+                    f"  {'  ' * depth + name:<28} {start_s:>10.3f} "
+                    f"{dur_s:>9.3f} {own[name]:>9.3f}")
+                rows(name, depth + 1)
+
+        rows(None, 0)
+        c = record["counters"]
+        lines.append(
+            f"  programs: {c['programs_traced']} traced, "
+            f"{c['programs_lowered']} lowered, {c['programs_compiled']} "
+            f"compiled, {c['cache_loads']} loaded from the compile cache, "
+            f"{c['small_compiles']} too small for it "
+            f"({c['small_compile_s']:.3f} s)"
+            + (f"; traces nested in the step's {c['kernel_trace_s']:.3f} s"
+               if "kernel_trace_s" in c else ""))
+        for event, function, secs in c["slow_events"][:8]:
+            lines.append(f"    {secs:>8.3f} s  {event}  {function}")
+    return lines
+
+
 def format_report(records: list[dict]) -> str:
     from mgwfbp_tpu.telemetry import events_of
 
@@ -149,6 +197,7 @@ def format_report(records: list[dict]) -> str:
     )
 
     steps = events_of(records, "step")
+    lines.extend(_setup_section(events_of(records, "setup")))
     for prog in events_of(records, "step_program"):
         lines.append(
             f"step program (built by step {prog.get('step')}): "
@@ -777,6 +826,23 @@ def _synthetic_stream(path: str) -> None:
            compiler_options=["xla_enable_async_all_reduce"])
     w.emit("attention_program", step=1, kernel=1, blocks=3)
     w.emit("experts_program", step=1, kernel=12, ragged=0, programs=4)
+    # what set-up was made of, as the Trainer writes it once step 1's
+    # results are read (telemetry/phases.py)
+    w.emit("setup", origin_wall=1790736000.0, spans={
+        "setup": [-30.0, 50.0, None], "before_init": [-30.0, 12.0, "setup"],
+        "import": [-29.5, 4.0, "before_init"], "init": [-18.0, 18.0, "setup"],
+        "data": [-17.0, 9.0, "init"], "dataset": [-17.0, 8.5, "data"],
+        "optimizer": [-8.0, 6.0, "init"],
+        "first_step": [1.0, 15.0, "setup"], "trace": [1.1, 4.0, "first_step"],
+        "lower": [5.1, 0.5, "first_step"], "compile": [5.6, 9.0, "first_step"],
+        "cache_load": [5.9, 1.5, "compile"],
+        "first_result": [16.0, 4.0, "setup"],
+    }, counters={
+        "programs_traced": 800, "programs_lowered": 90,
+        "programs_compiled": 0, "small_compiles": 80, "cache_loads": 10,
+        "small_compile_s": 3.2, "kernel_trace_s": 0.22,
+        "slow_events": [["backend_compile_duration", "jit(step)", 9.0]],
+    })
     hidden = sum(r.hidden_s for r in rows)
     total = sum(r.comm_s for r in rows)
     w.emit(
@@ -850,6 +916,7 @@ def selftest() -> int:
         records = read_events(path)
         report = format_report(records)
         assert "overlap efficiency" in report, report
+        assert "set-up (process started" in report, report
         assert "alarms:" in report and "straggler" in report, report
         # ISSUE 12: training-health section, health alarm row, and the
         # postmortem index table all render from the same stream
