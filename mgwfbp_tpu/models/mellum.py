@@ -58,6 +58,7 @@ from jax import lax
 
 from mgwfbp_tpu.ops.blockattn import blockwise_attention
 from mgwfbp_tpu.ops.groupmm import counted, grouped_product
+from mgwfbp_tpu.ops.rowperm import combine_rows, take_rows
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 # the step's metrics carry the routing counts under these keys (HEALTH_PREFIX
@@ -201,49 +202,6 @@ def route(u: jax.Array, router: jax.Array, top_k: int):
     return idx, top / jnp.sum(top, axis=-1, keepdims=True)
 
 
-@jax.custom_vjp
-def _dispatch(u, order, inverse, valid):
-    """Row r of the result is token `order[r] // k` of u (N, D), for the N * k
-    assignments sorted by expert; `valid` rows of them lie in a group. The
-    transpose as XLA derives it is a scatter-add of N * k rows; written here
-    as what it equals, a gather by the inverse permutation and a sum over a
-    token's k assignments. Cotangent rows past `valid` are no expert's: the
-    grouped product leaves them unwritten on the chip, so they are zeroed
-    here and never summed."""
-    return u[order // (order.shape[0] // u.shape[0])]
-
-
-def _dispatch_fwd(u, order, inverse, valid):
-    return _dispatch(u, order, inverse, valid), (inverse, valid, u.shape[0])
-
-
-def _dispatch_bwd(res, g):
-    inverse, valid, n = res
-    g = jnp.where(jnp.arange(g.shape[0])[:, None] < valid, g, 0)
-    return g[inverse].reshape(n, -1, g.shape[-1]).sum(axis=1), None, None, None
-
-
-_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
-
-
-@jax.custom_vjp
-def _unsort(rows, order, inverse):
-    """rows[inverse]: the sorted assignments back in (token, k) order. Its
-    transpose is the gather by `order`."""
-    return rows[inverse]
-
-
-def _unsort_fwd(rows, order, inverse):
-    return rows[inverse], order
-
-
-def _unsort_bwd(order, g):
-    return g[order], None, None
-
-
-_unsort.defvjp(_unsort_fwd, _unsort_bwd)
-
-
 def held_experts(u, idx, weights, w_gate, w_up, w_down, first: int):
     """The held experts' part of the sparse block for tokens u.
 
@@ -251,7 +209,6 @@ def held_experts(u, idx, weights, w_gate, w_up, w_down, first: int):
     w_down (E, F, D) the E held experts, expert `first` of the model first.
     Returns (y (N, D), tokens per held expert (E,), assignments to a held
     expert that no group took (a count; 0 by construction))."""
-    n, k = idx.shape
     count = w_gate.shape[0]
     local = idx - first
     held = (local >= 0) & (local < count)
@@ -263,18 +220,16 @@ def held_experts(u, idx, weights, w_gate, w_up, w_down, first: int):
         keys[:, None] == jnp.arange(count)[None, :], axis=0, dtype=jnp.int32)
     # (N * k, D), grouped by expert; the rows past the last group belong to
     # no expert: a grouped product leaves them UNWRITTEN on the chip (zero
-    # only on the CPU), forward and backward, so they are masked below and
-    # in _dispatch's transpose, never trusted
-    rows = _dispatch(u, order, inverse, jnp.sum(sizes))
+    # only on the CPU), forward and backward, and neither permutation moves
+    # or reads them (ops/rowperm.py): never trusted
+    rows = take_rows(u, order, inverse, sizes)
     gate = grouped_product(rows, w_gate, sizes)
     up = grouped_product(rows, w_up, sizes)
     mid = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32))
     out = grouped_product(mid.astype(u.dtype), w_down, sizes)
-    out = _unsort(out, order, inverse).reshape(n, k, -1)
-    out = jnp.where(held[..., None], out.astype(jnp.float32), 0.0)
-    y = jnp.sum(out * weights[..., None], axis=1)
+    y = combine_rows(out, order, inverse, weights, sizes)
     dropped = jnp.sum(held) - jnp.sum(sizes)
-    return y.astype(u.dtype), sizes, dropped
+    return y, sizes, dropped
 
 
 def sparse_block(p: dict, x: jax.Array, shape: MellumShape, first: int):

@@ -32,12 +32,13 @@ the tiles do not divide), called as the models called it before: the lowered
 text of a CPU step is what it was.
 
 Both leave the rows past the last group UNWRITTEN on the chip, forward and in
-d lhs (`tgmm` masks them out of d rhs): the caller masks them
-(`models/mellum._dispatch` and the `jnp.where` after `_unsort`), and no
-caller may rely on either way zeroing them.
+d lhs (`tgmm` masks them out of d rhs): the caller never reads them
+(`ops/rowperm.py`'s two permutations stop at the groups' sum), and no caller
+may rely on either way zeroing them.
 
 `LOWERED` counts, as programs are traced, how many products went each way and
-which kernel programs those through the kernel need, transposes included;
+which kernel programs those through the kernel need, transposes included
+(and, for `ops/rowperm.py`, the same of the row permutations round them);
 `counted` keeps the count right where jax reuses a cached trace.
 `make_train_step` reads it round the trace of its step (`lowered_since`), for
 the Trainer's `experts_program` telemetry record.
@@ -57,7 +58,8 @@ from mgwfbp_tpu.ops import blockattn
 
 # calls of `grouped_product` traced so far, by the way they went down
 # ("kernel", "ragged"), and under each kernel program's key (`_programs`) the
-# products traced so far that need it
+# products traced so far that need it; ops/rowperm.py's permutations likewise
+# ("rows_held", "rows_all", and its programs' keys)
 LOWERED: collections.Counter = collections.Counter()
 # what a call through `counted` traced, by its arguments' shapes
 _TRACED_BY: dict = {}
@@ -244,10 +246,19 @@ def counted(fn):
     return call
 
 
+_WAYS = ("kernel", "ragged", "rows_held", "rows_all")
+# what the key of a kernel program of `ops/rowperm.py` starts with
+ROWS_PROGRAM = "combine_rows"
+
+
 def lowered_since(before: collections.Counter) -> dict:
     """What was traced since `before` (a copy of `LOWERED`): products through
     the kernel, through `lax.ragged_dot`, and the distinct kernel programs
-    among the former with their transposes."""
+    among the former with their transposes; row permutations
+    (`ops/rowperm.py`) that move only the rows in a group, that move every
+    assignment's row, and the distinct kernel programs they and their
+    transposes need."""
     made = LOWERED - before
-    ways = {way: made.pop(way, 0) for way in ("kernel", "ragged")}
-    return {**ways, "programs": len(made)}
+    ways = {way: made.pop(way, 0) for way in _WAYS}
+    rows = sum(1 for key in made if key[0] == ROWS_PROGRAM)
+    return {**ways, "programs": len(made) - rows, "rows_programs": rows}

@@ -92,8 +92,14 @@ EVENT_TYPES: dict[str, tuple[str, ...]] = {
     # the same for the experts' grouped products (ops/groupmm.py): how many
     # went through the tiled kernel and how many through `lax.ragged_dot`
     # (3 a sparse layer held), and the distinct kernel programs among the
-    # former with their transposes; 0, 0 and 0 for a model without experts
-    "experts_program": ("step", "kernel", "ragged", "programs"),
+    # former with their transposes; and the row permutations round them
+    # (ops/rowperm.py: 2 a sparse layer held; `rows_held` move only the rows
+    # in a group, `rows_all` every assignment's row, `rows_programs` the
+    # distinct kernel programs they and their transposes need); all 0 for a
+    # model without experts
+    "experts_program": (
+        "step", "kernel", "ragged", "programs", "rows_held", "rows_all",
+        "rows_programs"),
     # what set-up was made of, once per process start, when the host has
     # read the first step's results, and once more after a rebuild that
     # recompiles the step (telemetry/phases.py): `spans` as

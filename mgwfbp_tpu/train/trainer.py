@@ -1422,13 +1422,15 @@ class Trainer:
         calls = self.train_step.experts_calls
         self.log.info(
             "experts: %d grouped product(s) of the step through the tiled "
-            "kernel (%d distinct kernel program(s)), %d through ragged_dot",
+            "kernel (%d distinct kernel program(s)), %d through ragged_dot; "
+            "%d row permutation(s) moving only the rows in a group (%d "
+            "distinct kernel program(s)), %d moving every assignment's row",
             calls["kernel"], calls["programs"], calls["ragged"],
+            calls["rows_held"], calls["rows_programs"], calls["rows_all"],
         )
         self._emit_event(
             "experts_program", step=int(self.iteration),
-            kernel=int(calls["kernel"]), ragged=int(calls["ragged"]),
-            programs=int(calls["programs"]),
+            **{name: int(n) for name, n in calls.items()},
         )
 
     def _schedule_state_doc(self) -> dict:

@@ -139,15 +139,19 @@ def test_falls_back_to_ragged_dot_off_the_tpu_and_on_a_shape_that_misfits(
     def operands(m, k, n, dtype=jnp.bfloat16):
         return jnp.ones((m, k), dtype), jnp.ones((2, k, n), dtype)
 
-    ragged = ({"kernel": 0, "ragged": 1, "programs": 0}, False)
+    def ways(kernel, ragged, programs):  # no row permutation beside them
+        return {"kernel": kernel, "ragged": ragged, "programs": programs,
+                "rows_held": 0, "rows_all": 0, "rows_programs": 0}
+
+    ragged = (ways(0, 1, 0), False)
     assert not blockattn.traced_for_tpu()
     assert traced_ways(product, *operands(512, 128, 128)) == ragged
     monkeypatch.setattr(blockattn, "traced_for_tpu", lambda: True)
     # K = N: the product and d lhs are one program, d rhs another
     assert traced_ways(product, *operands(512, 128, 128)) == (
-        {"kernel": 1, "ragged": 0, "programs": 2}, True)
+        ways(1, 0, 2), True)
     assert traced_ways(product, *operands(512, 256, 128)) == (
-        {"kernel": 1, "ragged": 0, "programs": 3}, True)
+        ways(1, 0, 3), True)
     assert traced_ways(product, *operands(384, 128, 128)) == ragged
     assert traced_ways(product, *operands(512, 96, 128)) == ragged
     assert traced_ways(product, *operands(512, 128, 200)) == ragged
@@ -156,11 +160,11 @@ def test_falls_back_to_ragged_dot_off_the_tpu_and_on_a_shape_that_misfits(
 
 def expert_blocks(layers: int):
     """Forward + gradient of `layers` checkpointed expert blocks as the
-    models call them, at a size the tiles divide: 128 tokens x 8 = 1,024
-    rows, 4 experts held of 8."""
+    models call them, at a size the products' tiles and the permutations'
+    blocks divide: 256 tokens x 8 = 2,048 rows, 4 experts held of 8."""
     from mgwfbp_tpu.models import mellum
 
-    tokens, d, f, experts, k = 128, 256, 128, 4, 8
+    tokens, d, f, experts, k = 256, 256, 128, 4, 8
     bf = jnp.bfloat16
     shape = jax.ShapeDtypeStruct
 
@@ -182,12 +186,16 @@ def expert_blocks(layers: int):
 def test_four_layers_lower_no_more_kernel_programs_than_one(monkeypatch):
     """Lowered for a TPU, here: the distinct `tpu_custom_call` programs of
     four expert blocks, forward + gradient, are those of one block, and no
-    more than the entry point's docstring states; every product of every
-    layer is counted all the same."""
+    more than the entry points' docstrings state (four of the grouped
+    products, two of the row permutations round them); every product and
+    every permutation of every layer is counted all the same."""
+    from mgwfbp_tpu.ops import rowperm
+
     monkeypatch.setattr(blockattn, "traced_for_tpu", lambda: True)
-    # the docstring's own number, so that the two cannot part
+    # the docstrings' own numbers, so that the two cannot part
     assert "at most FOUR distinct programs" in groupmm.__doc__
-    stated = 4
+    assert "**One kernel program a step.**" in rowperm.__doc__
+    stated = 4, 1
     seen = {}
     for layers in (1, 4):
         fn, args = expert_blocks(layers)
@@ -196,42 +204,68 @@ def test_four_layers_lower_no_more_kernel_programs_than_one(monkeypatch):
         assert "ragged_dot" not in text
         counted = groupmm.lowered_since(before)
         assert (counted["kernel"], counted["ragged"]) == (3 * layers, 0)
+        # a layer's combine through its kernel; its dispatch is XLA's gather
+        assert (counted["rows_held"], counted["rows_all"]) == (layers, layers)
         programs = {
             hashlib.sha256(config.encode()).hexdigest() for config in
             re.findall(r'backend_config = "([^"]*)"', text)}
         sites = text.count("stablehlo.custom_call @tpu_custom_call")
-        assert len(programs) == counted["programs"]
-        seen[layers] = (len(programs), sites)
+        assert len(programs) == (
+            counted["programs"] + counted["rows_programs"])
+        seen[layers] = (
+            counted["programs"], counted["rows_programs"], sites)
     assert seen[1] == seen[4]
-    assert 0 < seen[1][0] <= stated
+    assert 0 < seen[1][0] <= stated[0] and 0 < seen[1][1] <= stated[1]
 
 
-@pytest.mark.parametrize("flags,ragged", [
+PROGRAMS = [
+    # flags, grouped products (3 a sparse layer held), row permutations (2)
     (["--dnn", "mellum2_tiny", "--experts-held", "2:2", "--layers-held", "2",
-      *TOKENS], 6),
-    (["--dnn", "laguna_xs2_tiny", "--experts-held", "2:4", *TOKENS], 12),
-    (["--dnn", "resnet20"], 0),
-], ids=["mellum2_tiny", "laguna_xs2_tiny", "resnet20"])
+      *TOKENS], 6, 4),
+    (["--dnn", "laguna_xs2_tiny", "--experts-held", "2:4", *TOKENS], 12, 8),
+    (["--dnn", "resnet20"], 0, 0),
+]
+PROGRAM_IDS = ["mellum2_tiny", "laguna_xs2_tiny", "resnet20"]
+
+
+@functools.lru_cache(maxsize=None)
+def _two_epochs(which: int):
+    """(the built step's `experts_calls`, the stream's records) of two epochs
+    of `PROGRAMS[which]` under the Trainer, run once for both tests below."""
+    import tempfile
+
+    from mgwfbp_tpu.telemetry.events import read_events
+
+    flags = PROGRAMS[which][0]
+    with tempfile.TemporaryDirectory() as tmp, \
+            pytest.MonkeyPatch.context() as patch:
+        patch.setenv("MGWFBP_SYNTH_TRAIN_N", "16")
+        cfg, trainer = trainer_of(tmp, *flags)
+        try:
+            trainer.train_epoch(0)
+            trainer.train_epoch(1)
+            calls = dict(trainer.train_step.experts_calls)
+        finally:
+            trainer.close()
+        return calls, read_events(
+            os.path.join(tmp, cfg.tag(), "telemetry.jsonl"))
+
+
+@pytest.mark.parametrize(
+    "which,ragged", [(i, p[1]) for i, p in enumerate(PROGRAMS)],
+    ids=PROGRAM_IDS)
 def test_a_step_program_leaves_its_experts_count_on_the_telemetry(
-        tmp_path, monkeypatch, flags, ragged):
+        which, ragged):
     """One `experts_program` record a built step program, counted while the
     step was traced: 3 grouped products a sparse layer held, all through
     `lax.ragged_dot` on the CPU, layers that share a cached trace counted
     each; none in a model without experts. A second epoch runs the same
     program and adds no record; the report prints the line."""
-    from mgwfbp_tpu.telemetry.events import events_of, read_events
+    from mgwfbp_tpu.telemetry.events import events_of
 
-    monkeypatch.setenv("MGWFBP_SYNTH_TRAIN_N", "16")
-    cfg, trainer = trainer_of(tmp_path, *flags)
-    try:
-        trainer.train_epoch(0)
-        trainer.train_epoch(1)
-        assert trainer.train_step.experts_calls == {
-            "kernel": 0, "ragged": ragged, "programs": 0}
-    finally:
-        trainer.close()
-    records = read_events(
-        os.path.join(str(tmp_path), cfg.tag(), "telemetry.jsonl"))
+    calls, records = _two_epochs(which)
+    assert (calls["kernel"], calls["ragged"], calls["programs"]) == (
+        0, ragged, 0)
     (program,) = events_of(records, "experts_program")
     assert (program["step"], program["kernel"], program["ragged"],
             program["programs"]) == (1, 0, ragged, 0)
@@ -240,3 +274,29 @@ def test_a_step_program_leaves_its_experts_count_on_the_telemetry(
     assert (f"0 grouped product(s) through the tiled kernel (0 distinct "
             f"kernel program(s)), {ragged} through ragged_dot"
             ) in telemetry_report.format_report(records)
+
+
+@pytest.mark.parametrize(
+    "which,plain", [(i, p[2]) for i, p in enumerate(PROGRAMS)],
+    ids=PROGRAM_IDS)
+def test_a_step_program_leaves_its_row_permutations_on_the_telemetry(
+        which, plain):
+    """The same record says how the experts' rows were moved: 2 permutations
+    a sparse layer held (`take_rows`, `combine_rows`; their transposes are not
+    counted apart), on the CPU all of them plain gathers of every
+    assignment's row and none a kernel program, layers under one cached trace
+    counted each; none in a model without experts. The report prints them on
+    the experts' line."""
+    from mgwfbp_tpu.telemetry.events import events_of
+
+    calls, records = _two_epochs(which)
+    assert (calls["rows_held"], calls["rows_all"], calls["rows_programs"]
+            ) == (0, plain, 0)
+    (program,) = events_of(records, "experts_program")
+    assert (program["rows_held"], program["rows_all"],
+            program["rows_programs"]) == (0, plain, 0)
+    import telemetry_report
+
+    assert (f"0 row permutation(s) moving only the rows in a group (0 "
+            f"distinct kernel program(s)), {plain} moving every assignment's "
+            f"row") in telemetry_report.format_report(records)

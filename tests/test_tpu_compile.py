@@ -14,6 +14,7 @@ cannot be read back without one, and the next compile would only warn.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -161,6 +162,91 @@ def test_grouped_product_compiles_for_v5e_at_the_cells_shapes(
         lhs, rhs, sizes).compile().as_text()
     assert text.count("tpu_custom_call") == 3  # the product, d lhs, d rhs
     assert "ragged-dot" not in text
+
+
+CELLS = [(16384, 8, 2304, 896, 16), (8192, 8, 2048, 512, 32)]
+CELL_IDS = ["mellum2", "laguna-xs2"]
+
+
+@pytest.mark.parametrize("n,k,d,f,groups", CELLS, ids=CELL_IDS)
+def test_row_permutations_compile_for_v5e_at_the_cells_shapes(
+        topo, n, k, d, f, groups):
+    """ops/rowperm.py's ways down on the chip with their transposes at the
+    sparse cells' shapes (every assignment's row, bf16): `take_rows`, XLA's
+    one gather from the (N, D) table, whose transpose is the combine kernel;
+    and `combine_rows`' kernel, whose window fits VMEM, with the loop of
+    block gathers as its transpose and no gather of all M rows. Called
+    outright: this process traces for the CPU."""
+    from mgwfbp_tpu.ops import rowperm
+
+    one = SingleDeviceSharding(topo.devices[0])
+    m = n * k
+    src = jax.ShapeDtypeStruct((n, d), jnp.bfloat16, sharding=one)
+    rows = jax.ShapeDtypeStruct((m, d), jnp.bfloat16, sharding=one)
+    index = jax.ShapeDtypeStruct((m,), jnp.int32, sharding=one)
+    weights = jax.ShapeDtypeStruct((n, k), jnp.float32, sharding=one)
+    sizes = jax.ShapeDtypeStruct((groups,), jnp.int32, sharding=one)
+    plan = rowperm._kernel_plan(n, k, d, groups, jnp.bfloat16)
+    assert plan is not None
+
+    def taken(src, order, inverse, sizes):
+        out = rowperm._taken(src, order, inverse, sizes, plan, False)
+        return jnp.sum(out.astype(jnp.float32))
+
+    def combined(rows, order, inverse, weights, sizes):
+        out = rowperm._combined(
+            rows, order, inverse, weights, sizes, plan, False)
+        return jnp.sum(out.astype(jnp.float32))
+
+    whole = re.compile(rf"= \w+\[{m},{d}\]\S* gather\(")
+    text = jax.jit(jax.value_and_grad(taken)).lower(
+        src, index, index, sizes).compile().as_text()
+    assert text.count("tpu_custom_call") == 1  # d src: the combine kernel
+    assert len(whole.findall(text)) == 1  # the dispatch itself
+    text = jax.jit(jax.value_and_grad(combined, argnums=(0, 3))).lower(
+        rows, index, index, weights, sizes).compile().as_text()
+    assert text.count("tpu_custom_call") == 1  # the value; d rows is a loop
+    assert not whole.search(text)
+
+
+@pytest.mark.parametrize("n,k,d,f,groups", CELLS, ids=CELL_IDS)
+def test_held_experts_compiles_for_v5e_with_no_gather_from_all_rows(
+        topo, monkeypatch, n, k, d, f, groups):
+    """The whole expert block as the models call it (under `jax.checkpoint`),
+    value and gradients, at the sparse cells' shapes, traced as for a TPU:
+    its `tpu_custom_call`s are the three grouped products' (forward, again in
+    the recomputation, and two transposes each) and the combine's (forward
+    and as d `u`): 3 + 3 + 6 + 2, no `ragged-dot`, and the only `gather`s
+    that produce an (M, D) array are the dispatch's own from the (N, D)
+    table, forward and recomputed (the parent's program held six)."""
+    from mgwfbp_tpu.models import mellum
+    from mgwfbp_tpu.ops import blockattn
+
+    monkeypatch.setattr(blockattn, "traced_for_tpu", lambda: True)
+    one = SingleDeviceSharding(topo.devices[0])
+    bf = jnp.bfloat16
+    m = n * k
+
+    def shape(dims, dtype=bf):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+
+    def loss(u, weights, ws, idx):
+        y, _, dropped = jax.checkpoint(
+            mellum.held_experts, static_argnums=6)(u, idx, weights, *ws, 0)
+        return jnp.sum(y.astype(jnp.float32)) + dropped
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        shape((n, d)), shape((n, k), jnp.float32),
+        (shape((groups, d, f)), shape((groups, d, f)), shape((groups, f, d))),
+        shape((n, k), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 3 + 3 + 6 + 2
+    assert "ragged-dot" not in text
+    assert 1 <= len(
+        re.findall(rf"= \w+\[{m},{d}\]\S* gather\(", text)) <= 2
+    memory = compiled.memory_analysis()
+    assert (memory.temp_size_in_bytes + memory.argument_size_in_bytes
+            < HBM_BYTES)
 
 
 def _abstract_step_args(model, meta, tx, mesh, per_device_batch):

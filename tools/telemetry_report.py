@@ -216,7 +216,11 @@ def format_report(records: list[dict]) -> str:
             f"experts (step program built by step {prog.get('step')}): "
             f"{prog.get('kernel')} grouped product(s) through the tiled "
             f"kernel ({prog.get('programs')} distinct kernel program(s)), "
-            f"{prog.get('ragged')} through ragged_dot"
+            f"{prog.get('ragged')} through ragged_dot; "
+            f"{prog.get('rows_held')} row permutation(s) moving only the "
+            f"rows in a group ({prog.get('rows_programs')} distinct kernel "
+            f"program(s)), {prog.get('rows_all')} moving every assignment's "
+            f"row"
         )
     if steps:
         durs = [float(s["dur_s"]) for s in steps]
@@ -825,7 +829,8 @@ def _synthetic_stream(path: str) -> None:
     w.emit("step_program", step=1, collectives=33, async_collectives=5,
            compiler_options=["xla_enable_async_all_reduce"])
     w.emit("attention_program", step=1, kernel=1, blocks=3)
-    w.emit("experts_program", step=1, kernel=12, ragged=0, programs=4)
+    w.emit("experts_program", step=1, kernel=12, ragged=0, programs=4,
+           rows_held=4, rows_all=4, rows_programs=1)
     # what set-up was made of, as the Trainer writes it once step 1's
     # results are read (telemetry/phases.py)
     w.emit("setup", origin_wall=1790736000.0, spans={
