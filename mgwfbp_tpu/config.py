@@ -35,8 +35,9 @@ class TrainConfig:
     num_steps: Optional[int] = None  # LM window length override (default 35;
     # seq-parallel transformers need num_steps % seq_parallel == 0)
     # the part of a model this chip holds (models that can be held in part:
-    # the mellum2 and granite4h families). None = all of it
-    layers_held: Optional[int] = None  # the first n layers
+    # the mellum2, granite4h, laguna_xs2 and phi4flash families). None = all
+    layers_held: Optional[str] = None  # "N" the first N layers, or
+    # "FIRST:COUNT" a stage anywhere (models.parse_layers_held)
     experts_held: Optional[str] = None  # "first:count" of each layer's experts
     vocab_size: Optional[int] = None  # `tokens` dataset: ids 0..n-1, and so
     # the rows of the embedding and the head (the dataset's num_classes)
@@ -203,6 +204,15 @@ PRESETS: dict[str, dict] = {
                       max_epochs=40, lr_schedule="cosine", optimizer="adamw",
                       adam_b2=0.95, weight_decay=0.1, norm_clip=1.0),
     "granite4h_tiny": dict(dataset="tokens", batch_size=2, num_steps=64,
+                           lr=3e-3, max_epochs=40, lr_schedule="cosine",
+                           optimizer="adamw", adam_b2=0.95, weight_decay=0.1,
+                           norm_clip=1.0, vocab_size=256),
+    # decoder-hybrid-decoder LM (models/phi4flash.py): granite4h's recipe
+    # (none of it published) at one sequence of 8,192 tokens a device
+    "phi4flash": dict(dataset="tokens", batch_size=1, num_steps=8192, lr=3e-4,
+                      max_epochs=40, lr_schedule="cosine", optimizer="adamw",
+                      adam_b2=0.95, weight_decay=0.1, norm_clip=1.0),
+    "phi4flash_tiny": dict(dataset="tokens", batch_size=2, num_steps=64,
                            lr=3e-3, max_epochs=40, lr_schedule="cosine",
                            optimizer="adamw", adam_b2=0.95, weight_decay=0.1,
                            norm_clip=1.0, vocab_size=256),

@@ -341,7 +341,8 @@ def _lstman4(nc):
 
 def _held_lm_meta(name: str, nc: int, window_len: int) -> ModelMeta:
     """A decoder LM over the `tokens` dataset that takes its loss itself and
-    can be held in part (the mellum2, granite4h and laguna_xs2 families)."""
+    can be held in part (the mellum2, granite4h, laguna_xs2 and phi4flash
+    families)."""
     return ModelMeta(
         name=name, dataset="tokens", num_classes=nc,
         input_shape=(window_len,), input_dtype=jnp.int32, task="lm",
@@ -349,16 +350,42 @@ def _held_lm_meta(name: str, nc: int, window_len: int) -> ModelMeta:
     )
 
 
+def parse_layers_held(value) -> Optional[tuple[int, int]]:
+    """`--layers-held` as (first, count): None, a bare N (the first N, 0:N)
+    or FIRST:COUNT, a pipeline stage anywhere in the model (the form
+    `--experts-held` has)."""
+    if value is None or isinstance(value, tuple):
+        return value
+    try:
+        first, _, count = str(value).rpartition(":")
+        return int(first or 0), int(count)
+    except ValueError:
+        raise ValueError(
+            f"--layers-held {value!r} is neither N nor FIRST:COUNT (integers)"
+        ) from None
+
+
 def _register_held_lm(name: str, load: Callable[[], tuple[Any, Any]],
-                      window_len: int, takes_experts: bool):
+                      window_len: int, takes_experts: bool,
+                      takes_first: bool = False):
     """A decoder held in part by layers and vocabulary and, with
     `takes_experts`, by routed experts. `load` imports the family's module
-    when the model is first built and returns (class, shape)."""
+    when the model is first built and returns (class, shape). With
+    `takes_first` the module takes its layers as (first, count); the others
+    hold their first N and are handed N."""
     @register(name)
     def _factory(nc, layers_held=None, experts_held=None):
         cls, shape = load()
         nc = nc or shape.vocab_size
-        share = {"layers_held": layers_held}
+        layers = parse_layers_held(layers_held)
+        if layers is not None and not takes_first:
+            if layers[0] != 0:
+                raise ValueError(
+                    f"model {name!r} holds its first layers only: "
+                    f"--layers-held {layers[0]}:{layers[1]} with a FIRST "
+                    "other than 0 waits for a configuration that needs it")
+            layers = layers[1]
+        share = {"layers_held": layers}
         if takes_experts:
             share["experts_held"] = experts_held or (0, shape.num_experts)
         elif experts_held is not None:
@@ -390,15 +417,26 @@ def _granite4h(tiny: bool):
         granite.GRANITE4H_TINY if tiny else granite.GRANITE4H)
 
 
+def _phi4flash(tiny: bool):
+    from mgwfbp_tpu.models import phi4flash
+
+    return phi4flash.Phi4FlashLM, (
+        phi4flash.PHI4FLASH_TINY if tiny else phi4flash.PHI4FLASH)
+
+
 # each family at its published widths, and at a size the CPU tests hold:
 # mellum2 (hidden 64, 2 key/value heads, 8 experts top 2, window 16);
 # laguna_xs2 (hidden 64, 6 / 8 query heads over 2 key heads of 16, 16 experts
 # top 2 and a shared one, window 16, five layers: the dense one first);
-# granite4h (hidden 32, 4 Mamba heads of 16, state 8, chunk 16, four layers)
-for _name, _load, _experts in (
-        ("mellum2", _mellum2, True), ("laguna_xs2", _laguna_xs2, True),
-        ("granite4h", _granite4h, False)):
+# granite4h (hidden 32, 4 Mamba heads of 16, state 8, chunk 16, four layers);
+# phi4flash (hidden 32, 4 / 2 heads of 8, window 16, state 4, eight layers:
+# every kind by the publisher's rule), whose stage may start anywhere
+for _name, _load, _experts, _first in (
+        ("mellum2", _mellum2, True, False),
+        ("laguna_xs2", _laguna_xs2, True, False),
+        ("granite4h", _granite4h, False, False),
+        ("phi4flash", _phi4flash, False, True)):
     _register_held_lm(
-        _name, functools.partial(_load, False), 8192, _experts)
+        _name, functools.partial(_load, False), 8192, _experts, _first)
     _register_held_lm(
-        _name + "_tiny", functools.partial(_load, True), 64, _experts)
+        _name + "_tiny", functools.partial(_load, True), 64, _experts, _first)

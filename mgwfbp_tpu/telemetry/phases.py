@@ -63,7 +63,13 @@ computed (0: the layer is dropless). Read by `moe_here_share`,
 mean square of the scan's state after a sequence's last position, mean over
 the Mamba layers held, and `ssm_log_decay_min`, the most negative sum of
 log-decays over one chunk of the scan (any head, any layer); read by the
-reader files of the same names.
+reader files of the same names. models/phi4flash.py: `sel_scan_state_rms`,
+the root mean square of the selective scan's state after a sequence's last
+position, mean over the Mamba-1 layers held; `gmu_gate_rms`, that of the
+gated memory m . silu(u W_g), mean over the GMU layers held (0: the memory
+is not wired); `diff_lambda_mean`, the differential attention's lam, mean
+over the attention and cross layers held; read by the reader files of the
+same names and by `tools/telemetry_report.py`.
 
 The host runs about one step ahead of the chip: no span after the dispatch
 of step k needs step k itself. What stops it is the first read of step

@@ -100,11 +100,14 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--seq-parallel", dest="seq_parallel", type=int, default=None)
     p.add_argument("--num-steps", dest="num_steps", type=int, default=None,
                    help="LM window length (must divide by --seq-parallel)")
-    p.add_argument("--layers-held", dest="layers_held", type=int, default=None,
-                   help="hold only the first N layers of a model that can be "
-                        "held in part (mellum2, granite4h, laguna_xs2): one "
-                        "pipeline "
-                        "stage's share")
+    p.add_argument("--layers-held", dest="layers_held", default=None,
+                   metavar="N|FIRST:COUNT",
+                   help="hold only COUNT layers of a model that can be held "
+                        "in part, starting at layer FIRST: one pipeline "
+                        "stage's share. A bare N is 0:N, the first N "
+                        "(mellum2, granite4h, laguna_xs2 take no other "
+                        "FIRST; phi4flash's stage may start anywhere and "
+                        "its layers keep their published indices)")
     p.add_argument("--experts-held", dest="experts_held", default=None,
                    metavar="FIRST:COUNT",
                    help="hold only COUNT experts of every sparse layer, "

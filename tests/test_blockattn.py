@@ -190,7 +190,10 @@ TOKENS = ["--dataset", "tokens", "--vocab-size", "256", "--num-steps", "64"]
       *TOKENS], 2),
     (["--dnn", "granite4h_tiny", "--layers-held", "3", *TOKENS], 1),
     (["--dnn", "laguna_xs2_tiny", "--experts-held", "2:4", *TOKENS], 5),
-], ids=["mellum2_tiny", "granite4h_tiny", "laguna_xs2_tiny"])
+    # one stacked core a layer (window, window, full, cross); a length no
+    # other test traces these layers at, whose traces `jax.checkpoint` keeps
+    (["--dnn", "phi4flash_tiny", *TOKENS[:-1], "48"], 4),
+], ids=["mellum2_tiny", "granite4h_tiny", "laguna_xs2_tiny", "phi4flash_tiny"])
 def test_a_step_program_leaves_its_attention_count_on_the_telemetry(
         tmp_path, monkeypatch, flags, blocks):
     """One `attention_program` record a built step program, counted while the

@@ -24,7 +24,7 @@ ALL_IMAGE_MODELS = [
     n for n in zoo.model_names()
     if n not in ("lstm", "lstman4", "transformer", "mellum2", "mellum2_tiny",
                  "granite4h", "granite4h_tiny", "laguna_xs2",
-                 "laguna_xs2_tiny")
+                 "laguna_xs2_tiny", "phi4flash", "phi4flash_tiny")
 ]
 
 
@@ -134,6 +134,41 @@ def test_granite4h_traces_and_counts_its_parameters(name, share, want, layers):
         assert per_token.shape == (2, 64)
         assert stats["health/ssm_state"].shape == (3,)  # the Mamba layers
         assert stats["health/ssm_log_decay_min"].shape == (3,)
+
+
+@pytest.mark.parametrize("name,share,want,layers", [
+    # the whole model: 9 Mamba, 9 attention, 7 GMU and 7 cross layers, the
+    # tied embedding: the published 3.8 B
+    ("phi4flash", {}, 3_852_562_944, 32),
+    # one chip's share: the stage round the hinge, an eighth of the ids
+    ("phi4flash", dict(num_classes=25008, layers_held="14:6"),
+     697_094_272, 6),
+    ("phi4flash_tiny", {}, None, 8),
+])
+def test_phi4flash_traces_and_counts_its_parameters(name, share, want, layers):
+    model, meta = zoo.create_model(name, **share)
+    assert meta.task == "lm" and not meta.has_carry and meta.fused_loss
+    assert meta.dataset == "tokens"
+    x = _example_input(meta)
+    variables = jax.eval_shape(
+        lambda: model.init({"params": jax.random.PRNGKey(0)}, x, train=False))
+    params = variables["params"]
+    if want is not None:
+        assert sum(int(np.prod(leaf.shape))
+                   for leaf in jax.tree_util.tree_leaves(params)) == want
+    held = sorted(int(k.split("_")[1]) for k in params if k.startswith("layer_"))
+    first = 14 if "layers_held" in share else 0
+    assert held == list(range(first, first + layers))  # published indices
+    assert set(params["out"]) == {"norm", "norm_b"}  # no head: tied
+    if name == "phi4flash_tiny":
+        logits = jax.eval_shape(lambda v: model.apply(v, x), variables)
+        assert logits.shape == (2, 64, meta.num_classes)
+        per_token, stats = jax.eval_shape(
+            lambda v: model.apply(v, x, targets=x, train=True), variables)
+        assert per_token.shape == (2, 64)
+        assert stats["health/sel_scan_state"].shape == (3,)  # Mamba layers
+        assert stats["health/gmu_gate"].shape == (1,)
+        assert stats["health/diff_lambda"].shape == (4,)  # 2 window, full, cross
 
 
 @pytest.mark.parametrize(

@@ -251,6 +251,34 @@ def test_prometheus_text_dump(tmp_path, retired_records):
     assert "mgwfbp_serve" not in text and "mgwfbp_bench" not in text
 
 
+def test_report_prints_the_hybrid_decoders_counters():
+    """`sel_scan_state_rms`, `gmu_gate_rms`, `diff_lambda_mean` of the `step`
+    records (models/phi4flash.py, by the health drain's road) under the
+    phase table: the mean over the steps that carry each; a stage without a
+    GMU prints no gated memory; a stream without them no line."""
+    import telemetry_report
+
+    def step(i, **counters):
+        return {"event": "step", "step": i, "epoch": 0, "start_s": float(i),
+                "dur_s": 0.1, "phases": {"guard": [i + 0.2, 0.1]}, **counters}
+
+    header = {"event": "header", "schema_version": 2, "wall": 0.0}
+    report = telemetry_report.format_report([
+        header, step(1),
+        step(2, sel_scan_state_rms=0.02, gmu_gate_rms=0.5,
+             diff_lambda_mean=0.79),
+        step(3, sel_scan_state_rms=0.04, gmu_gate_rms=0.7,
+             diff_lambda_mean=0.81)])
+    assert ("hybrid decoder (2 steps): selective scan's final state rms 0.03; "
+            "gated memory rms 0.6; differential lambda 0.8") in report
+    report = telemetry_report.format_report([
+        header, step(1, sel_scan_state_rms=0.02, diff_lambda_mean=0.5)])
+    assert "hybrid decoder (1 steps)" in report
+    assert "gated memory" not in report
+    assert "hybrid decoder" not in telemetry_report.format_report(
+        [header, step(1, ssm_state_rms=0.1)])
+
+
 def test_report_selftest_runs():
     import telemetry_report
 

@@ -105,11 +105,14 @@ def test_flash_backward_is_not_compilable_yet(topo):
     (1, 32, 8, 64, None, 1.0 / 64),
     (1, 64, 8, 128, 512, 1.0),
     (1, 48, 8, 128, None, 1.0),
+    (1, 80, 40, 64, None, 0.125),
+    (1, 80, 40, 64, 512, 0.125),
 ], ids=["mellum2-full", "mellum2-window-1024", "granite4h",
-        "laguna-xs2-window-512-64-heads", "laguna-xs2-full-48-heads-groups-of-6"])
+        "laguna-xs2-window-512-64-heads", "laguna-xs2-full-48-heads-groups-of-6",
+        "phi4flash-full-80-stacked-heads", "phi4flash-window-512-80-stacked-heads"])
 def test_attention_core_compiles_for_v5e_at_the_cells_shapes(
         topo, b, h, hkv, d, window, scale):
-    """ops/blockattn.py's fused kernel, forward and backward, at the five
+    """ops/blockattn.py's fused kernel, forward and backward, at the seven
     call shapes of the language cells (T 8,192, bf16) and the tiles the shape
     test gives them: the tiles fit VMEM and the backward compiles. The kernel
     path is called outright: this process traces for the CPU."""
@@ -132,6 +135,39 @@ def test_attention_core_compiles_for_v5e_at_the_cells_shapes(
     assert text.count("tpu_custom_call") >= 2
     # no float32 array of a whole head's scores leaves the kernel
     assert f"f32[{t},{t}]" not in text and f",{t},{t}]" not in text
+
+
+def test_selective_scan_keeps_no_whole_sequence_of_states_on_a_v5e(topo):
+    """ops/selscan.py at the Phi-4-mini-flash cell's size (T 8,192, 5,120
+    channels x 16 states, the model's chunk and block), forward and backward:
+    it compiles for the chip and ALL its scratch (1.54 GiB: the four or five
+    (positions of a block, states, channels) float32 arrays one block's
+    backward holds at once, 0.33 GiB each) stays under what ONE float32 (T,
+    channels, states) array would take, 2.5 GiB: the states of a block's
+    positions live only inside that block's forward and recomputed
+    backward."""
+    from mgwfbp_tpu.models.phi4flash import PHI4FLASH, Phi4FlashLM
+    from mgwfbp_tpu.ops.selscan import selective_scan
+
+    t, d, n = 8192, PHI4FLASH.mamba_inner, PHI4FLASH.mamba_state
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def loss(x, dt, a, b, c):
+        y, state = selective_scan(
+            x, dt, a, b, c, chunk=PHI4FLASH.scan_chunk,
+            block=Phi4FlashLM.scan_block)
+        return jnp.sum(y) + jnp.sum(state)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        arg((1, t, d), jnp.bfloat16), arg((1, t, d), jnp.float32),
+        arg((d, n), jnp.float32), arg((1, t, n), jnp.bfloat16),
+        arg((1, t, n), jnp.bfloat16)).compile()
+    whole = t * d * n * 4  # 2.5 GiB
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.7 * whole
+    assert "tpu_custom_call" not in compiled.as_text()  # plain jax.numpy
 
 
 @pytest.mark.parametrize("m,k,n,groups", [

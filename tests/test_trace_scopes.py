@@ -146,3 +146,36 @@ GRANITE_SCOPES = [
 ])
 def test_classify_the_state_space_scopes(op_name, want):
     assert trace_scopes.classify(op_name, GRANITE_SCOPES) == want
+
+
+PHI4FLASH_SCOPES = [
+    "ssm_in_proj", "ssm_conv", "ssm_dt_proj", "ssm_sel_scan", "ssm_out_proj",
+    "gmu", "attn_proj", "attn_window", "attn_full", "attn_cross", "attn_diff",
+    "mlp", "lm_head", "loss",
+]
+
+
+@pytest.mark.parametrize("op_name,want", [
+    # the decoder-hybrid-decoder's scopes (models/phi4flash.py): each layer
+    # is under jax.checkpoint, the selective scan's blocks in a checkpointed
+    # loop whose body loops over a chunk's positions
+    ("jit(step)/jvp(Phi4FlashLM)/checkpoint/ssm_sel_scan/while/body/"
+     "checkpoint/while/body/exp", ("ssm_sel_scan", "forward")),
+    ("jit(step)/transpose(jvp(Phi4FlashLM))/checkpoint/rematted_computation/"
+     "ssm_sel_scan/while/body/checkpoint/rematted_computation/mul",
+     ("ssm_sel_scan", "backward")),
+    ("jit(step)/jvp(Phi4FlashLM)/checkpoint/ssm_dt_proj/softplus",
+     ("ssm_dt_proj", "forward")),
+    ("jit(step)/transpose(jvp(Phi4FlashLM))/checkpoint/gmu/dot_general",
+     ("gmu", "backward")),
+    ("jit(step)/jvp(Phi4FlashLM)/checkpoint/attn_cross/pallas_call",
+     ("attn_cross", "forward")),
+    ("jit(step)/transpose(jvp(Phi4FlashLM))/checkpoint/attn_diff/rsqrt",
+     ("attn_diff", "backward")),
+    # the older scan's name is no part of the selective scan's
+    ("jit(step)/jvp(Phi4FlashLM)/checkpoint/ssm_scan_not/x",
+     ("(model, no scope)", "forward")),
+])
+def test_classify_the_hybrid_decoders_scopes(op_name, want):
+    assert trace_scopes.classify(op_name, PHI4FLASH_SCOPES) == want
+

@@ -71,7 +71,8 @@ def _phase_section(steps: list[dict]) -> list[str]:
     and `stats_ready` (had the health drain's arrays finished when it asked;
     where not, the drain is where the host waited for the chip), and a
     sparse-expert model's routing counters (`moe_here`, `moe_load_max` over
-    `moe_load_mean`, `moe_dropped`)."""
+    `moe_load_mean`, `moe_dropped`), and a decoder-hybrid-decoder's
+    (`sel_scan_state_rms`, `gmu_gate_rms`, `diff_lambda_mean`)."""
     from mgwfbp_tpu.telemetry.phases import PHASES
 
     spans: dict[str, list[tuple[float, float]]] = {}
@@ -136,6 +137,18 @@ def _phase_section(steps: list[dict]) -> list[str]:
             f"expert {sum(imbalance) / max(len(imbalance), 1):.3f}x the "
             f"average (worst step {max(imbalance, default=0.0):.3f}x); "
             f"{sum(s['moe_dropped'] for s in routed):g} dropped")
+    # a decoder-hybrid-decoder's counters (models/phi4flash.py): each the
+    # mean over the steps that have it
+    hybrid = {
+        said: [s[name] for s in steps if name in s] for name, said in (
+            ("sel_scan_state_rms", "selective scan's final state rms"),
+            ("gmu_gate_rms", "gated memory rms"),
+            ("diff_lambda_mean", "differential lambda"))}
+    if any(hybrid.values()):
+        lines.append(
+            f"  hybrid decoder ({max(map(len, hybrid.values()))} steps): "
+            + "; ".join(f"{said} {sum(v) / len(v):.4g}"
+                        for said, v in hybrid.items() if v))
     return lines
 
 

@@ -8,7 +8,10 @@
 (models/mellum.py's scopes; models/granite.py's are ssm_in_proj, ssm_conv,
 ssm_scan, ssm_gate_norm, ssm_out_proj, mlp, attn_full, attn_proj, lm_head,
 loss; models/laguna.py's are attn_proj, attn_gate, attn_window, attn_full,
-mlp, moe_route, moe_shared, moe_experts, lm_head, loss.)
+mlp, moe_route, moe_shared, moe_experts, lm_head, loss; models/phi4flash.py's
+are ssm_in_proj, ssm_conv, ssm_dt_proj, ssm_sel_scan, ssm_out_proj, gmu,
+attn_proj, attn_window, attn_full, attn_cross, attn_diff, mlp, lm_head,
+loss.)
 
 A TPU trace names each event of the `XLA Ops` line after the HLO instruction
 it ran; the compiled module's per-instruction `op_name` metadata still carries
