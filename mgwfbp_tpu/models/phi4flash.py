@@ -49,7 +49,9 @@ k2] and the values [v1, v2, v1, v2]. Each pair's scores are so computed
 twice; a core that takes values of 128 beside keys of 64 would compute them
 once.
 
-**The scan** is `ops/selscan.py`'s chunked form; the gated MLP, the causal
+**The scan** is `ops/selscan.py`'s: its two kernels with the state in VMEM
+where the step is traced for a TPU and the shape fits, else its chunked form
+(which `scan_chunk` and `scan_block` size); the gated MLP, the causal
 convolution and its initialisers are `models/granite.py`'s, the head and loss
 `models/mellum.py`'s.
 
@@ -107,8 +109,9 @@ from mgwfbp_tpu.models.granite import (
     gated_mlp,
 )
 from mgwfbp_tpu.models.mellum import _Leaves, rms_norm, token_losses
+from mgwfbp_tpu.ops import selscan
 from mgwfbp_tpu.ops.blockattn import blockwise_attention
-from mgwfbp_tpu.ops.selscan import selective_scan
+from mgwfbp_tpu.ops.groupmm import counted
 
 MAMBA, GMU = "mamba", "gmu"
 WINDOW, FULL, CROSS = "sliding_attention", "full_attention", "cross_attention"
@@ -214,7 +217,7 @@ def mamba_mixer(p: dict, u: jax.Array, s: Phi4FlashShape, scan_block: int):
             (dbc[..., :rank] @ p["dt_proj"]).astype(jnp.float32)
             + p["dt_bias"].astype(jnp.float32))
     with jax.named_scope("ssm_sel_scan"):
-        y, state = selective_scan(
+        y, state = selscan.selective_scan(
             xs, dt, -jnp.exp(p["a_log"].astype(jnp.float32)),
             dbc[..., rank:rank + n], dbc[..., rank + n:],
             chunk=s.scan_chunk, block=scan_block)
@@ -417,8 +420,9 @@ class Phi4FlashLM(nn.Module):
         counters: dict = {}  # a layer's counter under its kind's key
         for p, index in zip(layers, held):
             kind = s.kind(index)
-            h, published, counter = jax.checkpoint(
-                layer, static_argnums=(3, 4, 5, 6))(
+            # the layer's scan is counted where its trace is a cached one too
+            h, published, counter = counted(jax.checkpoint(
+                layer, static_argnums=(3, 4, 5, 6)), selscan.LOWERED)(
                     p, h, reads.get(kind), index, s, self.attn_block,
                     self.scan_block)
             if published is not None:

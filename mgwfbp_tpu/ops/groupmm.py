@@ -223,24 +223,26 @@ def grouped_product(lhs: jax.Array, rhs: jax.Array,
     return out
 
 
-def counted(fn):
-    """`fn` with the grouped products of EVERY call counted. jax keeps one
-    trace of a function under `jax.checkpoint` for equal argument shapes, so
-    of four equal layers only the first runs `grouped_product`'s Python: a
-    call that counted no product is counted as the call of its shapes that
-    did."""
+def counted(fn, counter: collections.Counter = LOWERED):
+    """`fn` with what EVERY call of it traces counted in `counter` (the
+    grouped products here; `ops/selscan.py` hands in its own). jax keeps one
+    trace of a function under `jax.checkpoint` for equal static arguments
+    and argument shapes, so of four equal layers only the first runs
+    `grouped_product`'s Python, and a step built a second time in one
+    process runs none of it: a call that counted nothing is counted as the
+    call of its arguments that did."""
 
     def call(*args):
-        before = LOWERED.copy()
+        before = counter.copy()
         out = fn(*args)
-        key = jax.tree.structure(args), tuple(
+        key = id(counter), jax.tree.structure(args), tuple(
             (a.shape, a.dtype) if hasattr(a, "shape") else a
             for a in jax.tree.leaves(args))
-        traced = LOWERED - before
-        if traced["kernel"] or traced["ragged"]:
+        traced = counter - before
+        if traced:
             _TRACED_BY[key] = traced
         else:
-            LOWERED.update(_TRACED_BY.get(key, ()))
+            counter.update(_TRACED_BY.get(key, ()))
         return out
 
     return call

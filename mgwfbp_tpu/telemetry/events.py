@@ -100,6 +100,12 @@ EVENT_TYPES: dict[str, tuple[str, ...]] = {
     "experts_program": (
         "step", "kernel", "ragged", "programs", "rows_held", "rows_all",
         "rows_programs"),
+    # the same for the selective scans (ops/selscan.py): how many went
+    # through the kernels with the state in VMEM and how many through the
+    # plain chunked form (1 a Mamba-1 layer held), and the distinct kernel
+    # programs the former need (a forward and a backward one for each
+    # shape); all 0 for a model without a selective scan
+    "scan_program": ("step", "kernel", "plain", "programs"),
     # what set-up was made of, once per process start, when the host has
     # read the first step's results, and once more after a rebuild that
     # recompiles the step (telemetry/phases.py): `spans` as

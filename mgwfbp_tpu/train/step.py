@@ -37,7 +37,7 @@ from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from mgwfbp_tpu.models import ModelMeta
-from mgwfbp_tpu.ops import groupmm
+from mgwfbp_tpu.ops import groupmm, selscan
 from mgwfbp_tpu.ops.blockattn import LOWERED as ATTENTION_LOWERED
 from mgwfbp_tpu.parallel.allreduce import MergedAllreduce
 from mgwfbp_tpu.parallel.mesh import DATA_AXIS
@@ -721,21 +721,26 @@ def make_train_step(
     # cores went through the fused kernel and how many through the plain
     # blocks (ops/blockattn.py), and how many of its experts' grouped
     # products through the tiled kernel and through `lax.ragged_dot`, with
-    # the distinct kernel programs among them (ops/groupmm.py); Trainer
-    # records them as `attention_program` and `experts_program`
+    # the distinct kernel programs among them (ops/groupmm.py), and the same
+    # of its selective scans (ops/selscan.py: the kernels or the chunked
+    # form); Trainer records them as `attention_program`, `experts_program`
+    # and `scan_program`
     attention_calls: dict[str, int] = {}
     experts_calls: dict[str, int] = {}
+    scan_calls: dict[str, int] = {}
 
     def counting_programs(fn):
         def traced(*args):
             attention = dict(ATTENTION_LOWERED)
             experts = groupmm.LOWERED.copy()
+            scans = selscan.LOWERED.copy()
             out = fn(*args)
             attention_calls.update(
                 (way, n - attention[way])
                 for way, n in ATTENTION_LOWERED.items()
             )
             experts_calls.update(groupmm.lowered_since(experts))
+            scan_calls.update(selscan.lowered_since(scans))
             return out
 
         return traced
@@ -758,6 +763,7 @@ def make_train_step(
 
         step_lm.attention_calls = attention_calls
         step_lm.experts_calls = experts_calls
+        step_lm.scan_calls = scan_calls
         return step_lm
 
     def per_device_nocarry(state, batch):
@@ -781,6 +787,7 @@ def make_train_step(
 
     step.attention_calls = attention_calls
     step.experts_calls = experts_calls
+    step.scan_calls = scan_calls
     return step
 
 

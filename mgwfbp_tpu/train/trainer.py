@@ -1405,8 +1405,10 @@ class Trainer:
         many through the plain blocks (ops/blockattn.py), how many of its
         experts' grouped products through the tiled kernel and how many
         through `lax.ragged_dot`, and the distinct kernel programs among
-        them (ops/groupmm.py); both choose by platform and shape. Nothing
-        is compiled or read from the device."""
+        them (ops/groupmm.py), how many of its selective scans through the
+        kernels with the state in VMEM and how many through the chunked
+        form (ops/selscan.py); all choose by platform and shape. Nothing is
+        compiled or read from the device."""
         self._traced_programs_noted = True
         calls = getattr(self.train_step, "attention_calls", None)
         if not calls:  # not traced through make_train_step's own wrapper
@@ -1430,6 +1432,17 @@ class Trainer:
         )
         self._emit_event(
             "experts_program", step=int(self.iteration),
+            **{name: int(n) for name, n in calls.items()},
+        )
+        calls = self.train_step.scan_calls
+        self.log.info(
+            "scan: %d selective scan(s) of the step through the kernels with "
+            "the state in VMEM (%d distinct kernel program(s)), %d through "
+            "the chunked form",
+            calls["kernel"], calls["programs"], calls["plain"],
+        )
+        self._emit_event(
+            "scan_program", step=int(self.iteration),
             **{name: int(n) for name, n in calls.items()},
         )
 

@@ -219,6 +219,25 @@ def test_trains_with_counters_and_every_leaf_reduces_like_pmean(
     assert not [k for s in steps for k in s if k.startswith("health/")]
     # (the count of cores on the `attention_program` record is held in
     # tests/test_blockattn.py, where nothing has traced the layers before)
+    # the stage's two Mamba layers' scans, counted while the step was traced
+    # (the second policy's step finds the first's layers in jax's cache of
+    # traces, and counts them still): the chunked form on the CPU
+    assert trainer.train_step.scan_calls == {
+        "kernel": 0, "plain": 2, "programs": 0}
+    (program,) = events_of(records, "scan_program")
+    assert set(program) >= {"step", "kernel", "plain", "programs"}
+    assert (program["step"], program["kernel"], program["plain"],
+            program["programs"]) == (1, 0, 2, 0)
+    with open(os.path.join(
+            str(tmp_path / policy), cfg.tag(), "train.log")) as f:
+        assert ("scan: 0 selective scan(s) of the step through the kernels "
+                "with the state in VMEM (0 distinct kernel program(s)), 2 "
+                "through the chunked form") in f.read()
+    import telemetry_report
+
+    assert ("; 0 selective scan(s) through the kernels with the state in "
+            "VMEM (0 distinct kernel program(s)), 2 through the chunked form"
+            ) in telemetry_report.format_report(records)
 
 
 def test_exact_step_resume_is_bitwise(tmp_path, monkeypatch):

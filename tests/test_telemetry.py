@@ -277,6 +277,18 @@ def test_report_prints_the_hybrid_decoders_counters():
     assert "gated memory" not in report
     assert "hybrid decoder" not in telemetry_report.format_report(
         [header, step(1, ssm_state_rms=0.1)])
+    # the way its selective scans went down, off the newest `scan_program`
+    # record (ops/selscan.py), at the line's end
+    scans = {"event": "scan_program", "step": 1, "kernel": 2, "plain": 0,
+             "programs": 2}
+    report = telemetry_report.format_report([
+        header, {**scans, "kernel": 0, "plain": 2, "programs": 0}, scans,
+        step(1, sel_scan_state_rms=0.02, diff_lambda_mean=0.5)])
+    assert ("differential lambda 0.5; 2 selective scan(s) through the "
+            "kernels with the state in VMEM (2 distinct kernel program(s)), "
+            "0 through the chunked form") in report
+    assert "selective scan(s)" not in telemetry_report.format_report(
+        [header, {**scans, "kernel": 0, "programs": 0}, step(1)])
 
 
 def test_report_selftest_runs():

@@ -137,17 +137,10 @@ def test_attention_core_compiles_for_v5e_at_the_cells_shapes(
     assert f"f32[{t},{t}]" not in text and f",{t},{t}]" not in text
 
 
-def test_selective_scan_keeps_no_whole_sequence_of_states_on_a_v5e(topo):
-    """ops/selscan.py at the Phi-4-mini-flash cell's size (T 8,192, 5,120
-    channels x 16 states, the model's chunk and block), forward and backward:
-    it compiles for the chip and ALL its scratch (1.54 GiB: the four or five
-    (positions of a block, states, channels) float32 arrays one block's
-    backward holds at once, 0.33 GiB each) stays under what ONE float32 (T,
-    channels, states) array would take, 2.5 GiB: the states of a block's
-    positions live only inside that block's forward and recomputed
-    backward."""
-    from mgwfbp_tpu.models.phi4flash import PHI4FLASH, Phi4FlashLM
-    from mgwfbp_tpu.ops.selscan import selective_scan
+def _scan_arguments(topo):
+    """ops/selscan.py's arguments at the Phi-4-mini-flash cell's size, as
+    shapes on one described chip: x, dt, a (D, N), b, c."""
+    from mgwfbp_tpu.models.phi4flash import PHI4FLASH
 
     t, d, n = 8192, PHI4FLASH.mamba_inner, PHI4FLASH.mamba_state
     one = SingleDeviceSharding(topo.devices[0])
@@ -155,19 +148,81 @@ def test_selective_scan_keeps_no_whole_sequence_of_states_on_a_v5e(topo):
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
+    return arg, (
+        arg((1, t, d), jnp.bfloat16), arg((1, t, d), jnp.float32),
+        arg((d, n), jnp.float32), arg((1, t, n), jnp.bfloat16),
+        arg((1, t, n), jnp.bfloat16))
+
+
+def test_selective_scan_keeps_no_whole_sequence_of_states_on_a_v5e(topo):
+    """ops/selscan.py's chunked form at the Phi-4-mini-flash cell's size (T
+    8,192, 5,120 channels x 16 states, the model's chunk and block), forward
+    and backward: it compiles for the chip and ALL its scratch (1.54 GiB: the
+    four or five (positions of a block, states, channels) float32 arrays one
+    block's backward holds at once, 0.33 GiB each) stays under what ONE
+    float32 (T, channels, states) array would take, 2.5 GiB: the states of a
+    block's positions live only inside that block's forward and recomputed
+    backward."""
+    from mgwfbp_tpu.models.phi4flash import PHI4FLASH, Phi4FlashLM
+    from mgwfbp_tpu.ops.selscan import chunked_scan
+
+    _, args = _scan_arguments(topo)
+    (_, t, d), n = args[0].shape, args[2].shape[1]
+
     def loss(x, dt, a, b, c):
-        y, state = selective_scan(
+        y, state = chunked_scan(
             x, dt, a, b, c, chunk=PHI4FLASH.scan_chunk,
             block=Phi4FlashLM.scan_block)
         return jnp.sum(y) + jnp.sum(state)
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
-        arg((1, t, d), jnp.bfloat16), arg((1, t, d), jnp.float32),
-        arg((d, n), jnp.float32), arg((1, t, n), jnp.bfloat16),
-        arg((1, t, n), jnp.bfloat16)).compile()
+        *args).compile()
     whole = t * d * n * 4  # 2.5 GiB
     assert compiled.memory_analysis().temp_size_in_bytes < 0.7 * whole
     assert "tpu_custom_call" not in compiled.as_text()  # plain jax.numpy
+
+
+def test_selective_scan_kernels_keep_the_state_off_hbm_on_a_v5e(topo):
+    """ops/selscan.py's two kernels at the same size and the tiles the shape
+    test gives it, as one layer has them: the scan under `jax.checkpoint`,
+    its value and its pull-back. They compile for the chip (the tiles and
+    the block's recomputed states fit VMEM), as TWO distinct kernel programs
+    at two or three sites (the forward, its recomputation where the compiler
+    keeps it, the backward), no float32 (.., states, channels) array with a
+    dimension of positions before it exists in the text (what is saved is
+    one state a block of positions: 10 MiB), and the scratch stays under
+    0.2 GiB where the chunked form's is 1.54. The kernel path is called
+    outright: this process traces for the CPU."""
+    from mgwfbp_tpu.ops import selscan
+
+    arg, args = _scan_arguments(topo)
+    (_, t, d), n = args[0].shape, args[2].shape[1]
+    tiles = selscan._kernel_tiles(
+        t, d, n, (args[0].dtype, args[3].dtype, args[4].dtype))
+    assert tiles is not None
+
+    def layer(x, dt, a, b, c, dy, dlast):
+        out, pull = jax.vjp(
+            jax.checkpoint(lambda *v: selscan._kernel_scan(*v, tiles, False)),
+            x, dt, a.T, b, c)
+        return out, pull((dy, dlast))
+
+    compiled = jax.jit(layer).lower(
+        *args, arg((1, t, d), jnp.float32), arg((1, n, d), jnp.float32)
+    ).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) in (2, 3)
+    assert {re.search(r"(selective_scan_\w+)/pallas_call", line).group(1)
+            for line in calls} == {
+                "selective_scan_forward", "selective_scan_backward"}
+    # f32[1,32,16,5120], the state each block starts from, is there; nothing
+    # with as many states as a block has positions, or the sequence has, is
+    saved = t // tiles.rows
+    for shape in re.findall(rf"f32\[([\d,]*),{n},{d}\]", text):
+        assert all(int(size) in (1, saved) for size in shape.split(","))
+    assert saved * 8 <= tiles.rows
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.2 * 2 ** 30
 
 
 @pytest.mark.parametrize("m,k,n,groups", [

@@ -179,3 +179,41 @@ PHI4FLASH_SCOPES = [
 def test_classify_the_hybrid_decoders_scopes(op_name, want):
     assert trace_scopes.classify(op_name, PHI4FLASH_SCOPES) == want
 
+
+
+def test_the_selective_scans_kernels_land_in_its_scope():
+    """As the TPU compiler prints ops/selscan.py's two kernels in a
+    Phi-4-mini-flash step (compiled here for a v5e, PR 39): the jitted
+    `_forward_kernel` / `_backward_kernel` keep the model's name stack in
+    front of their own, so a layer's forward, its recomputation under the
+    layer's `jax.checkpoint` and its backward are all `ssm_sel_scan`, the
+    first forward and the other two backward."""
+    back = ("jit(step)/transpose(jvp(Phi4FlashLM))/jvp(Phi4FlashLM)/"
+            "checkpoint/")
+    hlo = "".join(
+        f'  %{name} = (f32[1,8192,5120]{{2,1,0:T(8,128)}}, '
+        'f32[1,16,5120]{2,1,0:T(8,128)}) custom-call(%a, %b), '
+        'custom_call_target="tpu_custom_call", frontend_attributes={'
+        'kernel_metadata={}}, '
+        f'metadata={{op_name="{op_name}/pallas_call" stack_frame_id=31}}, '
+        'backend_config={"custom_call_config":{"body":"TUzvUg"}}\n'
+        for name, op_name in [
+            ("selective_scan_forward.4",
+             "jit(step)/jvp(Phi4FlashLM)/ssm_sel_scan/jit(_forward_kernel)/"
+             "selective_scan_forward"),
+            ("selective_scan_forward.6",
+             back + "rematted_computation/ssm_sel_scan/jit(_forward_kernel)/"
+             "selective_scan_forward"),
+            ("selective_scan_backward.4",
+             back + "ssm_sel_scan/jit(_backward_kernel)/"
+             "selective_scan_backward")])
+    names = trace_scopes.hlo_op_names(hlo)
+    assert set(names) == {
+        "selective_scan_forward.4", "selective_scan_forward.6",
+        "selective_scan_backward.4"}
+    totals, top = trace_scopes.split(
+        [("selective_scan_forward.4", 0, 17), ("selective_scan_forward.6", 20,
+         18), ("selective_scan_backward.4", 40, 36)], names, PHI4FLASH_SCOPES)
+    assert totals == {("ssm_sel_scan", "forward"): 17,
+                      ("ssm_sel_scan", "backward"): 54}
+    assert top[0] == (36, "selective_scan_backward.4", "ssm_sel_scan")

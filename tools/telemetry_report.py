@@ -61,7 +61,7 @@ def _window_mean(spans: list[dict], sl: slice) -> float:
     return sum(float(s["dur_s"]) for s in w) / max(len(w), 1)
 
 
-def _phase_section(steps: list[dict]) -> list[str]:
+def _phase_section(steps: list[dict], scans: list[dict] = ()) -> list[str]:
     """One table of the host loop's phases (telemetry/phases.py): median
     milliseconds over the steps that have the phase, and the share of the
     loop's time (first span's start to last span's end) all its entries
@@ -72,7 +72,9 @@ def _phase_section(steps: list[dict]) -> list[str]:
     where not, the drain is where the host waited for the chip), and a
     sparse-expert model's routing counters (`moe_here`, `moe_load_max` over
     `moe_load_mean`, `moe_dropped`), and a decoder-hybrid-decoder's
-    (`sel_scan_state_rms`, `gmu_gate_rms`, `diff_lambda_mean`)."""
+    (`sel_scan_state_rms`, `gmu_gate_rms`, `diff_lambda_mean`) with the way
+    its selective scans went down (`scans`: the `scan_program` records, of
+    which the newest built step program's is said)."""
     from mgwfbp_tpu.telemetry.phases import PHASES
 
     spans: dict[str, list[tuple[float, float]]] = {}
@@ -145,10 +147,17 @@ def _phase_section(steps: list[dict]) -> list[str]:
             ("gmu_gate_rms", "gated memory rms"),
             ("diff_lambda_mean", "differential lambda"))}
     if any(hybrid.values()):
+        says = [f"{said} {sum(v) / len(v):.4g}"
+                for said, v in hybrid.items() if v]
+        for prog in scans[-1:]:
+            says.append(
+                f"{prog.get('kernel')} selective scan(s) through the kernels "
+                f"with the state in VMEM ({prog.get('programs')} distinct "
+                f"kernel program(s)), {prog.get('plain')} through the "
+                "chunked form")
         lines.append(
             f"  hybrid decoder ({max(map(len, hybrid.values()))} steps): "
-            + "; ".join(f"{said} {sum(v) / len(v):.4g}"
-                        for said, v in hybrid.items() if v))
+            + "; ".join(says))
     return lines
 
 
@@ -250,7 +259,8 @@ def format_report(records: list[dict]) -> str:
                 f"trend: first-10 {_fmt_s(first)} s -> last-10 "
                 f"{_fmt_s(last)} s ({drift:+.1f}%)"
             )
-        lines.extend(_phase_section(steps))
+        lines.extend(_phase_section(
+            steps, events_of(records, "scan_program")))
     else:
         lines.append("steps: none recorded")
 
