@@ -35,7 +35,8 @@ class TrainConfig:
     num_steps: Optional[int] = None  # LM window length override (default 35;
     # seq-parallel transformers need num_steps % seq_parallel == 0)
     # the part of a model this chip holds (models that can be held in part:
-    # the mellum2, granite4h, laguna_xs2 and phi4flash families). None = all
+    # the mellum2, granite4h, laguna_xs2, phi4flash and qwen3next families).
+    # None = all
     layers_held: Optional[str] = None  # "N" the first N layers, or
     # "FIRST:COUNT" a stage anywhere (models.parse_layers_held)
     experts_held: Optional[str] = None  # "first:count" of each layer's experts
@@ -226,6 +227,15 @@ PRESETS: dict[str, dict] = {
                             lr=3e-3, max_epochs=40, lr_schedule="cosine",
                             optimizer="adamw", adam_b2=0.95, weight_decay=0.1,
                             norm_clip=1.0, vocab_size=256),
+    # hybrid linear-attention sparse decoder LM (models/qwen3next.py):
+    # mellum2's recipe (none of it published) at 2 x 8,192 tokens a device
+    "qwen3next": dict(dataset="tokens", batch_size=2, num_steps=8192, lr=3e-4,
+                      max_epochs=40, lr_schedule="cosine", optimizer="adamw",
+                      adam_b2=0.95, weight_decay=0.1, norm_clip=1.0),
+    "qwen3next_tiny": dict(dataset="tokens", batch_size=2, num_steps=64,
+                           lr=3e-3, max_epochs=40, lr_schedule="cosine",
+                           optimizer="adamw", adam_b2=0.95, weight_decay=0.1,
+                           norm_clip=1.0, vocab_size=256),
     "fcn5net": dict(dataset="mnist", batch_size=64, lr=0.05, max_epochs=10),
     "lr": dict(dataset="mnist", batch_size=64, lr=0.01, max_epochs=10),
 }

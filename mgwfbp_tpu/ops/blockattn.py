@@ -16,8 +16,8 @@ tiles) and cached. The tiles are constants chosen from the shape, fitted on a
 v5e from the trace by scope (PERF.md section 6, PR 31).
 
 **The plain blocks.** Everywhere else (the CPU, a T the tiles do not divide,
-a head size other than 64 or 128), and as the kernel's reference: the queries
-are cut into blocks of `block` positions and each block is scored against the
+a head size other than 64, 128 or 256), and as the kernel's reference: the
+queries are cut into blocks of `block` positions and each block is scored against the
 one static slice of keys it can see:
 
   * full causal attention: keys [0, end of the block), so the blocks above
@@ -66,7 +66,7 @@ LOWERED = collections.Counter(kernel=0, blocks=0)
 # that computes the probabilities again. A window layer's tiles overhang its
 # band, so it takes smaller ones, and its partial sums cost more than the
 # second kernel. Chosen by shape, that is by `window` alone: every call shape
-# of the cells has D 64 or 128 and T 8,192. Fitted at a window of 1,024
+# of the cells has D 64, 128 or 256 and T 8,192. Fitted at a window of 1,024
 # (Mellum 2, 2 x 32 query heads: 17.0 ms forward + backward alone on the chip;
 # 256 tiles 24.9, 1,024 tiles 18.8, fused backward 21.6; PR 31) and again at
 # a window of 512 (Laguna-XS.2, 64 query heads over 8 key heads, where a 512
@@ -79,9 +79,15 @@ LOWERED = collections.Counter(kernel=0, blocks=0)
 # tiles: 1,024 x 1,024, keys 512 at a time, fused backward 23.33 ms; a dq
 # kernel of its own 28.08; keys 1,024 at a time 23.95; 512 tiles 29.26; the
 # plain blocks 74.65; 2,048 x 512 does not fit VMEM (PR 32).
+# Qwen3-Next's full layers (D 256, 2 x 16 query heads over 2 x 2 key heads)
+# keep the full tiles too: 1,024 x 1,024, keys 512 at a time, fused backward
+# 30.29 ms forward + backward alone on the chip (9.63 forward); keys 1,024 at
+# a time 30.59; 512 tiles 33.64; a dq kernel of its own 36.31 to 37.77 at
+# every tile; the plain blocks 54.07 to 56.08; 2,048 x 512 does not fit VMEM
+# (my chip run, PR 40).
 _TILES_FULL = (1024, 512, True)
 _TILES_WINDOW = (512, 512, False)
-_KERNEL_HEAD_DIMS = (64, 128)  # half a lane tile, and a whole one
+_KERNEL_HEAD_DIMS = (64, 128, 256)  # half a lane tile, one, and two
 
 
 def key_range(start: int, stop: int, window: Optional[int]) -> tuple[int, int]:

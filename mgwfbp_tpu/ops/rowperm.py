@@ -106,6 +106,8 @@ _TOKENS = 256
 # its windows may take of that beside the pipeline's buffers
 _VMEM_LIMIT = 110 * 2 ** 20
 _VMEM_WINDOW = 80 * 2 ** 20
+# a one-dimensional block in SMEM is whole tiles of this many elements
+_SMEM_TILE = 1024
 
 
 class Plan(NamedTuple):
@@ -234,6 +236,17 @@ def _combine_kernel(rows, index, weights, sizes, *, plan: Plan,
     tokens, most = plan.tokens, plan.chunks
     blocks = n // tokens
     chunks, count, where = _window_lists(index, sizes, plan)
+    weights = weights.reshape(-1)
+    # a block's tokens x k scalars in whole SMEM tiles: at k 8 they are (256
+    # x 8 = 2 tiles); at k 10 each block's 2,560 are padded to 3 tiles, which
+    # the kernel never reads (Mosaic refuses a block of 2,560: "not divisible
+    # by tiling", the chip's compiler asked here, PR 40)
+    span = -(-tokens * k // _SMEM_TILE) * _SMEM_TILE
+    if span != tokens * k:
+        where, weights = (
+            jnp.pad(a.reshape(blocks, tokens * k),
+                    ((0, 0), (0, span - tokens * k))).reshape(-1)
+            for a in (where, weights))
     direct = dtype == jnp.float32  # copied straight into the float32 window
     zeros = pl.ds(most * _CHUNK, _CHUNK)  # the window's rows of zeros
 
@@ -315,9 +328,9 @@ def _combine_kernel(rows, index, weights, sizes, *, plan: Plan,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(blocks,),
             in_specs=[
-                pl.BlockSpec((tokens * k,), lambda b, *_: (b,),
+                pl.BlockSpec((span,), lambda b, *_: (b,),
                              memory_space=pltpu.SMEM),
-                pl.BlockSpec((tokens * k,), lambda b, *_: (b,),
+                pl.BlockSpec((span,), lambda b, *_: (b,),
                              memory_space=pltpu.SMEM),
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
@@ -333,7 +346,7 @@ def _combine_kernel(rows, index, weights, sizes, *, plan: Plan,
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
         name="combine_rows",
-    )(count, chunks, where, weights.reshape(-1), rows)
+    )(count, chunks, where, weights, rows)
 
 
 def _planned(held: bool, n: int, k: int, d: int, sizes, dtype):

@@ -105,16 +105,16 @@ def build_parser() -> argparse.ArgumentParser:
                    help="hold only COUNT layers of a model that can be held "
                         "in part, starting at layer FIRST: one pipeline "
                         "stage's share. A bare N is 0:N, the first N "
-                        "(mellum2, granite4h, laguna_xs2 take no other "
-                        "FIRST; phi4flash's stage may start anywhere and "
+                        "(mellum2, granite4h, laguna_xs2, qwen3next take no "
+                        "other FIRST; phi4flash's stage may start anywhere and "
                         "its layers keep their published indices)")
     p.add_argument("--experts-held", dest="experts_held", default=None,
                    metavar="FIRST:COUNT",
                    help="hold only COUNT experts of every sparse layer, "
-                        "starting at expert FIRST (mellum2, laguna_xs2): one "
-                        "chip's "
-                        "share of an expert-parallel group. The router "
-                        "still scores all experts; what the absent ones "
+                        "starting at expert FIRST (mellum2, laguna_xs2, "
+                        "qwen3next): one chip's share of an expert-parallel "
+                        "group. The router still scores all experts; what "
+                        "the absent ones "
                         "would add is left out")
     p.add_argument("--vocab-size", dest="vocab_size", type=int, default=None,
                    help="--dataset tokens: ids 0..N-1, which are also the "

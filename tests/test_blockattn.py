@@ -53,6 +53,35 @@ def test_scale_value_and_gradient_against_dense(scale, want_scale):
     assert abs(float(other) - float(want)) > 1e-3 * abs(float(want))
 
 
+def test_head_size_256_value_and_gradients_against_the_plain_softmax():
+    """Qwen3-Next's core: 16 query heads over 2 key heads of 256, scores over
+    16. The entry point (the plain blocks on this CPU) and the fused kernel in
+    `interpret` mode at the tiles' shape, each against the softmax written
+    out, float32."""
+    keys = jax.random.split(jax.random.PRNGKey(5), 4)
+    t = 256
+    q = 4.0 * jax.random.normal(keys[0], (1, t, 16, 256))
+    k = jax.random.normal(keys[1], (1, t, 2, 256))
+    v = jax.random.normal(keys[2], (1, t, 2, 256))
+    w = jax.random.normal(keys[3], (1, t, 16, 256))
+
+    def through(fn):
+        return jax.jit(jax.value_and_grad(
+            lambda q, k, v: jnp.sum(fn(q, k, v) * w), argnums=(0, 1, 2)))
+
+    want, want_grads = through(
+        lambda q, k, v: dense_attention(q, k, v, 1.0 / 16))(q, k, v)
+    tiles = blockattn._kernel_tiles(t, 256, None)
+    assert tiles is not None and tiles.block_q == t
+    for fn in (lambda q, k, v: blockwise_attention(q, k, v, block=96),
+               lambda q, k, v: blockattn._fused(
+                   q, k, v, None, 1.0 / 16, tiles, interpret=True)):
+        got, got_grads = through(fn)(q, k, v)
+        assert abs(float(got) - float(want)) < 2e-5 * abs(float(want))
+        for g, wg in zip(got_grads, want_grads):
+            assert float(jnp.linalg.norm(g - wg) / jnp.linalg.norm(wg)) < 1e-5
+
+
 def tiles_of(size, **more):
     return blockattn._splash().BlockSizes(
         block_q=size, block_kv=size, block_kv_compute=size, block_q_dkv=size,
@@ -69,10 +98,11 @@ def tiles_of(size, **more):
     (1, 12, 2, 128, None, 1.0, tiles_of(256, use_fused_bwd_kernel=True)),
     (1, 8, 1, 128, 512, None, tiles_of(512)),
     (1, 6, 1, 64, 256, None, tiles_of(256)),
+    (1, 8, 1, 256, None, 1.0 / 16, tiles_of(256, use_fused_bwd_kernel=True)),
 ], ids=["d64-full-scale-1/64-groups-of-4", "d128-full", "d128-window-4-long",
         "batch-2", "batch-2-window-fused-backward",
         "laguna-full-groups-of-6-scale-in-q", "laguna-window-512-one-tile",
-        "groups-of-6-window-a-tile"])
+        "groups-of-6-window-a-tile", "qwen3next-d256-groups-of-8"])
 def test_fused_kernel_value_and_gradients_against_the_plain_blocks(
         b, h, hkv, d, window, scale, tiles):
     """Float32, so the two differ by rounding order alone; T 512 is four
@@ -124,10 +154,11 @@ def test_the_kernel_object_is_built_once_a_shape_and_outside_the_trace():
     (8192, 128, 512, True),
     (512, 64, None, True), (8192 + 512, 128, None, False),
     (640, 64, None, False), (40, 64, None, False), (8192, 96, None, False),
-    (8192, 256, None, False),
+    (8192, 256, None, True), (8192, 512, None, False),
 ], ids=["mellum2-full", "granite", "mellum2-window", "laguna-window-512",
         "short", "tiles-overhang",
-        "five-lane-tiles", "no-lane-tile", "head-96", "head-256"])
+        "five-lane-tiles", "no-lane-tile", "head-96", "qwen3next-head-256",
+        "head-512"])
 def test_shape_test_of_the_kernel(t, d, window, fits):
     tiles = blockattn._kernel_tiles(t, d, window)
     assert (tiles is not None) == fits
@@ -193,7 +224,12 @@ TOKENS = ["--dataset", "tokens", "--vocab-size", "256", "--num-steps", "64"]
     # one stacked core a layer (window, window, full, cross); a length no
     # other test traces these layers at, whose traces `jax.checkpoint` keeps
     (["--dnn", "phi4flash_tiny", *TOKENS[:-1], "48"], 4),
-], ids=["mellum2_tiny", "granite4h_tiny", "laguna_xs2_tiny", "phi4flash_tiny"])
+    # two periods: the two full layers share ONE cached trace and are both
+    # counted (`groupmm.counted` over this file's counter)
+    (["--dnn", "qwen3next_tiny", "--experts-held", "4:4", *TOKENS[:-1], "48"],
+     2),
+], ids=["mellum2_tiny", "granite4h_tiny", "laguna_xs2_tiny", "phi4flash_tiny",
+        "qwen3next_tiny"])
 def test_a_step_program_leaves_its_attention_count_on_the_telemetry(
         tmp_path, monkeypatch, flags, blocks):
     """One `attention_program` record a built step program, counted while the

@@ -24,7 +24,8 @@ ALL_IMAGE_MODELS = [
     n for n in zoo.model_names()
     if n not in ("lstm", "lstman4", "transformer", "mellum2", "mellum2_tiny",
                  "granite4h", "granite4h_tiny", "laguna_xs2",
-                 "laguna_xs2_tiny", "phi4flash", "phi4flash_tiny")
+                 "laguna_xs2_tiny", "phi4flash", "phi4flash_tiny",
+                 "qwen3next", "qwen3next_tiny")
 ]
 
 
@@ -169,6 +170,45 @@ def test_phi4flash_traces_and_counts_its_parameters(name, share, want, layers):
         assert stats["health/sel_scan_state"].shape == (3,)  # Mamba layers
         assert stats["health/gmu_gate"].shape == (1,)
         assert stats["health/diff_lambda"].shape == (4,)  # 2 window, full, cross
+
+
+@pytest.mark.parametrize("name,share,want,layers", [
+    # the whole model: 36 Gated DeltaNet and 12 full-attention layers, 512
+    # experts in every one, the untied vocabulary: the published 80 B less
+    # the multi-token-prediction module
+    ("qwen3next", {}, 79_674_391_296, 48),
+    # one chip's share: the first period, 32 of 512 experts, an eighth of
+    # the ids
+    ("qwen3next", dict(num_classes=18992, layers_held=4,
+                       experts_held=(0, 32)), 625_667_136, 4),
+    ("qwen3next_tiny", dict(experts_held=(4, 4)), None, 8),
+])
+def test_qwen3next_traces_and_counts_its_parameters(name, share, want, layers):
+    model, meta = zoo.create_model(name, **share)
+    assert meta.task == "lm" and not meta.has_carry and meta.fused_loss
+    assert meta.dataset == "tokens"
+    x = _example_input(meta)
+    variables = jax.eval_shape(
+        lambda: model.init({"params": jax.random.PRNGKey(0)}, x, train=False))
+    params = variables["params"]
+    if want is not None:
+        assert sum(int(np.prod(leaf.shape))
+                   for leaf in jax.tree_util.tree_leaves(params)) == want
+    assert sum(k.startswith("layer_") for k in params) == layers
+    assert set(params["out"]) == {"norm", "head"}  # untied
+    # every fourth layer is the full-attention one
+    assert [i for i in range(layers) if "wq" in params[f"layer_{i}"]] \
+        == list(range(3, layers, 4))
+    if name == "qwen3next_tiny":
+        logits = jax.eval_shape(lambda v: model.apply(v, x), variables)
+        assert logits.shape == (2, 64, meta.num_classes)
+        per_token, stats = jax.eval_shape(
+            lambda v: model.apply(v, x, targets=x, train=True), variables)
+        assert per_token.shape == (2, 64)
+        assert stats["health/delta_state"].shape == (6,)  # the delta layers
+        assert stats["health/delta_beta"].shape == (6,)
+        assert stats["health/shared_gate"].shape == (8,)
+        assert stats["health/moe_tokens"].shape == (8, 4)
 
 
 @pytest.mark.parametrize(

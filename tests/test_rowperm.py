@@ -37,6 +37,9 @@ CASES = {
     "float32_a_third": Case(512, 4, 128, 4, 16, 0.35, "float32"),
     "float32_all_held": Case(256, 4, 256, 4, 4, 1.0, "float32"),
     "float32_skew": Case(512, 4, 128, 2, 8, "skew", "float32"),
+    # Qwen3-Next's k: a block's 256 x 10 scalars are no whole SMEM tiles
+    "qwen3next_k10_a_fifth": Case(512, 10, 256, 4, 64, 0.2, "bfloat16"),
+    "qwen3next_k10_all_held": Case(256, 10, 128, 16, 16, 1.0, "float32"),
 }
 
 
@@ -308,8 +311,8 @@ def test_falls_back_to_the_plain_gathers_off_the_tpu_and_on_a_shape_that_misfits
 
 
 @pytest.mark.parametrize("n,k,d,groups", [
-    (16384, 8, 2304, 16), (8192, 8, 2048, 32)],
-    ids=["mellum2", "laguna_xs2"])
+    (16384, 8, 2304, 16), (8192, 8, 2048, 32), (16384, 10, 2048, 32)],
+    ids=["mellum2", "laguna_xs2", "qwen3next"])
 def test_the_cells_shapes_take_the_chips_way(n, k, d, groups):
     plan = rowperm._kernel_plan(n, k, d, groups, jnp.bfloat16)
     assert plan is not None

@@ -291,6 +291,39 @@ def test_report_prints_the_hybrid_decoders_counters():
         [header, {**scans, "kernel": 0, "programs": 0}, step(1)])
 
 
+def test_report_says_the_linear_attention_counters_and_the_delta_program():
+    """`delta_state_rms`, `delta_beta_mean`, `shared_gate_mean` of the `step`
+    records (models/qwen3next.py, by the health drain's road) under the phase
+    table, with the way the delta rules went down off the newest
+    `delta_program` record (ops/deltarule.py); a stream without them no
+    line, and the older family's line is not theirs."""
+    import telemetry_report
+
+    def step(i, **counters):
+        return {"event": "step", "step": i, "epoch": 0, "start_s": float(i),
+                "dur_s": 0.1, "phases": {"guard": [i + 0.2, 0.1]}, **counters}
+
+    header = {"event": "header", "schema_version": 2, "wall": 0.0}
+    program = {"event": "delta_program", "step": 1, "kernel": 0, "plain": 3,
+               "programs": 0}
+    report = telemetry_report.format_report([
+        header, program, step(1),
+        step(2, delta_state_rms=0.02, delta_beta_mean=0.5,
+             shared_gate_mean=0.49, moe_here=0.06, moe_load_max=400.0,
+             moe_load_mean=320.0, moe_dropped=0.0),
+        step(3, delta_state_rms=0.04, delta_beta_mean=0.52,
+             shared_gate_mean=0.51, moe_here=0.06, moe_load_max=400.0,
+             moe_load_mean=320.0, moe_dropped=0.0)])
+    assert ("linear attention (2 steps): delta rule's final state rms 0.03; "
+            "write gate beta 0.51; shared expert's gate 0.5; 0 gated delta "
+            "rule(s) through a kernel with the state in VMEM (0 distinct "
+            "kernel program(s)), 3 through the plain chunked form") in report
+    assert "expert routing (2 steps)" in report
+    assert "hybrid decoder" not in report
+    assert "linear attention" not in telemetry_report.format_report(
+        [header, program, step(1, sel_scan_state_rms=0.1)])
+
+
 def test_report_selftest_runs():
     import telemetry_report
 

@@ -13,6 +13,7 @@ cache is off around these tests: an entry written for a described chip
 cannot be read back without one, and the next compile would only warn.
 """
 
+import math
 import os
 import re
 
@@ -107,12 +108,14 @@ def test_flash_backward_is_not_compilable_yet(topo):
     (1, 48, 8, 128, None, 1.0),
     (1, 80, 40, 64, None, 0.125),
     (1, 80, 40, 64, 512, 0.125),
+    (2, 16, 2, 256, None, 1.0),
 ], ids=["mellum2-full", "mellum2-window-1024", "granite4h",
         "laguna-xs2-window-512-64-heads", "laguna-xs2-full-48-heads-groups-of-6",
-        "phi4flash-full-80-stacked-heads", "phi4flash-window-512-80-stacked-heads"])
+        "phi4flash-full-80-stacked-heads", "phi4flash-window-512-80-stacked-heads",
+        "qwen3next-full-head-256-groups-of-8"])
 def test_attention_core_compiles_for_v5e_at_the_cells_shapes(
         topo, b, h, hkv, d, window, scale):
-    """ops/blockattn.py's fused kernel, forward and backward, at the seven
+    """ops/blockattn.py's fused kernel, forward and backward, at the eight
     call shapes of the language cells (T 8,192, bf16) and the tiles the shape
     test gives them: the tiles fit VMEM and the backward compiles. The kernel
     path is called outright: this process traces for the CPU."""
@@ -182,6 +185,49 @@ def test_selective_scan_keeps_no_whole_sequence_of_states_on_a_v5e(topo):
     assert "tpu_custom_call" not in compiled.as_text()  # plain jax.numpy
 
 
+def test_delta_rule_keeps_no_whole_sequence_of_states_on_a_v5e(topo):
+    """ops/deltarule.py at the Qwen3-Next cell's size (2 sequences of 8,192,
+    16 key and 32 value heads of 128, the model's chunk and block), forward
+    and backward: it compiles for the chip at 0.95 GiB of scratch, and no
+    array holds a (keys, values) state for more than the 16 blocks' starts
+    or one block's 8 chunks (a state a chunk over the whole sequence would
+    be 128 of them a head): a block's states live only inside that block's
+    forward and recomputed backward."""
+    from mgwfbp_tpu.models.qwen3next import QWEN3NEXT as S, Qwen3NextLM
+    from mgwfbp_tpu.ops.deltarule import gated_delta_rule
+
+    one = SingleDeviceSharding(topo.devices[0])
+    b, t = 2, 8192
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    qk = arg((b, t, S.linear_key_heads, S.linear_key_dim), jnp.bfloat16)
+    v = arg((b, t, S.linear_value_heads, S.linear_value_dim), jnp.bfloat16)
+    gate = arg((b, t, S.linear_value_heads), jnp.float32)
+
+    def loss(q, k, v, g, beta):
+        o, state = gated_delta_rule(
+            q, k, v, g, beta, chunk=S.delta_chunk,
+            block=Qwen3NextLM.delta_block)
+        return jnp.sum(o.astype(jnp.float32)) + jnp.sum(state)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        qk, qk, v, gate, gate).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.25 * 2 ** 30
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text  # plain jax.numpy
+    assert "triangular-solve" not in text  # the inverse is formed by blocks
+    one_state = S.linear_key_dim * S.linear_value_dim
+    chunks = t // S.delta_chunk
+    states = [
+        math.prod(int(n) for n in dims.split(",")) // one_state
+        for dims in re.findall(
+            rf"f32\[([\d,]+),{S.linear_key_dim},{S.linear_value_dim}\]", text)]
+    assert states and max(states) <= b * S.linear_value_heads * max(
+        Qwen3NextLM.delta_block, chunks // Qwen3NextLM.delta_block)
+
+
 def test_selective_scan_kernels_keep_the_state_off_hbm_on_a_v5e(topo):
     """ops/selscan.py's two kernels at the same size and the tiles the shape
     test gives it, as one layer has them: the scan under `jax.checkpoint`,
@@ -228,8 +274,9 @@ def test_selective_scan_kernels_keep_the_state_off_hbm_on_a_v5e(topo):
 @pytest.mark.parametrize("m,k,n,groups", [
     (131072, 2304, 896, 16), (131072, 896, 2304, 16),
     (65536, 2048, 512, 32), (65536, 512, 2048, 32),
+    (163840, 2048, 512, 32), (163840, 512, 2048, 32),
 ], ids=["mellum2-gate-up", "mellum2-down", "laguna-xs2-gate-up",
-        "laguna-xs2-down"])
+        "laguna-xs2-down", "qwen3next-gate-up", "qwen3next-down"])
 def test_grouped_product_compiles_for_v5e_at_the_cells_shapes(
         topo, m, k, n, groups):
     """ops/groupmm.py's tiled kernel with both transposes at the sparse
@@ -255,8 +302,11 @@ def test_grouped_product_compiles_for_v5e_at_the_cells_shapes(
     assert "ragged-dot" not in text
 
 
-CELLS = [(16384, 8, 2304, 896, 16), (8192, 8, 2048, 512, 32)]
-CELL_IDS = ["mellum2", "laguna-xs2"]
+# Qwen3-Next's k 10: a block's 256 x 10 scalars are no whole SMEM tiles, which
+# Mosaic refuses ("not divisible by tiling"); the kernel pads them (PR 40)
+CELLS = [(16384, 8, 2304, 896, 16), (8192, 8, 2048, 512, 32),
+         (16384, 10, 2048, 512, 32)]
+CELL_IDS = ["mellum2", "laguna-xs2", "qwen3next"]
 
 
 @pytest.mark.parametrize("n,k,d,f,groups", CELLS, ids=CELL_IDS)

@@ -181,6 +181,42 @@ def test_classify_the_hybrid_decoders_scopes(op_name, want):
 
 
 
+QWEN3NEXT_SCOPES = [
+    "gdn_in_proj", "gdn_conv", "gdn_delta", "gdn_gate_norm", "gdn_out_proj",
+    "attn_proj", "attn_full", "attn_gate", "moe_route", "moe_shared",
+    "moe_experts", "lm_head", "loss",
+]
+
+
+@pytest.mark.parametrize("op_name,want", [
+    # the hybrid linear-attention decoder's scopes (models/qwen3next.py):
+    # each layer is under jax.checkpoint, the delta rule's blocks in a
+    # checkpointed loop with a triangular solve inside
+    ("jit(step)/jvp(Qwen3NextLM)/checkpoint/gdn_delta/while/body/checkpoint/"
+     "triangular_solve", ("gdn_delta", "forward")),
+    ("jit(step)/transpose(jvp(Qwen3NextLM))/checkpoint/rematted_computation/"
+     "gdn_delta/while/body/checkpoint/rematted_computation/dot_general",
+     ("gdn_delta", "backward")),
+    ("jit(step)/jvp(Qwen3NextLM)/checkpoint/gdn_in_proj/dot_general",
+     ("gdn_in_proj", "forward")),
+    ("jit(step)/jvp(Qwen3NextLM)/checkpoint/gdn_conv/mul",
+     ("gdn_conv", "forward")),
+    ("jit(step)/transpose(jvp(Qwen3NextLM))/checkpoint/gdn_gate_norm/rsqrt",
+     ("gdn_gate_norm", "backward")),
+    ("jit(step)/transpose(jvp(Qwen3NextLM))/checkpoint/gdn_out_proj/"
+     "dot_general", ("gdn_out_proj", "backward")),
+    ("jit(step)/jvp(Qwen3NextLM)/checkpoint/attn_gate/logistic",
+     ("attn_gate", "forward")),
+    ("jit(step)/jvp(Qwen3NextLM)/checkpoint/moe_shared/dot_general",
+     ("moe_shared", "forward")),
+    # the gate-norm's name is no part of the attention gate's
+    ("jit(step)/jvp(Qwen3NextLM)/checkpoint/gdn_delta_not/x",
+     ("(model, no scope)", "forward")),
+])
+def test_classify_the_linear_attention_scopes(op_name, want):
+    assert trace_scopes.classify(op_name, QWEN3NEXT_SCOPES) == want
+
+
 def test_the_selective_scans_kernels_land_in_its_scope():
     """As the TPU compiler prints ops/selscan.py's two kernels in a
     Phi-4-mini-flash step (compiled here for a v5e, PR 39): the jitted

@@ -61,7 +61,8 @@ def _window_mean(spans: list[dict], sl: slice) -> float:
     return sum(float(s["dur_s"]) for s in w) / max(len(w), 1)
 
 
-def _phase_section(steps: list[dict], scans: list[dict] = ()) -> list[str]:
+def _phase_section(steps: list[dict], scans: list[dict] = (),
+                   deltas: list[dict] = ()) -> list[str]:
     """One table of the host loop's phases (telemetry/phases.py): median
     milliseconds over the steps that have the phase, and the share of the
     loop's time (first span's start to last span's end) all its entries
@@ -74,7 +75,10 @@ def _phase_section(steps: list[dict], scans: list[dict] = ()) -> list[str]:
     `moe_load_mean`, `moe_dropped`), and a decoder-hybrid-decoder's
     (`sel_scan_state_rms`, `gmu_gate_rms`, `diff_lambda_mean`) with the way
     its selective scans went down (`scans`: the `scan_program` records, of
-    which the newest built step program's is said)."""
+    which the newest built step program's is said), and a hybrid
+    linear-attention decoder's (`delta_state_rms`, `delta_beta_mean`,
+    `shared_gate_mean`) with the way its delta rules did (`deltas`: the
+    `delta_program` records)."""
     from mgwfbp_tpu.telemetry.phases import PHASES
 
     spans: dict[str, list[tuple[float, float]]] = {}
@@ -139,24 +143,36 @@ def _phase_section(steps: list[dict], scans: list[dict] = ()) -> list[str]:
             f"expert {sum(imbalance) / max(len(imbalance), 1):.3f}x the "
             f"average (worst step {max(imbalance, default=0.0):.3f}x); "
             f"{sum(s['moe_dropped'] for s in routed):g} dropped")
-    # a decoder-hybrid-decoder's counters (models/phi4flash.py): each the
-    # mean over the steps that have it
-    hybrid = {
-        said: [s[name] for s in steps if name in s] for name, said in (
-            ("sel_scan_state_rms", "selective scan's final state rms"),
-            ("gmu_gate_rms", "gated memory rms"),
-            ("diff_lambda_mean", "differential lambda"))}
-    if any(hybrid.values()):
+    # a model family's own counters, each the mean over the steps that have
+    # it, then the way its recurrences went down, off the newest record of
+    # the built step program (`scans`, `deltas`): a decoder-hybrid-decoder's
+    # (models/phi4flash.py), a hybrid linear-attention decoder's
+    # (models/qwen3next.py)
+    for title, counters, programs, kernel, plain in (
+            ("hybrid decoder", (
+                ("sel_scan_state_rms", "selective scan's final state rms"),
+                ("gmu_gate_rms", "gated memory rms"),
+                ("diff_lambda_mean", "differential lambda")), scans,
+             "selective scan(s) through the kernels", "the chunked form"),
+            ("linear attention", (
+                ("delta_state_rms", "delta rule's final state rms"),
+                ("delta_beta_mean", "write gate beta"),
+                ("shared_gate_mean", "shared expert's gate")), deltas,
+             "gated delta rule(s) through a kernel",
+             "the plain chunked form")):
+        values = {said: [s[name] for s in steps if name in s]
+                  for name, said in counters}
+        if not any(values.values()):
+            continue
         says = [f"{said} {sum(v) / len(v):.4g}"
-                for said, v in hybrid.items() if v]
-        for prog in scans[-1:]:
+                for said, v in values.items() if v]
+        for prog in programs[-1:]:
             says.append(
-                f"{prog.get('kernel')} selective scan(s) through the kernels "
-                f"with the state in VMEM ({prog.get('programs')} distinct "
-                f"kernel program(s)), {prog.get('plain')} through the "
-                "chunked form")
+                f"{prog.get('kernel')} {kernel} with the state in VMEM "
+                f"({prog.get('programs')} distinct kernel program(s)), "
+                f"{prog.get('plain')} through {plain}")
         lines.append(
-            f"  hybrid decoder ({max(map(len, hybrid.values()))} steps): "
+            f"  {title} ({max(map(len, values.values()))} steps): "
             + "; ".join(says))
     return lines
 
@@ -260,7 +276,8 @@ def format_report(records: list[dict]) -> str:
                 f"{_fmt_s(last)} s ({drift:+.1f}%)"
             )
         lines.extend(_phase_section(
-            steps, events_of(records, "scan_program")))
+            steps, events_of(records, "scan_program"),
+            events_of(records, "delta_program")))
     else:
         lines.append("steps: none recorded")
 

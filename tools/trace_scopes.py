@@ -11,7 +11,9 @@ loss; models/laguna.py's are attn_proj, attn_gate, attn_window, attn_full,
 mlp, moe_route, moe_shared, moe_experts, lm_head, loss; models/phi4flash.py's
 are ssm_in_proj, ssm_conv, ssm_dt_proj, ssm_sel_scan, ssm_out_proj, gmu,
 attn_proj, attn_window, attn_full, attn_cross, attn_diff, mlp, lm_head,
-loss.)
+loss; models/qwen3next.py's are gdn_in_proj, gdn_conv, gdn_delta,
+gdn_gate_norm, gdn_out_proj, attn_proj, attn_full, attn_gate, moe_route,
+moe_shared, moe_experts, lm_head, loss.)
 
 A TPU trace names each event of the `XLA Ops` line after the HLO instruction
 it ran; the compiled module's per-instruction `op_name` metadata still carries
