@@ -38,8 +38,8 @@ anywhere:
           over the sum of the ten;  y = sum_j w_j E_{i_j}(u)
           + sigmoid(u w_s) . E_shared(u);  E(u) = (silu(u W_g) . u W_u) W_d
 
-The delta rule is `ops/deltarule.py`'s chunked form (chunks of 64); the
-attention core `ops/blockattn.py`'s; the router Mellum 2's (`mellum.route`:
+The delta rule is `ops/deltarule.py`'s (chunks of 64: its kernels on a TPU,
+its plain chunked form elsewhere); the attention core `ops/blockattn.py`'s; the router Mellum 2's (`mellum.route`:
 the same softmax, top k, renormalised); the routed experts
 `mellum.held_experts`; the rotary embedding `laguna.partial_rope`; the
 convolution `granite.causal_conv`; the loss `mellum.token_losses`.
@@ -60,7 +60,7 @@ differentiates it, so the mixer's and the experts' intermediates are never
 alive together (the loss and gradient of two sequences compile for a v5e
 at 7.20 GiB of temporaries with one checkpoint round the whole layer, which
 does not fit beside 9.3 GiB of state, and at 3.73 with the two halves; PERF.md,
-PR 40). Inside, the delta rule recomputes its blocks of chunks, the attention
+PR 40). Inside, the delta rule recomputes its blocks of positions, the attention
 core keeps no scores, the routed experts keep nothing but their inputs, and
 the loss recomputes its token blocks.
 

@@ -107,8 +107,8 @@ EVENT_TYPES: dict[str, tuple[str, ...]] = {
     # shape); all 0 for a model without a selective scan
     "scan_program": ("step", "kernel", "plain", "programs"),
     # the same for the gated delta rules (ops/deltarule.py; 1 a Gated
-    # DeltaNet layer held): there is no kernel yet, so all go `plain`; all 0
-    # for a model without a delta rule
+    # DeltaNet layer held; an inverse, a forward and a backward program for
+    # each shape); all 0 for a model without a delta rule
     "delta_program": ("step", "kernel", "plain", "programs"),
     # what set-up was made of, once per process start, when the host has
     # read the first step's results, and once more after a rebuild that

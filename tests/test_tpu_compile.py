@@ -228,6 +228,136 @@ def test_delta_rule_keeps_no_whole_sequence_of_states_on_a_v5e(topo):
         Qwen3NextLM.delta_block, chunks // Qwen3NextLM.delta_block)
 
 
+def test_delta_rule_kernels_keep_a_tiles_matrices_off_hbm_on_a_v5e(topo):
+    """ops/deltarule.py's three kernels at the same size and the block the
+    shape test gives it, as one layer has them: the rule under
+    `jax.checkpoint`, its value and its pull-back. They compile for the chip
+    (the blocks, the backward's recomputed states and solves and the
+    inverse's slabs fit VMEM), as THREE distinct kernel programs; what the
+    forward hands the backward beside the inputs is one state a block of 512
+    positions (f32[2,16,32,128,128], 64 MiB): no state a chunk, and the
+    chunks' (I + A)^-1 (f32[2,16,2,64,64,128] between the inverse kernel
+    and the kernel that reads it) is no residual; the scratch stays under
+    0.5 GiB where the plain form's is 0.72. The kernel path is called
+    outright: this process traces for the CPU."""
+    from mgwfbp_tpu.models.qwen3next import QWEN3NEXT as S
+    from mgwfbp_tpu.ops import deltarule
+
+    one = SingleDeviceSharding(topo.devices[0])
+    b, t = 2, 8192
+    hk, h = S.linear_key_heads, S.linear_value_heads
+    dk, dv = S.linear_key_dim, S.linear_value_dim
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    qk, v = arg((b, t, hk, dk), jnp.bfloat16), arg((b, t, h, dv), jnp.bfloat16)
+    gate = arg((b, t, h), jnp.float32)
+    rows = deltarule._kernel_rows(
+        t, hk, h, dk, dv, S.delta_chunk, (jnp.bfloat16,) * 3)
+    assert rows is not None
+
+    def rule(*x):
+        return deltarule._kernel_rule(*x, S.delta_chunk, rows, False)
+
+    def layer(q, k, v, g, beta, do, dlast):
+        out, pull = jax.vjp(jax.checkpoint(rule), q, k, v, g, beta)
+        return out, pull((do, dlast))
+
+    compiled = jax.jit(layer).lower(
+        qk, qk, v, gate, gate, v, arg((b, h, dk, dv), jnp.float32)).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert {re.search(r"(gated_delta_rule_\w+)/pallas_call", line).group(1)
+            for line in calls} == {
+                "gated_delta_rule_inverse", "gated_delta_rule_forward",
+                "gated_delta_rule_backward"}
+    assert len(calls) in (5, 6)  # the compiler may share one inverse
+    # states: the blocks' starts and the final state's cotangent, no more
+    blocks = t // rows
+    for shape in re.findall(rf"f32\[([\d,]*),{dk},{dv}\]", text):
+        assert math.prod(int(n) for n in shape.split(",")) <= b * h * blocks
+    assert blocks * 8 == t // S.delta_chunk
+    residuals = jax.eval_shape(
+        lambda *x: deltarule._kernel_rule_fwd(
+            *x, S.delta_chunk, rows, False)[1], qk, qk, v, gate, gate)
+    held = sorted(math.prod(x.shape) for x in residuals)
+    assert held == sorted([
+        *(math.prod(x.shape) for x in (qk, qk, v, gate, gate)),
+        b * blocks * h * dk * dv])
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5 * 2 ** 30
+
+
+@pytest.mark.parametrize("dtype,hk,h,t,rows", [
+    (jnp.float32, 2, 8, 384, 128),    # four value heads a key head, one
+    # tile a block; the inverse's step takes one tile: lanes no tile fills
+    (jnp.float32, 8, 8, 768, 256),    # one value head a key head
+    (jnp.float32, 4, 8, 2048, 512),   # the cell's group and block, float32
+    (jnp.float32, 2, 8, 2048, 512),   # the most VMEM the kernels are let
+    (jnp.bfloat16, 2, 8, 1024, 512),  # groups of four, the solve's 3 passes
+    (jnp.bfloat16, 8, 8, 256, 256),   # no group
+    (jnp.bfloat16, 4, 8, 640, 128),   # the cell's group, the smallest block
+])
+def test_delta_rule_kernels_compile_for_v5e_wherever_they_are_chosen(
+        topo, dtype, hk, h, t, rows):
+    """`_kernel_rows` sends float32 as well as bfloat16, one, two and four
+    value heads a key head, blocks of 512, 256 and 128 positions and any
+    number of tiles down the kernels, and a shape that Mosaic refused would
+    fail the step's compile where the plain form was to be had: interpret
+    mode takes shapes the chip's compiler does not (PR 40's SMEM block).
+    So each corner is compiled for the described chip, value and pull-back,
+    at a short T. Nothing runs: the values are `tests/test_deltarule.py`'s,
+    interpreted."""
+    from mgwfbp_tpu.ops import deltarule
+
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def arg(shape, of=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, of, sharding=one)
+
+    assert deltarule._kernel_rows(t, hk, h, 128, 128, 64, (dtype,) * 3) == rows
+
+    def layer(q, k, v, g, beta, do, dlast):
+        out, pull = jax.vjp(
+            lambda *x: deltarule._kernel_rule(*x, 64, rows, False),
+            q, k, v, g, beta)
+        return out, pull((do, dlast))
+
+    qk, v = arg((1, t, hk, 128), dtype), arg((1, t, h, 128), dtype)
+    text = jax.jit(layer).lower(
+        qk, qk, v, arg((1, t, h)), arg((1, t, h)), v,
+        arg((1, h, 128, 128))).compile().as_text()
+    assert {"gated_delta_rule_inverse", "gated_delta_rule_forward",
+            "gated_delta_rule_backward"} <= set(
+                re.findall(r"(gated_delta_rule_\w+)/pallas_call", text))
+
+
+def test_qwen3next_step_counts_three_rules_through_three_programs(
+        topo, monkeypatch):
+    """The Qwen3-Next cell's step (four layers, 32 of 512 experts, 18,992
+    ids, two sequences of 8,192) traced and lowered for the described chip
+    (said so by the test: `traced_for_tpu` asks the default backend, which
+    is the CPU here; nothing is compiled: the kernels are above, and the
+    whole step takes the chip's compiler two minutes): `delta_program` reads
+    3 + 0 and 3, three layers sharing the inverse's, the forward's and the
+    backward's program."""
+    from mgwfbp_tpu.ops import blockattn
+
+    monkeypatch.setattr(blockattn, "traced_for_tpu", lambda: True)
+    mesh = Mesh(np.asarray(topo.devices[:1]), (DATA_AXIS,))
+    model, meta = zoo.create_model(
+        "qwen3next", num_classes=18992, layers_held=4, experts_held=(0, 32))
+    tx = _imagenet_sgd()
+    state, batch = _abstract_step_args(model, meta, tx, mesh, 2)
+    batch["y"] = jax.ShapeDtypeStruct(
+        batch["x"].shape, jnp.int32, sharding=batch["x"].sharding)
+    step = make_train_step(
+        model, meta, tx, mesh, None, compute_dtype=jnp.bfloat16, donate=True)
+    text = step.lower(state, batch).as_text()
+    assert step.delta_calls == {"kernel": 3, "plain": 0, "programs": 3}
+    assert "gated_delta_rule_backward" in text
+
+
 def test_selective_scan_kernels_keep_the_state_off_hbm_on_a_v5e(topo):
     """ops/selscan.py's two kernels at the same size and the tiles the shape
     test gives it, as one layer has them: the scan under `jax.checkpoint`,
