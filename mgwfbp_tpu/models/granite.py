@@ -68,7 +68,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from mgwfbp_tpu.models.mellum import _Leaves, rms_norm, token_losses
+from mgwfbp_tpu.ops import shortconv
 from mgwfbp_tpu.ops.blockattn import blockwise_attention
+from mgwfbp_tpu.ops.groupmm import counted
 from mgwfbp_tpu.ops.ssd import ssd_scan
 
 MAMBA, ATTENTION = "mamba", "attention"
@@ -136,18 +138,6 @@ def _conv_init(key, shape, dtype=jnp.float32):
     return jax.random.uniform(key, shape, dtype, -bound, bound)
 
 
-def causal_conv(x: jax.Array, w: jax.Array, bias: jax.Array) -> jax.Array:
-    """Depthwise causal convolution along T: x (B, T, C), w (K, C) with
-    w[K - 1] on the current position, bias (C,). Float32 sums, x's dtype
-    out."""
-    k, t = w.shape[0], x.shape[1]
-    padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0))).astype(jnp.float32)
-    w = w.astype(jnp.float32)
-    out = bias.astype(jnp.float32) + sum(
-        padded[:, i:i + t] * w[i] for i in range(k))
-    return out.astype(x.dtype)
-
-
 def mamba_mixer(p: dict, u: jax.Array, shape: GraniteShape, scan_block: int):
     """The Mamba-2 mixer on the normed input u (B, T, hidden): (y (B, T,
     hidden), root mean square of the final state, most negative chunk sum of
@@ -161,8 +151,7 @@ def mamba_mixer(p: dict, u: jax.Array, shape: GraniteShape, scan_block: int):
         xbc = zxbcdt[..., inner:inner + shape.conv_channels]
         dt = zxbcdt[..., inner + shape.conv_channels:]
     with jax.named_scope("ssm_conv"):
-        xbc = causal_conv(xbc, p["conv_w"], p["conv_b"])
-        xbc = jax.nn.silu(xbc.astype(jnp.float32)).astype(u.dtype)
+        xbc = shortconv.causal_conv_silu(xbc, p["conv_w"], p["conv_b"])
     with jax.named_scope("ssm_scan"):
         xs = xbc[..., :inner].reshape(b, t, heads, hd)
         dt = jax.nn.softplus(
@@ -287,8 +276,10 @@ class Granite4HLM(nn.Module):
         h = jnp.asarray(s.embedding_multiplier, embed.dtype) * embed[x]
         state_rms, low = [], []
         for p, kind in zip(layers, kinds):
-            h, layer_rms, layer_low = jax.checkpoint(
-                layer, static_argnums=(2, 3, 4, 5))(
+            # the layer's convolution is counted where its trace is a
+            # cached one too
+            h, layer_rms, layer_low = counted(jax.checkpoint(
+                layer, static_argnums=(2, 3, 4, 5)), shortconv.LOWERED)(
                     p, h, kind, s, self.attn_block, self.scan_block)
             if kind == MAMBA:
                 state_rms.append(layer_rms)

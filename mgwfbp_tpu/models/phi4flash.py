@@ -105,11 +105,10 @@ from jax import lax
 from mgwfbp_tpu.models.granite import (
     _conv_init,
     _dt_bias_init,
-    causal_conv,
     gated_mlp,
 )
 from mgwfbp_tpu.models.mellum import _Leaves, rms_norm, token_losses
-from mgwfbp_tpu.ops import selscan
+from mgwfbp_tpu.ops import selscan, shortconv
 from mgwfbp_tpu.ops.blockattn import blockwise_attention
 from mgwfbp_tpu.ops.groupmm import counted
 
@@ -209,8 +208,7 @@ def mamba_mixer(p: dict, u: jax.Array, s: Phi4FlashShape, scan_block: int):
         xz = u @ p["in_proj"]
         xs, z = xz[..., :inner], xz[..., inner:]
     with jax.named_scope("ssm_conv"):
-        xs = causal_conv(xs, p["conv_w"], p["conv_b"])
-        xs = jax.nn.silu(xs.astype(jnp.float32)).astype(u.dtype)
+        xs = shortconv.causal_conv_silu(xs, p["conv_w"], p["conv_b"])
     with jax.named_scope("ssm_dt_proj"):
         dbc = xs @ p["x_proj"]
         dt = jax.nn.softplus(
@@ -420,9 +418,11 @@ class Phi4FlashLM(nn.Module):
         counters: dict = {}  # a layer's counter under its kind's key
         for p, index in zip(layers, held):
             kind = s.kind(index)
-            # the layer's scan is counted where its trace is a cached one too
-            h, published, counter = counted(jax.checkpoint(
-                layer, static_argnums=(3, 4, 5, 6)), selscan.LOWERED)(
+            # the layer's scan and convolution are counted where its trace
+            # is a cached one too
+            h, published, counter = counted(counted(jax.checkpoint(
+                layer, static_argnums=(3, 4, 5, 6)), selscan.LOWERED),
+                shortconv.LOWERED)(
                     p, h, reads.get(kind), index, s, self.attn_block,
                     self.scan_block)
             if published is not None:

@@ -42,7 +42,7 @@ The delta rule is `ops/deltarule.py`'s (chunks of 64: its kernels on a TPU,
 its plain chunked form elsewhere); the attention core `ops/blockattn.py`'s; the router Mellum 2's (`mellum.route`:
 the same softmax, top k, renormalised); the routed experts
 `mellum.held_experts`; the rotary embedding `laguna.partial_rope`; the
-convolution `granite.causal_conv`; the loss `mellum.token_losses`.
+convolution with its SiLU `ops/shortconv.py`'s; the loss `mellum.token_losses`.
 
 **A chip's share**, as models/laguna.py takes it: `layers_held` (the first n
 layers), `experts_held = (first, count)` of every layer and `vocab_size`. The
@@ -103,7 +103,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from mgwfbp_tpu.models.granite import _conv_init, _dt_bias_init, causal_conv
+from mgwfbp_tpu.models.granite import _conv_init, _dt_bias_init
 from mgwfbp_tpu.models.laguna import partial_rope, swiglu
 from mgwfbp_tpu.models.mellum import (
     MOE_DROPPED_KEY,
@@ -116,7 +116,7 @@ from mgwfbp_tpu.models.mellum import (
     routing_counters,
     token_losses,
 )
-from mgwfbp_tpu.ops import blockattn, deltarule
+from mgwfbp_tpu.ops import blockattn, deltarule, shortconv
 from mgwfbp_tpu.ops.groupmm import counted
 
 GDN, FULL = "linear_attention", "full_attention"
@@ -219,8 +219,7 @@ def delta_mixer(p: dict, u: jax.Array, s: Qwen3NextShape, delta_block: int):
         qkv = jnp.concatenate([
             q.reshape(b, t, s.key_dim), k.reshape(b, t, s.key_dim),
             v.reshape(b, t, s.value_dim)], axis=-1)
-        qkv = causal_conv(qkv, p["conv_w"], jnp.zeros((), jnp.float32))
-        qkv = jax.nn.silu(qkv.astype(jnp.float32)).astype(u.dtype)
+        qkv = shortconv.causal_conv_silu(qkv, p["conv_w"])
     with jax.named_scope("gdn_delta"):
         q = l2_norm(qkv[..., :s.key_dim].reshape(b, t, hk, dk),
                     s.l2_norm_eps, dk ** -0.5)
@@ -414,9 +413,10 @@ class Qwen3NextLM(nn.Module):
 
         # equal halves share ONE cached trace under `jax.checkpoint`: what a
         # trace counted (grouped products and permutations, delta rules,
-        # attention cores) is counted again where it is replayed
+        # convolutions, attention cores) is counted again where it is replayed
         mixer = jax.checkpoint(mixer_half, static_argnums=(2, 3, 4, 5))
-        for counter in (deltarule.LOWERED, blockattn.LOWERED):
+        for counter in (
+                deltarule.LOWERED, shortconv.LOWERED, blockattn.LOWERED):
             mixer = counted(mixer, counter)
         sparse = counted(jax.checkpoint(sparse_half, static_argnums=(2, 3)))
         h = embed[x]

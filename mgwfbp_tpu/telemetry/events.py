@@ -110,6 +110,10 @@ EVENT_TYPES: dict[str, tuple[str, ...]] = {
     # DeltaNet layer held; an inverse, a forward and a backward program for
     # each shape); all 0 for a model without a delta rule
     "delta_program": ("step", "kernel", "plain", "programs"),
+    # the same for the short causal convolutions with their SiLU
+    # (ops/shortconv.py; 1 a Mamba or Gated DeltaNet layer held; a forward
+    # and a backward program for each shape); all 0 for a model without one
+    "conv_program": ("step", "kernel", "plain", "programs"),
     # what set-up was made of, once per process start, when the host has
     # read the first step's results, and once more after a rebuild that
     # recompiles the step (telemetry/phases.py): `spans` as

@@ -37,7 +37,7 @@ from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from mgwfbp_tpu.models import ModelMeta
-from mgwfbp_tpu.ops import deltarule, groupmm, selscan
+from mgwfbp_tpu.ops import deltarule, groupmm, selscan, shortconv
 from mgwfbp_tpu.ops.blockattn import LOWERED as ATTENTION_LOWERED
 from mgwfbp_tpu.parallel.allreduce import MergedAllreduce
 from mgwfbp_tpu.parallel.mesh import DATA_AXIS
@@ -723,13 +723,15 @@ def make_train_step(
     # products through the tiled kernel and through `lax.ragged_dot`, with
     # the distinct kernel programs among them (ops/groupmm.py), and the same
     # of its selective scans (ops/selscan.py: the kernels or the chunked
-    # form) and of its gated delta rules (ops/deltarule.py); Trainer records
-    # them as `attention_program`, `experts_program`, `scan_program` and
-    # `delta_program`
+    # form), of its gated delta rules (ops/deltarule.py) and of its short
+    # convolutions (ops/shortconv.py); Trainer records them as
+    # `attention_program`, `experts_program`, `scan_program`, `delta_program`
+    # and `conv_program`
     attention_calls: dict[str, int] = {}
     experts_calls: dict[str, int] = {}
     scan_calls: dict[str, int] = {}
     delta_calls: dict[str, int] = {}
+    conv_calls: dict[str, int] = {}
 
     def counting_programs(fn):
         def traced(*args):
@@ -737,6 +739,7 @@ def make_train_step(
             experts = groupmm.LOWERED.copy()
             scans = selscan.LOWERED.copy()
             deltas = deltarule.LOWERED.copy()
+            convs = shortconv.LOWERED.copy()
             out = fn(*args)
             attention_calls.update(
                 (way, n - attention[way])
@@ -745,6 +748,7 @@ def make_train_step(
             experts_calls.update(groupmm.lowered_since(experts))
             scan_calls.update(selscan.lowered_since(scans))
             delta_calls.update(deltarule.lowered_since(deltas))
+            conv_calls.update(shortconv.lowered_since(convs))
             return out
 
         return traced
@@ -769,6 +773,7 @@ def make_train_step(
         step_lm.experts_calls = experts_calls
         step_lm.scan_calls = scan_calls
         step_lm.delta_calls = delta_calls
+        step_lm.conv_calls = conv_calls
         return step_lm
 
     def per_device_nocarry(state, batch):
@@ -794,6 +799,7 @@ def make_train_step(
     step.experts_calls = experts_calls
     step.scan_calls = scan_calls
     step.delta_calls = delta_calls
+    step.conv_calls = conv_calls
     return step
 
 

@@ -1407,8 +1407,9 @@ class Trainer:
         through `lax.ragged_dot`, and the distinct kernel programs among
         them (ops/groupmm.py), how many of its selective scans through the
         kernels with the state in VMEM and how many through the chunked
-        form (ops/selscan.py), and the same of its gated delta rules
-        (ops/deltarule.py); all choose by platform and shape. Nothing is
+        form (ops/selscan.py), the same of its gated delta rules
+        (ops/deltarule.py) and of its short convolutions with their SiLU
+        (ops/shortconv.py); all choose by platform and shape. Nothing is
         compiled or read from the device."""
         self._traced_programs_noted = True
         calls = getattr(self.train_step, "attention_calls", None)
@@ -1455,6 +1456,17 @@ class Trainer:
         )
         self._emit_event(
             "delta_program", step=int(self.iteration),
+            **{name: int(n) for name, n in calls.items()},
+        )
+        calls = self.train_step.conv_calls
+        self.log.info(
+            "convolution: %d short convolution(s) of the step through the "
+            "kernels of one pass (%d distinct kernel program(s)), %d through "
+            "the plain form",
+            calls["kernel"], calls["programs"], calls["plain"],
+        )
+        self._emit_event(
+            "conv_program", step=int(self.iteration),
             **{name: int(n) for name, n in calls.items()},
         )
 

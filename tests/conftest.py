@@ -38,3 +38,37 @@ def mesh8():
     from mgwfbp_tpu.parallel.mesh import MeshSpec, make_mesh
 
     return make_mesh(MeshSpec(data=8, seq=1))
+
+
+@pytest.fixture(scope="module")
+def topo():
+    """A TPU v5e:2x2 that is described, not attached: what the files that ask
+    the chip's compiler without a chip compile for
+    (tests/test_tpu_compile.py, tests/test_tpu_compile_shortconv.py). Made
+    inside the fixture, so that only a worker that runs such a test loads
+    the TPU's library; skipped where it cannot be described."""
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs to /tmp
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — no libtpu / unknown topology
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e}")
+
+
+@pytest.fixture
+def _compile_cache_off():
+    """The persistent compile cache off round a test that compiles for the
+    described chip (`pytestmark = pytest.mark.usefixtures(...)` in those
+    files): an entry written for a described chip cannot be read back
+    without one, and the next compile would only warn."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()

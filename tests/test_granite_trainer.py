@@ -129,10 +129,18 @@ def test_trains_with_counters_and_the_tied_leaf_reduces_like_pmean(
     health = {h["step"]: h for h in events_of(records, "health")}
     assert [s["step"] for s in steps] == list(range(1, 13))
     assert set(health) == set(range(1, 13))
+    # the first and the twelfth loss are the parent commit's to the last
+    # digit, under either policy (read there on the same seeds, PR 42: the
+    # convolution's plain form moved to ops/shortconv.py, it did not change)
+    assert (health[1]["loss"], health[12]["loss"]) == (5.5441083908081055, 5.335992813110352)
     assert health[12]["loss"] < health[1]["loss"] - 0.05
     assert all(np.isfinite(h["grad_norm"]) and h["grad_norm"] > 0
                for h in health.values())
     assert events_of(records, "bad_step") == []
+    # the three Mamba-2 layers' convolutions (ops/shortconv.py), counted
+    # while the step was traced and through cached traces: the plain form
+    (convs,) = events_of(records, "conv_program")
+    assert (convs["kernel"], convs["plain"], convs["programs"]) == (0, 3, 0)
     with_counters = [s for s in steps if "ssm_state_rms" in s]
     assert len(with_counters) >= 10
     for s in with_counters:

@@ -205,6 +205,10 @@ def test_trains_with_counters_and_every_leaf_reduces_like_pmean(
     health = {h["step"]: h for h in events_of(records, "health")}
     assert [s["step"] for s in steps] == list(range(1, 13))
     assert set(health) == set(range(1, 13))
+    # the first and the twelfth loss are the parent commit's to the last
+    # digit, under either policy (read there on the same seeds, PR 42: the
+    # convolution's plain form moved to ops/shortconv.py, it did not change)
+    assert (health[1]["loss"], health[12]["loss"]) == (5.552222728729248, 4.329502582550049)
     assert health[12]["loss"] < health[1]["loss"] - 0.05
     assert all(np.isfinite(h["grad_norm"]) and h["grad_norm"] > 0
                for h in health.values())
@@ -233,11 +237,18 @@ def test_trains_with_counters_and_every_leaf_reduces_like_pmean(
         assert ("scan: 0 selective scan(s) of the step through the kernels "
                 "with the state in VMEM (0 distinct kernel program(s)), 2 "
                 "through the chunked form") in f.read()
+    # and their two convolutions (ops/shortconv.py), likewise
+    (convs,) = events_of(records, "conv_program")
+    assert (convs["step"], convs["kernel"], convs["plain"],
+            convs["programs"]) == (1, 0, 2, 0)
     import telemetry_report
 
+    report = telemetry_report.format_report(records)
     assert ("; 0 selective scan(s) through the kernels with the state in "
             "VMEM (0 distinct kernel program(s)), 2 through the chunked form"
-            ) in telemetry_report.format_report(records)
+            ) in report
+    assert ("short convolution: 0 through the kernels of one pass (0 "
+            "distinct kernel program(s)), 2 through the plain form") in report
 
 
 def test_exact_step_resume_is_bitwise(tmp_path, monkeypatch):

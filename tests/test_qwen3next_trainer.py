@@ -165,6 +165,10 @@ def test_trains_with_counters_and_every_leaf_reduces_like_pmean(
     health = {h["step"]: h for h in events_of(records, "health")}
     assert [s["step"] for s in steps] == list(range(1, 13))
     assert set(health) == set(range(1, 13))
+    # the first and the twelfth loss are the parent commit's to the last
+    # digit, under either policy (read there on the same seeds, PR 42: the
+    # convolution's plain form moved to ops/shortconv.py, it did not change)
+    assert (health[1]["loss"], health[12]["loss"]) == (5.552236080169678, 3.8970396518707275)
     assert health[12]["loss"] < health[1]["loss"] - 0.05
     assert all(np.isfinite(h["grad_norm"]) and h["grad_norm"] > 0
                for h in health.values())
@@ -192,11 +196,18 @@ def test_trains_with_counters_and_every_leaf_reduces_like_pmean(
             program["programs"]) == (1, 0, 3, 0)
     (scans,) = events_of(records, "scan_program")
     assert (scans["kernel"], scans["plain"]) == (0, 0)
+    # the three Gated DeltaNet layers' convolutions (ops/shortconv.py)
+    (convs,) = events_of(records, "conv_program")
+    assert (convs["kernel"], convs["plain"], convs["programs"]) == (0, 3, 0)
     with open(os.path.join(
             str(tmp_path / policy), cfg.tag(), "train.log")) as f:
         assert ("delta rule: 0 gated delta rule(s) of the step through a "
                 "kernel with the state in VMEM (0 distinct kernel "
-                "program(s)), 3 through the plain chunked form") in f.read()
+                "program(s)), 3 through the plain chunked form") in (
+                    log := f.read())
+    assert ("convolution: 0 short convolution(s) of the step through the "
+            "kernels of one pass (0 distinct kernel program(s)), 3 through "
+            "the plain form") in log
     import telemetry_report
 
     report = telemetry_report.format_report(records)

@@ -324,6 +324,29 @@ def test_report_says_the_linear_attention_counters_and_the_delta_program():
         [header, program, step(1, sel_scan_state_rms=0.1)])
 
 
+def test_report_says_the_way_the_short_convolutions_went():
+    """The newest `conv_program` record (ops/shortconv.py) under the phase
+    table, whatever the model family; no line where it counts none (the
+    image models, Mellum 2, Laguna-XS.2) or where there is no record."""
+    import telemetry_report
+
+    header = {"event": "header", "schema_version": 2, "wall": 0.0}
+    step = {"event": "step", "step": 1, "epoch": 0, "start_s": 1.0,
+            "dur_s": 0.1, "phases": {"guard": [1.2, 0.1]},
+            "ssm_state_rms": 0.1}
+    program = {"event": "conv_program", "step": 1, "kernel": 9, "plain": 0,
+               "programs": 2}
+    report = telemetry_report.format_report(
+        [header, {**program, "kernel": 0, "plain": 9, "programs": 0},
+         program, step])
+    assert ("short convolution: 9 through the kernels of one pass (2 "
+            "distinct kernel program(s)), 0 through the plain form") in report
+    assert "short convolution" not in telemetry_report.format_report(
+        [header, {**program, "kernel": 0, "programs": 0}, step])
+    assert "short convolution" not in telemetry_report.format_report(
+        [header, step])
+
+
 def test_report_selftest_runs():
     import telemetry_report
 

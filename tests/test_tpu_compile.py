@@ -14,7 +14,6 @@ cannot be read back without one, and the next compile would only warn.
 """
 
 import math
-import os
 import re
 
 import jax
@@ -39,29 +38,9 @@ from mgwfbp_tpu.train import create_train_state, make_train_step
 HBM_BYTES = 16 * 1024**3  # one v5e chip
 
 
-@pytest.fixture(scope="module")
-def topo():
-    from jax.experimental import topologies
-
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs to /tmp
-    try:
-        return topologies.get_topology_desc(
-            platform="tpu", topology_name="v5e:2x2"
-        )
-    except Exception as e:  # noqa: BLE001 — no libtpu / unknown topology
-        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e}")
-
-
-@pytest.fixture(autouse=True)
-def _compile_cache_off():
-    from jax.experimental.compilation_cache import compilation_cache as cc
-
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    cc.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", was)
-    cc.reset_cache()
+# `topo` (the described v5e:2x2) and `_compile_cache_off` are tests/conftest.py's,
+# shared with tests/test_tpu_compile_shortconv.py
+pytestmark = pytest.mark.usefixtures("_compile_cache_off")
 
 
 @pytest.mark.parametrize(

@@ -62,7 +62,8 @@ def _window_mean(spans: list[dict], sl: slice) -> float:
 
 
 def _phase_section(steps: list[dict], scans: list[dict] = (),
-                   deltas: list[dict] = ()) -> list[str]:
+                   deltas: list[dict] = (),
+                   convs: list[dict] = ()) -> list[str]:
     """One table of the host loop's phases (telemetry/phases.py): median
     milliseconds over the steps that have the phase, and the share of the
     loop's time (first span's start to last span's end) all its entries
@@ -78,7 +79,9 @@ def _phase_section(steps: list[dict], scans: list[dict] = (),
     which the newest built step program's is said), and a hybrid
     linear-attention decoder's (`delta_state_rms`, `delta_beta_mean`,
     `shared_gate_mean`) with the way its delta rules did (`deltas`: the
-    `delta_program` records)."""
+    `delta_program` records), and the way the short convolutions of any of
+    them did (`convs`: the `conv_program` records; no line where the newest
+    counts none)."""
     from mgwfbp_tpu.telemetry.phases import PHASES
 
     spans: dict[str, list[tuple[float, float]]] = {}
@@ -174,6 +177,12 @@ def _phase_section(steps: list[dict], scans: list[dict] = (),
         lines.append(
             f"  {title} ({max(map(len, values.values()))} steps): "
             + "; ".join(says))
+    for prog in convs[-1:]:
+        if prog.get("kernel") or prog.get("plain"):
+            lines.append(
+                f"  short convolution: {prog.get('kernel')} through the "
+                f"kernels of one pass ({prog.get('programs')} distinct kernel "
+                f"program(s)), {prog.get('plain')} through the plain form")
     return lines
 
 
@@ -277,7 +286,8 @@ def format_report(records: list[dict]) -> str:
             )
         lines.extend(_phase_section(
             steps, events_of(records, "scan_program"),
-            events_of(records, "delta_program")))
+            events_of(records, "delta_program"),
+            events_of(records, "conv_program")))
     else:
         lines.append("steps: none recorded")
 
