@@ -310,16 +310,17 @@ def train_phase(dnn: str = "resnet50", epochs: int = 4) -> None:
 
 
 def kernel_phase(
-    b: int = 4, t: int = 2048, h: int = 8, d: int = 64,
-    interpret: bool = False, iters: int = 20,
+    b: int = 4, t: int = 2048, h: int = 8, d: int = 64, iters: int = 20,
 ) -> None:
-    """The compiled Pallas flash forward against dense attention, on the
-    same device: agreement first, both times as information."""
+    """The language cells' attention core (`ops.blockattn`: on the chip its
+    fused kernel, elsewhere its plain blocks) against dense attention, on
+    the same device: agreement first, both times as information."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from mgwfbp_tpu.ops import flash_attention
+    from mgwfbp_tpu.ops import programs
+    from mgwfbp_tpu.ops.blockattn import blockwise_attention
     from mgwfbp_tpu.parallel.ringattn import local_attention
     from mgwfbp_tpu.profiling import measure_step_time
 
@@ -327,19 +328,22 @@ def kernel_phase(
         jax.random.normal(key, (b, t, h, d), jnp.float32).astype(jnp.bfloat16)
         for key in jax.random.split(jax.random.PRNGKey(SEED), 3)
     )
-    flash = jax.jit(lambda q, k, v: flash_attention(
-        q, k, v, causal=True, interpret=interpret))
+    core = jax.jit(lambda q, k, v: blockwise_attention(q, k, v))
     dense = jax.jit(lambda q, k, v: local_attention(q, k, v, causal=True))
-    got = np.asarray(flash(q, k, v), np.float32)
+    before = programs.LOWERED.copy()
+    got = np.asarray(core(q, k, v), np.float32)
+    went = programs.lowered_since(before)["attention"]
     want = np.asarray(dense(q, k, v), np.float32)
-    t_flash = measure_step_time(flash, q, k, v, warmup=3, iters=iters)
+    t_core = measure_step_time(core, q, k, v, warmup=3, iters=iters)
     t_dense = measure_step_time(dense, q, k, v, warmup=3, iters=iters)
-    say("kernel", f"B{b} T{t} H{h} D{d} bf16 causal forward, interpret="
-        f"{interpret}: flash {t_flash * 1e3:.3f} ms, dense "
-        f"{t_dense * 1e3:.3f} ms per call (host clock, {iters} calls, one "
-        "sync; informational)")
-    say("kernel", f"max abs error flash vs dense "
+    say("kernel", f"B{b} T{t} H{h} D{d} bf16 causal forward through "
+        f"{'the fused kernel' if went['kernel'] else 'the plain blocks'}: "
+        f"core {t_core * 1e3:.3f} ms, dense {t_dense * 1e3:.3f} ms per call "
+        f"(host clock, {iters} calls, one sync; informational)")
+    say("kernel", f"max abs error core vs dense "
         f"{float(np.abs(got - want).max()):.3e} (bound: rtol 2e-2 atol 2e-2)")
+    require(went["kernel"] == int(programs.traced_for_tpu()),
+            f"on a TPU the core is the fused kernel ({went})")
     require(got.shape == (b, t, h, d), f"output shape {got.shape}")
     require(bool(np.isfinite(got).all()), "finite kernel output")
     np.testing.assert_allclose(got, want, rtol=2e-2, atol=2e-2)

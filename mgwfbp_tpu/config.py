@@ -161,6 +161,13 @@ _DATASET_SGD: dict[str, dict] = {
     "imagenet": dict(momentum=0.875, weight_decay=2 * 3.0517578125e-05),
     "ptb": dict(momentum=0.0, weight_decay=0.0),
 }
+# Mellum 2's recipe, which every decoder LM takes (nothing of it is
+# published): AdamW as sparse language models are usually trained, 2 x 8,192
+# tokens a device and step, cosine after the program's warm-up
+_LM = dict(dataset="tokens", batch_size=2, num_steps=8192, lr=3e-4,
+           max_epochs=40, lr_schedule="cosine", optimizer="adamw",
+           adam_b2=0.95, weight_decay=0.1, norm_clip=1.0)
+_LM_TINY = dict(_LM, num_steps=64, lr=3e-3, vocab_size=256)
 PRESETS: dict[str, dict] = {
     "mnistnet": dict(dataset="mnist", batch_size=64, lr=0.01, max_epochs=10),
     "lenet": dict(dataset="mnist", batch_size=64, lr=0.01, max_epochs=10),
@@ -189,53 +196,23 @@ PRESETS: dict[str, dict] = {
     # out, dl_trainer.py:219-222: wd stays 1e-4, momentum 0.9)
     "lstman4": dict(dataset="an4", batch_size=4, lr=2e-4, max_epochs=100,
                     lr_schedule="anneal", norm_clip=400.0),
-    # sparse decoder LM (models/mellum.py). Nothing of the recipe is
-    # published: AdamW as sparse language models are usually trained, 2 x
-    # 8,192 tokens a device and step, cosine after the program's warm-up
-    "mellum2": dict(dataset="tokens", batch_size=2, num_steps=8192, lr=3e-4,
-                    max_epochs=40, lr_schedule="cosine", optimizer="adamw",
-                    adam_b2=0.95, weight_decay=0.1, norm_clip=1.0),
-    "mellum2_tiny": dict(dataset="tokens", batch_size=2, num_steps=64,
-                         lr=3e-3, max_epochs=40, lr_schedule="cosine",
-                         optimizer="adamw", adam_b2=0.95, weight_decay=0.1,
-                         norm_clip=1.0, vocab_size=256),
-    # hybrid state-space decoder LM (models/granite.py): mellum2's recipe
-    # (none of it published) at one sequence of 8,192 tokens a device
-    "granite4h": dict(dataset="tokens", batch_size=1, num_steps=8192, lr=3e-4,
-                      max_epochs=40, lr_schedule="cosine", optimizer="adamw",
-                      adam_b2=0.95, weight_decay=0.1, norm_clip=1.0),
-    "granite4h_tiny": dict(dataset="tokens", batch_size=2, num_steps=64,
-                           lr=3e-3, max_epochs=40, lr_schedule="cosine",
-                           optimizer="adamw", adam_b2=0.95, weight_decay=0.1,
-                           norm_clip=1.0, vocab_size=256),
-    # decoder-hybrid-decoder LM (models/phi4flash.py): granite4h's recipe
-    # (none of it published) at one sequence of 8,192 tokens a device
-    "phi4flash": dict(dataset="tokens", batch_size=1, num_steps=8192, lr=3e-4,
-                      max_epochs=40, lr_schedule="cosine", optimizer="adamw",
-                      adam_b2=0.95, weight_decay=0.1, norm_clip=1.0),
-    "phi4flash_tiny": dict(dataset="tokens", batch_size=2, num_steps=64,
-                           lr=3e-3, max_epochs=40, lr_schedule="cosine",
-                           optimizer="adamw", adam_b2=0.95, weight_decay=0.1,
-                           norm_clip=1.0, vocab_size=256),
-    # sparse decoder LM with a dense first layer, a shared expert and a head
-    # count by layer (models/laguna.py): mellum2's recipe (none of it
-    # published) at one sequence of 8,192 tokens a device
-    "laguna_xs2": dict(dataset="tokens", batch_size=1, num_steps=8192, lr=3e-4,
-                       max_epochs=40, lr_schedule="cosine", optimizer="adamw",
-                       adam_b2=0.95, weight_decay=0.1, norm_clip=1.0),
-    "laguna_xs2_tiny": dict(dataset="tokens", batch_size=2, num_steps=64,
-                            lr=3e-3, max_epochs=40, lr_schedule="cosine",
-                            optimizer="adamw", adam_b2=0.95, weight_decay=0.1,
-                            norm_clip=1.0, vocab_size=256),
-    # hybrid linear-attention sparse decoder LM (models/qwen3next.py):
-    # mellum2's recipe (none of it published) at 2 x 8,192 tokens a device
-    "qwen3next": dict(dataset="tokens", batch_size=2, num_steps=8192, lr=3e-4,
-                      max_epochs=40, lr_schedule="cosine", optimizer="adamw",
-                      adam_b2=0.95, weight_decay=0.1, norm_clip=1.0),
-    "qwen3next_tiny": dict(dataset="tokens", batch_size=2, num_steps=64,
-                           lr=3e-3, max_epochs=40, lr_schedule="cosine",
-                           optimizer="adamw", adam_b2=0.95, weight_decay=0.1,
-                           norm_clip=1.0, vocab_size=256),
+    # the decoder LMs share ONE recipe (`_LM`, `_LM_TINY`), none of it
+    # published; a family says what differs: sparse (models/mellum.py), hybrid
+    # state-space (models/granite.py), decoder-hybrid-decoder
+    # (models/phi4flash.py), sparse with a dense first layer, a shared expert
+    # and a head count by layer (models/laguna.py), and hybrid
+    # linear-attention sparse (models/qwen3next.py). `batch_size`: two
+    # sequences of 8,192 tokens a device and step, or one
+    "mellum2": dict(_LM),
+    "mellum2_tiny": dict(_LM_TINY),
+    "granite4h": dict(_LM, batch_size=1),
+    "granite4h_tiny": dict(_LM_TINY),
+    "phi4flash": dict(_LM, batch_size=1),
+    "phi4flash_tiny": dict(_LM_TINY),
+    "laguna_xs2": dict(_LM, batch_size=1),
+    "laguna_xs2_tiny": dict(_LM_TINY),
+    "qwen3next": dict(_LM),
+    "qwen3next_tiny": dict(_LM_TINY),
     "fcn5net": dict(dataset="mnist", batch_size=64, lr=0.05, max_epochs=10),
     "lr": dict(dataset="mnist", batch_size=64, lr=0.01, max_epochs=10),
 }

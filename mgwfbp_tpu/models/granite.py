@@ -59,7 +59,6 @@ at its start.
 from __future__ import annotations
 
 import dataclasses
-import math
 from typing import Optional
 
 import flax.linen as nn
@@ -67,10 +66,17 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from mgwfbp_tpu.models.mellum import _Leaves, rms_norm, token_losses
+from mgwfbp_tpu.models.lm_parts import (
+    _Leaves,
+    _conv_init,
+    _dt_bias_init,
+    gated_mlp,
+    rms_norm,
+    token_losses,
+)
 from mgwfbp_tpu.ops import shortconv
 from mgwfbp_tpu.ops.blockattn import blockwise_attention
-from mgwfbp_tpu.ops.groupmm import counted
+from mgwfbp_tpu.ops.programs import counted
 from mgwfbp_tpu.ops.ssd import ssd_scan
 
 MAMBA, ATTENTION = "mamba", "attention"
@@ -126,18 +132,6 @@ def _a_log_init(key, shape, dtype=jnp.float32):
     return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
 
 
-def _dt_bias_init(key, shape, dtype=jnp.float32):
-    """Inverse softplus of a log-uniform time step in [1e-3, 1e-1]."""
-    dt = jnp.exp(jax.random.uniform(
-        key, shape, dtype, math.log(1e-3), math.log(1e-1)))
-    return dt + jnp.log(-jnp.expm1(-dt))
-
-
-def _conv_init(key, shape, dtype=jnp.float32):
-    bound = 1.0 / math.sqrt(GRANITE4H.mamba_conv)
-    return jax.random.uniform(key, shape, dtype, -bound, bound)
-
-
 def mamba_mixer(p: dict, u: jax.Array, shape: GraniteShape, scan_block: int):
     """The Mamba-2 mixer on the normed input u (B, T, hidden): (y (B, T,
     hidden), root mean square of the final state, most negative chunk sum of
@@ -184,15 +178,6 @@ def attention(p: dict, u: jax.Array, shape: GraniteShape, block: int):
             q, k, v, block=block, scale=shape.attention_multiplier)
     with jax.named_scope("attn_proj"):
         return a.reshape(b, t, shape.num_heads * hd) @ p["wo"]
-
-
-def gated_mlp(p: dict, v: jax.Array, shape: GraniteShape) -> jax.Array:
-    with jax.named_scope("mlp"):
-        pq = v @ p["w1"]
-        f = shape.intermediate_size
-        mid = jax.nn.silu(pq[..., :f].astype(jnp.float32)) \
-            * pq[..., f:].astype(jnp.float32)
-        return mid.astype(v.dtype) @ p["w2"]
 
 
 def layer(p: dict, x: jax.Array, kind: str, shape: GraniteShape,
@@ -276,10 +261,10 @@ class Granite4HLM(nn.Module):
         h = jnp.asarray(s.embedding_multiplier, embed.dtype) * embed[x]
         state_rms, low = [], []
         for p, kind in zip(layers, kinds):
-            # the layer's convolution is counted where its trace is a
-            # cached one too
+            # what the layer traces is counted where its trace is a cached
+            # one too
             h, layer_rms, layer_low = counted(jax.checkpoint(
-                layer, static_argnums=(2, 3, 4, 5)), shortconv.LOWERED)(
+                layer, static_argnums=(2, 3, 4, 5)))(
                     p, h, kind, s, self.attn_block, self.scan_block)
             if kind == MAMBA:
                 state_rms.append(layer_rms)

@@ -81,22 +81,23 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from mgwfbp_tpu.models.mellum import (
+from mgwfbp_tpu.models.lm_parts import (
     FULL,
     MOE_DROPPED_KEY,
     MOE_TOKENS_KEY,
     SLIDING,
     _Leaves,
-    apply_rope,
     held_experts,
+    partial_rope,
     plain_inv_freq,
     rms_norm,
     routing_counters,
+    swiglu,
     token_losses,
     yarn_inv_freq,
 )
 from mgwfbp_tpu.ops.blockattn import blockwise_attention
-from mgwfbp_tpu.ops.groupmm import counted
+from mgwfbp_tpu.ops.programs import counted
 
 DENSE, SPARSE = "dense", "sparse"
 # the step's metrics carry these under HEALTH_PREFIX of train/step.py
@@ -163,20 +164,6 @@ def rope_inv_freq(shape: LagunaShape, kind: str) -> tuple[jax.Array, float]:
     ), shape.yarn_attention_factor
 
 
-def partial_rope(x: jax.Array, inv_freq: jax.Array, factor: float,
-                 scale: float = 1.0) -> jax.Array:
-    """x (B, T, H, D): its first 2 x len(inv_freq) dimensions rotated by
-    position (half-split inside them, cos and sin times `factor`), the rest
-    passed through; all of it times `scale`, in float32, rounded once."""
-    rotary = 2 * inv_freq.shape[0]
-    if rotary == x.shape[-1]:
-        return apply_rope(x, inv_freq, factor * scale)
-    passed = (x[..., rotary:].astype(jnp.float32) * scale).astype(x.dtype)
-    return jnp.concatenate(
-        [apply_rope(x[..., :rotary], inv_freq, factor * scale), passed],
-        axis=-1)
-
-
 def attention_gate(u: jax.Array, w_gate: jax.Array) -> jax.Array:
     """What `gating: true` is taken to be: g = sigmoid(u W_g), one scalar a
     head and token, float32. u (B, T, hidden), w_gate (hidden, heads)."""
@@ -225,12 +212,6 @@ def route(u: jax.Array, router: jax.Array, top_k: int, scaling: float):
     top, idx = lax.top_k(scores, top_k)
     total = jnp.sum(top, axis=-1, keepdims=True)
     return idx, top / total * scaling, total[:, 0]
-
-
-def swiglu(v: jax.Array, w_gate, w_up, w_down) -> jax.Array:
-    mid = jax.nn.silu((v @ w_gate).astype(jnp.float32)) \
-        * (v @ w_up).astype(jnp.float32)
-    return mid.astype(v.dtype) @ w_down
 
 
 def sparse_block(p: dict, x: jax.Array, shape: LagunaShape, first: int):

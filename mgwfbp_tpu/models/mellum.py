@@ -48,23 +48,31 @@ weights normal(0, 0.02), norms at one.
 from __future__ import annotations
 
 import dataclasses
-import math
 from typing import Optional
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-from jax import lax
+# `lax`: tests/benchmark/test_mellum2_reference.py poisons `mellum.lax.ragged_dot`
+from jax import lax  # noqa: F401
 
+from mgwfbp_tpu.models.lm_parts import (
+    FULL,
+    MOE_DROPPED_KEY,
+    MOE_TOKENS_KEY,
+    SLIDING,
+    _Leaves,
+    apply_rope,
+    held_experts,
+    plain_inv_freq,
+    rms_norm,
+    route,
+    routing_counters,
+    token_losses,
+    yarn_inv_freq,
+)
 from mgwfbp_tpu.ops.blockattn import blockwise_attention
-from mgwfbp_tpu.ops.groupmm import counted, grouped_product
-from mgwfbp_tpu.ops.rowperm import combine_rows, take_rows
-
-SLIDING, FULL = "sliding_attention", "full_attention"
-# the step's metrics carry the routing counts under these keys (HEALTH_PREFIX
-# of train/step.py, so they leave the chip by the health statistics' road)
-MOE_TOKENS_KEY = "health/moe_tokens"
-MOE_DROPPED_KEY = "health/moe_dropped"
+from mgwfbp_tpu.ops.programs import counted
 
 
 @dataclasses.dataclass(frozen=True)
@@ -100,34 +108,6 @@ MELLUM2_TINY = MellumShape(
 )
 
 
-def plain_inv_freq(dim: int, theta: float) -> jax.Array:
-    """theta ** (-2i / dim) for the dim / 2 pairs of a rotation over `dim`
-    dimensions of a head."""
-    return theta ** (-jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
-
-
-def yarn_inv_freq(dim: int, theta: float, factor: float, original_len: int,
-                  beta_fast: float, beta_slow: float) -> jax.Array:
-    """YaRN as `transformers._compute_yarn_parameters`, over the `dim`
-    dimensions of a head that rotate: interpolate (divide by the factor) the
-    low frequencies, keep the high ones, blend linearly between the two
-    correction dimensions."""
-    base = plain_inv_freq(dim, theta)
-
-    def correction_dim(rotations: float) -> float:
-        return dim * math.log(
-            original_len / (rotations * 2 * math.pi)
-        ) / (2 * math.log(theta))
-
-    low = max(math.floor(correction_dim(beta_fast)), 0)
-    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
-    if low == high:
-        high += 0.001
-    ramp = jnp.clip(
-        (jnp.arange(dim // 2, dtype=jnp.float32) - low) / (high - low), 0, 1)
-    return (1 - ramp) * base + ramp * base / factor
-
-
 def rope_inv_freq(shape: MellumShape, kind: str) -> tuple[jax.Array, float]:
     """(inverse frequencies (head_dim / 2,), factor on cos and sin) of a layer
     of `kind`: plain on window layers, YaRN on full ones."""
@@ -137,25 +117,6 @@ def rope_inv_freq(shape: MellumShape, kind: str) -> tuple[jax.Array, float]:
         shape.head_dim, shape.rope_theta, shape.yarn_factor,
         shape.yarn_original_len, shape.yarn_beta_fast, shape.yarn_beta_slow,
     ), shape.yarn_attention_factor
-
-
-def apply_rope(x: jax.Array, inv_freq: jax.Array, factor: float) -> jax.Array:
-    """x (B, T, H, D) rotated by position in the half-split ("rotate_half")
-    layout, float32 inside, x's dtype out."""
-    t = x.shape[1]
-    angles = jnp.arange(t, dtype=jnp.float32)[:, None] * inv_freq[None, :]
-    angles = jnp.concatenate([angles, angles], axis=-1)[None, :, None, :]
-    cos, sin = jnp.cos(angles) * factor, jnp.sin(angles) * factor
-    x32 = x.astype(jnp.float32)
-    half = x.shape[-1] // 2
-    rotated = jnp.concatenate([-x32[..., half:], x32[..., :half]], axis=-1)
-    return (x32 * cos + rotated * sin).astype(x.dtype)
-
-
-def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
-    x32 = x.astype(jnp.float32)
-    y = x32 * lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + eps)
-    return (y * scale.astype(jnp.float32)).astype(x.dtype)
 
 
 def attention(p: dict, x: jax.Array, shape: MellumShape, kind: str,
@@ -189,49 +150,6 @@ def attention(p: dict, x: jax.Array, shape: MellumShape, kind: str,
         return a.reshape(b, t, shape.num_heads * hd) @ p["wo"]
 
 
-def route(u: jax.Array, router: jax.Array, top_k: int):
-    """Softmax router over ALL experts in float32 (operands as they are
-    stored, product at `highest`): (indices (N, k), weights (N, k) summing to
-    one over the k chosen)."""
-    logits = jnp.dot(
-        u.astype(jnp.float32), router.astype(jnp.float32),
-        precision=lax.Precision.HIGHEST,
-    )
-    probs = jax.nn.softmax(logits, axis=-1)
-    top, idx = lax.top_k(probs, top_k)
-    return idx, top / jnp.sum(top, axis=-1, keepdims=True)
-
-
-def held_experts(u, idx, weights, w_gate, w_up, w_down, first: int):
-    """The held experts' part of the sparse block for tokens u.
-
-    u (N, D); idx, weights (N, k) from `route`; w_gate, w_up (E, D, F) and
-    w_down (E, F, D) the E held experts, expert `first` of the model first.
-    Returns (y (N, D), tokens per held expert (E,), assignments to a held
-    expert that no group took (a count; 0 by construction))."""
-    count = w_gate.shape[0]
-    local = idx - first
-    held = (local >= 0) & (local < count)
-    # unheld assignments sort behind every held expert, into no group
-    keys = jnp.where(held, local, count).reshape(-1)
-    order = jnp.argsort(keys, stable=True)
-    inverse = jnp.argsort(order)
-    sizes = jnp.sum(
-        keys[:, None] == jnp.arange(count)[None, :], axis=0, dtype=jnp.int32)
-    # (N * k, D), grouped by expert; the rows past the last group belong to
-    # no expert: a grouped product leaves them UNWRITTEN on the chip (zero
-    # only on the CPU), forward and backward, and neither permutation moves
-    # or reads them (ops/rowperm.py): never trusted
-    rows = take_rows(u, order, inverse, sizes)
-    gate = grouped_product(rows, w_gate, sizes)
-    up = grouped_product(rows, w_up, sizes)
-    mid = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32))
-    out = grouped_product(mid.astype(u.dtype), w_down, sizes)
-    y = combine_rows(out, order, inverse, weights, sizes)
-    dropped = jnp.sum(held) - jnp.sum(sizes)
-    return y, sizes, dropped
-
-
 def sparse_block(p: dict, x: jax.Array, shape: MellumShape, first: int):
     """The sparse block on the normed input x (B, T, hidden): (y, tokens per
     held expert (E,) float32, dropped float32). The experts' part keeps
@@ -248,64 +166,6 @@ def sparse_block(p: dict, x: jax.Array, shape: MellumShape, first: int):
         y.reshape(b, t, d), sizes.astype(jnp.float32),
         dropped.astype(jnp.float32),
     )
-
-
-def token_losses(h: jax.Array, head: jax.Array, targets: jax.Array,
-                 block: int) -> jax.Array:
-    """-log softmax(h @ head)[target] per token, float32, `block` tokens at a
-    time: a block's (block, vocabulary) logits live only inside its own
-    forward and (recomputed) backward."""
-    n = h.shape[0]
-    block = block if n % block == 0 else n
-
-    def one(args):
-        hb, yb = args
-        with jax.named_scope("lm_head"):
-            logits = jnp.dot(hb, head, preferred_element_type=jnp.float32)
-        with jax.named_scope("loss"):
-            lse = jax.nn.logsumexp(logits, axis=-1)
-            picked = jnp.take_along_axis(logits, yb[:, None], axis=-1)[:, 0]
-            return lse - picked
-
-    return lax.map(jax.checkpoint(one), (
-        h.reshape(n // block, block, -1), targets.reshape(n // block, block),
-    )).reshape(n)
-
-
-def routing_counters(stats: dict, assignments: int) -> dict:
-    """The `step` record's routing counters from one step's statistics as
-    host arrays (MOE_TOKENS_KEY (sparse layers held, experts held),
-    MOE_DROPPED_KEY); `assignments` a layer's (token, expert) pairs on one
-    device. Shared by every model that routes through `held_experts`."""
-    held = stats[MOE_TOKENS_KEY]
-    worst = int(held.max(axis=1).argmax())  # the layer of the fullest
-    return {
-        "moe_here": float(held.sum(axis=1).mean() / assignments),
-        "moe_load_max": float(held[worst].max()),
-        "moe_load_mean": float(held[worst].mean()),
-        "moe_dropped": float(stats[MOE_DROPPED_KEY]),
-    }
-
-
-class _Leaves(nn.Module):
-    """Declares a group of parameters and hands them back as a dict."""
-
-    # ((name, shape, init), ...): True a norm's scale (ones), False a weight
-    # (normal 0.02), or an initializer of the leaf's own
-    shapes: tuple
-
-    @nn.compact
-    def __call__(self) -> dict:
-        return {
-            name: self.param(
-                name,
-                init if callable(init)
-                else nn.initializers.ones if init
-                else nn.initializers.normal(0.02),
-                shape,
-            )
-            for name, shape, init in self.shapes
-        }
 
 
 class Mellum2LM(nn.Module):

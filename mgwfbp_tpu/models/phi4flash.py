@@ -102,15 +102,17 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from mgwfbp_tpu.models.granite import (
+from mgwfbp_tpu.models.lm_parts import (
+    _Leaves,
     _conv_init,
     _dt_bias_init,
     gated_mlp,
+    rms_norm,
+    token_losses,
 )
-from mgwfbp_tpu.models.mellum import _Leaves, rms_norm, token_losses
 from mgwfbp_tpu.ops import selscan, shortconv
 from mgwfbp_tpu.ops.blockattn import blockwise_attention
-from mgwfbp_tpu.ops.groupmm import counted
+from mgwfbp_tpu.ops.programs import counted
 
 MAMBA, GMU = "mamba", "gmu"
 WINDOW, FULL, CROSS = "sliding_attention", "full_attention", "cross_attention"
@@ -418,11 +420,10 @@ class Phi4FlashLM(nn.Module):
         counters: dict = {}  # a layer's counter under its kind's key
         for p, index in zip(layers, held):
             kind = s.kind(index)
-            # the layer's scan and convolution are counted where its trace
-            # is a cached one too
-            h, published, counter = counted(counted(jax.checkpoint(
-                layer, static_argnums=(3, 4, 5, 6)), selscan.LOWERED),
-                shortconv.LOWERED)(
+            # what the layer traces is counted where its trace is a cached
+            # one too
+            h, published, counter = counted(jax.checkpoint(
+                layer, static_argnums=(3, 4, 5, 6)))(
                     p, h, reads.get(kind), index, s, self.attn_block,
                     self.scan_block)
             if published is not None:

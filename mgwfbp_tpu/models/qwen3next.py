@@ -103,21 +103,23 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from mgwfbp_tpu.models.granite import _conv_init, _dt_bias_init
-from mgwfbp_tpu.models.laguna import partial_rope, swiglu
-from mgwfbp_tpu.models.mellum import (
+from mgwfbp_tpu.models.lm_parts import (
     MOE_DROPPED_KEY,
     MOE_TOKENS_KEY,
     _Leaves,
+    _conv_init,
+    _dt_bias_init,
     held_experts,
+    partial_rope,
     plain_inv_freq,
     rms_norm,
     route,
     routing_counters,
+    swiglu,
     token_losses,
 )
 from mgwfbp_tpu.ops import blockattn, deltarule, shortconv
-from mgwfbp_tpu.ops.groupmm import counted
+from mgwfbp_tpu.ops.programs import counted
 
 GDN, FULL = "linear_attention", "full_attention"
 # the step's metrics carry these under HEALTH_PREFIX of train/step.py
@@ -414,10 +416,8 @@ class Qwen3NextLM(nn.Module):
         # equal halves share ONE cached trace under `jax.checkpoint`: what a
         # trace counted (grouped products and permutations, delta rules,
         # convolutions, attention cores) is counted again where it is replayed
-        mixer = jax.checkpoint(mixer_half, static_argnums=(2, 3, 4, 5))
-        for counter in (
-                deltarule.LOWERED, shortconv.LOWERED, blockattn.LOWERED):
-            mixer = counted(mixer, counter)
+        mixer = counted(
+            jax.checkpoint(mixer_half, static_argnums=(2, 3, 4, 5)))
         sparse = counted(jax.checkpoint(sparse_half, static_argnums=(2, 3)))
         h = embed[x]
         deltas, routing = [], []

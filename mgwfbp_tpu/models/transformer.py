@@ -31,7 +31,6 @@ class Block(nn.Module):
     d_ff: int
     dropout: float
     seq_axis: Optional[str]
-    attn_impl: str = "dense"
 
     @nn.compact
     def __call__(self, h: jax.Array, train: bool) -> jax.Array:
@@ -45,20 +44,6 @@ class Block(nn.Module):
         v = v.reshape(b, t, self.num_heads, dh)
         if self.seq_axis is not None:
             a = ring_attention(q, k, v, axis_name=self.seq_axis, causal=True)
-        elif self.attn_impl == "flash":
-            # Pallas kernel (ops/flashattn.py): scores never leave VMEM —
-            # for long contexts where the dense (T, T) matrix can't fit.
-            # FORWARD-ONLY on the chip (no custom_vjp; the compiled
-            # kernel cannot be differentiated), so this branch trains
-            # only on the CPU interpreter and dense stays the default.
-            # Shapes outside the kernel's block contract fall back to
-            # dense.
-            from mgwfbp_tpu.ops import flash_attention, flash_supported
-
-            if flash_supported(t, dh):
-                a = flash_attention(q, k, v, causal=True)
-            else:
-                a = local_attention(q, k, v, causal=True)
         else:
             a = local_attention(q, k, v, causal=True)
         a = nn.Dense(self.d_model, name="proj")(a.reshape(b, t, d))
@@ -84,8 +69,6 @@ class TransformerLM(nn.Module):
     max_len: int = 4096
     dropout: float = 0.1
     seq_axis: Optional[str] = None
-    # dense | flash (ops/flashattn.py Pallas kernel; forward-only on a TPU)
-    attn_impl: str = "dense"
 
     @nn.compact
     def __call__(self, x: jax.Array, train: bool = False) -> jax.Array:
@@ -101,7 +84,7 @@ class TransformerLM(nn.Module):
         for i in range(self.num_layers):
             h = Block(
                 self.d_model, self.num_heads, self.d_ff, self.dropout,
-                self.seq_axis, self.attn_impl, name=f"Block_{i}",
+                self.seq_axis, name=f"Block_{i}",
             )(h, train)
         h = nn.LayerNorm(name="ln_out")(h)
         return nn.Dense(self.vocab_size, name="head")(h)

@@ -1,13 +1,7 @@
-"""Custom TPU kernels (Pallas) for hot ops.
-
-The compute path of this framework is XLA-compiled JAX; Pallas kernels are
-reserved for ops where manual VMEM blocking beats XLA's fusions. The first
-resident: flash attention (ops/flashattn.py), used by the transformer's
-attention when enabled. Every kernel has a pure-jnp reference
-implementation and dispatch helpers that fall back when shapes don't
-qualify or the backend lacks Mosaic support.
+"""The language models' hot ops, each one entry point that chooses by
+platform and shape between a TPU kernel (Pallas) and a plain `jax.numpy` form
+that is also the kernel's reference: the attention core (blockattn.py), the
+experts' grouped product and row permutations (groupmm.py, rowperm.py), the
+scans (ssd.py, selscan.py), the gated delta rule (deltarule.py) and the short
+convolution (shortconv.py). programs.py counts which way each call went.
 """
-
-from mgwfbp_tpu.ops.flashattn import flash_attention, flash_supported
-
-__all__ = ["flash_attention", "flash_supported"]

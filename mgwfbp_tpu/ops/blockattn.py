@@ -37,14 +37,13 @@ causal pair is computed, and no (T, T) array exists in either pass. The blocks
 cast the exponentials to the values' dtype for the second product; the
 kernel's forward keeps them float32 there (its backward casts them).
 
-`LOWERED` counts, as programs are traced, how many calls went each way:
-`make_train_step` reads it round the trace of its step, for the Trainer's
-`attention_program` telemetry record. Written for one device's whole sequence.
+Each call notes the way it went (`ops/programs.py`, op `attention`), for
+the Trainer's `attention_program` telemetry record. Written for one device's
+whole sequence.
 """
 
 from __future__ import annotations
 
-import collections
 import functools
 import math
 from typing import Optional
@@ -53,11 +52,10 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from mgwfbp_tpu.ops import programs
+
 _NEG_INF = -1e30  # finite mask value, as parallel/ringattn.py
 _ALIGN = 128  # key ranges start on a lane-tile boundary
-
-# calls of `blockwise_attention` traced so far, by the way they went down
-LOWERED = collections.Counter(kernel=0, blocks=0)
 
 # The fused kernel's tiles as (tile, keys of a tile taken at a time, fused
 # backward), fitted on a v5e at T 8,192 (PERF.md section 6, PR 31). One tile
@@ -155,12 +153,6 @@ def _blocks(q, k, v, window: Optional[int], block: int, scale: float):
     return out.transpose(0, 3, 1, 2, 4).reshape(b, t, h, d)
 
 
-def traced_for_tpu() -> bool:
-    """Whether what is traced now will be lowered for a TPU: this package
-    builds its meshes from the default backend's devices."""
-    return jax.default_backend() == "tpu"
-
-
 def _splash():
     """The library, imported where a kernel is wanted: a second of imports
     that the CPU and the models without attention never pay."""
@@ -239,9 +231,9 @@ def blockwise_attention(
         raise ValueError(
             f"{h} query heads do not divide over {k.shape[2]} key heads")
     scale = 1.0 / math.sqrt(d) if scale is None else float(scale)
-    tiles = _kernel_tiles(t, d, window) if traced_for_tpu() else None
+    tiles = _kernel_tiles(t, d, window) if programs.traced_for_tpu() else None
     if tiles is None:
-        LOWERED["blocks"] += 1
+        programs.note("attention", "blocks")
         return _blocks(q, k, v, window, block, scale)
-    LOWERED["kernel"] += 1
+    programs.note("attention", "kernel")
     return _fused(q, k, v, window, scale, tiles)

@@ -62,9 +62,9 @@ eight pairs a step, q, k and v all bfloat16 or all float32 and T a whole
 number of blocks (`_kernel_rows`): three Pallas kernels of this module's own
 under a `custom_vjp`. Anything else (the CPU, every tier-1 test, the tiny
 preset, a T that is padded): the plain chunked form below, which is also the
-kernels' reference. `LOWERED` counts the calls each way and the distinct
-kernel programs (`lowered_since`: the Trainer's `delta_program`, beside
-`scan_program`).
+kernels' reference. Each call notes the way it went and the kernel programs
+it needs (`ops/programs.py`, op `delta`: the Trainer's `delta_program`,
+beside `scan_program`).
 
 **The kernels.** q, k, v come in and o goes out as (B, T, heads x 128): a
 head is one lane tile of columns that the index maps pick, and nothing is
@@ -118,7 +118,6 @@ V) array exists in either pass, down either way.
 
 from __future__ import annotations
 
-import collections
 import functools
 from typing import Optional
 
@@ -126,10 +125,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from mgwfbp_tpu.ops import blockattn
+from mgwfbp_tpu.ops import programs
 
-# calls of `gated_delta_rule` traced so far, by the way they went down
-LOWERED: collections.Counter = collections.Counter()
 _HI = lax.Precision.HIGHEST
 _BASE = 8  # rows of a diagonal block inverted by the finite product
 
@@ -936,12 +933,11 @@ def gated_delta_rule(
         raise ValueError(f"a chunk of {chunk} positions is no power of two")
     g, beta = g.astype(jnp.float32), beta.astype(jnp.float32)
     rows = None
-    if blockattn.traced_for_tpu():
+    if programs.traced_for_tpu():
         rows = _kernel_rows(
             t, hk, h, dk, dv, chunk, (q.dtype, k.dtype, v.dtype))
     if rows is not None:
-        LOWERED["kernel"] += 1
-        LOWERED.update(_programs(q, v, chunk, rows))
+        programs.note("delta", "kernel", _programs(q, v, chunk, rows))
         o, state = _kernel_rule(q, k, v, g, beta, chunk, rows, False)
         # the backward program is traced HERE, into jax's cache of traces,
         # and found there by the backward pass (ops/groupmm.py has the
@@ -951,7 +947,7 @@ def gated_delta_rule(
             q, k, v, g, beta, jax.ShapeDtypeStruct(
                 (bsz, t // rows, h, dk, dv), jnp.float32), o, state)
         return o, state
-    LOWERED["plain"] += 1
+    programs.note("delta", "plain")
     pad = -t % chunk
     if pad:
         q, k, v, g, beta = (
@@ -984,11 +980,3 @@ def _programs(q, v, chunk: int, rows: int) -> list[tuple]:
     return [("inverse", *shape), ("forward", *shape, rows),
             ("backward", *shape, rows)]
 
-
-def lowered_since(before: collections.Counter) -> dict:
-    """What was traced since `before` (a copy of `LOWERED`), under
-    `scan_program`'s names: delta rules through the kernels, through the
-    plain chunked form, and the distinct kernel programs the former need."""
-    made = LOWERED - before
-    ways = {way: made.pop(way, 0) for way in ("kernel", "plain")}
-    return {**ways, "programs": len(made)}

@@ -36,17 +36,13 @@ d lhs (`tgmm` masks them out of d rhs): the caller never reads them
 (`ops/rowperm.py`'s two permutations stop at the groups' sum), and no caller
 may rely on either way zeroing them.
 
-`LOWERED` counts, as programs are traced, how many products went each way and
-which kernel programs those through the kernel need, transposes included
-(and, for `ops/rowperm.py`, the same of the row permutations round them);
-`counted` keeps the count right where jax reuses a cached trace.
-`make_train_step` reads it round the trace of its step (`lowered_since`), for
-the Trainer's `experts_program` telemetry record.
+Each product notes the way it went and the kernel programs it needs,
+transposes included (`ops/programs.py`, op `experts`), for the Trainer's
+`experts_program` telemetry record.
 """
 
 from __future__ import annotations
 
-import collections
 import functools
 from typing import NamedTuple, Optional
 
@@ -54,15 +50,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from mgwfbp_tpu.ops import blockattn
-
-# calls of `grouped_product` traced so far, by the way they went down
-# ("kernel", "ragged"), and under each kernel program's key (`_programs`) the
-# products traced so far that need it; ops/rowperm.py's permutations likewise
-# ("rows_held", "rows_all", and its programs' keys)
-LOWERED: collections.Counter = collections.Counter()
-# what a call through `counted` traced, by its arguments' shapes
-_TRACED_BY: dict = {}
+from mgwfbp_tpu.ops import programs
 
 _LANES = 128  # K and N are whole lane tiles, or the kernel is not asked
 
@@ -205,13 +193,12 @@ def grouped_product(lhs: jax.Array, rhs: jax.Array,
     hold anything, in the result and in lhs's cotangent."""
     m, k = lhs.shape
     tiles = None
-    if blockattn.traced_for_tpu() and lhs.dtype == rhs.dtype:
+    if programs.traced_for_tpu() and lhs.dtype == rhs.dtype:
         tiles = _kernel_tiles(m, k, rhs.shape[2], lhs.dtype)
     if tiles is None:
-        LOWERED["ragged"] += 1
+        programs.note("experts", "ragged")
         return lax.ragged_dot(lhs, rhs, group_sizes)
-    LOWERED["kernel"] += 1
-    LOWERED.update(_programs(lhs, rhs, tiles))
+    programs.note("experts", "kernel", _programs(lhs, rhs, tiles))
     out = _kernel_product(lhs, rhs, group_sizes, tiles)
     # the transposes' programs are traced HERE, into jax's cache of traces,
     # and found there by the backward pass: first traced in the backward
@@ -222,45 +209,3 @@ def grouped_product(lhs: jax.Array, rhs: jax.Array,
         tiles, False, (lhs, rhs, group_sizes), out))
     return out
 
-
-def counted(fn, counter: collections.Counter = LOWERED):
-    """`fn` with what EVERY call of it traces counted in `counter` (the
-    grouped products here; `ops/selscan.py` hands in its own). jax keeps one
-    trace of a function under `jax.checkpoint` for equal static arguments
-    and argument shapes, so of four equal layers only the first runs
-    `grouped_product`'s Python, and a step built a second time in one
-    process runs none of it: a call that counted nothing is counted as the
-    call of its arguments that did."""
-
-    def call(*args):
-        before = counter.copy()
-        out = fn(*args)
-        key = id(counter), jax.tree.structure(args), tuple(
-            (a.shape, a.dtype) if hasattr(a, "shape") else a
-            for a in jax.tree.leaves(args))
-        traced = counter - before
-        if traced:
-            _TRACED_BY[key] = traced
-        else:
-            counter.update(_TRACED_BY.get(key, ()))
-        return out
-
-    return call
-
-
-_WAYS = ("kernel", "ragged", "rows_held", "rows_all")
-# what the key of a kernel program of `ops/rowperm.py` starts with
-ROWS_PROGRAM = "combine_rows"
-
-
-def lowered_since(before: collections.Counter) -> dict:
-    """What was traced since `before` (a copy of `LOWERED`): products through
-    the kernel, through `lax.ragged_dot`, and the distinct kernel programs
-    among the former with their transposes; row permutations
-    (`ops/rowperm.py`) that move only the rows in a group, that move every
-    assignment's row, and the distinct kernel programs they and their
-    transposes need."""
-    made = LOWERED - before
-    ways = {way: made.pop(way, 0) for way in _WAYS}
-    rows = sum(1 for key in made if key[0] == ROWS_PROGRAM)
-    return {**ways, "programs": len(made) - rows, "rows_programs": rows}

@@ -77,11 +77,10 @@ and of d `src` are one program. `take_rows` traces it on the way forward
 (`jax.eval_shape` over its own transpose), where a trace costs a fifth of
 what it costs inside the backward pass (PERF.md, PR 35).
 
-`groupmm.LOWERED` counts the permutations (the forward calls, 2 a sparse
-layer) by what they move: "rows_held" only the rows in a group (the kernel),
-"rows_all" all N x k (XLA's gather), and the kernel programs they and their
-transposes need; `groupmm.counted` keeps the count right under a cached
-trace.
+Each permutation (the forward calls, 2 a sparse layer) notes what it moves
+(`ops/programs.py`, op `rows`): "rows_held" only the rows in a group (the
+kernel), "rows_all" all N x k (XLA's gather), and the kernel program it and
+its transpose need, for the Trainer's `experts_program` telemetry record.
 """
 
 from __future__ import annotations
@@ -93,8 +92,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from mgwfbp_tpu.ops import blockattn
-from mgwfbp_tpu.ops.groupmm import LOWERED, ROWS_PROGRAM
+from mgwfbp_tpu.ops import programs
 
 _LANES = 128
 _CHUNK = 16  # rows of one copy into the window: a bfloat16 tile's sublanes
@@ -351,16 +349,17 @@ def _combine_kernel(rows, index, weights, sizes, *, plan: Plan,
 
 def _planned(held: bool, n: int, k: int, d: int, sizes, dtype):
     """The plan of a permutation of this shape traced now (None: the plain
-    gathers), counted in `LOWERED`: by whether it moves only the rows held
-    when there is a plan, and under the key of the kernel program that it or
-    its transpose then needs, as jax tells programs apart."""
+    gathers), noted: by whether it moves only the rows held when there is a
+    plan, and with the key of the kernel program that it or its transpose
+    then needs, as jax tells programs apart."""
     plan = None
-    if blockattn.traced_for_tpu():
+    if programs.traced_for_tpu():
         plan = _kernel_plan(n, k, d, sizes.shape[0], dtype)
-    LOWERED["rows_held" if held and plan is not None else "rows_all"] += 1
-    if plan is not None:
-        LOWERED[(ROWS_PROGRAM, n, k, d, sizes.shape[0],
-                 jnp.dtype(dtype).name, plan)] += 1
+    programs.note(
+        "rows", "rows_held" if held and plan is not None else "rows_all",
+        () if plan is None else [
+            ("combine_rows", n, k, d, sizes.shape[0], jnp.dtype(dtype).name,
+             plan)])
     return plan
 
 

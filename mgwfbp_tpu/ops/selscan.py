@@ -73,16 +73,13 @@ stretch of one chunk, times A: <= 0, so no factor can overflow and nothing
 is ever divided by a decay. Everything here is float32 whatever the inputs'
 dtype: time steps, decays, sums, the state and y.
 
-`LOWERED` counts, as programs are traced, how many scans went each way
-("kernel", "plain") and under each kernel program's key the scans that need
-it; `make_train_step` reads it round the trace of its step
-(`lowered_since`), for the Trainer's `scan_program` telemetry record, and
-`groupmm.counted` keeps the count right where jax reuses a cached trace.
+Each scan notes the way it went ("kernel", "plain") and the kernel programs
+it needs (`ops/programs.py`, op `scan`), for the Trainer's `scan_program`
+telemetry record.
 """
 
 from __future__ import annotations
 
-import collections
 import functools
 from typing import NamedTuple, Optional
 
@@ -90,12 +87,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from mgwfbp_tpu.ops import blockattn
-
-# calls of `selective_scan` traced so far, by the way they went down
-# ("kernel", "plain"), and under each kernel program's key the scans traced
-# so far that need it
-LOWERED: collections.Counter = collections.Counter()
+from mgwfbp_tpu.ops import programs
 
 _LANES = 128
 _SUBLANES = 8  # of a float32 tile; a two-byte dtype's tile has twice as many
@@ -512,13 +504,12 @@ def selective_scan(
     mixer is the caller's. `chunk` and `block` are the chunked form's."""
     (bsz, t, d), n = x.shape, a.shape[1]
     tiles = None
-    if blockattn.traced_for_tpu():
+    if programs.traced_for_tpu():
         tiles = _kernel_tiles(t, d, n, (x.dtype, b.dtype, c.dtype))
     if tiles is None:
-        LOWERED["plain"] += 1
+        programs.note("scan", "plain")
         return chunked_scan(x, dt, a, b, c, chunk=chunk, block=block)
-    LOWERED["kernel"] += 1
-    LOWERED.update(_programs(x, n, b, c, tiles))
+    programs.note("scan", "kernel", _programs(x, n, b, c, tiles))
     # (N, D): the channels in the lanes
     args = (x, dt.astype(jnp.float32), a.astype(jnp.float32).T, b, c)
     y, last = _kernel_scan(*args, tiles, False)
@@ -530,11 +521,3 @@ def selective_scan(
         y, last)
     return y, jnp.swapaxes(last, 1, 2)
 
-
-def lowered_since(before: collections.Counter) -> dict:
-    """What was traced since `before` (a copy of `LOWERED`): scans through
-    the kernels, through the chunked form, and the distinct kernel programs
-    the former need."""
-    made = LOWERED - before
-    ways = {way: made.pop(way, 0) for way in ("kernel", "plain")}
-    return {**ways, "programs": len(made)}

@@ -52,16 +52,13 @@ its recomputation under the layer's `jax.checkpoint` and its backward share
 TWO kernel programs; the backward's is traced on the way forward
 (`jax.eval_shape`; `ops/groupmm.py` has the measurement).
 
-`LOWERED` counts, as programs are traced, how many convolutions went each
-way ("kernel", "plain") and under each kernel program's key the calls that
-need it; `make_train_step` reads it round the trace of its step
-(`lowered_since`), for the Trainer's `conv_program` telemetry record, and
-`groupmm.counted` keeps the count right where jax reuses a cached trace.
+Each call notes the way it went ("kernel", "plain") and the kernel programs
+it needs (`ops/programs.py`, op `conv`), for the Trainer's `conv_program`
+telemetry record.
 """
 
 from __future__ import annotations
 
-import collections
 import functools
 from typing import NamedTuple, Optional
 
@@ -69,12 +66,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from mgwfbp_tpu.ops import blockattn
-
-# calls of `causal_conv_silu` traced so far, by the way they went down
-# ("kernel", "plain"), and under each kernel program's key the calls traced
-# so far that need it
-LOWERED: collections.Counter = collections.Counter()
+from mgwfbp_tpu.ops import programs
 
 _LANES = 128
 _SUBLANES = 8  # of a float32 tile; a two-byte dtype's tile has twice as many
@@ -421,13 +413,12 @@ def causal_conv_silu(
     sums; x's dtype out of the convolution and out of the SiLU."""
     (_, t, c), k = x.shape, w.shape[0]
     tiles = None
-    if blockattn.traced_for_tpu():
+    if programs.traced_for_tpu():
         tiles = _kernel_tiles(t, c, k, x.dtype)
     if tiles is None:
-        LOWERED["plain"] += 1
+        programs.note("conv", "plain")
         return plain_conv_silu(x, w, bias)
-    LOWERED["kernel"] += 1
-    LOWERED.update(_programs(x, k, bias, tiles))
+    programs.note("conv", "kernel", _programs(x, k, bias, tiles))
     args = (x, w.astype(jnp.float32),
             None if bias is None else bias.astype(jnp.float32))
     y = _kernel_conv(*args, tiles, False)
@@ -436,11 +427,3 @@ def causal_conv_silu(
     jax.eval_shape(functools.partial(_kernel_conv_bwd, tiles, False), args, y)
     return y
 
-
-def lowered_since(before: collections.Counter) -> dict:
-    """What was traced since `before` (a copy of `LOWERED`): convolutions
-    through the kernels, through the plain form, and the distinct kernel
-    programs the former need."""
-    made = LOWERED - before
-    ways = {way: made.pop(way, 0) for way in ("kernel", "plain")}
-    return {**ways, "programs": len(made)}

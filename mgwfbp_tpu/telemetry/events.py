@@ -84,8 +84,9 @@ EVENT_TYPES: dict[str, tuple[str, ...]] = {
     # how many collectives it issues and how many of them the compiler
     # made asynchronous; `compiler_options` (names) rides along
     "step_program": ("step", "collectives", "async_collectives"),
-    # one built step program, counted while it was traced
-    # (Trainer._note_traced_programs): how many of its attention cores
+    # one built step program, counted while it was traced (ops/programs.py
+    # has the ops and the records' table; Trainer._note_traced_programs
+    # writes them): how many of its attention cores
     # went through the fused kernel and how many through the plain blocks
     # (ops/blockattn.py); 0 and 0 for a model without attention
     "attention_program": ("step", "kernel", "blocks"),

@@ -37,8 +37,7 @@ from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from mgwfbp_tpu.models import ModelMeta
-from mgwfbp_tpu.ops import deltarule, groupmm, selscan, shortconv
-from mgwfbp_tpu.ops.blockattn import LOWERED as ATTENTION_LOWERED
+from mgwfbp_tpu.ops import programs
 from mgwfbp_tpu.parallel.allreduce import MergedAllreduce
 from mgwfbp_tpu.parallel.mesh import DATA_AXIS
 
@@ -717,50 +716,19 @@ def make_train_step(
     else:
         # (nsteps, batch, time): batch over data, time over seq
         batch_spec = P(None, data_axes, seq_axis)
-    # filled when the step is traced: how many of the program's attention
-    # cores went through the fused kernel and how many through the plain
-    # blocks (ops/blockattn.py), and how many of its experts' grouped
-    # products through the tiled kernel and through `lax.ragged_dot`, with
-    # the distinct kernel programs among them (ops/groupmm.py), and the same
-    # of its selective scans (ops/selscan.py: the kernels or the chunked
-    # form), of its gated delta rules (ops/deltarule.py) and of its short
-    # convolutions (ops/shortconv.py); Trainer records them as
-    # `attention_program`, `experts_program`, `scan_program`, `delta_program`
-    # and `conv_program`
-    attention_calls: dict[str, int] = {}
-    experts_calls: dict[str, int] = {}
-    scan_calls: dict[str, int] = {}
-    delta_calls: dict[str, int] = {}
-    conv_calls: dict[str, int] = {}
-
-    def counting_programs(fn):
-        def traced(*args):
-            attention = dict(ATTENTION_LOWERED)
-            experts = groupmm.LOWERED.copy()
-            scans = selscan.LOWERED.copy()
-            deltas = deltarule.LOWERED.copy()
-            convs = shortconv.LOWERED.copy()
-            out = fn(*args)
-            attention_calls.update(
-                (way, n - attention[way])
-                for way, n in ATTENTION_LOWERED.items()
-            )
-            experts_calls.update(groupmm.lowered_since(experts))
-            scan_calls.update(selscan.lowered_since(scans))
-            delta_calls.update(deltarule.lowered_since(deltas))
-            conv_calls.update(shortconv.lowered_since(convs))
-            return out
-
-        return traced
+    # filled when the step is traced: which way each call of the ops' entry
+    # points went down, by op (ops/programs.py); the Trainer records them as
+    # the `*_program` telemetry records
+    traced_programs: dict[str, dict[str, int]] = {}
 
     if has_carry:
-        fn = counting_programs(shard_map(
+        fn = programs.traced_into(shard_map(
             per_device,
             mesh=mesh,
             in_specs=(state_spec, batch_spec, P(data_axes)),
             out_specs=(state_spec, P(), P(data_axes)),
             check_vma=False,
-        ))
+        ), traced_programs)
 
         @partial(
             jax.jit, donate_argnums=(0, 2) if donate else (),
@@ -769,24 +737,20 @@ def make_train_step(
         def step_lm(state, batch, carry):
             return fn(state, batch, carry)
 
-        step_lm.attention_calls = attention_calls
-        step_lm.experts_calls = experts_calls
-        step_lm.scan_calls = scan_calls
-        step_lm.delta_calls = delta_calls
-        step_lm.conv_calls = conv_calls
+        step_lm.traced_programs = traced_programs
         return step_lm
 
     def per_device_nocarry(state, batch):
         s, m, _ = per_device(state, batch, None)
         return s, m
 
-    fn = counting_programs(shard_map(
+    fn = programs.traced_into(shard_map(
         per_device_nocarry,
         mesh=mesh,
         in_specs=(state_spec, batch_spec),
         out_specs=(state_spec, P()),
         check_vma=False,
-    ))
+    ), traced_programs)
 
     @partial(
         jax.jit, donate_argnums=(0,) if donate else (),
@@ -795,11 +759,7 @@ def make_train_step(
     def step(state, batch):
         return fn(state, batch)
 
-    step.attention_calls = attention_calls
-    step.experts_calls = experts_calls
-    step.scan_calls = scan_calls
-    step.delta_calls = delta_calls
-    step.conv_calls = conv_calls
+    step.traced_programs = traced_programs
     return step
 
 

@@ -40,6 +40,7 @@ from mgwfbp_tpu.checkpoint import (
 )
 from mgwfbp_tpu.config import TrainConfig
 from mgwfbp_tpu.data import ShardInfo, data_prepare
+from mgwfbp_tpu.ops import programs
 from mgwfbp_tpu.optim import make_optimizer
 from mgwfbp_tpu.parallel.allreduce import make_merged_allreduce
 from mgwfbp_tpu.parallel.costmodel import load_profile, lookup_alpha_beta
@@ -1400,75 +1401,20 @@ class Trainer:
 
     def _note_traced_programs(self) -> None:
         """Once per step-program build, after its first dispatch, what
-        make_train_step counted while the step was traced: how many of the
-        program's attention cores went through the fused kernel and how
-        many through the plain blocks (ops/blockattn.py), how many of its
-        experts' grouped products through the tiled kernel and how many
-        through `lax.ragged_dot`, and the distinct kernel programs among
-        them (ops/groupmm.py), how many of its selective scans through the
-        kernels with the state in VMEM and how many through the chunked
-        form (ops/selscan.py), the same of its gated delta rules
-        (ops/deltarule.py) and of its short convolutions with their SiLU
-        (ops/shortconv.py); all choose by platform and shape. Nothing is
-        compiled or read from the device."""
+        make_train_step noted while the step was traced: which way each call
+        of the ops' entry points went down, by platform and shape, and the
+        distinct kernel programs those calls need (ops/programs.py has the
+        records and their log lines). Nothing is compiled or read from the
+        device."""
         self._traced_programs_noted = True
-        calls = getattr(self.train_step, "attention_calls", None)
+        calls = getattr(self.train_step, "traced_programs", None)
         if not calls:  # not traced through make_train_step's own wrapper
             return
-        self.log.info(
-            "attention: %d core(s) of the step through the fused kernel, "
-            "%d through the plain blocks", calls["kernel"], calls["blocks"],
-        )
-        self._emit_event(
-            "attention_program", step=int(self.iteration),
-            kernel=int(calls["kernel"]), blocks=int(calls["blocks"]),
-        )
-        calls = self.train_step.experts_calls
-        self.log.info(
-            "experts: %d grouped product(s) of the step through the tiled "
-            "kernel (%d distinct kernel program(s)), %d through ragged_dot; "
-            "%d row permutation(s) moving only the rows in a group (%d "
-            "distinct kernel program(s)), %d moving every assignment's row",
-            calls["kernel"], calls["programs"], calls["ragged"],
-            calls["rows_held"], calls["rows_programs"], calls["rows_all"],
-        )
-        self._emit_event(
-            "experts_program", step=int(self.iteration),
-            **{name: int(n) for name, n in calls.items()},
-        )
-        calls = self.train_step.scan_calls
-        self.log.info(
-            "scan: %d selective scan(s) of the step through the kernels with "
-            "the state in VMEM (%d distinct kernel program(s)), %d through "
-            "the chunked form",
-            calls["kernel"], calls["programs"], calls["plain"],
-        )
-        self._emit_event(
-            "scan_program", step=int(self.iteration),
-            **{name: int(n) for name, n in calls.items()},
-        )
-        calls = self.train_step.delta_calls
-        self.log.info(
-            "delta rule: %d gated delta rule(s) of the step through a kernel "
-            "with the state in VMEM (%d distinct kernel program(s)), %d "
-            "through the plain chunked form",
-            calls["kernel"], calls["programs"], calls["plain"],
-        )
-        self._emit_event(
-            "delta_program", step=int(self.iteration),
-            **{name: int(n) for name, n in calls.items()},
-        )
-        calls = self.train_step.conv_calls
-        self.log.info(
-            "convolution: %d short convolution(s) of the step through the "
-            "kernels of one pass (%d distinct kernel program(s)), %d through "
-            "the plain form",
-            calls["kernel"], calls["programs"], calls["plain"],
-        )
-        self._emit_event(
-            "conv_program", step=int(self.iteration),
-            **{name: int(n) for name, n in calls.items()},
-        )
+        for record, ops, line in programs.RECORDS:
+            fields = {
+                name: int(n) for op in ops for name, n in calls[op].items()}
+            self.log.info(line, fields)
+            self._emit_event(record, step=int(self.iteration), **fields)
 
     def _schedule_state_doc(self) -> dict:
         """The committed schedule + cost-model state, JSON-able — the

@@ -44,7 +44,7 @@ def mesh8():
 def topo():
     """A TPU v5e:2x2 that is described, not attached: what the files that ask
     the chip's compiler without a chip compile for
-    (tests/test_tpu_compile.py, tests/test_tpu_compile_shortconv.py). Made
+    (tests/test_tpu_compile*.py, one file a kind of program). Made
     inside the fixture, so that only a worker that runs such a test loads
     the TPU's library; skipped where it cannot be described."""
     from jax.experimental import topologies

@@ -3,17 +3,17 @@ own multiplier (granite-4.0-h: 1 / 64 at head size 64, a group of 4 query
 heads a key head) replaces it, value and gradients against dense attention.
 Its fused kernel (the installed splash_attention, here in `interpret` mode on
 the CPU) against its plain blocks at the call shapes of both language cells;
-the test of platform and shape that chooses between the two; and the count of
-both that a step program leaves on the telemetry."""
+and the test of platform and shape that chooses between the two. (The count
+of both that a step program leaves on the telemetry is held where a Trainer
+runs each preset anyway: tests/test_<family>_trainer.py.)"""
 
 import dataclasses
-import os
 
 import jax
 import jax.numpy as jnp
 import pytest
 
-from mgwfbp_tpu.ops import blockattn
+from mgwfbp_tpu.ops import blockattn, programs
 from mgwfbp_tpu.ops.blockattn import blockwise_attention
 
 
@@ -176,11 +176,11 @@ def test_shape_test_of_the_kernel(t, d, window, fits):
 
 
 def traced_ways(fn, *args):
-    before = dict(blockattn.LOWERED)
+    before = programs.LOWERED.copy()
     # a fresh function each time: a cached trace calls nothing and counts none
     jaxpr = jax.make_jaxpr(lambda *a: fn(*a))(*args)
-    ways = {way: n - before[way] for way, n in blockattn.LOWERED.items()}
-    return ways, "pallas_call" in str(jaxpr)
+    return (programs.lowered_since(before)["attention"],
+            "pallas_call" in str(jaxpr))
 
 
 def test_falls_back_to_the_blocks_off_the_tpu_and_on_a_shape_that_misfits(
@@ -193,69 +193,11 @@ def test_falls_back_to_the_blocks_off_the_tpu_and_on_a_shape_that_misfits(
     def attend(q):
         return blockwise_attention(q, q[:, :, :1], q[:, :, :1])
 
-    assert not blockattn.traced_for_tpu()
+    assert not programs.traced_for_tpu()
     assert traced_ways(attend, fits) == ({"kernel": 0, "blocks": 1}, False)
-    monkeypatch.setattr(blockattn, "traced_for_tpu", lambda: True)
+    monkeypatch.setattr(programs, "traced_for_tpu", lambda: True)
     assert traced_ways(attend, fits) == ({"kernel": 1, "blocks": 0}, True)
     assert traced_ways(attend, misfits) == ({"kernel": 0, "blocks": 1}, False)
-
-
-def trainer_of(tmp_path, *flags):
-    from mgwfbp_tpu import train_cli
-    from mgwfbp_tpu.train.trainer import Trainer
-
-    args = train_cli.build_parser().parse_args([
-        *flags, "--batch-size", "2", "--lr", "0.01", "--lr-schedule", "const",
-        "--synthetic", "--telemetry", "--no-profile-backward",
-        "--num-batches-per-epoch", "2", "--max-epochs", "1", "--seed", "5",
-        "--logdir", str(tmp_path)])
-    cfg = train_cli.config_from_args(args)
-    return cfg, Trainer(cfg, profile_backward=False, synthetic_data=True)
-
-
-TOKENS = ["--dataset", "tokens", "--vocab-size", "256", "--num-steps", "64"]
-
-
-@pytest.mark.parametrize("flags,blocks", [
-    (["--dnn", "mellum2_tiny", "--experts-held", "2:2", "--layers-held", "2",
-      *TOKENS], 2),
-    (["--dnn", "granite4h_tiny", "--layers-held", "3", *TOKENS], 1),
-    (["--dnn", "laguna_xs2_tiny", "--experts-held", "2:4", *TOKENS], 5),
-    # one stacked core a layer (window, window, full, cross); a length no
-    # other test traces these layers at, whose traces `jax.checkpoint` keeps
-    (["--dnn", "phi4flash_tiny", *TOKENS[:-1], "48"], 4),
-    # two periods: the two full layers share ONE cached trace and are both
-    # counted (`groupmm.counted` over this file's counter)
-    (["--dnn", "qwen3next_tiny", "--experts-held", "4:4", *TOKENS[:-1], "48"],
-     2),
-], ids=["mellum2_tiny", "granite4h_tiny", "laguna_xs2_tiny", "phi4flash_tiny",
-        "qwen3next_tiny"])
-def test_a_step_program_leaves_its_attention_count_on_the_telemetry(
-        tmp_path, monkeypatch, flags, blocks):
-    """One `attention_program` record a built step program, counted while the
-    step was traced: on the CPU every core of the tiny models goes through
-    the blocks. A second epoch runs the same program and adds no record; the
-    report prints the line."""
-    from mgwfbp_tpu.telemetry.events import events_of, read_events
-
-    monkeypatch.setenv("MGWFBP_SYNTH_TRAIN_N", "16")
-    cfg, trainer = trainer_of(tmp_path, *flags)
-    try:
-        trainer.train_epoch(0)
-        trainer.train_epoch(1)
-        assert trainer.train_step.attention_calls == {
-            "kernel": 0, "blocks": blocks}
-    finally:
-        trainer.close()
-    records = read_events(
-        os.path.join(str(tmp_path), cfg.tag(), "telemetry.jsonl"))
-    (program,) = events_of(records, "attention_program")
-    assert (program["step"], program["kernel"], program["blocks"]) \
-        == (1, 0, blocks)
-    import telemetry_report
-
-    assert (f"0 core(s) through the fused kernel, {blocks} through the plain "
-            "blocks") in telemetry_report.format_report(records)
 
 
 @pytest.mark.parametrize("model,want", [
@@ -269,7 +211,7 @@ def test_traced_as_for_a_tpu_the_models_cores_go_where_the_shape_test_sends_them
     count a step program would record on the chip."""
     from mgwfbp_tpu.models import granite, laguna, mellum
 
-    monkeypatch.setattr(blockattn, "traced_for_tpu", lambda: True)
+    monkeypatch.setattr(programs, "traced_for_tpu", lambda: True)
     if model == "laguna_xs2":
         # 6 | 8 query heads over 2 key heads: every one of the five layers'
         # cores is counted, the three window layers alike among them

@@ -54,14 +54,14 @@ def test_phases_rehearsed_tiny_on_the_cpu_mesh(monkeypatch, tmp_path):
     """--multichip's whole comparison (production `--policy auto` Trainer vs
     `--policy none`, same seed: trajectories, final params, mesh / batch /
     param placement on distinct devices) and the kernel phase, at lenet
-    size on the virtual devices, Pallas interpreted."""
+    size on the virtual devices (the attention core's plain blocks there)."""
     import chip_smoke
 
     monkeypatch.setattr(chip_smoke, "OUT_DIR", str(tmp_path))
     monkeypatch.setenv("MGWFBP_SYNTH_TRAIN_N", "1024")
     monkeypatch.setenv("MGWFBP_SYNTH_VAL_N", "64")
     chip_smoke.multichip_phase(dnn="lenet", epochs=2)
-    chip_smoke.kernel_phase(b=1, t=128, h=2, d=32, interpret=True, iters=2)
+    chip_smoke.kernel_phase(b=1, t=128, h=2, d=32, iters=2)
 
 
 def test_compile_cache_placed_from_outside_sets_nothing(monkeypatch, tmp_path):

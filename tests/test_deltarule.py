@@ -9,14 +9,14 @@ value heads; decays near 0 and near -20 a token; bfloat16 inputs within a
 stated tolerance; a cotangent on the final state alone; the inverse of both
 ways against the triangular solve; each of the rule's parts shown to matter
 (beta, the erasure); and the test of platform and shape that chooses between
-the two, with what `LOWERED` counts."""
+the two, with what each call notes (`ops/programs.py`)."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from mgwfbp_tpu.ops import blockattn, deltarule
+from mgwfbp_tpu.ops import deltarule, programs
 from mgwfbp_tpu.ops.deltarule import gated_delta_rule
 
 HI = jax.lax.Precision.HIGHEST
@@ -331,22 +331,22 @@ def test_lowered_since_counts_kernel_plain_and_programs(
     form."""
     widths, t, chunk, want = SHAPES[shape]
     if tpu:
-        monkeypatch.setattr(blockattn, "traced_for_tpu", lambda: True)
+        monkeypatch.setattr(programs, "traced_for_tpu", lambda: True)
     else:
         want = {"kernel": 0, "plain": 1, "programs": 0}
     args = draws(0, t, bsz=1, **widths)
-    before = deltarule.LOWERED.copy()
+    before = programs.LOWERED.copy()
     jaxpr = jax.make_jaxpr(
         lambda *x: gated_delta_rule(*x, chunk=chunk))(*args)
-    assert deltarule.lowered_since(before) == want
+    assert programs.lowered_since(before)["delta"] == want
     assert ("pallas_call" in str(jaxpr)) == bool(want["kernel"])
-    before = deltarule.LOWERED.copy()
+    before = programs.LOWERED.copy()
     (o, s), _ = jax.eval_shape(lambda *x: (
         gated_delta_rule(*x, chunk=chunk),
         gated_delta_rule(*x, chunk=chunk)), *args)
     assert o.shape == args[2].shape and s.shape == (
         1, widths["h"], widths["dk"], widths["dv"])
-    assert deltarule.lowered_since(before) == {
+    assert programs.lowered_since(before)["delta"] == {
         **{way: 2 * n for way, n in want.items()},
         "programs": want["programs"]}
 
@@ -354,14 +354,14 @@ def test_lowered_since_counts_kernel_plain_and_programs(
 def test_a_shape_the_kernels_refuse_falls_to_the_plain_form(monkeypatch):
     """... and computes the rule there: traced for a TPU, narrow keys and a
     T that is padded give the recurrence's values through the plain form."""
-    monkeypatch.setattr(blockattn, "traced_for_tpu", lambda: True)
+    monkeypatch.setattr(programs, "traced_for_tpu", lambda: True)
     for widths, t in ((dict(hk=2, h=4, dk=64, dv=128), 128),
                       (dict(hk=8, h=8, dk=128, dv=128), 72)):
         args = draws(t, t, bsz=1, **widths)
-        before = deltarule.LOWERED.copy()
+        before = programs.LOWERED.copy()
         with jax.default_matmul_precision("highest"):
             got = gated_delta_rule(*args, chunk=64)
             want = literal(*args)
-        assert deltarule.lowered_since(before) == {
+        assert programs.lowered_since(before)["delta"] == {
             "kernel": 0, "plain": 1, "programs": 0}
         agree(got, want)

@@ -7,18 +7,17 @@ lookup) is reduced like any other leaf: last in arrival order, and equal to
 `lax.pmean`'s of the same per-device gradients. The equations are held
 against the plain reference in tests/benchmark/test_granite4h_reference.py."""
 
-import os
-
 import jax
 import jax.numpy as jnp
 import numpy as np
+import program_records
 import pytest
 from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 from mgwfbp_tpu import train_cli
 from mgwfbp_tpu.parallel.mesh import DATA_AXIS, MeshSpec, make_mesh
-from mgwfbp_tpu.telemetry.events import events_of, read_events
+from mgwfbp_tpu.telemetry.events import events_of
 from mgwfbp_tpu.train.step import make_loss_fn
 from mgwfbp_tpu.train.trainer import Trainer
 
@@ -70,11 +69,11 @@ def test_preset_and_flags_reach_the_factory_and_the_optimizer(
         create_model("granite4h_tiny", experts_held=(0, 2))
 
 
-@pytest.mark.parametrize("policy", ["mgwfbp", "wfbp"])
-def test_trains_with_counters_and_the_tied_leaf_reduces_like_pmean(
-        tmp_path, monkeypatch, policy):
-    monkeypatch.setenv("MGWFBP_SYNTH_TRAIN_N", str(6 * 2 * WORLD))
-    monkeypatch.setenv("MGWFBP_SYNTH_VAL_N", "8")
+def trained(tmp_path, patch, policy):
+    """Two epochs under `policy`, after the tied leaf was seen to reduce like
+    `lax.pmean`'s: what `program_records.read_run` reads of them."""
+    patch.setenv("MGWFBP_SYNTH_TRAIN_N", str(6 * 2 * WORLD))
+    patch.setenv("MGWFBP_SYNTH_VAL_N", "8")
     cfg, trainer = build(tmp_path, policy, "--policy", policy)
     try:
         reducer = trainer.reducer
@@ -123,8 +122,23 @@ def test_trains_with_counters_and_the_tied_leaf_reduces_like_pmean(
         assert trainer.iteration == 12
     finally:
         trainer.close()
-    records = read_events(os.path.join(
-        str(tmp_path / policy), cfg.tag(), "telemetry.jsonl"))
+    return program_records.read_run(str(tmp_path / policy), cfg, trainer)
+
+
+@pytest.fixture(scope="module")
+def wfbp_run(tmp_path_factory):
+    """The file's one training under `wfbp` with the telemetry on, for every
+    test that reads what it left."""
+    with pytest.MonkeyPatch.context() as patch:
+        return trained(tmp_path_factory.mktemp("wfbp"), patch, "wfbp")
+
+
+@pytest.mark.parametrize("policy", ["mgwfbp", "wfbp"])
+def test_trains_with_counters_and_the_tied_leaf_reduces_like_pmean(
+        tmp_path, monkeypatch, request, policy):
+    noted, records, _ = (
+        request.getfixturevalue("wfbp_run") if policy == "wfbp"
+        else trained(tmp_path, monkeypatch, policy))
     steps = events_of(records, "step")
     health = {h["step"]: h for h in events_of(records, "health")}
     assert [s["step"] for s in steps] == list(range(1, 13))
@@ -139,14 +153,28 @@ def test_trains_with_counters_and_the_tied_leaf_reduces_like_pmean(
     assert events_of(records, "bad_step") == []
     # the three Mamba-2 layers' convolutions (ops/shortconv.py), counted
     # while the step was traced and through cached traces: the plain form
-    (convs,) = events_of(records, "conv_program")
-    assert (convs["kernel"], convs["plain"], convs["programs"]) == (0, 3, 0)
+    assert noted["conv"] == {"kernel": 0, "plain": 3, "programs": 0}
     with_counters = [s for s in steps if "ssm_state_rms" in s]
     assert len(with_counters) >= 10
     for s in with_counters:
         assert s["ssm_state_rms"] > 0.0 and s["ssm_log_decay_min"] < 0.0
         assert "stats_ready" in s
     assert not [k for s in steps for k in s if k.startswith("health/")]
+
+
+@pytest.mark.parametrize("op,want", [
+    # (mamba, mamba, attention, mamba): the attention layer's core, and a
+    # convolution a Mamba-2 layer (its scan is ops/ssd.py's, which has one
+    # way down and notes none); the layers share cached traces
+    ("attention", {"kernel": 0, "blocks": 1}),
+    ("experts", {"kernel": 0, "ragged": 0, "programs": 0}),
+    ("rows", {"rows_held": 0, "rows_all": 0, "rows_programs": 0}),
+    ("scan", {"kernel": 0, "plain": 0, "programs": 0}),
+    ("delta", {"kernel": 0, "plain": 0, "programs": 0}),
+    ("conv", {"kernel": 0, "plain": 3, "programs": 0}),
+], ids=program_records.OPS)
+def test_the_step_program_leaves_its_records(wfbp_run, op, want):
+    program_records.holds(wfbp_run, op, want)
 
 
 def test_the_step_verifies_and_the_counters_add_no_collective():

@@ -3,14 +3,14 @@ under this module's custom VJP, here in `interpret` mode on the CPU) against
 a per-group dense product in float32: forward, d lhs and d rhs, at group
 sizes no tile divides, with empty and one-row groups, with the rows past the
 last group poisoned, at both sparse cells' (K, N). The test of platform and
-shape that chooses between the kernel and `lax.ragged_dot`; the count of
-distinct kernel programs in a module lowered for a TPU; and the count a step
-program leaves on the telemetry."""
+shape that chooses between the kernel and `lax.ragged_dot`; and the count of
+distinct kernel programs in a module lowered for a TPU. (The count a step
+program leaves on the telemetry is held where a Trainer runs each preset
+anyway: tests/test_<family>_trainer.py, tests/test_telemetry.py.)"""
 
 import collections
 import functools
 import hashlib
-import os
 import re
 
 import jax
@@ -18,9 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from mgwfbp_tpu.ops import blockattn, groupmm
-
-from test_blockattn import TOKENS, trainer_of  # the Trainer at a tiny size
+from mgwfbp_tpu.ops import groupmm, programs
 
 Case = collections.namedtuple(
     "Case", "m k n sizes dtype tiles", defaults=(None,))
@@ -118,11 +116,17 @@ def test_kernel_against_a_dense_product_a_group(name, which):
                 assert not got[i].any()
 
 
+def lowered_since(before):
+    """The experts' record: the grouped products' fields and the rows'."""
+    made = programs.lowered_since(before)
+    return {**made["experts"], **made["rows"]}
+
+
 def traced_ways(fn, *args):
-    before = groupmm.LOWERED.copy()
+    before = programs.LOWERED.copy()
     # a fresh function each time: a cached trace calls nothing and counts none
     jaxpr = jax.make_jaxpr(lambda *a: fn(*a))(*args)
-    return groupmm.lowered_since(before), "pallas_call" in str(jaxpr)
+    return lowered_since(before), "pallas_call" in str(jaxpr)
 
 
 def test_falls_back_to_ragged_dot_off_the_tpu_and_on_a_shape_that_misfits(
@@ -144,9 +148,9 @@ def test_falls_back_to_ragged_dot_off_the_tpu_and_on_a_shape_that_misfits(
                 "rows_held": 0, "rows_all": 0, "rows_programs": 0}
 
     ragged = (ways(0, 1, 0), False)
-    assert not blockattn.traced_for_tpu()
+    assert not programs.traced_for_tpu()
     assert traced_ways(product, *operands(512, 128, 128)) == ragged
-    monkeypatch.setattr(blockattn, "traced_for_tpu", lambda: True)
+    monkeypatch.setattr(programs, "traced_for_tpu", lambda: True)
     # K = N: the product and d lhs are one program, d rhs another
     assert traced_ways(product, *operands(512, 128, 128)) == (
         ways(1, 0, 2), True)
@@ -162,7 +166,7 @@ def expert_blocks(layers: int):
     """Forward + gradient of `layers` checkpointed expert blocks as the
     models call them, at a size the products' tiles and the permutations'
     blocks divide: 256 tokens x 8 = 2,048 rows, 4 experts held of 8."""
-    from mgwfbp_tpu.models import mellum
+    from mgwfbp_tpu.models import lm_parts
 
     tokens, d, f, experts, k = 256, 256, 128, 4, 8
     bf = jnp.bfloat16
@@ -170,8 +174,8 @@ def expert_blocks(layers: int):
 
     def loss(ws, u, idx, weights):
         for w_gate, w_up, w_down in ws:
-            y, _, _ = groupmm.counted(jax.checkpoint(
-                mellum.held_experts, static_argnums=6))(
+            y, _, _ = programs.counted(jax.checkpoint(
+                lm_parts.held_experts, static_argnums=6))(
                     u, idx, weights, w_gate, w_up, w_down, 0)
             u = u + y.astype(u.dtype)
         return jnp.sum(u.astype(jnp.float32))
@@ -191,7 +195,7 @@ def test_four_layers_lower_no_more_kernel_programs_than_one(monkeypatch):
     every permutation of every layer is counted all the same."""
     from mgwfbp_tpu.ops import rowperm
 
-    monkeypatch.setattr(blockattn, "traced_for_tpu", lambda: True)
+    monkeypatch.setattr(programs, "traced_for_tpu", lambda: True)
     # the docstrings' own numbers, so that the two cannot part
     assert "at most FOUR distinct programs" in groupmm.__doc__
     assert "**One kernel program a step.**" in rowperm.__doc__
@@ -199,104 +203,20 @@ def test_four_layers_lower_no_more_kernel_programs_than_one(monkeypatch):
     seen = {}
     for layers in (1, 4):
         fn, args = expert_blocks(layers)
-        before = groupmm.LOWERED.copy()
+        before = programs.LOWERED.copy()
         text = fn.trace(*args).lower(lowering_platforms=("tpu",)).as_text()
         assert "ragged_dot" not in text
-        counted = groupmm.lowered_since(before)
+        counted = lowered_since(before)
         assert (counted["kernel"], counted["ragged"]) == (3 * layers, 0)
         # a layer's combine through its kernel; its dispatch is XLA's gather
         assert (counted["rows_held"], counted["rows_all"]) == (layers, layers)
-        programs = {
+        distinct = {
             hashlib.sha256(config.encode()).hexdigest() for config in
             re.findall(r'backend_config = "([^"]*)"', text)}
         sites = text.count("stablehlo.custom_call @tpu_custom_call")
-        assert len(programs) == (
+        assert len(distinct) == (
             counted["programs"] + counted["rows_programs"])
         seen[layers] = (
             counted["programs"], counted["rows_programs"], sites)
     assert seen[1] == seen[4]
     assert 0 < seen[1][0] <= stated[0] and 0 < seen[1][1] <= stated[1]
-
-
-PROGRAMS = [
-    # flags, grouped products (3 a sparse layer held), row permutations (2)
-    (["--dnn", "mellum2_tiny", "--experts-held", "2:2", "--layers-held", "2",
-      *TOKENS], 6, 4),
-    (["--dnn", "laguna_xs2_tiny", "--experts-held", "2:4", *TOKENS], 12, 8),
-    (["--dnn", "resnet20"], 0, 0),
-]
-PROGRAM_IDS = ["mellum2_tiny", "laguna_xs2_tiny", "resnet20"]
-
-
-@functools.lru_cache(maxsize=None)
-def _two_epochs(which: int):
-    """(the built step's `experts_calls`, the stream's records) of two epochs
-    of `PROGRAMS[which]` under the Trainer, run once for both tests below."""
-    import tempfile
-
-    from mgwfbp_tpu.telemetry.events import read_events
-
-    flags = PROGRAMS[which][0]
-    with tempfile.TemporaryDirectory() as tmp, \
-            pytest.MonkeyPatch.context() as patch:
-        patch.setenv("MGWFBP_SYNTH_TRAIN_N", "16")
-        cfg, trainer = trainer_of(tmp, *flags)
-        try:
-            trainer.train_epoch(0)
-            trainer.train_epoch(1)
-            calls = dict(trainer.train_step.experts_calls)
-        finally:
-            trainer.close()
-        return calls, read_events(
-            os.path.join(tmp, cfg.tag(), "telemetry.jsonl"))
-
-
-@pytest.mark.parametrize(
-    "which,ragged", [(i, p[1]) for i, p in enumerate(PROGRAMS)],
-    ids=PROGRAM_IDS)
-def test_a_step_program_leaves_its_experts_count_on_the_telemetry(
-        which, ragged):
-    """One `experts_program` record a built step program, counted while the
-    step was traced: 3 grouped products a sparse layer held, all through
-    `lax.ragged_dot` on the CPU, layers that share a cached trace counted
-    each; none in a model without experts. A second epoch runs the same
-    program and adds no record; the report prints the line."""
-    from mgwfbp_tpu.telemetry.events import events_of
-
-    calls, records = _two_epochs(which)
-    assert (calls["kernel"], calls["ragged"], calls["programs"]) == (
-        0, ragged, 0)
-    (program,) = events_of(records, "experts_program")
-    assert (program["step"], program["kernel"], program["ragged"],
-            program["programs"]) == (1, 0, ragged, 0)
-    import telemetry_report
-
-    assert (f"0 grouped product(s) through the tiled kernel (0 distinct "
-            f"kernel program(s)), {ragged} through ragged_dot"
-            ) in telemetry_report.format_report(records)
-
-
-@pytest.mark.parametrize(
-    "which,plain", [(i, p[2]) for i, p in enumerate(PROGRAMS)],
-    ids=PROGRAM_IDS)
-def test_a_step_program_leaves_its_row_permutations_on_the_telemetry(
-        which, plain):
-    """The same record says how the experts' rows were moved: 2 permutations
-    a sparse layer held (`take_rows`, `combine_rows`; their transposes are not
-    counted apart), on the CPU all of them plain gathers of every
-    assignment's row and none a kernel program, layers under one cached trace
-    counted each; none in a model without experts. The report prints them on
-    the experts' line."""
-    from mgwfbp_tpu.telemetry.events import events_of
-
-    calls, records = _two_epochs(which)
-    assert (calls["rows_held"], calls["rows_all"], calls["rows_programs"]
-            ) == (0, plain, 0)
-    (program,) = events_of(records, "experts_program")
-    assert (program["rows_held"], program["rows_all"],
-            program["rows_programs"]) == (0, plain, 0)
-    import telemetry_report
-
-    assert (f"0 row permutation(s) moving only the rows in a group (0 "
-            f"distinct kernel program(s)), {plain} moving every assignment's "
-            f"row") in telemetry_report.format_report(records)

@@ -9,18 +9,17 @@ of a dense family, fails with the message `mellum2` gives. The equations are
 held against the plain reference in
 tests/benchmark/test_laguna_xs2_reference.py."""
 
-import os
-
 import jax
 import jax.numpy as jnp
 import numpy as np
+import program_records
 import pytest
 from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 from mgwfbp_tpu import train_cli
 from mgwfbp_tpu.parallel.mesh import DATA_AXIS, MeshSpec, make_mesh
-from mgwfbp_tpu.telemetry.events import events_of, read_events
+from mgwfbp_tpu.telemetry.events import events_of
 from mgwfbp_tpu.train.step import make_loss_fn
 from mgwfbp_tpu.train.trainer import Trainer
 
@@ -83,11 +82,11 @@ def test_preset_and_flags_reach_the_factory_and_the_dense_layer_stays_first(
         trainer.close()
 
 
-@pytest.mark.parametrize("policy", ["mgwfbp", "wfbp"])
-def test_trains_with_counters_and_every_leaf_reduces_like_pmean(
-        tmp_path, monkeypatch, policy):
-    monkeypatch.setenv("MGWFBP_SYNTH_TRAIN_N", str(6 * 2 * WORLD))
-    monkeypatch.setenv("MGWFBP_SYNTH_VAL_N", "8")
+def trained(tmp_path, patch, policy):
+    """Two epochs under `policy`, after every leaf was seen to reduce like
+    `lax.pmean`'s: what `program_records.read_run` reads of them."""
+    patch.setenv("MGWFBP_SYNTH_TRAIN_N", str(6 * 2 * WORLD))
+    patch.setenv("MGWFBP_SYNTH_VAL_N", "8")
     cfg, trainer = build(tmp_path, policy, "--policy", policy)
     try:
         reducer = trainer.reducer
@@ -136,8 +135,23 @@ def test_trains_with_counters_and_every_leaf_reduces_like_pmean(
         assert trainer.iteration == 12
     finally:
         trainer.close()
-    records = read_events(os.path.join(
-        str(tmp_path / policy), cfg.tag(), "telemetry.jsonl"))
+    return program_records.read_run(str(tmp_path / policy), cfg, trainer)
+
+
+@pytest.fixture(scope="module")
+def wfbp_run(tmp_path_factory):
+    """The file's one training under `wfbp` with the telemetry on, for every
+    test that reads what it left."""
+    with pytest.MonkeyPatch.context() as patch:
+        return trained(tmp_path_factory.mktemp("wfbp"), patch, "wfbp")
+
+
+@pytest.mark.parametrize("policy", ["mgwfbp", "wfbp"])
+def test_trains_with_counters_and_every_leaf_reduces_like_pmean(
+        tmp_path, monkeypatch, request, policy):
+    _, records, _ = (
+        request.getfixturevalue("wfbp_run") if policy == "wfbp"
+        else trained(tmp_path, monkeypatch, policy))
     steps = events_of(records, "step")
     health = {h["step"]: h for h in events_of(records, "health")}
     assert [s["step"] for s in steps] == list(range(1, 13))
@@ -163,6 +177,21 @@ def test_trains_with_counters_and_every_leaf_reduces_like_pmean(
     import telemetry_report
 
     assert "expert routing" in telemetry_report.format_report(records)
+
+
+@pytest.mark.parametrize("op,want", [
+    # five layers' cores (6 | 8 query heads; no layer is under a
+    # `jax.checkpoint`, so each is traced); the dense first layer has no
+    # experts, the four sparse ones 3 grouped products and 2 permutations
+    ("attention", {"kernel": 0, "blocks": 5}),
+    ("experts", {"kernel": 0, "ragged": 12, "programs": 0}),
+    ("rows", {"rows_held": 0, "rows_all": 8, "rows_programs": 0}),
+    ("scan", {"kernel": 0, "plain": 0, "programs": 0}),
+    ("delta", {"kernel": 0, "plain": 0, "programs": 0}),
+    ("conv", {"kernel": 0, "plain": 0, "programs": 0}),
+], ids=program_records.OPS)
+def test_the_step_program_leaves_its_records(wfbp_run, op, want):
+    program_records.holds(wfbp_run, op, want)
 
 
 def test_the_step_verifies_and_the_counters_add_no_collective():

@@ -5,14 +5,13 @@ with an exact-step resume of the new tree (stacked expert leaves, AdamW
 moments) continues bitwise. The equations themselves are held against the
 plain reference in tests/benchmark/test_mellum2_reference.py."""
 
-import os
-
 import jax
 import numpy as np
+import program_records
 import pytest
 
 from mgwfbp_tpu import train_cli
-from mgwfbp_tpu.telemetry.events import events_of, read_events
+from mgwfbp_tpu.telemetry.events import events_of
 from mgwfbp_tpu.train.trainer import Trainer
 from mgwfbp_tpu.utils.faults import Preempted
 
@@ -32,11 +31,6 @@ def build(tmp_path, name, *extra):
     return cfg, Trainer(
         cfg, profile_backward=not args.no_profile_backward,
         synthetic_data=True if args.synthetic else None)
-
-
-def stream(tmp_path, name, cfg):
-    return read_events(
-        os.path.join(str(tmp_path / name), cfg.tag(), "telemetry.jsonl"))
 
 
 def test_flags_reach_the_factory_and_the_optimizer(tmp_path, monkeypatch):
@@ -68,16 +62,25 @@ def test_flags_reach_the_factory_and_the_optimizer(tmp_path, monkeypatch):
         trainer.close()
 
 
-def test_fit_loss_falls_health_and_counters_every_step(tmp_path, monkeypatch):
-    monkeypatch.setenv("MGWFBP_SYNTH_TRAIN_N", "96")
-    monkeypatch.setenv("MGWFBP_SYNTH_VAL_N", "8")
-    cfg, trainer = build(tmp_path, "fit")
-    try:
-        trainer.fit(2)
-        assert trainer.iteration == 12
-    finally:
-        trainer.close()
-    records = stream(tmp_path, "fit", cfg)
+@pytest.fixture(scope="module")
+def fit_run(tmp_path_factory):
+    """The file's one training of two epochs with the telemetry on, for every
+    test that reads what it left (`program_records.read_run`)."""
+    tmp_path = tmp_path_factory.mktemp("fit")
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setenv("MGWFBP_SYNTH_TRAIN_N", "96")
+        patch.setenv("MGWFBP_SYNTH_VAL_N", "8")
+        cfg, trainer = build(tmp_path, "fit")
+        try:
+            trainer.fit(2)
+            assert trainer.iteration == 12
+        finally:
+            trainer.close()
+    return program_records.read_run(str(tmp_path / "fit"), cfg, trainer)
+
+
+def test_fit_loss_falls_health_and_counters_every_step(fit_run):
+    _, records, _ = fit_run
     steps = events_of(records, "step")
     health = {h["step"]: h for h in events_of(records, "health")}
     assert [s["step"] for s in steps] == list(range(1, 13))
@@ -97,6 +100,21 @@ def test_fit_loss_falls_health_and_counters_every_step(tmp_path, monkeypatch):
         assert s["moe_load_mean"] * 2 <= 2 * 64 * 2
     # the model's statistics never reach the log-facing metrics
     assert not [k for s in steps for k in s if k.startswith("health/")]
+
+
+@pytest.mark.parametrize("op,want", [
+    # four layers: a core each (none under a `jax.checkpoint`: each is
+    # traced), 3 grouped products and 2 permutations each (the held experts'
+    # part shares ONE cached trace)
+    ("attention", {"kernel": 0, "blocks": 4}),
+    ("experts", {"kernel": 0, "ragged": 12, "programs": 0}),
+    ("rows", {"rows_held": 0, "rows_all": 8, "rows_programs": 0}),
+    ("scan", {"kernel": 0, "plain": 0, "programs": 0}),
+    ("delta", {"kernel": 0, "plain": 0, "programs": 0}),
+    ("conv", {"kernel": 0, "plain": 0, "programs": 0}),
+], ids=program_records.OPS)
+def test_the_step_program_leaves_its_records(fit_run, op, want):
+    program_records.holds(fit_run, op, want)
 
 
 def test_exact_step_resume_of_the_new_tree_is_bitwise(tmp_path, monkeypatch):

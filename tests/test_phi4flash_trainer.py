@@ -11,11 +11,10 @@ backward, which the reverse order of the layers already is. The equations are
 held against the plain reference in
 tests/benchmark/test_phi4flash_reference.py."""
 
-import os
-
 import jax
 import jax.numpy as jnp
 import numpy as np
+import program_records
 import pytest
 from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
@@ -23,7 +22,7 @@ from jax.sharding import PartitionSpec as P
 from mgwfbp_tpu import train_cli
 from mgwfbp_tpu.models import create_model, parse_layers_held
 from mgwfbp_tpu.parallel.mesh import DATA_AXIS, MeshSpec, make_mesh
-from mgwfbp_tpu.telemetry.events import events_of, read_events
+from mgwfbp_tpu.telemetry.events import events_of
 from mgwfbp_tpu.train.step import make_loss_fn
 from mgwfbp_tpu.train.trainer import Trainer
 from mgwfbp_tpu.utils.faults import Preempted
@@ -139,11 +138,11 @@ def test_a_bare_n_is_the_program_it_was(name, n, share):
     assert lowered(modules[0]) == lowered(modules[2])
 
 
-@pytest.mark.parametrize("policy", ["mgwfbp", "wfbp"])
-def test_trains_with_counters_and_every_leaf_reduces_like_pmean(
-        tmp_path, monkeypatch, policy):
-    monkeypatch.setenv("MGWFBP_SYNTH_TRAIN_N", str(6 * 2 * WORLD))
-    monkeypatch.setenv("MGWFBP_SYNTH_VAL_N", "8")
+def trained(tmp_path, patch, policy):
+    """Two epochs under `policy`, after every leaf was seen to reduce like
+    `lax.pmean`'s: what `program_records.read_run` reads of them."""
+    patch.setenv("MGWFBP_SYNTH_TRAIN_N", str(6 * 2 * WORLD))
+    patch.setenv("MGWFBP_SYNTH_VAL_N", "8")
     cfg, trainer = build(tmp_path, policy, "--policy", policy)
     try:
         reducer = trainer.reducer
@@ -199,8 +198,23 @@ def test_trains_with_counters_and_every_leaf_reduces_like_pmean(
         assert trainer.iteration == 12
     finally:
         trainer.close()
-    records = read_events(os.path.join(
-        str(tmp_path / policy), cfg.tag(), "telemetry.jsonl"))
+    return program_records.read_run(str(tmp_path / policy), cfg, trainer)
+
+
+@pytest.fixture(scope="module")
+def wfbp_run(tmp_path_factory):
+    """The file's one training under `wfbp` with the telemetry on, for every
+    test that reads what it left."""
+    with pytest.MonkeyPatch.context() as patch:
+        return trained(tmp_path_factory.mktemp("wfbp"), patch, "wfbp")
+
+
+@pytest.mark.parametrize("policy", ["mgwfbp", "wfbp"])
+def test_trains_with_counters_and_every_leaf_reduces_like_pmean(
+        tmp_path, monkeypatch, request, policy):
+    _, records, _ = (
+        request.getfixturevalue("wfbp_run") if policy == "wfbp"
+        else trained(tmp_path, monkeypatch, policy))
     steps = events_of(records, "step")
     health = {h["step"]: h for h in events_of(records, "health")}
     assert [s["step"] for s in steps] == list(range(1, 13))
@@ -221,34 +235,22 @@ def test_trains_with_counters_and_every_leaf_reduces_like_pmean(
         assert 0.3 < s["diff_lambda_mean"] < 1.0
         assert "stats_ready" in s
     assert not [k for s in steps for k in s if k.startswith("health/")]
-    # (the count of cores on the `attention_program` record is held in
-    # tests/test_blockattn.py, where nothing has traced the layers before)
-    # the stage's two Mamba layers' scans, counted while the step was traced
-    # (the second policy's step finds the first's layers in jax's cache of
-    # traces, and counts them still): the chunked form on the CPU
-    assert trainer.train_step.scan_calls == {
-        "kernel": 0, "plain": 2, "programs": 0}
-    (program,) = events_of(records, "scan_program")
-    assert set(program) >= {"step", "kernel", "plain", "programs"}
-    assert (program["step"], program["kernel"], program["plain"],
-            program["programs"]) == (1, 0, 2, 0)
-    with open(os.path.join(
-            str(tmp_path / policy), cfg.tag(), "train.log")) as f:
-        assert ("scan: 0 selective scan(s) of the step through the kernels "
-                "with the state in VMEM (0 distinct kernel program(s)), 2 "
-                "through the chunked form") in f.read()
-    # and their two convolutions (ops/shortconv.py), likewise
-    (convs,) = events_of(records, "conv_program")
-    assert (convs["step"], convs["kernel"], convs["plain"],
-            convs["programs"]) == (1, 0, 2, 0)
-    import telemetry_report
 
-    report = telemetry_report.format_report(records)
-    assert ("; 0 selective scan(s) through the kernels with the state in "
-            "VMEM (0 distinct kernel program(s)), 2 through the chunked form"
-            ) in report
-    assert ("short convolution: 0 through the kernels of one pass (0 "
-            "distinct kernel program(s)), 2 through the plain form") in report
+
+@pytest.mark.parametrize("op,want", [
+    # one stacked core a layer (window, full, cross), the stage's two Mamba
+    # layers' scans and convolutions; every layer is a trace of its own (its
+    # index is a static argument), and where another test of this process
+    # traced it before, `counted` notes what that trace noted
+    ("attention", {"kernel": 0, "blocks": 3}),
+    ("experts", {"kernel": 0, "ragged": 0, "programs": 0}),
+    ("rows", {"rows_held": 0, "rows_all": 0, "rows_programs": 0}),
+    ("scan", {"kernel": 0, "plain": 2, "programs": 0}),
+    ("delta", {"kernel": 0, "plain": 0, "programs": 0}),
+    ("conv", {"kernel": 0, "plain": 2, "programs": 0}),
+], ids=program_records.OPS)
+def test_the_step_program_leaves_its_records(wfbp_run, op, want):
+    program_records.holds(wfbp_run, op, want)
 
 
 def test_exact_step_resume_is_bitwise(tmp_path, monkeypatch):

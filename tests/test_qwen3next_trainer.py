@@ -10,11 +10,10 @@ reduced like `lax.pmean`'s, it resumes from a checkpoint bitwise, the
 verifier finds the step clean. The equations are held against the plain
 reference in tests/benchmark/test_qwen3next_reference.py."""
 
-import os
-
 import jax
 import jax.numpy as jnp
 import numpy as np
+import program_records
 import pytest
 from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
@@ -22,7 +21,7 @@ from jax.sharding import PartitionSpec as P
 from mgwfbp_tpu import train_cli
 from mgwfbp_tpu.models import create_model
 from mgwfbp_tpu.parallel.mesh import DATA_AXIS, MeshSpec, make_mesh
-from mgwfbp_tpu.telemetry.events import events_of, read_events
+from mgwfbp_tpu.telemetry.events import events_of
 from mgwfbp_tpu.train.step import make_loss_fn
 from mgwfbp_tpu.train.trainer import Trainer
 from mgwfbp_tpu.utils.faults import Preempted
@@ -96,11 +95,11 @@ def test_preset_and_flags_reach_the_factory_and_the_optimizer(
         trainer.close()
 
 
-@pytest.mark.parametrize("policy", ["mgwfbp", "wfbp"])
-def test_trains_with_counters_and_every_leaf_reduces_like_pmean(
-        tmp_path, monkeypatch, policy):
-    monkeypatch.setenv("MGWFBP_SYNTH_TRAIN_N", str(6 * 2 * WORLD))
-    monkeypatch.setenv("MGWFBP_SYNTH_VAL_N", "8")
+def trained(tmp_path, patch, policy):
+    """Two epochs under `policy`, after every leaf was seen to reduce like
+    `lax.pmean`'s: what `program_records.read_run` reads of them."""
+    patch.setenv("MGWFBP_SYNTH_TRAIN_N", str(6 * 2 * WORLD))
+    patch.setenv("MGWFBP_SYNTH_VAL_N", "8")
     cfg, trainer = build(tmp_path, policy, "--policy", policy)
     try:
         reducer = trainer.reducer
@@ -147,20 +146,25 @@ def test_trains_with_counters_and_every_leaf_reduces_like_pmean(
 
         trainer.fit(2)
         assert trainer.iteration == 12
-        # the share's three delta rules, counted while the step was traced
-        # (three equal layers share ONE cached trace under jax.checkpoint,
-        # and the second policy's step finds it in jax's cache still): the
-        # plain chunked form, the only one there is
-        assert trainer.train_step.delta_calls == {
-            "kernel": 0, "plain": 3, "programs": 0}
-        # 3 grouped products and 2 permutations a layer, through every
-        # layer's cached or fresh trace
-        assert trainer.train_step.experts_calls["ragged"] == 12
-        assert trainer.train_step.experts_calls["rows_all"] == 8
     finally:
         trainer.close()
-    records = read_events(os.path.join(
-        str(tmp_path / policy), cfg.tag(), "telemetry.jsonl"))
+    return program_records.read_run(str(tmp_path / policy), cfg, trainer)
+
+
+@pytest.fixture(scope="module")
+def wfbp_run(tmp_path_factory):
+    """The file's one training under `wfbp` with the telemetry on, for every
+    test that reads what it left."""
+    with pytest.MonkeyPatch.context() as patch:
+        return trained(tmp_path_factory.mktemp("wfbp"), patch, "wfbp")
+
+
+@pytest.mark.parametrize("policy", ["mgwfbp", "wfbp"])
+def test_trains_with_counters_and_every_leaf_reduces_like_pmean(
+        tmp_path, monkeypatch, request, policy):
+    noted, records, _ = (
+        request.getfixturevalue("wfbp_run") if policy == "wfbp"
+        else trained(tmp_path, monkeypatch, policy))
     steps = events_of(records, "step")
     health = {h["step"]: h for h in events_of(records, "health")}
     assert [s["step"] for s in steps] == list(range(1, 13))
@@ -191,28 +195,34 @@ def test_trains_with_counters_and_every_leaf_reduces_like_pmean(
     assert with_counters[0]["delta_beta_mean"] == pytest.approx(0.5, abs=0.05)
     assert with_counters[0]["shared_gate_mean"] == pytest.approx(0.5, abs=0.02)
     assert not [k for s in steps for k in s if k.startswith("health/")]
-    (program,) = events_of(records, "delta_program")
-    assert (program["step"], program["kernel"], program["plain"],
-            program["programs"]) == (1, 0, 3, 0)
-    (scans,) = events_of(records, "scan_program")
-    assert (scans["kernel"], scans["plain"]) == (0, 0)
-    # the three Gated DeltaNet layers' convolutions (ops/shortconv.py)
-    (convs,) = events_of(records, "conv_program")
-    assert (convs["kernel"], convs["plain"], convs["programs"]) == (0, 3, 0)
-    with open(os.path.join(
-            str(tmp_path / policy), cfg.tag(), "train.log")) as f:
-        assert ("delta rule: 0 gated delta rule(s) of the step through a "
-                "kernel with the state in VMEM (0 distinct kernel "
-                "program(s)), 3 through the plain chunked form") in (
-                    log := f.read())
-    assert ("convolution: 0 short convolution(s) of the step through the "
-            "kernels of one pass (0 distinct kernel program(s)), 3 through "
-            "the plain form") in log
+    # the share's three delta rules, counted while the step was traced
+    # (three equal layers share ONE cached trace under jax.checkpoint, and
+    # the second policy's step finds it in jax's cache still): the plain
+    # chunked form, the only one there is; 3 grouped products and 2
+    # permutations a layer, through every layer's cached or fresh trace
+    # (the records of the `wfbp` run: the test below)
+    assert noted["delta"] == {"kernel": 0, "plain": 3, "programs": 0}
+    assert (noted["experts"]["ragged"], noted["rows"]["rows_all"]) == (12, 8)
     import telemetry_report
 
     report = telemetry_report.format_report(records)
     assert "expert routing" in report and "linear attention (" in report
     assert "3 through the plain chunked form" in report
+
+
+@pytest.mark.parametrize("op,want", [
+    # the first period: three Gated DeltaNet layers (ONE cached trace of the
+    # mixer's half, a delta rule and a convolution each) and a full layer's
+    # core; 3 grouped products and 2 permutations a layer's sparse half
+    ("attention", {"kernel": 0, "blocks": 1}),
+    ("experts", {"kernel": 0, "ragged": 12, "programs": 0}),
+    ("rows", {"rows_held": 0, "rows_all": 8, "rows_programs": 0}),
+    ("scan", {"kernel": 0, "plain": 0, "programs": 0}),
+    ("delta", {"kernel": 0, "plain": 3, "programs": 0}),
+    ("conv", {"kernel": 0, "plain": 3, "programs": 0}),
+], ids=program_records.OPS)
+def test_the_step_program_leaves_its_records(wfbp_run, op, want):
+    program_records.holds(wfbp_run, op, want)
 
 
 def test_exact_step_resume_is_bitwise(tmp_path, monkeypatch):
@@ -302,17 +312,17 @@ def test_the_older_language_presets_trace_no_delta_rule_and_no_new_scope(
     tiny presets' lowered programs name none of the new scopes and count no
     delta rule. (That their text equals the parent commit's was checked
     once, tree against tree: CHANGES.md, PR 40.)"""
-    from mgwfbp_tpu.ops import deltarule
+    from mgwfbp_tpu.ops import programs
 
     module, _ = create_model(name, **share)
     x = jnp.zeros((1, 64), jnp.int32)
     params = jax.eval_shape(
         lambda: module.init({"params": jax.random.PRNGKey(0)}, x))
-    before = deltarule.LOWERED.copy()
+    before = programs.LOWERED.copy()
     text = jax.jit(jax.grad(lambda p: jnp.mean(
         module.apply(p, x, targets=x, train=True)[0]))).lower(params).as_text(
             debug_info=True)
-    assert deltarule.lowered_since(before) == {
+    assert programs.lowered_since(before)["delta"] == {
         "kernel": 0, "plain": 0, "programs": 0}
     assert "gdn_" not in text and "triangular" not in text
     assert "lm_head" in text  # the scopes are in the text at all

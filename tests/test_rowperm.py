@@ -17,7 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from mgwfbp_tpu.ops import blockattn, groupmm, rowperm
+from mgwfbp_tpu.ops import groupmm, programs, rowperm
 
 Case = collections.namedtuple("Case", "n k d groups experts held dtype")
 EDGE = 1024  # the loop's block in the cases that stop at a block's edge
@@ -207,21 +207,21 @@ def experts_block(d, f, experts, k, n, dtype, seed=0):
 def held_experts_both_ways():
     """(through the kernel and the loop, through the plain gathers): value
     and gradients of one expert block, each way run once."""
-    from mgwfbp_tpu.models import mellum
+    from mgwfbp_tpu.models import lm_parts
 
     u, idx, weights, ws = experts_block(256, 128, 4, 8, 256, jnp.bfloat16)
 
     def loss(u, weights, ws):
-        y, sizes, dropped = mellum.held_experts(u, idx, weights, *ws, 0)
+        y, sizes, dropped = lm_parts.held_experts(u, idx, weights, *ws, 0)
         return jnp.sum(y.astype(jnp.float32) * jnp.cos(
             jnp.arange(y.size).reshape(y.shape))), (y, dropped)
 
     def run():
-        before = groupmm.LOWERED.copy()
+        before = programs.LOWERED.copy()
         (_, (y, dropped)), (d_u, d_weights, d_ws) = jax.value_and_grad(
             loss, argnums=(0, 1, 2), has_aux=True)(u, weights, ws)
         assert int(dropped) == 0
-        made = groupmm.lowered_since(before)
+        made = programs.lowered_since(before)["rows"]
         return {"y": y, "d_u": d_u, "d_weights": d_weights,
                 "d_w_down": d_ws[2]}, (
                     made["rows_held"], made["rows_all"], made["rows_programs"])
@@ -232,7 +232,7 @@ def held_experts_both_ways():
     with pytest.MonkeyPatch.context() as patch:
         # the choice as on a TPU, the kernel interpreted; the grouped
         # products stay `lax.ragged_dot`, as on both sides here
-        patch.setattr(blockattn, "traced_for_tpu", lambda: True)
+        patch.setattr(programs, "traced_for_tpu", lambda: True)
         patch.setattr(groupmm, "_kernel_tiles", lambda *a: None)
         patch.setattr(
             rowperm, "_combine_kernel",
@@ -259,9 +259,9 @@ def test_held_experts_through_the_kernel_as_through_the_plain_gathers(which):
 def traced_ways(fn, *args):
     """((moving only the rows held, moving all rows, kernel programs), the
     primitives of the traced program that tell the ways apart)."""
-    before = groupmm.LOWERED.copy()
+    before = programs.LOWERED.copy()
     text = str(jax.make_jaxpr(lambda *a: fn(*a))(*args))
-    made = groupmm.lowered_since(before)
+    made = programs.lowered_since(before)["rows"]
     way = ("pallas_call" if "pallas_call" in text
            else "while" if "while" in text else "gather")
     return (made["rows_held"], made["rows_all"], made["rows_programs"]), way
@@ -291,9 +291,9 @@ def test_falls_back_to_the_plain_gathers_off_the_tpu_and_on_a_shape_that_misfits
             jax.ShapeDtypeStruct((groups,), jnp.int32))
 
     plain = ((0, 1, 0), "gather")
-    assert not blockattn.traced_for_tpu()
+    assert not programs.traced_for_tpu()
     assert take(256, 8, 256) == combine(256, 8, 256) == plain
-    monkeypatch.setattr(blockattn, "traced_for_tpu", lambda: True)
+    monkeypatch.setattr(programs, "traced_for_tpu", lambda: True)
     # `take_rows` is XLA's gather there too; its transpose's kernel program
     # is traced with it (under `eval_shape`) and counted
     assert take(256, 8, 256) == ((0, 1, 1), "gather")

@@ -8,14 +8,14 @@ one block of positions and several, one strip of channels and several;
 bfloat16 inputs computed in float32 inside; a decay so strong that a factor
 1 / exp(L_s) would overflow; the states the forward kernel saves for the
 backward one; and the test of platform and shape that chooses between the
-two, with what `LOWERED` counts."""
+two, with what each call notes (`ops/programs.py`)."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from mgwfbp_tpu.ops import blockattn, selscan
+from mgwfbp_tpu.ops import programs, selscan
 from mgwfbp_tpu.ops.selscan import selective_scan
 
 
@@ -208,30 +208,30 @@ def test_a_shape_the_kernels_refuse_falls_to_the_chunked_form(monkeypatch):
     that are no whole lane tiles, go down the chunked form and are counted
     `plain`; a shape that fits is counted `kernel` with its two programs,
     once however many scans of that shape there are."""
-    monkeypatch.setattr(blockattn, "traced_for_tpu", lambda: True)
+    monkeypatch.setattr(programs, "traced_for_tpu", lambda: True)
     for t, d, n in ((29, 256, 8), (64, 100, 8), (64, 256, 4)):
         args = draws(t, t, bsz=1, d=d, n=n)
-        before = selscan.LOWERED.copy()
+        before = programs.LOWERED.copy()
         y, h = selective_scan(*args, chunk=8, block=2)
-        assert selscan.lowered_since(before) == {
+        assert programs.lowered_since(before)["scan"] == {
             "kernel": 0, "plain": 1, "programs": 0}
         want_y, want_h = literal(*args)
         np.testing.assert_allclose(y, want_y, rtol=2e-5, atol=2e-5)
         np.testing.assert_allclose(h, want_h, rtol=2e-5, atol=2e-5)
     # nothing runs: a kernel traced for a TPU cannot on the CPU
     args = draws(1, 2 * selscan._ROWS, bsz=1, d=256, n=8)
-    before = selscan.LOWERED.copy()
+    before = programs.LOWERED.copy()
     (y, h), _ = jax.eval_shape(
         lambda *v: (selective_scan(*v), selective_scan(*v)), *args)
     assert (y.shape, h.shape) == ((1, 2 * selscan._ROWS, 256), (1, 256, 8))
-    assert selscan.lowered_since(before) == {
+    assert programs.lowered_since(before)["scan"] == {
         "kernel": 2, "plain": 0, "programs": 2}
 
 
 def test_off_a_tpu_the_scan_is_the_chunked_form():
     args = draws(1, 2 * selscan._ROWS, bsz=1, d=256, n=8)
-    before = selscan.LOWERED.copy()
+    before = programs.LOWERED.copy()
     jaxpr = jax.make_jaxpr(lambda *v: selective_scan(*v))(*args)
     assert "pallas_call" not in str(jaxpr)
-    assert selscan.lowered_since(before) == {
+    assert programs.lowered_since(before)["scan"] == {
         "kernel": 0, "plain": 1, "programs": 0}

@@ -19,7 +19,7 @@ import pytest
 from jax.sharding import Mesh
 
 from mgwfbp_tpu import models as zoo
-from mgwfbp_tpu.ops import blockattn, shortconv
+from mgwfbp_tpu.ops import programs, shortconv
 from mgwfbp_tpu.parallel.mesh import DATA_AXIS
 from mgwfbp_tpu.train import create_train_state, make_train_step
 
@@ -124,7 +124,7 @@ def test_a_sequence_never_reads_the_one_before_it():
 def test_the_rule_refuses_what_the_kernels_do_not_take(monkeypatch):
     """C no whole number of lane tiles, a T the block does not divide, more
     taps than a sublane tile, a dtype that is neither: the plain form, and
-    `LOWERED` says so; off a TPU the plain form whatever the shape."""
+    the call's note says so; off a TPU the plain form whatever the shape."""
     bf16 = jnp.dtype(jnp.bfloat16)
     assert shortconv._kernel_tiles(8192, 8192, 4, bf16) == (ROWS, 2048)
     assert shortconv._kernel_tiles(8192, 4352, 4, bf16) == (ROWS, 2176)
@@ -137,16 +137,16 @@ def test_the_rule_refuses_what_the_kernels_do_not_take(monkeypatch):
 
     def went(t, c, k=4):
         x, w, bias, _ = _arguments(2, t, c, k, jnp.bfloat16, True)
-        before = shortconv.LOWERED.copy()
+        before = programs.LOWERED.copy()
         y = shortconv.causal_conv_silu(x, w, bias)
         np.testing.assert_array_equal(
             np.asarray(y, np.float32),
             np.asarray(shortconv.plain_conv_silu(x, w, bias), np.float32))
-        return shortconv.lowered_since(before)
+        return programs.lowered_since(before)["conv"]
 
     plain = {"kernel": 0, "plain": 1, "programs": 0}
     assert went(ROWS, 128) == plain  # traced for the CPU
-    monkeypatch.setattr(blockattn, "traced_for_tpu", lambda: True)
+    monkeypatch.setattr(programs, "traced_for_tpu", lambda: True)
     assert went(ROWS, 96) == plain
     assert went(ROWS - 12, 128) == plain
 
@@ -158,7 +158,7 @@ def test_the_rule_refuses_what_the_kernels_do_not_take(monkeypatch):
 ])
 def test_a_tiny_preset_counts_its_convolutions(name, share, convolutions):
     """The step of a tiny share traced for the CPU (lowered, not compiled):
-    `conv_program` (the step's `conv_calls`) reads 0 + n, a convolution a
+    `conv_program` (the step's `traced_programs`) reads 0 + n, a convolution a
     Mamba or Gated DeltaNet layer held. That the losses are the parent
     commit's to the last digit is held where a Trainer runs these presets
     anyway (tests/test_granite_trainer.py, test_phi4flash_trainer.py,
@@ -171,5 +171,5 @@ def test_a_tiny_preset_counts_its_convolutions(name, share, convolutions):
     mesh = Mesh(np.asarray(jax.devices()[:1]), (DATA_AXIS,))
     step = make_train_step(model, meta, tx, mesh, None, donate=False)
     step.lower(state, {"x": tokens[None], "y": tokens[None]})
-    assert step.conv_calls == {
+    assert step.traced_programs["conv"] == {
         "kernel": 0, "plain": convolutions, "programs": 0}

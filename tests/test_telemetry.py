@@ -11,6 +11,7 @@ import os
 
 import jax
 import numpy as np
+import program_records
 import pytest
 
 from mgwfbp_tpu.config import make_config
@@ -367,19 +368,26 @@ def _cfg(dnn="lenet", **kw):
     return make_config(dnn, **base)
 
 
-def test_trainer_smoke_emits_step_and_group_events(tmp_path):
-    """A lenet CPU-mesh run with telemetry on produces step spans, comm
-    spans, and an overlap snapshot — the acceptance path of ISSUE 4 —
-    with scalars (tensorboard view) in the SAME stream."""
+@pytest.fixture(scope="module")
+def smoke_run(tmp_path_factory):
+    """The trainer smoke's one lenet epoch, for every test that reads what it
+    left: (the closed trainer, `program_records.read_run`'s)."""
     from mgwfbp_tpu.train.trainer import Trainer
 
+    tmp_path = tmp_path_factory.mktemp("smoke")
     cfg = _cfg(logdir=str(tmp_path), telemetry=True, tensorboard=True,
                checkpoint_dir=str(tmp_path / "ckpt"))
     t = Trainer(cfg, synthetic_data=True, profile_backward=False)
     t.fit(1)
     t.close()
-    path = os.path.join(str(tmp_path), cfg.tag(), "telemetry.jsonl")
-    recs = read_events(path)
+    return t, program_records.read_run(str(tmp_path), cfg, t)
+
+
+def test_trainer_smoke_emits_step_and_group_events(smoke_run):
+    """A lenet CPU-mesh run with telemetry on produces step spans, comm
+    spans, and an overlap snapshot — the acceptance path of ISSUE 4 —
+    with scalars (tensorboard view) in the SAME stream."""
+    t, (_, recs, _) = smoke_run
     assert recs[0]["event"] == "header"
     steps = events_of(recs, "step")
     assert len(steps) == 6
@@ -406,6 +414,22 @@ def test_trainer_smoke_emits_step_and_group_events(tmp_path):
     assert "overlap efficiency" in report
     doc = chrome_trace(recs)
     assert any(e.get("ph") == "X" for e in doc["traceEvents"])
+
+
+@pytest.mark.parametrize("op,fields", [
+    ("attention", ("kernel", "blocks")),
+    ("experts", ("kernel", "ragged", "programs")),
+    ("rows", ("rows_held", "rows_all", "rows_programs")),
+    ("scan", ("kernel", "plain", "programs")),
+    ("delta", ("kernel", "plain", "programs")),
+    ("conv", ("kernel", "plain", "programs")),
+], ids=program_records.OPS)
+def test_a_model_that_calls_none_of_the_ops_records_them_at_nought(
+        smoke_run, op, fields):
+    """Every built step program leaves every `*_program` record: all 0 for a
+    model without attention, experts or a recurrence, and the report still
+    has the attention's and the experts' lines."""
+    program_records.holds(smoke_run[1], op, dict.fromkeys(fields, 0))
 
 
 def test_trainer_records_its_step_programs_collectives(tmp_path):

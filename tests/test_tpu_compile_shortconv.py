@@ -1,10 +1,9 @@
 """Ask the TPU's compiler about ops/shortconv.py's kernels, without a chip.
 
-As tests/test_tpu_compile.py (whose `topo` and `_compile_cache_off` these
-are, from tests/conftest.py): libtpu compiles for a described v5e, nothing
-runs, and a compile that passes says nothing about results or speed. In a
-file of its own so that `tests/test_tpu_compile.py`, the longest file of a
-worker under `--dist loadfile`, takes no new case.
+As the other tests/test_tpu_compile*.py (`topo` and `_compile_cache_off` are
+tests/conftest.py's): libtpu compiles for a described v5e, nothing runs, and
+a compile that passes says nothing about results or speed. One file a kind of
+program, so that under `--dist loadfile` no one worker carries them all.
 """
 
 import re
