@@ -35,7 +35,8 @@ class TrainConfig:
     num_steps: Optional[int] = None  # LM window length override (default 35;
     # seq-parallel transformers need num_steps % seq_parallel == 0)
     # the part of a model this chip holds (models that can be held in part:
-    # the mellum2, granite4h, laguna_xs2, phi4flash and qwen3next families).
+    # the mellum2, granite4h, laguna_xs2, phi4flash, qwen3next and xing4
+    # families).
     # None = all
     layers_held: Optional[str] = None  # "N" the first N layers, or
     # "FIRST:COUNT" a stage anywhere (models.parse_layers_held)
@@ -201,8 +202,10 @@ PRESETS: dict[str, dict] = {
     # state-space (models/granite.py), decoder-hybrid-decoder
     # (models/phi4flash.py), sparse with a dense first layer, a shared expert
     # and a head count by layer (models/laguna.py), and hybrid
-    # linear-attention sparse (models/qwen3next.py). `batch_size`: two
-    # sequences of 8,192 tokens a device and step, or one
+    # linear-attention sparse (models/qwen3next.py), and latent attention
+    # under four residual streams with bias-selected experts
+    # (models/xing4.py). `batch_size`: two sequences of 8,192 tokens a device
+    # and step, or one
     "mellum2": dict(_LM),
     "mellum2_tiny": dict(_LM_TINY),
     "granite4h": dict(_LM, batch_size=1),
@@ -213,6 +216,8 @@ PRESETS: dict[str, dict] = {
     "laguna_xs2_tiny": dict(_LM_TINY),
     "qwen3next": dict(_LM),
     "qwen3next_tiny": dict(_LM_TINY),
+    "xing4": dict(_LM, batch_size=1),
+    "xing4_tiny": dict(_LM_TINY),
     "fcn5net": dict(dataset="mnist", batch_size=64, lr=0.05, max_epochs=10),
     "lr": dict(dataset="mnist", batch_size=64, lr=0.01, max_epochs=10),
 }

@@ -341,8 +341,8 @@ def _lstman4(nc):
 
 def _held_lm_meta(name: str, nc: int, window_len: int) -> ModelMeta:
     """A decoder LM over the `tokens` dataset that takes its loss itself and
-    can be held in part (the mellum2, granite4h, laguna_xs2, phi4flash and
-    qwen3next families)."""
+    can be held in part (the mellum2, granite4h, laguna_xs2, phi4flash,
+    qwen3next and xing4 families)."""
     return ModelMeta(
         name=name, dataset="tokens", num_classes=nc,
         input_shape=(window_len,), input_dtype=jnp.int32, task="lm",
@@ -431,6 +431,12 @@ def _qwen3next(tiny: bool):
         qwen3next.QWEN3NEXT_TINY if tiny else qwen3next.QWEN3NEXT)
 
 
+def _xing4(tiny: bool):
+    from mgwfbp_tpu.models import xing4
+
+    return xing4.Xing4LM, xing4.XING4_TINY if tiny else xing4.XING4
+
+
 # each family at its published widths, and at a size the CPU tests hold:
 # mellum2 (hidden 64, 2 key/value heads, 8 experts top 2, window 16);
 # laguna_xs2 (hidden 64, 6 / 8 query heads over 2 key heads of 16, 16 experts
@@ -439,13 +445,18 @@ def _qwen3next(tiny: bool):
 # phi4flash (hidden 32, 4 / 2 heads of 8, window 16, state 4, eight layers:
 # every kind by the publisher's rule), whose stage may start anywhere;
 # qwen3next (hidden 32, 2 key / 4 value delta-rule heads of 8, 4 / 1 attention
-# heads of 16, 16 experts top 3 and a gated shared one, eight layers)
+# heads of 16, 16 experts top 3 and a gated shared one, eight layers);
+# xing4 (hidden 32, four residual streams, 4 latent-attention heads scoring
+# over 16 + 8 and summing values of 16, 8 experts top 2 chosen with a
+# selection bias and a shared one, four layers: two dense first), whose stage
+# may start anywhere
 for _name, _load, _experts, _first in (
         ("mellum2", _mellum2, True, False),
         ("laguna_xs2", _laguna_xs2, True, False),
         ("granite4h", _granite4h, False, False),
         ("phi4flash", _phi4flash, False, True),
-        ("qwen3next", _qwen3next, True, False)):
+        ("qwen3next", _qwen3next, True, False),
+        ("xing4", _xing4, True, True)):
     _register_held_lm(
         _name, functools.partial(_load, False), 8192, _experts, _first)
     _register_held_lm(

@@ -16,7 +16,7 @@ tiles) and cached. The tiles are constants chosen from the shape, fitted on a
 v5e from the trace by scope (PERF.md section 6, PR 31).
 
 **The plain blocks.** Everywhere else (the CPU, a T the tiles do not divide,
-a head size other than 64, 128 or 256), and as the kernel's reference: the
+a head size other than 64, 128, 192 or 256), and as the kernel's reference: the
 queries are cut into blocks of `block` positions and each block is scored against the
 one static slice of keys it can see:
 
@@ -85,7 +85,9 @@ _ALIGN = 128  # key ranges start on a lane-tile boundary
 # (my chip run, PR 40).
 _TILES_FULL = (1024, 512, True)
 _TILES_WINDOW = (512, 512, False)
-_KERNEL_HEAD_DIMS = (64, 128, 256)  # half a lane tile, one, and two
+# half a lane tile, one, one and a half (latent attention's score width: 128
+# + 64 rotary, beside values of 128) and two
+_KERNEL_HEAD_DIMS = (64, 128, 192, 256)
 
 
 def key_range(start: int, stop: int, window: Optional[int]) -> tuple[int, int]:
@@ -98,7 +100,8 @@ def key_range(start: int, stop: int, window: Optional[int]) -> tuple[int, int]:
 
 def _one_block(q, k, v, q_start: int, k_start: int, window: Optional[int],
                scale: float):
-    """q (B, Hkv, G, Tq, D) against k, v (B, Hkv, Tk, D): (B, Hkv, G, Tq, D).
+    """q (B, Hkv, G, Tq, D) against k (B, Hkv, Tk, D) and v (B, Hkv, Tk, Dv):
+    (B, Hkv, G, Tq, Dv).
     `q_start`, `k_start`: the global positions of the first query and key;
     `scale` multiplies the scores.
     A key head's G query heads are rows of ONE (G * Tq, D) x (D, Tk) product
@@ -132,11 +135,12 @@ def _one_block(q, k, v, q_start: int, k_start: int, window: Optional[int],
         preferred_element_type=jnp.float32,
     ) / jnp.sum(e, axis=-1, keepdims=True)
     out = out.astype(v.dtype)
-    return out.reshape(b, hkv, g, tq, d)
+    return out.reshape(b, hkv, g, tq, v.shape[-1])
 
 
 def _blocks(q, k, v, window: Optional[int], block: int, scale: float):
-    """The plain blocks: q (B, T, H, D), k and v (B, T, Hkv, D)."""
+    """The plain blocks: q (B, T, H, D), k (B, T, Hkv, D), v (B, T, Hkv,
+    Dv)."""
     b, t, h, d = q.shape
     hkv = k.shape[2]
     # heads before positions, once for all blocks
@@ -149,8 +153,8 @@ def _blocks(q, k, v, window: Optional[int], block: int, scale: float):
         lo, hi = key_range(start, stop, window)
         out.append(one(q[:, :, :, start:stop], k[:, :, lo:hi], v[:, :, lo:hi],
                        start, lo, window, scale))
-    out = jnp.concatenate(out, axis=3)  # (B, Hkv, G, T, D)
-    return out.transpose(0, 3, 1, 2, 4).reshape(b, t, h, d)
+    out = jnp.concatenate(out, axis=3)  # (B, Hkv, G, T, Dv)
+    return out.transpose(0, 3, 1, 2, 4).reshape(b, t, h, v.shape[-1])
 
 
 def _splash():
@@ -161,14 +165,17 @@ def _splash():
     return splash_attention
 
 
-def _kernel_tiles(t: int, d: int, window: Optional[int]):
+def _kernel_tiles(t: int, d: int, window: Optional[int],
+                  dv: Optional[int] = None):
     """The fused kernel's tiles (splash_attention's BlockSizes) for a call of
-    this shape, each cut to T where T is shorter, or None where the plain
+    this shape (`d` the width the scores sum over, `dv` the values' where it
+    is another), each cut to T where T is shorter, or None where the plain
     blocks stay: a head size the kernel has no tile for, or a T that its
     tiles do not divide."""
     block, compute, fused = _TILES_FULL if window is None else _TILES_WINDOW
     block, compute = min(block, t), min(compute, t)
-    if d not in _KERNEL_HEAD_DIMS or t % _ALIGN or t % block or block % compute:
+    if (d not in _KERNEL_HEAD_DIMS or (dv or d) not in _KERNEL_HEAD_DIMS
+            or t % _ALIGN or t % block or block % compute):
         return None
     return _splash().BlockSizes(
         block_q=block, block_kv=block, block_kv_compute=compute,
@@ -200,19 +207,20 @@ def _splash_kernel(t: int, heads: int, window: Optional[int], tiles,
 
 def _fused(q, k, v, window: Optional[int], scale: float, tiles,
            interpret: bool = False):
-    """The fused kernel: q (B, T, H, D), k and v (B, T, Hkv, D), `tiles` a
-    BlockSizes. `interpret` runs it without a TPU (the tests' way in)."""
+    """The fused kernel: q (B, T, H, D), k (B, T, Hkv, D), v (B, T, Hkv, Dv),
+    `tiles` a BlockSizes. `interpret` runs it without a TPU (the tests' way
+    in)."""
     b, t, h, d = q.shape
-    hkv = k.shape[2]
+    hkv, dv = k.shape[2], v.shape[3]
     kernel = _splash_kernel(t, b * h, window, tiles, interpret)
     # the kernel has no scale of its own: the scores' factor goes into q
     q = (q.astype(jnp.float32) * scale).astype(q.dtype)
     out = kernel(
         q.transpose(0, 2, 1, 3).reshape(b * h, t, d),
         k.transpose(0, 2, 1, 3).reshape(b * hkv, t, d),
-        v.transpose(0, 2, 1, 3).reshape(b * hkv, t, d),
+        v.transpose(0, 2, 1, 3).reshape(b * hkv, t, dv),
     )
-    return out.reshape(b, h, t, d).transpose(0, 2, 1, 3)
+    return out.reshape(b, h, t, dv).transpose(0, 2, 1, 3)
 
 
 def blockwise_attention(
@@ -220,9 +228,11 @@ def blockwise_attention(
     window: Optional[int] = None, block: int = 512,
     scale: Optional[float] = None,
 ) -> jax.Array:
-    """Causal (optionally windowed) attention. q: (B, T, H, D); k, v:
-    (B, T, Hkv, D) with H a multiple of Hkv, query head i served by key head
-    i // (H / Hkv). Returns (B, T, H, D) in q's dtype. `scale` multiplies the
+    """Causal (optionally windowed) attention. q: (B, T, H, D); k: (B, T,
+    Hkv, D); v: (B, T, Hkv, Dv), Dv = D unless the model sums values of
+    another width than it scores over (latent attention: 192 and 128), with H
+    a multiple of Hkv, query head i served by key head i // (H / Hkv).
+    Returns (B, T, H, Dv) in v's dtype. `scale` multiplies the
     scores before the softmax: 1 / sqrt(D) unless a model publishes its own.
     `block` is the plain blocks' query block (T need not be a multiple of it:
     the last block is shorter); the fused kernel has its own tiles."""
@@ -231,7 +241,8 @@ def blockwise_attention(
         raise ValueError(
             f"{h} query heads do not divide over {k.shape[2]} key heads")
     scale = 1.0 / math.sqrt(d) if scale is None else float(scale)
-    tiles = _kernel_tiles(t, d, window) if programs.traced_for_tpu() else None
+    tiles = (_kernel_tiles(t, d, window, v.shape[3])
+             if programs.traced_for_tpu() else None)
     if tiles is None:
         programs.note("attention", "blocks")
         return _blocks(q, k, v, window, block, scale)

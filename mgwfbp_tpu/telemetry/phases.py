@@ -69,7 +69,16 @@ position, mean over the Mamba-1 layers held; `gmu_gate_rms`, that of the
 gated memory m . silu(u W_g), mean over the GMU layers held (0: the memory
 is not wired); `diff_lambda_mean`, the differential attention's lam, mean
 over the attention and cross layers held; read by the reader files of the
-same names and by `tools/telemetry_report.py`.
+same names and by `tools/telemetry_report.py`. models/xing4.py:
+`mhc_res_gap`, the largest |row or column sum of H_res - 1| over a step's
+tokens (how far twenty Sinkhorn iterations leave the residual mapping from
+doubly stochastic), and `mhc_res_offdiag`, the mass of a row of H_res off its
+diagonal (0 the plain residual, 0.75 four streams fully mixed), both means
+over the sub-layers held; `mla_kv_latent_rms`, the root mean square of latent
+attention's c_kv before its norm, mean over the layers held;
+`moe_bias_swap_share`, the share of the tokens' expert choices that score +
+selection bias made and the score alone would not have, mean over the sparse
+layers held; read likewise.
 
 The host runs about one step ahead of the chip: no span after the dispatch
 of step k needs step k itself. What stops it is the first read of step

@@ -106,13 +106,15 @@ def build_parser() -> argparse.ArgumentParser:
                         "in part, starting at layer FIRST: one pipeline "
                         "stage's share. A bare N is 0:N, the first N "
                         "(mellum2, granite4h, laguna_xs2, qwen3next take no "
-                        "other FIRST; phi4flash's stage may start anywhere and "
-                        "its layers keep their published indices)")
+                        "other FIRST; phi4flash's and xing4's stage may start "
+                        "anywhere and its layers keep their published "
+                        "indices)")
     p.add_argument("--experts-held", dest="experts_held", default=None,
                    metavar="FIRST:COUNT",
                    help="hold only COUNT experts of every sparse layer, "
                         "starting at expert FIRST (mellum2, laguna_xs2, "
-                        "qwen3next): one chip's share of an expert-parallel "
+                        "qwen3next, xing4): one chip's share of an "
+                        "expert-parallel "
                         "group. The router still scores all experts; what "
                         "the absent ones "
                         "would add is left out")

@@ -149,18 +149,105 @@ def test_the_kernel_object_is_built_once_a_shape_and_outside_the_trace():
                for x in jax.tree_util.tree_leaves(kernel))
 
 
+@pytest.mark.parametrize("d,dv,way", [
+    (192, 128, "blocks"), (192, 128, "kernel"), (24, 16, "blocks"),
+    (128, 128, "blocks"), (128, 128, "kernel"),
+], ids=["latent-192-128-blocks", "latent-192-128-kernel", "tiny-24-16-blocks",
+        "equal-128-blocks", "equal-128-kernel"])
+def test_a_value_width_other_than_the_score_width_against_the_plain_softmax(
+        d, dv, way):
+    """Latent attention scores over 128 + 64 rotary and sums values of 128:
+    the entry point's plain blocks (the last block shorter) and the fused
+    kernel in `interpret` mode, value and the three gradients against the
+    softmax written out, float32; the output has the VALUES' width. At equal
+    widths the same calls are what they were."""
+    keys = jax.random.split(jax.random.PRNGKey(7), 4)
+    t = 256 if way == "kernel" else 200
+    q = 2.0 * jax.random.normal(keys[0], (1, t, 4, d))
+    k = jax.random.normal(keys[1], (1, t, 4, d))
+    v = jax.random.normal(keys[2], (1, t, 4, dv))
+    w = jax.random.normal(keys[3], (1, t, 4, dv))
+    scale = 0.1446796 if d == 192 else d ** -0.5
+
+    def through(fn):
+        return jax.jit(jax.value_and_grad(
+            lambda q, k, v: jnp.sum(jnp.square(fn(q, k, v)) * w),
+            argnums=(0, 1, 2)))
+
+    if way == "kernel":
+        tiles = blockattn._kernel_tiles(t, d, None, dv)
+        assert tiles is not None and tiles.block_q == t
+
+        def fn(q, k, v):
+            return blockattn._fused(q, k, v, None, scale, tiles,
+                                    interpret=True)
+    else:
+        def fn(q, k, v):
+            return blockwise_attention(q, k, v, block=96, scale=scale)
+    assert jax.eval_shape(fn, q, k, v).shape == (1, t, 4, dv)
+    got, got_grads = through(fn)(q, k, v)
+    want, want_grads = through(
+        lambda q, k, v: dense_attention(q, k, v, scale))(q, k, v)
+    assert abs(float(got) - float(want)) < 2e-5 * abs(float(want))
+    for g, wg in zip(got_grads, want_grads):
+        assert g.shape == wg.shape
+        assert float(jnp.linalg.norm(g - wg) / jnp.linalg.norm(wg)) < 1e-5
+
+
+def test_at_equal_widths_the_traced_program_is_the_one_width_programs():
+    """The plain blocks at D = Dv = 64 trace to the text they traced to when
+    the core took one head size: no pad, no slice of the values, and the same
+    equations as a copy of that one-width block written out here."""
+    q = jnp.ones((1, 160, 4, 64))
+    kv = jnp.ones((1, 160, 2, 64))
+
+    def one_width_block(q, k, v, q_start, k_start, scale):
+        b, hkv, g, tq, d = q.shape
+        s = jnp.einsum(
+            "bhmd,bhkd->bhmk", q.reshape(b, hkv, g * tq, d), k,
+            preferred_element_type=jnp.float32) * scale
+        qi = q_start + jnp.arange(tq)[:, None]
+        kj = k_start + jnp.arange(k.shape[2])[None, :]
+        s = jnp.where(jnp.tile(kj <= qi, (g, 1)), s, blockattn._NEG_INF)
+        m = jax.lax.optimization_barrier(
+            jax.lax.stop_gradient(jnp.max(s, axis=-1, keepdims=True)))
+        e = jnp.exp(s - m)
+        out = jnp.einsum(
+            "bhmk,bhkd->bhmd", e.astype(v.dtype), v,
+            preferred_element_type=jnp.float32,
+        ) / jnp.sum(e, axis=-1, keepdims=True)
+        return out.astype(v.dtype).reshape(b, hkv, g, tq, d)
+
+    got = str(jax.make_jaxpr(lambda q, k, v: blockattn._one_block(
+        q, k, v, 64, 0, None, 0.125))(
+            jnp.ones((1, 2, 2, 96, 64)), kv.transpose(0, 2, 1, 3),
+            kv.transpose(0, 2, 1, 3)))
+    want = str(jax.make_jaxpr(lambda q, k, v: one_width_block(
+        q, k, v, 64, 0, 0.125))(
+            jnp.ones((1, 2, 2, 96, 64)), kv.transpose(0, 2, 1, 3),
+            kv.transpose(0, 2, 1, 3)))
+    assert got == want
+    whole = str(jax.make_jaxpr(
+        lambda q, k, v: blockwise_attention(q, k, v, block=96))(q, kv, kv))
+    assert "pad" not in whole and "f32[1,160,4,64]" in whole
+
+
 @pytest.mark.parametrize("t,d,window,fits", [
     (8192, 128, None, True), (8192, 64, None, True), (8192, 128, 1024, True),
     (8192, 128, 512, True),
     (512, 64, None, True), (8192 + 512, 128, None, False),
     (640, 64, None, False), (40, 64, None, False), (8192, 96, None, False),
     (8192, 256, None, True), (8192, 512, None, False),
+    (8192, (192, 128), None, True), (8192, (192, 96), None, False),
+    (8192, (320, 128), None, False),
 ], ids=["mellum2-full", "granite", "mellum2-window", "laguna-window-512",
         "short", "tiles-overhang",
         "five-lane-tiles", "no-lane-tile", "head-96", "qwen3next-head-256",
-        "head-512"])
+        "head-512", "xing4-scores-192-values-128", "values-96",
+        "scores-320"])
 def test_shape_test_of_the_kernel(t, d, window, fits):
-    tiles = blockattn._kernel_tiles(t, d, window)
+    d, dv = d if isinstance(d, tuple) else (d, None)
+    tiles = blockattn._kernel_tiles(t, d, window, dv)
     assert (tiles is not None) == fits
     if fits:
         sizes = [size for name, size in dataclasses.asdict(tiles).items()
@@ -204,12 +291,13 @@ def test_falls_back_to_the_blocks_off_the_tpu_and_on_a_shape_that_misfits(
     ("mellum2", {"kernel": 4, "blocks": 0}),
     ("granite4h", {"kernel": 1, "blocks": 0}),
     ("laguna_xs2", {"kernel": 5, "blocks": 0}),
+    ("xing4", {"kernel": 3, "blocks": 0}),
 ])
 def test_traced_as_for_a_tpu_the_models_cores_go_where_the_shape_test_sends_them(
         monkeypatch, model, want):
     """The tiny models at a head size the kernel has tiles for, T 256: the
     count a step program would record on the chip."""
-    from mgwfbp_tpu.models import granite, laguna, mellum
+    from mgwfbp_tpu.models import granite, laguna, mellum, xing4
 
     monkeypatch.setattr(programs, "traced_for_tpu", lambda: True)
     if model == "laguna_xs2":
@@ -218,6 +306,14 @@ def test_traced_as_for_a_tpu_the_models_cores_go_where_the_shape_test_sends_them
         module = laguna.LagunaLM(
             vocab_size=256, experts_held=(0, 2), shape=dataclasses.replace(
                 laguna.LAGUNA_XS2_TINY, head_dim=64, sliding_window=128))
+    elif model == "xing4":
+        # scores over 128 + 64, values of 128; three layers' cores, each
+        # sub-layer's cached trace counted again where it is replayed
+        module = xing4.Xing4LM(
+            vocab_size=256, layers_held=(1, 3), experts_held=(0, 2),
+            shape=dataclasses.replace(
+                xing4.XING4_TINY, qk_nope_head_dim=128, qk_rope_head_dim=64,
+                v_head_dim=128))
     elif model == "mellum2":
         module = mellum.Mellum2LM(
             vocab_size=256, experts_held=(0, 2), shape=dataclasses.replace(

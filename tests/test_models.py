@@ -25,7 +25,7 @@ ALL_IMAGE_MODELS = [
     if n not in ("lstm", "lstman4", "transformer", "mellum2", "mellum2_tiny",
                  "granite4h", "granite4h_tiny", "laguna_xs2",
                  "laguna_xs2_tiny", "phi4flash", "phi4flash_tiny",
-                 "qwen3next", "qwen3next_tiny")
+                 "qwen3next", "qwen3next_tiny", "xing4", "xing4_tiny")
 ]
 
 
@@ -209,6 +209,50 @@ def test_qwen3next_traces_and_counts_its_parameters(name, share, want, layers):
         assert stats["health/delta_beta"].shape == (6,)
         assert stats["health/shared_gate"].shape == (8,)
         assert stats["health/moe_tokens"].shape == (8, 4)
+
+
+@pytest.mark.parametrize("name,share,want,layers", [
+    # the whole model: two dense layers, 38 sparse ones of 64 experts, the
+    # untied vocabulary: the published 29.5 B less the multi-token-prediction
+    # module
+    ("xing4", {}, 29_505_505_264, tuple(range(40))),
+    # one chip's share: layers 1 to 5 of the stage behind the embedding, 8 of
+    # 64 experts, an eighth of the ids
+    ("xing4", dict(num_classes=16384, layers_held="1:5",
+                   experts_held=(0, 8)), 759_346_446, (1, 2, 3, 4, 5)),
+    ("xing4_tiny", dict(layers_held=(1, 3), experts_held=(2, 4)), None,
+     (1, 2, 3)),
+])
+def test_xing4_traces_and_counts_its_parameters(name, share, want, layers):
+    model, meta = zoo.create_model(name, **share)
+    assert meta.task == "lm" and not meta.has_carry and meta.fused_loss
+    assert meta.dataset == "tokens"
+    x = _example_input(meta)
+    variables = jax.eval_shape(
+        lambda: model.init({"params": jax.random.PRNGKey(0)}, x, train=False))
+    params = variables["params"]
+    if want is not None:
+        assert sum(int(np.prod(leaf.shape))
+                   for leaf in jax.tree_util.tree_leaves(params)) == want
+    # a stage's layers keep their published numbers
+    assert {k for k in params if k.startswith("layer_")} \
+        == {f"layer_{i}" for i in layers}
+    assert set(params["out"]) == {"norm", "head"}  # untied
+    # the leading layers are dense, every later one sparse
+    first_sparse = 2
+    assert [i for i in layers if "router_bias" in params[f"layer_{i}"]] \
+        == [i for i in layers if i >= first_sparse]
+    if name == "xing4_tiny":
+        logits = jax.eval_shape(lambda v: model.apply(v, x), variables)
+        assert logits.shape == (2, 64, meta.num_classes)
+        per_token, stats = jax.eval_shape(
+            lambda v: model.apply(v, x, targets=x, train=True), variables)
+        assert per_token.shape == (2, 64)
+        assert stats["health/mhc_res_gap"].shape == (6,)  # two a layer
+        assert stats["health/mhc_res_offdiag"].shape == (6,)
+        assert stats["health/mla_kv_latent_rms"].shape == (3,)
+        assert stats["health/moe_bias_swap"].shape == (2,)  # sparse layers
+        assert stats["health/moe_tokens"].shape == (2, 4)
 
 
 @pytest.mark.parametrize(

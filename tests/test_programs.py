@@ -25,7 +25,8 @@ from mgwfbp_tpu.ops import (
 )
 
 PACKAGE = pathlib.Path(mgwfbp_tpu.__file__).parent
-DECODERS = ("mellum", "granite", "laguna", "phi4flash", "qwen3next")
+DECODERS = ("mellum", "granite", "laguna", "phi4flash", "qwen3next",
+            "xing4")
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 
 

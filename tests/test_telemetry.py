@@ -325,6 +325,43 @@ def test_report_says_the_linear_attention_counters_and_the_delta_program():
         [header, program, step(1, sel_scan_state_rms=0.1)])
 
 
+def test_report_says_the_residual_streams_counters():
+    """`mhc_res_gap`, `mhc_res_offdiag`, `mla_kv_latent_rms`,
+    `moe_bias_swap_share` of the `step` records (models/xing4.py, by the
+    health drain's road) on a line of their own under the phase table, beside
+    the routing line every `held_experts` model has; a stream without them no
+    such line, and no other family's line is theirs."""
+    import telemetry_report
+
+    def step(i, **counters):
+        return {"event": "step", "step": i, "epoch": 0, "start_s": float(i),
+                "dur_s": 0.1, "phases": {"guard": [i + 0.2, 0.1]}, **counters}
+
+    header = {"event": "header", "schema_version": 2, "wall": 0.0}
+    routing = dict(moe_here=0.125, moe_load_max=600.0, moe_load_mean=512.0,
+                   moe_dropped=0.0)
+    report = telemetry_report.format_report([
+        header, step(1),
+        step(2, mhc_res_gap=2e-5, mhc_res_offdiag=0.30,
+             mla_kv_latent_rms=1.0, moe_bias_swap_share=0.10, **routing),
+        step(3, mhc_res_gap=4e-5, mhc_res_offdiag=0.32,
+             mla_kv_latent_rms=1.2, moe_bias_swap_share=0.20, **routing)])
+    assert ("residual streams (2 steps): H_res's largest row or column sum "
+            "off one 3e-05; H_res's mass off its diagonal 0.31; latent "
+            "c_kv's rms 1.1; share of choices the selection bias made 0.15"
+            ) in report
+    assert "expert routing (2 steps)" in report
+    assert "hybrid decoder" not in report and "linear attention" not in report
+    # a dense stage has the streams' and the latent's counters alone
+    dense = telemetry_report.format_report([
+        header, step(1, mhc_res_gap=2e-5, mhc_res_offdiag=0.3,
+                     mla_kv_latent_rms=1.0)])
+    assert "residual streams (1 steps)" in dense
+    assert "selection bias" not in dense and "expert routing" not in dense
+    assert "residual streams" not in telemetry_report.format_report(
+        [header, step(1, delta_state_rms=0.1)])
+
+
 def test_report_says_the_way_the_short_convolutions_went():
     """The newest `conv_program` record (ops/shortconv.py) under the phase
     table, whatever the model family; no line where it counts none (the

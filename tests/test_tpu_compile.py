@@ -268,3 +268,35 @@ def test_qwen3next_step_counts_three_rules_through_three_programs(
     assert step.traced_programs["delta"] == {
         "kernel": 3, "plain": 0, "programs": 3}
     assert "gated_delta_rule_backward" in text
+
+
+def test_xing4_step_counts_five_cores_through_the_kernel(topo, monkeypatch):
+    """The Xing4.0 cell's step (layers 1 to 5, 8 of 64 experts, 16,384 ids,
+    one sequence of 8,192) traced and lowered for the described chip (said so
+    by the test: `traced_for_tpu` asks the default backend, which is the CPU
+    here; nothing is compiled: the core at scores of 192 and values of 128 is
+    asked in tests/test_tpu_compile_attention.py, and the whole step takes
+    the chip's compiler a minute and a half): `attention_program` reads 5 +
+    0, the four sparse layers' twelve grouped products go through the tiled
+    kernel, and 759,346,446 parameters are held."""
+    from mgwfbp_tpu.ops import programs
+
+    monkeypatch.setattr(programs, "traced_for_tpu", lambda: True)
+    mesh = Mesh(np.asarray(topo.devices[:1]), (DATA_AXIS,))
+    model, meta = zoo.create_model(
+        "xing4", num_classes=16384, layers_held="1:5", experts_held=(0, 8))
+    tx = _imagenet_sgd()
+    state, batch = _abstract_step_args(model, meta, tx, mesh, 1)
+    assert sum(int(np.prod(leaf.shape)) for leaf in
+               jax.tree_util.tree_leaves(state.params)) == 759346446
+    batch["y"] = jax.ShapeDtypeStruct(
+        batch["x"].shape, jnp.int32, sharding=batch["x"].sharding)
+    step = make_train_step(
+        model, meta, tx, mesh, None, compute_dtype=jnp.bfloat16, donate=True)
+    text = step.lower(state, batch).as_text()
+    assert step.traced_programs["attention"] == {"kernel": 5, "blocks": 0}
+    assert step.traced_programs["experts"]["kernel"] == 12
+    assert step.traced_programs["experts"]["ragged"] == 0
+    # the streams between the sub-layers are bf16, four times the hidden size
+    assert "4x1x8192x3584xbf16" in text
+    assert "4x1x8192x3584xf32" not in text.split("func.func")[1][:2000]

@@ -20,6 +20,7 @@ pytestmark = pytest.mark.usefixtures("_compile_cache_off")
 
 
 @pytest.mark.parametrize("b,h,hkv,d,window,scale", [
+    (1, 32, 32, (192, 128), None, 1.0),
     (2, 32, 4, 128, None, 1.0),
     (2, 32, 4, 128, 1024, 1.0),
     (1, 32, 8, 64, None, 1.0 / 64),
@@ -28,23 +29,27 @@ pytestmark = pytest.mark.usefixtures("_compile_cache_off")
     (1, 80, 40, 64, None, 0.125),
     (1, 80, 40, 64, 512, 0.125),
     (2, 16, 2, 256, None, 1.0),
-], ids=["mellum2-full", "mellum2-window-1024", "granite4h",
+], ids=["xing4-latent-scores-192-values-128",
+        "mellum2-full", "mellum2-window-1024", "granite4h",
         "laguna-xs2-window-512-64-heads", "laguna-xs2-full-48-heads-groups-of-6",
         "phi4flash-full-80-stacked-heads", "phi4flash-window-512-80-stacked-heads",
         "qwen3next-full-head-256-groups-of-8"])
 def test_attention_core_compiles_for_v5e_at_the_cells_shapes(
         topo, b, h, hkv, d, window, scale):
-    """ops/blockattn.py's fused kernel, forward and backward, at the eight
-    call shapes of the language cells (T 8,192, bf16) and the tiles the shape
-    test gives them: the tiles fit VMEM and the backward compiles. The kernel
-    path is called outright: this process traces for the CPU."""
+    """ops/blockattn.py's fused kernel, forward and backward, at the nine
+    call shapes of the language cells (T 8,192, bf16; the newest scores over
+    192 and sums values of 128) and the tiles the shape test gives them: the
+    tiles fit VMEM and the backward compiles. The kernel path is called
+    outright: this process traces for the CPU."""
     from mgwfbp_tpu.ops import blockattn
 
     t = 8192
+    d, dv = d if isinstance(d, tuple) else (d, d)
     one = SingleDeviceSharding(topo.devices[0])
     q = jax.ShapeDtypeStruct((b, t, h, d), jnp.bfloat16, sharding=one)
     kv = jax.ShapeDtypeStruct((b, t, hkv, d), jnp.bfloat16, sharding=one)
-    tiles = blockattn._kernel_tiles(t, d, window)
+    v = jax.ShapeDtypeStruct((b, t, hkv, dv), jnp.bfloat16, sharding=one)
+    tiles = blockattn._kernel_tiles(t, d, window, dv)
     assert tiles is not None
 
     def loss(q, k, v):
@@ -52,7 +57,7 @@ def test_attention_core_compiles_for_v5e_at_the_cells_shapes(
         return jnp.sum(out.astype(jnp.float32))
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
-        q, kv, kv).compile().as_text()
+        q, kv, v).compile().as_text()
     # forward, dk/dv and dq (or the two of a fused backward)
     assert text.count("tpu_custom_call") >= 2
     # no float32 array of a whole head's scores leaves the kernel

@@ -253,3 +253,40 @@ def test_the_selective_scans_kernels_land_in_its_scope():
     assert totals == {("ssm_sel_scan", "forward"): 17,
                       ("ssm_sel_scan", "backward"): 54}
     assert top[0] == (36, "selective_scan_backward.4", "ssm_sel_scan")
+
+
+XING4_SCOPES = [
+    "mhc_map", "mhc_mix", "mla_q_proj", "mla_kv_proj", "attn_full",
+    "mla_out_proj", "mlp", "moe_route", "moe_shared", "moe_experts",
+    "lm_head", "loss",
+]
+
+
+@pytest.mark.parametrize("op_name,want", [
+    # the scopes of a decoder under several residual streams
+    # (models/xing4.py): every sub-layer is under jax.checkpoint
+    ("jit(step)/jvp(Xing4LM)/checkpoint/mhc_map/dot_general",
+     ("mhc_map", "forward")),
+    ("jit(step)/transpose(jvp(Xing4LM))/checkpoint/rematted_computation/"
+     "mhc_map/div", ("mhc_map", "backward")),
+    ("jit(step)/jvp(Xing4LM)/checkpoint/mhc_mix/reduce_sum",
+     ("mhc_mix", "forward")),
+    ("jit(step)/transpose(jvp(Xing4LM))/checkpoint/mhc_mix/mul",
+     ("mhc_mix", "backward")),
+    ("jit(step)/jvp(Xing4LM)/mhc_mix/reduce_sum", ("mhc_mix", "forward")),
+    ("jit(step)/jvp(Xing4LM)/checkpoint/mla_q_proj/dot_general",
+     ("mla_q_proj", "forward")),
+    ("jit(step)/jvp(Xing4LM)/checkpoint/mla_kv_proj/concatenate",
+     ("mla_kv_proj", "forward")),
+    ("jit(step)/transpose(jvp(Xing4LM))/checkpoint/attn_full/pallas_call",
+     ("attn_full", "backward")),
+    ("jit(step)/transpose(jvp(Xing4LM))/checkpoint/mla_out_proj/dot_general",
+     ("mla_out_proj", "backward")),
+    ("jit(step)/jvp(Xing4LM)/checkpoint/moe_route/top_k",
+     ("moe_route", "forward")),
+    # a sub-layer's function name is no scope
+    ("jit(step)/jvp(Xing4LM)/checkpoint/mlp_half/x",
+     ("(model, no scope)", "forward")),
+])
+def test_xing4_scopes_classify(op_name, want):
+    assert trace_scopes.classify(op_name, XING4_SCOPES) == want

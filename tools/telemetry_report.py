@@ -79,7 +79,10 @@ def _phase_section(steps: list[dict], scans: list[dict] = (),
     which the newest built step program's is said), and a hybrid
     linear-attention decoder's (`delta_state_rms`, `delta_beta_mean`,
     `shared_gate_mean`) with the way its delta rules did (`deltas`: the
-    `delta_program` records), and the way the short convolutions of any of
+    `delta_program` records), and a decoder's with several residual streams,
+    latent attention and a selection bias (`mhc_res_gap`, `mhc_res_offdiag`,
+    `mla_kv_latent_rms`, `moe_bias_swap_share`), and the way the short
+    convolutions of any of
     them did (`convs`: the `conv_program` records; no line where the newest
     counts none)."""
     from mgwfbp_tpu.telemetry.phases import PHASES
@@ -150,7 +153,8 @@ def _phase_section(steps: list[dict], scans: list[dict] = (),
     # it, then the way its recurrences went down, off the newest record of
     # the built step program (`scans`, `deltas`): a decoder-hybrid-decoder's
     # (models/phi4flash.py), a hybrid linear-attention decoder's
-    # (models/qwen3next.py)
+    # (models/qwen3next.py), a decoder's under several residual streams
+    # (models/xing4.py: no recurrence, so no such record)
     for title, counters, programs, kernel, plain in (
             ("hybrid decoder", (
                 ("sel_scan_state_rms", "selective scan's final state rms"),
@@ -162,7 +166,13 @@ def _phase_section(steps: list[dict], scans: list[dict] = (),
                 ("delta_beta_mean", "write gate beta"),
                 ("shared_gate_mean", "shared expert's gate")), deltas,
              "gated delta rule(s) through a kernel",
-             "the plain chunked form")):
+             "the plain chunked form"),
+            ("residual streams", (
+                ("mhc_res_gap", "H_res's largest row or column sum off one"),
+                ("mhc_res_offdiag", "H_res's mass off its diagonal"),
+                ("mla_kv_latent_rms", "latent c_kv's rms"),
+                ("moe_bias_swap_share",
+                 "share of choices the selection bias made")), (), "", "")):
         values = {said: [s[name] for s in steps if name in s]
                   for name, said in counters}
         if not any(values.values()):

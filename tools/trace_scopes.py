@@ -13,6 +13,8 @@ are ssm_in_proj, ssm_conv, ssm_dt_proj, ssm_sel_scan, ssm_out_proj, gmu,
 attn_proj, attn_window, attn_full, attn_cross, attn_diff, mlp, lm_head,
 loss; models/qwen3next.py's are gdn_in_proj, gdn_conv, gdn_delta,
 gdn_gate_norm, gdn_out_proj, attn_proj, attn_full, attn_gate, moe_route,
+moe_shared, moe_experts, lm_head, loss; models/xing4.py's are mhc_map,
+mhc_mix, mla_q_proj, mla_kv_proj, attn_full, mla_out_proj, mlp, moe_route,
 moe_shared, moe_experts, lm_head, loss.)
 
 A TPU trace names each event of the `XLA Ops` line after the HLO instruction
