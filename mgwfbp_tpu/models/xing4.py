@@ -58,11 +58,13 @@ multi-token-prediction module (the model's last layer) is not built.
 **Memory.** Every sub-layer (mapping, mixer or feed-forward, write-back) is
 under one `jax.checkpoint`: what is saved is the four streams in the compute
 dtype at each of the 2 x layers boundaries (T 8,192: 235 MB each) and nothing
-of float32 as wide as the streams: the mappings read them with reductions
-(the norm's sum of squares, four products with phi's rows, the weighted sums)
-that ask for no float32 copy. (The chip's compiler still stores one between
-some of its fusions: the passes are plain `jax.numpy`, and a kernel of one
-pass is a `perf_opt` issue's.)
+of float32 as wide as the streams. The passes over the streams (the norm's
+sum of squares, the products with phi's rows, the read, the write-back) are
+`ops/streams.py`'s: on the chip four Pallas kernels of one read each, forward
+and backward, elsewhere the plain `jax.numpy` form they were here (PR 44),
+each pass under a `jax.checkpoint` of its own. What is left here is the
+arithmetic on a token's 24 floats: the gates, the clamp, the Sinkhorn
+iterations.
 
 **Counters.** With `targets` the model returns Mellum 2's routing counts and,
 per sub-layer, the largest |row or column sum of H_res - 1| and the mean mass
@@ -110,6 +112,7 @@ from mgwfbp_tpu.models.lm_parts import (
     token_losses,
     yarn_inv_freq,
 )
+from mgwfbp_tpu.ops import streams
 from mgwfbp_tpu.ops.blockattn import blockwise_attention
 from mgwfbp_tpu.ops.programs import counted
 
@@ -244,14 +247,13 @@ def stream_maps(phi, b, alpha, x: jax.Array, s: Xing4Shape):
     """The three mappings of one sub-layer from the streams x (n, B, T, C):
     (H_pre (n, B, T), H_post (n, B, T), H_res (n, n, B, T) as [to, from]),
     float32, the token's position last so that the 4 x 4 arithmetic runs over
-    whole lanes. The norm needs no copy of the streams: x~ phi = (x phi) over
-    the token's rms, a sum of squares and n products with phi's rows."""
-    n, _, _, c = x.shape
+    whole lanes; and, fourth, what `ops/streams.py`'s pass over the streams
+    has read already (None, or u made from these alphas and the streams for
+    the write-back: for `streams.read_streams`). The passes over the streams
+    are `ops/streams.py`'s: x~ phi = (x phi) over the token's rms."""
+    n = x.shape[0]
+    a, read = streams.map_streams(phi, b, alpha, x, s.hc_eps)
     f32 = jnp.float32
-    phi = phi.reshape(n, c, s.map_width)
-    a = sum(
-        jnp.dot(x[i], phi[i], preferred_element_type=f32) for i in range(n))
-    a = jnp.moveaxis(a * _inverse_rms(x, s.hc_eps)[..., None], -1, 0)
     b = b.astype(f32)[:, None, None]
     alpha = alpha.astype(f32)
     pre = jax.nn.sigmoid(alpha[0] * a[:n] + b[:n])
@@ -261,24 +263,7 @@ def stream_maps(phi, b, alpha, x: jax.Array, s: Xing4Shape):
         jnp.clip(alpha[2] * a[2 * n:] + b[2 * n:], lo, hi).reshape(
             n, n, *a.shape[1:]),
         s.hc_sinkhorn_iters, s.hc_eps)
-    return pre, post, res
-
-
-# The three passes over the streams (`_inverse_rms`, `read_streams`,
-# `write_streams`) are under a `jax.checkpoint` each, inside the sub-layer's
-# own: what autodiff keeps of them is then the streams as they are stored (the
-# compute dtype) and the small mappings, never `x.astype(float32)` (470 MB a
-# sub-layer at the cell's size). What the chip's compiler then stores between
-# its own fusions is another matter: PERF.md section 6, PR 44.
-
-
-@jax.checkpoint
-def _inverse_rms(x: jax.Array, eps: float) -> jax.Array:
-    """1 / sqrt(mean over the n streams' C of x^2 + eps): (B, T) float32."""
-    n, _, _, c = x.shape
-    return lax.rsqrt(
-        jnp.sum(jnp.square(x.astype(jnp.float32)), axis=(0, 3)) / (n * c)
-        + eps)
+    return pre, post, res, read
 
 
 def res_counters(res: jax.Array):
@@ -294,29 +279,9 @@ def res_counters(res: jax.Array):
     return lax.stop_gradient(gap), lax.stop_gradient(offdiag)
 
 
-@jax.checkpoint
-def read_streams(x: jax.Array, pre: jax.Array) -> jax.Array:
-    """u = sum_i H_pre[i] x[i]: (B, T, C) in x's dtype, float32 inside."""
-    return jnp.sum(
-        pre[..., None] * x.astype(jnp.float32), axis=0).astype(x.dtype)
-
-
-@jax.checkpoint
-def write_streams(x: jax.Array, res: jax.Array, post: jax.Array,
-                  y: jax.Array) -> jax.Array:
-    """x'[i] = sum_j H_res[i, j] x[j] + H_post[i] y, float32 inside."""
-    # a stream at a time from the stored slices, then stacked: of the forms
-    # tried on the chip's compiler (one broadcast product summed over `from`;
-    # slices of the float32 of all four) this one moves the fewest bytes, 1.5
-    # GB forward and 3.4 backward at the cell's size against 2.7 and 3.9 to
-    # 5.4 (what it needs: 0.5 and 0.8; PERF.md section 6, PR 44)
-    n = x.shape[0]
-    y32 = y.astype(jnp.float32)
-    return jnp.stack([
-        (post[i][..., None] * y32 + sum(
-            res[i, j][..., None] * x[j].astype(jnp.float32)
-            for j in range(n))).astype(x.dtype)
-        for i in range(n)])
+# x'[i] = sum_j H_res[i, j] x[j] + H_post[i] y: `write_streams(x, res, post,
+# y)`, under the name the reference's tests call it by
+write_streams = streams.write_streams
 
 
 def latent_attention(p: dict, u: jax.Array, s: Xing4Shape, block: int):
@@ -403,11 +368,11 @@ def _sub_layer(p: dict, x: jax.Array, which: str, s: Xing4Shape, fn):
     # write-back that produced the streams and stores both
     x = lax.optimization_barrier(x)
     with jax.named_scope("mhc_map"):
-        pre, post, res = stream_maps(
+        pre, post, res, read = stream_maps(
             p[which + "_phi"], p[which + "_b"], p[which + "_alpha"], x, s)
         counters = res_counters(res)
     with jax.named_scope("mhc_mix"):
-        u = read_streams(x, pre)
+        u, x = streams.read_streams(x, pre, read)
     y, *rest = fn(rms_norm(u, p[which + "_norm"], s.rms_norm_eps))
     with jax.named_scope("mhc_mix"):
         return write_streams(x, res, post, y), counters, rest

@@ -2,7 +2,8 @@
 
 Each entry point (`blockattn.blockwise_attention`, `groupmm.grouped_product`,
 `rowperm`'s two permutations, `selscan.selective_scan`,
-`deltarule.gated_delta_rule`, `shortconv.causal_conv_silu`) chooses its way
+`deltarule.gated_delta_rule`, `shortconv.causal_conv_silu`, `streams`'
+`map_streams` and `write_streams`) chooses its way
 down by platform (`traced_for_tpu`) and shape, and says which it took with
 `note(op, way, programs)`: the call, and the keys of the kernel programs
 that it and its transposes need, as jax tells programs apart (what makes two
@@ -33,6 +34,7 @@ OPS: dict[str, tuple[tuple[str, ...], str | None]] = {
     "scan": (("kernel", "plain"), "programs"),
     "delta": (("kernel", "plain"), "programs"),
     "conv": (("kernel", "plain"), "programs"),
+    "streams": (("kernel", "plain"), "programs"),
 }
 # (telemetry record, the ops whose fields it carries, the Trainer's log line
 # over those fields)
@@ -58,6 +60,10 @@ RECORDS: tuple[tuple[str, tuple[str, ...], str], ...] = (
      "convolution: %(kernel)d short convolution(s) of the step through the "
      "kernels of one pass (%(programs)d distinct kernel program(s)), "
      "%(plain)d through the plain form"),
+    ("streams_program", ("streams",),
+     "streams: %(kernel)d pass(es) of the step through the kernels of one "
+     "read (%(programs)d distinct kernel program(s)), %(plain)d through the "
+     "plain form"),
 )
 
 # calls traced so far under (op, way), and under (op, a kernel program's key)

@@ -115,6 +115,10 @@ EVENT_TYPES: dict[str, tuple[str, ...]] = {
     # (ops/shortconv.py; 1 a Mamba or Gated DeltaNet layer held; a forward
     # and a backward program for each shape); all 0 for a model without one
     "conv_program": ("step", "kernel", "plain", "programs"),
+    # the same for the passes over several residual streams (ops/streams.py;
+    # 2 a sub-layer: the mapping with its read, and the write-back; a forward
+    # and a backward program for each); all 0 for a model with one stream
+    "streams_program": ("step", "kernel", "plain", "programs"),
     # what set-up was made of, once per process start, when the host has
     # read the first step's results, and once more after a rebuild that
     # recompiles the step (telemetry/phases.py): `spans` as

@@ -10,7 +10,7 @@ import telemetry_report
 
 from mgwfbp_tpu.telemetry.events import events_of, read_events
 
-OPS = ("attention", "experts", "rows", "scan", "delta", "conv")
+OPS = ("attention", "experts", "rows", "scan", "delta", "conv", "streams")
 # op -> (its record, its part of the Trainer's log line, its part of the
 # report's line)
 SAID = {
@@ -57,6 +57,14 @@ SAID = {
         "kernels of one pass ({programs} distinct kernel program(s)), "
         "{plain} through the plain form",
         "short convolution: {kernel} through the kernels of one pass "
+        "({programs} distinct kernel program(s)), {plain} through the plain "
+        "form"),
+    "streams": (
+        "streams_program",
+        "streams: {kernel} pass(es) of the step through the kernels of one "
+        "read ({programs} distinct kernel program(s)), {plain} through the "
+        "plain form",
+        "streams' passes: {kernel} through the kernels of one read "
         "({programs} distinct kernel program(s)), {plain} through the plain "
         "form"),
 }

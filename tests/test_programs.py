@@ -22,6 +22,7 @@ from mgwfbp_tpu.ops import (
     rowperm,
     selscan,
     shortconv,
+    streams,
 )
 
 PACKAGE = pathlib.Path(mgwfbp_tpu.__file__).parent
@@ -70,6 +71,12 @@ CALLS = {
         shortconv.causal_conv_silu,
         (a(2, shortconv._ROWS, 128), a(4, 128, dtype=F32),
          a(128, dtype=F32)),
+        {"kernel": 0, "plain": 1, "programs": 0},
+        {"kernel": 1, "plain": 0, "programs": 2}),
+    "streams": (
+        streams.write_streams,
+        (a(4, 1, 128, 128), a(4, 4, 1, 128, dtype=F32),
+         a(4, 1, 128, dtype=F32), a(1, 128, 128)),
         {"kernel": 0, "plain": 1, "programs": 0},
         {"kernel": 1, "plain": 0, "programs": 2}),
 }

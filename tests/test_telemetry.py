@@ -385,6 +385,29 @@ def test_report_says_the_way_the_short_convolutions_went():
         [header, step])
 
 
+def test_report_says_the_way_the_streams_passes_went():
+    """The newest `streams_program` record (ops/streams.py) beside the short
+    convolutions' line; no line where it counts none (every model with one
+    residual stream) or where there is no record."""
+    import telemetry_report
+
+    header = {"event": "header", "schema_version": 2, "wall": 0.0}
+    step = {"event": "step", "step": 1, "epoch": 0, "start_s": 1.0,
+            "dur_s": 0.1, "phases": {"guard": [1.2, 0.1]},
+            "mhc_res_gap": 1e-3}
+    program = {"event": "streams_program", "step": 1, "kernel": 20,
+               "plain": 0, "programs": 4}
+    report = telemetry_report.format_report(
+        [header, {**program, "kernel": 0, "plain": 20, "programs": 0},
+         program, step])
+    assert ("streams' passes: 20 through the kernels of one read (4 "
+            "distinct kernel program(s)), 0 through the plain form") in report
+    assert "streams' passes" not in telemetry_report.format_report(
+        [header, {**program, "kernel": 0, "programs": 0}, step])
+    assert "streams' passes" not in telemetry_report.format_report(
+        [header, step])
+
+
 def test_report_selftest_runs():
     import telemetry_report
 
@@ -460,6 +483,7 @@ def test_trainer_smoke_emits_step_and_group_events(smoke_run):
     ("scan", ("kernel", "plain", "programs")),
     ("delta", ("kernel", "plain", "programs")),
     ("conv", ("kernel", "plain", "programs")),
+    ("streams", ("kernel", "plain", "programs")),
 ], ids=program_records.OPS)
 def test_a_model_that_calls_none_of_the_ops_records_them_at_nought(
         smoke_run, op, fields):

@@ -278,7 +278,9 @@ def test_xing4_step_counts_five_cores_through_the_kernel(topo, monkeypatch):
     asked in tests/test_tpu_compile_attention.py, and the whole step takes
     the chip's compiler a minute and a half): `attention_program` reads 5 +
     0, the four sparse layers' twelve grouped products go through the tiled
-    kernel, and 759,346,446 parameters are held."""
+    kernel, the ten sub-layers' twenty passes over the streams through
+    ops/streams.py's four kernel programs, and 759,346,446 parameters are
+    held."""
     from mgwfbp_tpu.ops import programs
 
     monkeypatch.setattr(programs, "traced_for_tpu", lambda: True)
@@ -297,6 +299,8 @@ def test_xing4_step_counts_five_cores_through_the_kernel(topo, monkeypatch):
     assert step.traced_programs["attention"] == {"kernel": 5, "blocks": 0}
     assert step.traced_programs["experts"]["kernel"] == 12
     assert step.traced_programs["experts"]["ragged"] == 0
+    assert step.traced_programs["streams"] == {
+        "kernel": 20, "plain": 0, "programs": 4}
     # the streams between the sub-layers are bf16, four times the hidden size
     assert "4x1x8192x3584xbf16" in text
     assert "4x1x8192x3584xf32" not in text.split("func.func")[1][:2000]

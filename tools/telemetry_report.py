@@ -63,7 +63,8 @@ def _window_mean(spans: list[dict], sl: slice) -> float:
 
 def _phase_section(steps: list[dict], scans: list[dict] = (),
                    deltas: list[dict] = (),
-                   convs: list[dict] = ()) -> list[str]:
+                   convs: list[dict] = (),
+                   streams: list[dict] = ()) -> list[str]:
     """One table of the host loop's phases (telemetry/phases.py): median
     milliseconds over the steps that have the phase, and the share of the
     loop's time (first span's start to last span's end) all its entries
@@ -84,7 +85,8 @@ def _phase_section(steps: list[dict], scans: list[dict] = (),
     `mla_kv_latent_rms`, `moe_bias_swap_share`), and the way the short
     convolutions of any of
     them did (`convs`: the `conv_program` records; no line where the newest
-    counts none)."""
+    counts none), and the passes over several residual streams (`streams`:
+    the `streams_program` records, likewise)."""
     from mgwfbp_tpu.telemetry.phases import PHASES
 
     spans: dict[str, list[tuple[float, float]]] = {}
@@ -154,7 +156,7 @@ def _phase_section(steps: list[dict], scans: list[dict] = (),
     # the built step program (`scans`, `deltas`): a decoder-hybrid-decoder's
     # (models/phi4flash.py), a hybrid linear-attention decoder's
     # (models/qwen3next.py), a decoder's under several residual streams
-    # (models/xing4.py: no recurrence, so no such record)
+    # (models/xing4.py: no recurrence; its passes' record has a line below)
     for title, counters, programs, kernel, plain in (
             ("hybrid decoder", (
                 ("sel_scan_state_rms", "selective scan's final state rms"),
@@ -187,12 +189,16 @@ def _phase_section(steps: list[dict], scans: list[dict] = (),
         lines.append(
             f"  {title} ({max(map(len, values.values()))} steps): "
             + "; ".join(says))
-    for prog in convs[-1:]:
-        if prog.get("kernel") or prog.get("plain"):
-            lines.append(
-                f"  short convolution: {prog.get('kernel')} through the "
-                f"kernels of one pass ({prog.get('programs')} distinct kernel "
-                f"program(s)), {prog.get('plain')} through the plain form")
+    for title, kernels, programs in (
+            ("short convolution", "kernels of one pass", convs),
+            ("streams' passes", "kernels of one read", streams)):
+        for prog in programs[-1:]:
+            if prog.get("kernel") or prog.get("plain"):
+                lines.append(
+                    f"  {title}: {prog.get('kernel')} through the "
+                    f"{kernels} ({prog.get('programs')} distinct kernel "
+                    f"program(s)), {prog.get('plain')} through the plain "
+                    f"form")
     return lines
 
 
@@ -297,7 +303,8 @@ def format_report(records: list[dict]) -> str:
         lines.extend(_phase_section(
             steps, events_of(records, "scan_program"),
             events_of(records, "delta_program"),
-            events_of(records, "conv_program")))
+            events_of(records, "conv_program"),
+            events_of(records, "streams_program")))
     else:
         lines.append("steps: none recorded")
 
