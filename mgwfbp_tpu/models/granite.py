@@ -23,7 +23,9 @@ no bias but the convolution's:
 
 B and C are shared by all heads (`mamba_n_groups` 1) and the gated norm runs
 over all inner channels at once. The scan is `ops/ssd.py`'s chunked form
-(chunk 256 as published).
+(chunk 256 as published), which reads x, B and C in place out of the
+convolution's output (`ssd_scan_in_place`: kernels on the chip, the plain
+form elsewhere).
 
 **A chip's share.** `layers_held` (the first n of `layer_types`) and
 `vocab_size` (the rows of the tied embedding that live here), as
@@ -33,7 +35,7 @@ share out.
 **Memory.** Every layer is under `jax.checkpoint`: what the forward pass
 keeps per layer is the residual stream, and the backward pass recomputes a
 layer before it differentiates it. Inside, the scan recomputes its blocks of
-chunks, the attention core keeps no scores (ops/blockattn.py), and the loss recomputes its token blocks
+chunks (its kernels a chunk's matrices, in VMEM), the attention core keeps no scores (ops/blockattn.py), and the loss recomputes its token blocks
 (`mellum.token_losses`), so neither a (chunk, chunk) decay matrix per head
 and chunk, nor a (T, T) score matrix, nor (tokens, vocabulary) logits
 outlive their block.
@@ -77,7 +79,7 @@ from mgwfbp_tpu.models.lm_parts import (
 from mgwfbp_tpu.ops import shortconv
 from mgwfbp_tpu.ops.blockattn import blockwise_attention
 from mgwfbp_tpu.ops.programs import counted
-from mgwfbp_tpu.ops.ssd import ssd_scan
+from mgwfbp_tpu.ops.ssd import ssd_scan_in_place
 
 MAMBA, ATTENTION = "mamba", "attention"
 # the step's metrics carry these under HEALTH_PREFIX of train/step.py
@@ -137,7 +139,7 @@ def mamba_mixer(p: dict, u: jax.Array, shape: GraniteShape, scan_block: int):
     hidden), root mean square of the final state, most negative chunk sum of
     log-decays)."""
     b, t, _ = u.shape
-    inner, n = shape.mamba_inner, shape.mamba_state
+    inner = shape.mamba_inner
     heads, hd = shape.mamba_heads, shape.mamba_head_dim
     with jax.named_scope("ssm_in_proj"):
         zxbcdt = u @ p["in_proj"]
@@ -151,11 +153,12 @@ def mamba_mixer(p: dict, u: jax.Array, shape: GraniteShape, scan_block: int):
         dt = jax.nn.softplus(
             dt.astype(jnp.float32) + p["dt_bias"].astype(jnp.float32))
         a = -jnp.exp(p["a_log"].astype(jnp.float32))
-        y, state, low = ssd_scan(
-            xs, dt, a, xbc[..., inner:inner + n], xbc[..., inner + n:],
-            chunk=shape.mamba_chunk, block=scan_block)
-        y = y.astype(jnp.float32) + (
-            p["d"].astype(jnp.float32)[:, None] * xs.astype(jnp.float32))
+        # x, B and C where the convolution left them: the kernels pick
+        # their columns out of xbc, the plain form cuts B and C out; y
+        # comes back float32 with the `D x` skip on it
+        y, state, low = ssd_scan_in_place(
+            xbc, xs, dt, a, p["d"], chunk=shape.mamba_chunk,
+            block=scan_block)
         state_rms = jnp.sqrt(jnp.mean(jnp.square(state)))
     with jax.named_scope("ssm_gate_norm"):
         y = y.reshape(b, t, inner) * jax.nn.silu(z.astype(jnp.float32))

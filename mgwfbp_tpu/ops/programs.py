@@ -3,7 +3,7 @@
 Each entry point (`blockattn.blockwise_attention`, `groupmm.grouped_product`,
 `rowperm`'s two permutations, `selscan.selective_scan`,
 `deltarule.gated_delta_rule`, `shortconv.causal_conv_silu`, `streams`'
-`map_streams` and `write_streams`) chooses its way
+`map_streams` and `write_streams`, `ssd`'s two scans) chooses its way
 down by platform (`traced_for_tpu`) and shape, and says which it took with
 `note(op, way, programs)`: the call, and the keys of the kernel programs
 that it and its transposes need, as jax tells programs apart (what makes two
@@ -35,6 +35,7 @@ OPS: dict[str, tuple[tuple[str, ...], str | None]] = {
     "delta": (("kernel", "plain"), "programs"),
     "conv": (("kernel", "plain"), "programs"),
     "streams": (("kernel", "plain"), "programs"),
+    "ssd": (("kernel", "plain"), "programs"),
 }
 # (telemetry record, the ops whose fields it carries, the Trainer's log line
 # over those fields)
@@ -64,6 +65,10 @@ RECORDS: tuple[tuple[str, tuple[str, ...], str], ...] = (
      "streams: %(kernel)d pass(es) of the step through the kernels of one "
      "read (%(programs)d distinct kernel program(s)), %(plain)d through the "
      "plain form"),
+    ("ssd_program", ("ssd",),
+     "state-space scan: %(kernel)d chunked scan(s) of the step through the "
+     "kernels with a chunk's matrices and the state in VMEM (%(programs)d "
+     "distinct kernel program(s)), %(plain)d through the plain form"),
 )
 
 # calls traced so far under (op, way), and under (op, a kernel program's key)

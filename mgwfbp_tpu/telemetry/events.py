@@ -119,6 +119,10 @@ EVENT_TYPES: dict[str, tuple[str, ...]] = {
     # 2 a sub-layer: the mapping with its read, and the write-back; a forward
     # and a backward program for each); all 0 for a model with one stream
     "streams_program": ("step", "kernel", "plain", "programs"),
+    # the same for the chunked state-space scans (ops/ssd.py; 1 a Mamba-2
+    # layer held; a forward and a backward program for each shape); all 0
+    # for a model without one
+    "ssd_program": ("step", "kernel", "plain", "programs"),
     # what set-up was made of, once per process start, when the host has
     # read the first step's results, and once more after a rebuild that
     # recompiles the step (telemetry/phases.py): `spans` as

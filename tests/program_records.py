@@ -10,7 +10,8 @@ import telemetry_report
 
 from mgwfbp_tpu.telemetry.events import events_of, read_events
 
-OPS = ("attention", "experts", "rows", "scan", "delta", "conv", "streams")
+OPS = ("attention", "experts", "rows", "scan", "delta", "conv", "streams",
+       "ssd")
 # op -> (its record, its part of the Trainer's log line, its part of the
 # report's line)
 SAID = {
@@ -67,6 +68,14 @@ SAID = {
         "streams' passes: {kernel} through the kernels of one read "
         "({programs} distinct kernel program(s)), {plain} through the plain "
         "form"),
+    "ssd": (
+        "ssd_program",
+        "state-space scan: {kernel} chunked scan(s) of the step through the "
+        "kernels with a chunk's matrices and the state in VMEM ({programs} "
+        "distinct kernel program(s)), {plain} through the plain form",
+        "state-space scan: {kernel} through the kernels with the state in "
+        "VMEM ({programs} distinct kernel program(s)), {plain} through the "
+        "plain form"),
 }
 
 

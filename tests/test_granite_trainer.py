@@ -164,8 +164,9 @@ def test_trains_with_counters_and_the_tied_leaf_reduces_like_pmean(
 
 @pytest.mark.parametrize("op,want", [
     # (mamba, mamba, attention, mamba): the attention layer's core, and a
-    # convolution a Mamba-2 layer (its scan is ops/ssd.py's, which has one
-    # way down and notes none); the layers share cached traces
+    # convolution and a scan a Mamba-2 layer (ops/ssd.py's plain form: the
+    # tiny preset's heads of 16 over a state of 8 are no lane tiles); the
+    # layers share cached traces
     ("attention", {"kernel": 0, "blocks": 1}),
     ("experts", {"kernel": 0, "ragged": 0, "programs": 0}),
     ("rows", {"rows_held": 0, "rows_all": 0, "rows_programs": 0}),
@@ -173,6 +174,7 @@ def test_trains_with_counters_and_the_tied_leaf_reduces_like_pmean(
     ("delta", {"kernel": 0, "plain": 0, "programs": 0}),
     ("conv", {"kernel": 0, "plain": 3, "programs": 0}),
     ("streams", {"kernel": 0, "plain": 0, "programs": 0}),
+    ("ssd", {"kernel": 0, "plain": 3, "programs": 0}),
 ], ids=program_records.OPS)
 def test_the_step_program_leaves_its_records(wfbp_run, op, want):
     program_records.holds(wfbp_run, op, want)

@@ -113,6 +113,7 @@ def test_fit_loss_falls_health_and_counters_every_step(fit_run):
     ("delta", {"kernel": 0, "plain": 0, "programs": 0}),
     ("conv", {"kernel": 0, "plain": 0, "programs": 0}),
     ("streams", {"kernel": 0, "plain": 0, "programs": 0}),
+    ("ssd", {"kernel": 0, "plain": 0, "programs": 0}),
 ], ids=program_records.OPS)
 def test_the_step_program_leaves_its_records(fit_run, op, want):
     program_records.holds(fit_run, op, want)

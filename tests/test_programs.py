@@ -22,6 +22,7 @@ from mgwfbp_tpu.ops import (
     rowperm,
     selscan,
     shortconv,
+    ssd,
     streams,
 )
 
@@ -79,6 +80,12 @@ CALLS = {
          a(4, 1, 128, dtype=F32), a(1, 128, 128)),
         {"kernel": 0, "plain": 1, "programs": 0},
         {"kernel": 1, "plain": 0, "programs": 2}),
+    "ssd": (
+        ssd.ssd_scan,
+        (a(1, 256, 2, 64), a(1, 256, 2, dtype=F32), a(2, dtype=F32),
+         a(1, 256, 128), a(1, 256, 128)),
+        {"kernel": 0, "plain": 1, "programs": 0},
+        {"kernel": 1, "plain": 0, "programs": 2}),
 }
 NOTHING = {
     op: dict.fromkeys((*ways, *([count] if count else ())), 0)
@@ -110,7 +117,8 @@ def test_a_call_notes_its_own_way_and_nothing_under_another_op(
 
 def two_layers():
     """A step of two EQUAL layers under `jax.checkpoint`, each an attention
-    core, a short convolution and a selective scan: jax traces the first and
+    core, a short convolution, a selective scan and a chunked state-space
+    scan: jax traces the first and
     finds the second in its cache of traces. A function of its own each
     call, so that no other test's trace is found there."""
 
@@ -121,7 +129,10 @@ def two_layers():
         x = shortconv.causal_conv_silu(x, w)
         y, _ = selscan.selective_scan(
             x, jax.nn.softplus(x), -jnp.exp(a_log), x[..., :4], x[..., 4:8])
-        return y
+        heads, _, _ = ssd.ssd_scan(
+            q, jax.nn.softplus(x[..., :2]), -jnp.exp(a_log[:2, 0]),
+            x[..., :4], x[..., 4:8], chunk=16)
+        return y + heads.reshape(b, t, d)
 
     one = programs.counted(jax.checkpoint(layer))
 
@@ -138,12 +149,13 @@ def two_layers():
 TWO_LAYERS = {
     **NOTHING, "attention": {"kernel": 0, "blocks": 2},
     "scan": {"kernel": 0, "plain": 2, "programs": 0},
-    "conv": {"kernel": 0, "plain": 2, "programs": 0}}
+    "conv": {"kernel": 0, "plain": 2, "programs": 0},
+    "ssd": {"kernel": 0, "plain": 2, "programs": 0}}
 
 
 def test_a_cached_trace_notes_every_op_its_first_trace_noted():
     """The second layer is never traced, and is noted as the first was:
-    attention core, convolution and scan alike, by ONE `counted`."""
+    attention core, convolution and both scans alike, by ONE `counted`."""
     step, args = two_layers()
     calls = []
     real = blockattn._blocks

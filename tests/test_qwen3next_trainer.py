@@ -221,6 +221,7 @@ def test_trains_with_counters_and_every_leaf_reduces_like_pmean(
     ("delta", {"kernel": 0, "plain": 3, "programs": 0}),
     ("conv", {"kernel": 0, "plain": 3, "programs": 0}),
     ("streams", {"kernel": 0, "plain": 0, "programs": 0}),
+    ("ssd", {"kernel": 0, "plain": 0, "programs": 0}),
 ], ids=program_records.OPS)
 def test_the_step_program_leaves_its_records(wfbp_run, op, want):
     program_records.holds(wfbp_run, op, want)

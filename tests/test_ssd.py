@@ -2,13 +2,18 @@
 B_t^T, y_t = S_t C_t, one position at a time: outputs, final state and every
 input's gradient; T a multiple of the chunk and not; float32 tight, bfloat16
 inputs (float32 decays and state inside) within a stated tolerance; and a
-decay so strong that exp(L_t) * exp(-L_s) overflows."""
+decay so strong that exp(L_t) * exp(-L_s) overflows. Then the kernels
+(Pallas `interpret=True`: off the chip they run no other way) against the
+plain form at shapes `_kernel_dims` admits, what it refuses, and the tiny
+preset's lowered text against the mixer as it stood before the kernels."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from mgwfbp_tpu.models import granite
+from mgwfbp_tpu.ops import programs, ssd
 from mgwfbp_tpu.ops.ssd import ssd_scan
 
 HI = jax.lax.Precision.HIGHEST
@@ -119,3 +124,219 @@ def test_a_decay_that_overflows_the_naive_form_stays_finite_and_right():
     for g, w in zip(grads, want):
         assert bool(jnp.all(jnp.isfinite(g)))
         np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-4)
+
+
+# --- the kernels, interpreted ------------------------------------------------
+
+
+def kernels(chunk):
+    """The scan with the mixer's skip on it down the kernels' way,
+    interpreted: x, B and C side by side as the mixer's convolution leaves
+    them -> (y + d x float32, the final state, the chunks' lowest sum)."""
+
+    def scan(x, dt, a, b, c, d):
+        bsz, t, h, p = x.shape
+        dims = ssd._kernel_dims(
+            t, h, p, b.shape[-1], chunk, (x.dtype, b.dtype, c.dtype))
+        assert dims is not None
+        return ssd._kernel_scan(jnp.concatenate(
+            [x.reshape(bsz, t, h * p), b, c], axis=-1), dt, a, d, dims, True)
+
+    return scan
+
+
+def plain(chunk):
+    """The same of the plain form, as `ssd_scan_in_place` adds the skip."""
+
+    def scan(x, dt, a, b, c, d):
+        y, s, low = ssd._plain_scan(x, dt, a, b, c, chunk, 2)
+        return y.astype(jnp.float32) + d[:, None] * x.astype(jnp.float32), \
+            s, low
+
+    return scan
+
+
+def lane_draws(seed, bsz, t, p, dtype, h=4, n=128):
+    x, dt, a, b, c = draws(seed, t, bsz=bsz, h=h, p=p, n=n)
+    d = 1.0 + jax.random.normal(jax.random.PRNGKey(seed + 100), (h,))
+    return x.astype(dtype), dt, a, b.astype(dtype), c.astype(dtype), d
+
+
+def close(got, want, share):
+    """Within `share` of the largest entry wanted, and finite."""
+    got, want = (np.asarray(v, np.float32) for v in (got, want))
+    assert np.all(np.isfinite(got))
+    assert np.abs(got - want).max() <= share * np.abs(want).max()
+
+
+@pytest.mark.parametrize("bsz,chunks,p,chunk,dtype", [
+    (1, 2, 64, 128, jnp.float32),
+    (2, 4, 128, 128, jnp.float32),
+    (1, 2, 64, 256, jnp.float32),
+    (2, 2, 64, 128, jnp.bfloat16),
+    (1, 4, 128, 256, jnp.bfloat16),
+    (1, 2, 64, 256, jnp.bfloat16),
+], ids=lambda v: getattr(v, "__name__", str(v)))
+def test_the_kernels_are_the_plain_form_forward_and_pulled_back(
+        bsz, chunks, p, chunk, dtype):
+    """y with the skip on it, the final state, the third result and every
+    pull-back (the skip's weight's too) through a scalar that also reads the
+    final state, to the plain form's own rounding: float32 to float32's (the sums run in another order), bfloat16
+    to a bfloat16 digit of the largest entry (the kernels keep d `mixed` and
+    d `scores` float32 where autodiff rounds them to x's dtype)."""
+    args = lane_draws(chunks, bsz, chunks * chunk, p, dtype)
+    y, s, low = jax.jit(kernels(chunk))(*args)
+    want_y, want_s, want_low = jax.jit(plain(chunk))(*args)
+    assert y.dtype == s.dtype == jnp.float32
+    share = 2e-5 if dtype == jnp.float32 else 2.0 ** -7
+    close(y, want_y, share)
+    close(s, want_s, 2e-5)
+    assert float(low) == float(want_low)
+    got = jax.jit(jax.grad(lambda *v: weighted(kernels(chunk), v),
+                           argnums=(0, 1, 2, 3, 4, 5)))(*args)
+    want = jax.jit(jax.grad(lambda *v: weighted(plain(chunk), v),
+                            argnums=(0, 1, 2, 3, 4, 5)))(*args)
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        close(g, w, 2e-5 if dtype == jnp.float32 else 2.0 ** -6)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_the_kernels_decay_from_differences_masked_before_the_exponential(
+        dtype):
+    """The Range note's case: a head with a = -16 and dt = 0.1 over a whole
+    chunk (its sum -204.8 at 128 positions: exp(-L_s) is inf in float32)
+    beside a mild one. No inf, no nan, forward or backward, and the plain
+    form's numbers."""
+    x, dt, a, b, c, d = lane_draws(3, 1, 256, 64, dtype, h=2)
+    dt = dt.at[:, :, 0].set(0.1)
+    a = a.at[0].set(-16.0)
+    args = (x, dt, a, b, c, d)
+    y, s, low = jax.jit(kernels(128))(*args)
+    assert float(low) == pytest.approx(-204.8, rel=1e-5)
+    want_y, want_s, _ = jax.jit(plain(128))(*args)
+    share = 2e-5 if dtype == jnp.float32 else 2.0 ** -7
+    close(y, want_y, share)
+    close(s, want_s, 2e-5)
+    got = jax.jit(jax.grad(lambda *v: weighted(kernels(128), v),
+                           argnums=(0, 1, 2, 3, 4, 5)))(*args)
+    want = jax.jit(jax.grad(lambda *v: weighted(plain(128), v),
+                            argnums=(0, 1, 2, 3, 4, 5)))(*args)
+    for g, w in zip(got, want):
+        close(g, w, 2e-5 if dtype == jnp.float32 else 2.0 ** -6)
+
+
+BF16, F32 = jnp.bfloat16, jnp.float32
+
+
+@pytest.mark.parametrize("t,h,p,n,chunk,dtypes,admitted", [
+    (8192, 64, 64, 128, 256, (BF16,) * 3, True),  # the Granite cell
+    (256, 4, 128, 256, 128, (F32,) * 3, True),
+    (64, 4, 16, 8, 16, (F32,) * 3, False),  # GRANITE4H_TINY
+    (256, 4, 16, 128, 128, (BF16,) * 3, False),  # P 16
+    (256, 4, 64, 8, 128, (BF16,) * 3, False),  # N 8
+    (200, 4, 64, 128, 128, (BF16,) * 3, False),  # a padded T
+    (256, 4, 64, 128, 128, (BF16, F32, BF16), False),  # mixed dtypes
+    (256, 3, 64, 128, 128, (BF16,) * 3, False),  # half a lane tile left
+    (256, 4, 64, 128, 64, (BF16,) * 3, False),  # a chunk under a lane tile
+], ids=lambda v: str(v) if isinstance(v, (int, bool)) else "dtypes")
+def test_the_rule_sends_to_the_plain_form_what_the_kernels_do_not_take(
+        monkeypatch, t, h, p, n, chunk, dtypes, admitted):
+    """Traced as for a TPU (said so by the test: this process's default
+    backend is the CPU; nothing runs), both entry points go the way
+    `_kernel_dims` says and `ssd_program` says which."""
+    monkeypatch.setattr(programs, "traced_for_tpu", lambda: True)
+    assert (ssd._kernel_dims(t, h, p, n, chunk, dtypes) is not None) \
+        == admitted
+
+    def shape(*dims, dtype=F32):
+        return jax.ShapeDtypeStruct(dims, dtype)
+
+    x, b, c = (shape(1, t, *dims, dtype=dtype) for dims, dtype in zip(
+        ((h, p), (n,), (n,)), dtypes))
+    calls = [(lambda *v: ssd_scan(*v, chunk=chunk),
+              (x, shape(1, t, h), shape(h), b, c))]
+    if len(set(dtypes)) == 1:  # side by side they have one dtype
+        calls.append((
+            lambda xbc, dt, a, d: ssd.ssd_scan_in_place(
+                xbc, xbc[..., :h * p].reshape(1, t, h, p), dt, a, d,
+                chunk=chunk),
+            (shape(1, t, h * p + 2 * n, dtype=dtypes[0]), shape(1, t, h),
+             shape(h), shape(h))))
+    for fn, args in calls:
+        before = programs.LOWERED.copy()
+        jaxpr = jax.make_jaxpr(fn)(*args)
+        assert programs.lowered_since(before)["ssd"] == {
+            "kernel": int(admitted), "plain": int(not admitted),
+            "programs": 2 * admitted}
+        assert ("pallas_call" in str(jaxpr)) == admitted
+        assert [v.aval.shape for v in jaxpr.jaxpr.outvars] == [
+            (1, t, h, p), (1, h, p, n), ()]
+
+
+def _parent_mamba_mixer(p, u, shape, scan_block):
+    """models/granite.mamba_mixer as it stood before ops/ssd.py had kernels
+    (PR 45's, verbatim): what the tiny preset lowered to."""
+    b, t, _ = u.shape
+    inner, n = shape.mamba_inner, shape.mamba_state
+    heads, hd = shape.mamba_heads, shape.mamba_head_dim
+    with jax.named_scope("ssm_in_proj"):
+        zxbcdt = u @ p["in_proj"]
+        z = zxbcdt[..., :inner]
+        xbc = zxbcdt[..., inner:inner + shape.conv_channels]
+        dt = zxbcdt[..., inner + shape.conv_channels:]
+    with jax.named_scope("ssm_conv"):
+        xbc = granite.shortconv.causal_conv_silu(
+            xbc, p["conv_w"], p["conv_b"])
+    with jax.named_scope("ssm_scan"):
+        xs = xbc[..., :inner].reshape(b, t, heads, hd)
+        dt = jax.nn.softplus(
+            dt.astype(jnp.float32) + p["dt_bias"].astype(jnp.float32))
+        a = -jnp.exp(p["a_log"].astype(jnp.float32))
+        y, state, low = ssd_scan(
+            xs, dt, a, xbc[..., inner:inner + n], xbc[..., inner + n:],
+            chunk=shape.mamba_chunk, block=scan_block)
+        y = y.astype(jnp.float32) + (
+            p["d"].astype(jnp.float32)[:, None] * xs.astype(jnp.float32))
+        state_rms = jnp.sqrt(jnp.mean(jnp.square(state)))
+    with jax.named_scope("ssm_gate_norm"):
+        y = y.reshape(b, t, inner) * jax.nn.silu(z.astype(jnp.float32))
+        y = granite.rms_norm(
+            y, p["gate_norm"], shape.rms_norm_eps).astype(u.dtype)
+    with jax.named_scope("ssm_out_proj"):
+        return y @ p["out_proj"], jax.lax.stop_gradient(state_rms), low
+
+
+@pytest.mark.parametrize("dtype", [F32, BF16], ids=["float32", "bfloat16"])
+def test_on_the_cpu_the_tiny_preset_lowers_to_the_text_it_was(
+        monkeypatch, dtype):
+    """The tiny preset's loss and gradients (mamba, mamba, attention, mamba,
+    every layer under its checkpoint) lowered with the mixer as it is, and
+    with the parent's mixer put back in its place: the same text, character
+    for character."""
+    from mgwfbp_tpu.models import create_model
+
+    model, _ = create_model("granite4h_tiny", num_classes=256)
+    tokens = jnp.zeros((2, 64), jnp.int32)
+    params = jax.eval_shape(
+        lambda: model.init({"params": jax.random.PRNGKey(0)}, tokens,
+                           train=False))["params"]
+    params = jax.tree.map(
+        lambda p: jax.ShapeDtypeStruct(p.shape, dtype), params)
+
+    def loss(params, tokens):
+        losses, _ = model.apply({"params": params}, tokens, targets=tokens)
+        return jnp.mean(losses)
+
+    def lowered():
+        jax.clear_caches()  # the layers' traces are cached
+        return jax.jit(jax.value_and_grad(loss)).lower(
+            params, tokens).as_text()
+
+    now = lowered()
+    monkeypatch.setattr(granite, "mamba_mixer", _parent_mamba_mixer)
+    was = lowered()
+    jax.clear_caches()
+    assert "cumsum" in now and len(now) > 100000
+    assert now == was

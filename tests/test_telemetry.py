@@ -408,6 +408,30 @@ def test_report_says_the_way_the_streams_passes_went():
         [header, step])
 
 
+def test_report_says_the_way_the_state_space_scans_went():
+    """The newest `ssd_program` record (ops/ssd.py) beside the short
+    convolutions' line; no line where it counts none (every model without a
+    Mamba-2 layer) or where there is no record."""
+    import telemetry_report
+
+    header = {"event": "header", "schema_version": 2, "wall": 0.0}
+    step = {"event": "step", "step": 1, "epoch": 0, "start_s": 1.0,
+            "dur_s": 0.1, "phases": {"guard": [1.2, 0.1]},
+            "ssm_state_rms": 1e-2}
+    program = {"event": "ssd_program", "step": 1, "kernel": 9, "plain": 0,
+               "programs": 2}
+    report = telemetry_report.format_report(
+        [header, {**program, "kernel": 0, "plain": 9, "programs": 0},
+         program, step])
+    assert ("state-space scan: 9 through the kernels with the state in VMEM "
+            "(2 distinct kernel program(s)), 0 through the plain form"
+            ) in report
+    assert "state-space scan" not in telemetry_report.format_report(
+        [header, {**program, "kernel": 0, "programs": 0}, step])
+    assert "state-space scan" not in telemetry_report.format_report(
+        [header, step])
+
+
 def test_report_selftest_runs():
     import telemetry_report
 
@@ -484,6 +508,7 @@ def test_trainer_smoke_emits_step_and_group_events(smoke_run):
     ("delta", ("kernel", "plain", "programs")),
     ("conv", ("kernel", "plain", "programs")),
     ("streams", ("kernel", "plain", "programs")),
+    ("ssd", ("kernel", "plain", "programs")),
 ], ids=program_records.OPS)
 def test_a_model_that_calls_none_of_the_ops_records_them_at_nought(
         smoke_run, op, fields):

@@ -304,3 +304,63 @@ def test_xing4_step_counts_five_cores_through_the_kernel(topo, monkeypatch):
     # the streams between the sub-layers are bf16, four times the hidden size
     assert "4x1x8192x3584xbf16" in text
     assert "4x1x8192x3584xf32" not in text.split("func.func")[1][:2000]
+
+
+def test_granite_mixer_compiles_with_the_scan_as_two_kernel_programs(
+        topo, monkeypatch):
+    """models/granite.mamba_mixer at the Granite cell's shape (one sequence
+    of 8,192, 64 heads of 64 over a state of 128, chunks of 256, bfloat16)
+    under a `jax.checkpoint` as the layer has it, its value and its
+    pull-back with the cotangent handed in, traced as for a TPU (said so by
+    the test: this process's default backend is the CPU). It compiles for
+    the chip with SIX Mosaic sites (the convolution's and the scan's forward
+    kernels, both again under the checkpoint, and the two backward kernels)
+    of FOUR distinct programs; x, B and C reach the scan's kernels as the
+    convolution's one output, no (chunk, chunk) float32 matrix and no state
+    a position exists in the text, the states the 32 chunks start from do
+    (67 MB), and the mixer's scratch is 0.64 GiB where the parent's, asked
+    the same way, is 0.78."""
+    from jax.sharding import SingleDeviceSharding
+
+    from mgwfbp_tpu.models import granite
+    from mgwfbp_tpu.ops import programs
+
+    monkeypatch.setattr(programs, "traced_for_tpu", lambda: True)
+    one = SingleDeviceSharding(topo.devices[0])
+    s = granite.GRANITE4H
+    t, chunks = 8192, 8192 // s.mamba_chunk
+
+    def arg(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one)
+
+    p = {name: arg(*shape)
+         for name, shape, _ in granite.layer_leaves(granite.MAMBA, s)}
+    u = arg(1, t, s.hidden_size)
+
+    def mixer(p, u, g):
+        out, pull = jax.vjp(jax.checkpoint(
+            lambda p, u: granite.mamba_mixer(p, u, s, 8)[0]), p, u)
+        return out, pull(g)
+
+    before = programs.LOWERED.copy()
+    compiled = jax.jit(mixer).lower(p, u, u).compile()
+    noted = programs.lowered_since(before)
+    assert noted["ssd"] == {"kernel": 1, "plain": 0, "programs": 2}
+    assert noted["conv"] == {"kernel": 1, "plain": 0, "programs": 2}
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    kernels = [re.search(r"(\w+)/pallas_call", line).group(1)
+               for line in calls]
+    assert sorted(kernels) == [
+        "causal_conv_silu_backward", "causal_conv_silu_forward",
+        "causal_conv_silu_forward", "ssd_scan_backward", "ssd_scan_forward",
+        "ssd_scan_forward"]
+    inner, n = s.mamba_inner, s.mamba_state
+    for line, kernel in zip(calls, kernels):
+        if kernel.startswith("ssd_scan"):  # xbc whole, where it lies
+            assert f"bf16[1,{t},{s.conv_channels}]" in line
+    assert f"f32[1,{chunks},{inner},{n}]" in text
+    assert not re.search(rf"f32\[[\d,]*{s.mamba_chunk},{s.mamba_chunk}\]",
+                         text)
+    assert f"f32[1,{t},{inner},{n}]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.7 * 2 ** 30

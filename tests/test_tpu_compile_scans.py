@@ -256,3 +256,41 @@ def test_selective_scan_kernels_keep_the_state_off_hbm_on_a_v5e(topo):
         assert all(int(size) in (1, saved) for size in shape.split(","))
     assert saved * 8 <= tiles.rows
     assert compiled.memory_analysis().temp_size_in_bytes < 0.2 * 2 ** 30
+
+
+@pytest.mark.parametrize("dtype,bsz,t,h,p,n,chunk", [
+    (jnp.float32, 1, 8192, 64, 64, 128, 256),  # the Granite cell in float32
+    (jnp.float32, 2, 512, 4, 128, 256, 128),   # whole lane tiles, two of state
+    (jnp.bfloat16, 2, 512, 4, 64, 128, 128),   # half lane tiles, short chunks
+    (jnp.bfloat16, 1, 512, 2, 128, 128, 256),  # one head a lane tile
+])
+def test_ssd_kernels_compile_for_v5e_wherever_they_are_chosen(
+        topo, dtype, bsz, t, h, p, n, chunk):
+    """`ops/ssd._kernel_dims` sends float32 as well as bfloat16, heads of a
+    whole and of half a lane tile, chunks of 128 and 256 and a state of more
+    than one lane tile down the kernels, and a shape that Mosaic refused
+    would fail the step's compile where the plain form was to be had. So
+    each corner is compiled for the described chip, value and pull-back
+    (the cell's own corner, bfloat16, is tests/test_tpu_compile.py's mixer).
+    Nothing runs: the values are `tests/test_ssd.py`'s, interpreted."""
+    from mgwfbp_tpu.ops import ssd
+
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def arg(shape, of=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, of, sharding=one)
+
+    dims = ssd._kernel_dims(t, h, p, n, chunk, (dtype,) * 3)
+    assert dims == (h, p, n, chunk)
+
+    def layer(xbc, dt, a, d, dy, dlast):
+        out, pull = jax.vjp(
+            lambda *v: ssd._kernel_rule(*v, dims, False)[:2], xbc, dt, a, d)
+        return out, pull((dy, dlast))
+
+    text = jax.jit(layer).lower(
+        arg((bsz, t, h * p + 2 * n), dtype), arg((bsz, t, h)), arg((h,)),
+        arg((h,)), arg((bsz, t, h, p)), arg((bsz, h * p, n))
+    ).compile().as_text()
+    assert {"ssd_scan_forward", "ssd_scan_backward"} == set(
+        re.findall(r"(ssd_scan_\w+)/pallas_call", text))

@@ -211,6 +211,7 @@ def test_trains_with_counters_and_every_leaf_reduces_like_pmean(wfbp_run):
     # two passes a sub-layer (the mapping with its read, the write-back),
     # six sub-layers: ops/streams.py's plain form on the CPU
     ("streams", {"kernel": 0, "plain": 12, "programs": 0}),
+    ("ssd", {"kernel": 0, "plain": 0, "programs": 0}),
 ], ids=program_records.OPS)
 def test_the_step_program_leaves_its_records(wfbp_run, op, want):
     program_records.holds(wfbp_run, op, want)

@@ -64,7 +64,8 @@ def _window_mean(spans: list[dict], sl: slice) -> float:
 def _phase_section(steps: list[dict], scans: list[dict] = (),
                    deltas: list[dict] = (),
                    convs: list[dict] = (),
-                   streams: list[dict] = ()) -> list[str]:
+                   streams: list[dict] = (),
+                   ssds: list[dict] = ()) -> list[str]:
     """One table of the host loop's phases (telemetry/phases.py): median
     milliseconds over the steps that have the phase, and the share of the
     loop's time (first span's start to last span's end) all its entries
@@ -85,8 +86,9 @@ def _phase_section(steps: list[dict], scans: list[dict] = (),
     `mla_kv_latent_rms`, `moe_bias_swap_share`), and the way the short
     convolutions of any of
     them did (`convs`: the `conv_program` records; no line where the newest
-    counts none), and the passes over several residual streams (`streams`:
-    the `streams_program` records, likewise)."""
+    counts none), the passes over several residual streams (`streams`:
+    the `streams_program` records, likewise) and the chunked state-space
+    scans (`ssds`: the `ssd_program` records, likewise)."""
     from mgwfbp_tpu.telemetry.phases import PHASES
 
     spans: dict[str, list[tuple[float, float]]] = {}
@@ -191,7 +193,8 @@ def _phase_section(steps: list[dict], scans: list[dict] = (),
             + "; ".join(says))
     for title, kernels, programs in (
             ("short convolution", "kernels of one pass", convs),
-            ("streams' passes", "kernels of one read", streams)):
+            ("streams' passes", "kernels of one read", streams),
+            ("state-space scan", "kernels with the state in VMEM", ssds)):
         for prog in programs[-1:]:
             if prog.get("kernel") or prog.get("plain"):
                 lines.append(
@@ -304,7 +307,8 @@ def format_report(records: list[dict]) -> str:
             steps, events_of(records, "scan_program"),
             events_of(records, "delta_program"),
             events_of(records, "conv_program"),
-            events_of(records, "streams_program")))
+            events_of(records, "streams_program"),
+            events_of(records, "ssd_program")))
     else:
         lines.append("steps: none recorded")
 
