@@ -35,12 +35,14 @@ class TrainConfig:
     num_steps: Optional[int] = None  # LM window length override (default 35;
     # seq-parallel transformers need num_steps % seq_parallel == 0)
     # the part of a model this chip holds (models that can be held in part:
-    # the mellum2, granite4h, laguna_xs2, phi4flash, qwen3next and xing4
-    # families).
+    # the mellum2, granite4h, laguna_xs2, phi4flash, qwen3next, xing4 and
+    # nemotron3s families).
     # None = all
     layers_held: Optional[str] = None  # "N" the first N layers, or
     # "FIRST:COUNT" a stage anywhere (models.parse_layers_held)
     experts_held: Optional[str] = None  # "first:count" of each layer's experts
+    tensor_share: Optional[str] = None  # "index:of": member INDEX of the OF
+    # chips that share each layer's heads (models.parse_tensor_share)
     vocab_size: Optional[int] = None  # `tokens` dataset: ids 0..n-1, and so
     # the rows of the embedding and the head (the dataset's num_classes)
 
@@ -204,8 +206,9 @@ PRESETS: dict[str, dict] = {
     # and a head count by layer (models/laguna.py), and hybrid
     # linear-attention sparse (models/qwen3next.py), and latent attention
     # under four residual streams with bias-selected experts
-    # (models/xing4.py). `batch_size`: two sequences of 8,192 tokens a device
-    # and step, or one
+    # (models/xing4.py), and layers of one mixer each, Mamba-2, LatentMoE or
+    # attention (models/nemotronh.py). `batch_size`: two sequences of 8,192
+    # tokens a device and step, or one
     "mellum2": dict(_LM),
     "mellum2_tiny": dict(_LM_TINY),
     "granite4h": dict(_LM, batch_size=1),
@@ -218,6 +221,8 @@ PRESETS: dict[str, dict] = {
     "qwen3next_tiny": dict(_LM_TINY),
     "xing4": dict(_LM, batch_size=1),
     "xing4_tiny": dict(_LM_TINY),
+    "nemotron3s": dict(_LM, batch_size=1),
+    "nemotron3s_tiny": dict(_LM_TINY),
     "fcn5net": dict(dataset="mnist", batch_size=64, lr=0.05, max_epochs=10),
     "lr": dict(dataset="mnist", batch_size=64, lr=0.01, max_epochs=10),
 }

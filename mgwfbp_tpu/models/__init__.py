@@ -76,7 +76,8 @@ def create_model(
     dl_trainer.py:87-135). dataset/num_classes override the model's default;
     for image models a dataset override also retargets meta.input_shape so
     callers building batches from meta stay consistent. `share` (the part of
-    the model one chip holds: `layers_held`, `experts_held`) goes to the
+    the model one chip holds: `layers_held`, `experts_held`, `tensor_share`)
+    goes to the
     factories that take it (the mellum2 family); for any other model it is
     an error."""
     if name not in _REGISTRY:
@@ -342,7 +343,7 @@ def _lstman4(nc):
 def _held_lm_meta(name: str, nc: int, window_len: int) -> ModelMeta:
     """A decoder LM over the `tokens` dataset that takes its loss itself and
     can be held in part (the mellum2, granite4h, laguna_xs2, phi4flash,
-    qwen3next and xing4 families)."""
+    qwen3next, xing4 and nemotron3s families)."""
     return ModelMeta(
         name=name, dataset="tokens", num_classes=nc,
         input_shape=(window_len,), input_dtype=jnp.int32, task="lm",
@@ -365,16 +366,31 @@ def parse_layers_held(value) -> Optional[tuple[int, int]]:
         ) from None
 
 
+def parse_tensor_share(value) -> Optional[tuple[int, int]]:
+    """`--tensor-share` as (index, of): None, or INDEX:OF, member INDEX of
+    the OF chips that share each layer's heads."""
+    if value is None or isinstance(value, tuple):
+        return value
+    try:
+        index, of = (int(v) for v in str(value).split(":"))
+    except ValueError:
+        raise ValueError(
+            f"--tensor-share {value!r} is not INDEX:OF (two integers)"
+        ) from None
+    return index, of
+
+
 def _register_held_lm(name: str, load: Callable[[], tuple[Any, Any]],
                       window_len: int, takes_experts: bool,
-                      takes_first: bool = False):
+                      takes_first: bool = False, takes_tensor: bool = False):
     """A decoder held in part by layers and vocabulary and, with
-    `takes_experts`, by routed experts. `load` imports the family's module
-    when the model is first built and returns (class, shape). With
-    `takes_first` the module takes its layers as (first, count); the others
-    hold their first N and are handed N."""
+    `takes_experts`, by routed experts, and, with `takes_tensor`, by the
+    heads of each layer. `load` imports the family's module when the model
+    is first built and returns (class, shape). With `takes_first` the module
+    takes its layers as (first, count); the others hold their first N and
+    are handed N."""
     @register(name)
-    def _factory(nc, layers_held=None, experts_held=None):
+    def _factory(nc, layers_held=None, experts_held=None, tensor_share=None):
         cls, shape = load()
         nc = nc or shape.vocab_size
         layers = parse_layers_held(layers_held)
@@ -391,6 +407,13 @@ def _register_held_lm(name: str, load: Callable[[], tuple[Any, Any]],
         elif experts_held is not None:
             raise ValueError(
                 f"model {name!r} is dense: it has no experts to hold in part")
+        tensor = parse_tensor_share(tensor_share)
+        if takes_tensor:
+            share["tensor_share"] = tensor or (0, 1)
+        elif tensor is not None:
+            raise ValueError(
+                f"model {name!r} holds every layer's heads whole: "
+                "--tensor-share waits for a configuration that needs it")
         return (cls(vocab_size=nc, shape=shape, **share),
                 _held_lm_meta(name, nc, window_len))
 
@@ -437,6 +460,13 @@ def _xing4(tiny: bool):
     return xing4.Xing4LM, xing4.XING4_TINY if tiny else xing4.XING4
 
 
+def _nemotron3s(tiny: bool):
+    from mgwfbp_tpu.models import nemotronh
+
+    return nemotronh.NemotronHLM, (
+        nemotronh.NEMOTRON3S_TINY if tiny else nemotronh.NEMOTRON3S)
+
+
 # each family at its published widths, and at a size the CPU tests hold:
 # mellum2 (hidden 64, 2 key/value heads, 8 experts top 2, window 16);
 # laguna_xs2 (hidden 64, 6 / 8 query heads over 2 key heads of 16, 16 experts
@@ -449,15 +479,22 @@ def _xing4(tiny: bool):
 # xing4 (hidden 32, four residual streams, 4 latent-attention heads scoring
 # over 16 + 8 and summing values of 16, 8 experts top 2 chosen with a
 # selection bias and a shared one, four layers: two dense first), whose stage
-# may start anywhere
-for _name, _load, _experts, _first in (
-        ("mellum2", _mellum2, True, False),
-        ("laguna_xs2", _laguna_xs2, True, False),
-        ("granite4h", _granite4h, False, False),
-        ("phi4flash", _phi4flash, False, True),
-        ("qwen3next", _qwen3next, True, False),
-        ("xing4", _xing4, True, True)):
+# may start anywhere;
+# nemotron3s (hidden 32, seven layers of ONE mixer each, M E M * E M E: 8
+# Mamba heads of 8 over 4 B/C groups, 8 / 2 attention heads of 8, 8 relu^2
+# experts top 3 of width 24 in a latent of 16 and a shared one of 48), whose
+# stage may start anywhere and whose heads may be shared out (--tensor-share)
+for _name, _load, _experts, _first, _tensor in (
+        ("mellum2", _mellum2, True, False, False),
+        ("laguna_xs2", _laguna_xs2, True, False, False),
+        ("granite4h", _granite4h, False, False, False),
+        ("phi4flash", _phi4flash, False, True, False),
+        ("qwen3next", _qwen3next, True, False, False),
+        ("xing4", _xing4, True, True, False),
+        ("nemotron3s", _nemotron3s, True, True, True)):
     _register_held_lm(
-        _name, functools.partial(_load, False), 8192, _experts, _first)
+        _name, functools.partial(_load, False), 8192, _experts, _first,
+        _tensor)
     _register_held_lm(
-        _name + "_tiny", functools.partial(_load, True), 64, _experts, _first)
+        _name + "_tiny", functools.partial(_load, True), 64, _experts, _first,
+        _tensor)

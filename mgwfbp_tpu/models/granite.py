@@ -70,16 +70,14 @@ import numpy as np
 
 from mgwfbp_tpu.models.lm_parts import (
     _Leaves,
-    _conv_init,
-    _dt_bias_init,
     gated_mlp,
+    mamba2_leaves,
+    mamba2_mixer,
     rms_norm,
     token_losses,
 )
-from mgwfbp_tpu.ops import shortconv
 from mgwfbp_tpu.ops.blockattn import blockwise_attention
 from mgwfbp_tpu.ops.programs import counted
-from mgwfbp_tpu.ops.ssd import ssd_scan_in_place
 
 MAMBA, ATTENTION = "mamba", "attention"
 # the step's metrics carry these under HEALTH_PREFIX of train/step.py
@@ -130,41 +128,14 @@ GRANITE4H_TINY = GraniteShape(
 )
 
 
-def _a_log_init(key, shape, dtype=jnp.float32):
-    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
-
-
 def mamba_mixer(p: dict, u: jax.Array, shape: GraniteShape, scan_block: int):
     """The Mamba-2 mixer on the normed input u (B, T, hidden): (y (B, T,
     hidden), root mean square of the final state, most negative chunk sum of
-    log-decays)."""
-    b, t, _ = u.shape
-    inner = shape.mamba_inner
-    heads, hd = shape.mamba_heads, shape.mamba_head_dim
-    with jax.named_scope("ssm_in_proj"):
-        zxbcdt = u @ p["in_proj"]
-        z = zxbcdt[..., :inner]
-        xbc = zxbcdt[..., inner:inner + shape.conv_channels]
-        dt = zxbcdt[..., inner + shape.conv_channels:]
-    with jax.named_scope("ssm_conv"):
-        xbc = shortconv.causal_conv_silu(xbc, p["conv_w"], p["conv_b"])
-    with jax.named_scope("ssm_scan"):
-        xs = xbc[..., :inner].reshape(b, t, heads, hd)
-        dt = jax.nn.softplus(
-            dt.astype(jnp.float32) + p["dt_bias"].astype(jnp.float32))
-        a = -jnp.exp(p["a_log"].astype(jnp.float32))
-        # x, B and C where the convolution left them: the kernels pick
-        # their columns out of xbc, the plain form cuts B and C out; y
-        # comes back float32 with the `D x` skip on it
-        y, state, low = ssd_scan_in_place(
-            xbc, xs, dt, a, p["d"], chunk=shape.mamba_chunk,
-            block=scan_block)
-        state_rms = jnp.sqrt(jnp.mean(jnp.square(state)))
-    with jax.named_scope("ssm_gate_norm"):
-        y = y.reshape(b, t, inner) * jax.nn.silu(z.astype(jnp.float32))
-        y = rms_norm(y, p["gate_norm"], shape.rms_norm_eps).astype(u.dtype)
-    with jax.named_scope("ssm_out_proj"):
-        return y @ p["out_proj"], jax.lax.stop_gradient(state_rms), low
+    log-decays). `lm_parts.mamba2_mixer` at this model's one group."""
+    return mamba2_mixer(
+        p, u, heads=shape.mamba_heads, head_dim=shape.mamba_head_dim,
+        state=shape.mamba_state, groups=1, chunk=shape.mamba_chunk,
+        eps=shape.rms_norm_eps, scan_block=scan_block)
 
 
 def attention(p: dict, u: jax.Array, shape: GraniteShape, block: int):
@@ -209,15 +180,10 @@ def layer_leaves(kind: str, s: GraniteShape) -> tuple:
         return (("norm", (d,), True), ("wq", (d, dq), False),
                 ("wk", (d, dkv), False), ("wv", (d, dkv), False),
                 ("wo", (dq, d), False), *mlp)
-    inner, heads = s.mamba_inner, s.mamba_heads
     return (
         ("norm", (d,), True),
-        ("in_proj", (d, inner + s.conv_channels + heads), False),
-        ("conv_w", (s.mamba_conv, s.conv_channels), _conv_init),
-        ("conv_b", (s.conv_channels,), _conv_init),
-        ("dt_bias", (heads,), _dt_bias_init), ("a_log", (heads,), _a_log_init),
-        ("d", (heads,), True), ("gate_norm", (inner,), True),
-        ("out_proj", (inner, d), False), *mlp)
+        *mamba2_leaves(d, s.mamba_heads, s.mamba_head_dim, s.mamba_state, 1),
+        *mlp)
 
 
 class Granite4HLM(nn.Module):

@@ -1,9 +1,11 @@
 """What more than one of the decoders (mellum.py, granite.py, laguna.py,
-phi4flash.py, qwen3next.py) is built from: the leaves' declaration, the norm,
-the rotary embedding, the dense MLPs, the softmax router with the held
-experts' part of a sparse block and its counters, the loss a block of tokens
-at a time, and the state-space mixers' initializers. What one decoder alone
-uses is in its own file; no decoder imports another's.
+phi4flash.py, qwen3next.py, xing4.py, nemotronh.py) is built from: the
+leaves' declaration, the norm, the rotary embedding, the dense MLPs, the
+softmax router and the sigmoid router with a selection bias, the held
+experts' part of a sparse block (experts of three products or of two) and
+its counters, the Mamba-2 mixer, the loss a block of tokens at a time, and
+the state-space mixers' initializers. What one decoder alone uses is in its
+own file; no decoder imports another's.
 """
 
 from __future__ import annotations
@@ -15,8 +17,10 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from mgwfbp_tpu.ops import shortconv
 from mgwfbp_tpu.ops.groupmm import grouped_product
 from mgwfbp_tpu.ops.rowperm import combine_rows, take_rows
+from mgwfbp_tpu.ops.ssd import ssd_scan, ssd_scan_in_place
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 # the step's metrics carry the routing counts under these keys (HEALTH_PREFIX
@@ -136,14 +140,34 @@ def route(u: jax.Array, router: jax.Array, top_k: int):
     return idx, top / jnp.sum(top, axis=-1, keepdims=True)
 
 
-def held_experts(u, idx, weights, w_gate, w_up, w_down, first: int):
-    """The held experts' part of the sparse block for tokens u.
+def sigmoid_bias_route(u: jax.Array, router: jax.Array, bias: jax.Array,
+                       top_k: int, scaling: float, eps: float = 0.0):
+    """`noaux_tc`: s = sigmoid(u W_r) over ALL experts in float32 (operands
+    as stored, product at `highest`); the `top_k` largest of s + bias are
+    chosen, and weigh s (not s + bias) over (the sum of the chosen + `eps`),
+    times `scaling`. (indices (N, k), weights (N, k), share of the N x k
+    choices that s alone would not have made)."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        u.astype(jnp.float32), router.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST,
+    ))
+    _, idx = lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    top = jnp.take_along_axis(scores, idx, axis=-1)
+    total = jnp.sum(top, axis=-1, keepdims=True)
+    weights = top / (total + eps if eps else total) * scaling
+    _, unbiased = lax.top_k(scores, top_k)
+    swapped = jnp.mean(jnp.all(
+        idx[:, :, None] != unbiased[:, None, :], axis=-1).astype(jnp.float32))
+    return idx, weights, lax.stop_gradient(swapped)
 
-    u (N, D); idx, weights (N, k) from `route`; w_gate, w_up (E, D, F) and
-    w_down (E, F, D) the E held experts, expert `first` of the model first.
-    Returns (y (N, D), tokens per held expert (E,), assignments to a held
-    expert that no group took (a count; 0 by construction))."""
-    count = w_gate.shape[0]
+
+def _grouped_experts(u, idx, weights, count: int, first: int, experts):
+    """The held experts' part of the sparse block for tokens u (N, D): the
+    N x k assignments sorted by held expert, `experts(rows (N * k, D), sizes)
+    -> (out (N * k, D'), a statistic)` on the rows as grouped, and the k
+    terms of a token added up under its weights. Returns (y (N, D'), tokens
+    per held expert (E,), assignments to a held expert that no group took (a
+    count; 0 by construction), the statistic)."""
     local = idx - first
     held = (local >= 0) & (local < count)
     # unheld assignments sort behind every held expert, into no group
@@ -157,13 +181,48 @@ def held_experts(u, idx, weights, w_gate, w_up, w_down, first: int):
     # only on the CPU), forward and backward, and neither permutation moves
     # or reads them (ops/rowperm.py): never trusted
     rows = take_rows(u, order, inverse, sizes)
-    gate = grouped_product(rows, w_gate, sizes)
-    up = grouped_product(rows, w_up, sizes)
-    mid = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32))
-    out = grouped_product(mid.astype(u.dtype), w_down, sizes)
+    out, stat = experts(rows, sizes)
     y = combine_rows(out, order, inverse, weights, sizes)
     dropped = jnp.sum(held) - jnp.sum(sizes)
-    return y, sizes, dropped
+    return y, sizes, dropped, stat
+
+
+def held_experts(u, idx, weights, w_gate, w_up, w_down, first: int):
+    """The held experts' part of the sparse block for tokens u, experts of
+    three products (SwiGLU).
+
+    u (N, D); idx, weights (N, k) from `route`; w_gate, w_up (E, D, F) and
+    w_down (E, F, D) the E held experts, expert `first` of the model first.
+    Returns (y (N, D), tokens per held expert (E,), assignments to a held
+    expert that no group took (a count; 0 by construction))."""
+    def experts(rows, sizes):
+        gate = grouped_product(rows, w_gate, sizes)
+        up = grouped_product(rows, w_up, sizes)
+        mid = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32))
+        return grouped_product(mid.astype(u.dtype), w_down, sizes), None
+
+    return _grouped_experts(
+        u, idx, weights, w_gate.shape[0], first, experts)[:3]
+
+
+def held_relu2_experts(u, idx, weights, w_up, w_down, first: int):
+    """`held_experts` for experts of two products and no gate: relu(u
+    W_up)^2 W_down, w_up (E, D, F), w_down (E, F, D). Returns a fourth
+    value: the share of the held experts' hidden units, over the rows in a
+    group, that relu left above zero (float32, no gradient; 0 where no row
+    is in a group)."""
+    def experts(rows, sizes):
+        up = grouped_product(rows, w_up, sizes).astype(jnp.float32)
+        # the rows past the last group are unwritten: not counted
+        in_groups = jnp.sum(sizes)
+        grouped = (jnp.arange(up.shape[0]) < in_groups)[:, None]
+        active = jnp.sum((up > 0) & grouped, dtype=jnp.float32) / jnp.maximum(
+            in_groups * up.shape[1], 1).astype(jnp.float32)
+        mid = jnp.square(jax.nn.relu(up)).astype(u.dtype)
+        return (grouped_product(mid, w_down, sizes),
+                lax.stop_gradient(active))
+
+    return _grouped_experts(u, idx, weights, w_up.shape[0], first, experts)
 
 
 def routing_counters(stats: dict, assignments: int) -> dict:
@@ -210,6 +269,95 @@ def _dt_bias_init(key, shape, dtype=jnp.float32):
     return dt + jnp.log(-jnp.expm1(-dt))
 
 
+def _bias_init(width: float):
+    """A selection bias's seeded draw, uniform in +-`width`."""
+    def init(key, shape, dtype=jnp.float32):
+        return jax.random.uniform(key, shape, dtype, -width, width)
+
+    return init
+
+
 def _conv_init(key, shape, dtype=jnp.float32):
     bound = 1.0 / math.sqrt(_CONV_TAPS)
     return jax.random.uniform(key, shape, dtype, -bound, bound)
+
+
+def _a_log_init(key, shape, dtype=jnp.float32):
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+
+def mamba2_leaves(hidden: int, heads: int, head_dim: int, state: int,
+                  groups: int, out_init=False) -> tuple:
+    """A Mamba-2 mixer's leaves for `_Leaves`: `heads` heads of `head_dim`
+    over `groups` pairs of B and C of `state` each; `out_init` the output
+    projection's initializer (False: normal 0.02)."""
+    inner = heads * head_dim
+    channels = inner + 2 * groups * state
+    return (
+        ("in_proj", (hidden, inner + channels + heads), False),
+        ("conv_w", (_CONV_TAPS, channels), _conv_init),
+        ("conv_b", (channels,), _conv_init),
+        ("dt_bias", (heads,), _dt_bias_init), ("a_log", (heads,), _a_log_init),
+        ("d", (heads,), True), ("gate_norm", (inner,), True),
+        ("out_proj", (inner, hidden), out_init))
+
+
+def mamba2_mixer(p: dict, u: jax.Array, *, heads: int, head_dim: int,
+                 state: int, groups: int, chunk: int, eps: float,
+                 scan_block: int):
+    """The Mamba-2 mixer on the normed input u (B, T, hidden): (y (B, T,
+    hidden), root mean square of the final state, most negative chunk sum of
+    log-decays). [z | xBC | dt] = u W_in with xBC = [xs (heads x head_dim) |
+    B (groups x state) | C (groups x state)]; head h reads group h // (heads
+    / groups); the gated norm runs over each group's heads x head_dim /
+    groups channels apart, gate first (one group: over all of them at once).
+    One group goes down `ssd_scan_in_place` (x, B and C where the
+    convolution left them); several go a group at a time down `ssd_scan`."""
+    b, t, _ = u.shape
+    inner = heads * head_dim
+    channels = inner + 2 * groups * state
+    f32 = jnp.float32
+    with jax.named_scope("ssm_in_proj"):
+        zxbcdt = u @ p["in_proj"]
+        z = zxbcdt[..., :inner]
+        xbc = zxbcdt[..., inner:inner + channels]
+        dt = zxbcdt[..., inner + channels:]
+    with jax.named_scope("ssm_conv"):
+        xbc = shortconv.causal_conv_silu(xbc, p["conv_w"], p["conv_b"])
+    with jax.named_scope("ssm_scan"):
+        xs = xbc[..., :inner].reshape(b, t, heads, head_dim)
+        dt = jax.nn.softplus(dt.astype(f32) + p["dt_bias"].astype(f32))
+        a = -jnp.exp(p["a_log"].astype(f32))
+        if groups == 1:
+            # x, B and C where the convolution left them: the kernels pick
+            # their columns out of xbc, the plain form cuts B and C out; y
+            # comes back float32 with the `D x` skip on it
+            y, last, low = ssd_scan_in_place(
+                xbc, xs, dt, a, p["d"], chunk=chunk, block=scan_block)
+        else:
+            per = heads // groups
+
+            def one(g):  # group g's heads over its own B and C
+                own = slice(g * per, (g + 1) * per)
+                at = inner + g * state
+                return ssd_scan(
+                    xs[:, :, own], dt[..., own], a[own],
+                    xbc[..., at:at + state],
+                    xbc[..., at + groups * state:at + (groups + 1) * state],
+                    chunk=chunk, block=scan_block)
+
+            ys, lasts, lows = zip(*map(one, range(groups)))
+            y = jnp.concatenate(ys, axis=2).astype(f32) \
+                + p["d"].astype(f32)[:, None] * xs.astype(f32)
+            last = jnp.concatenate(lasts, axis=1)
+            low = jnp.min(jnp.stack(lows))
+        state_rms = jnp.sqrt(jnp.mean(jnp.square(last)))
+    with jax.named_scope("ssm_gate_norm"):
+        y = y.reshape(b, t, inner) * jax.nn.silu(z.astype(f32))
+        scale = p["gate_norm"]
+        if groups > 1:
+            y = y.reshape(b, t, groups, inner // groups)
+            scale = scale.reshape(groups, inner // groups)
+        y = rms_norm(y, scale, eps).reshape(b, t, inner).astype(u.dtype)
+    with jax.named_scope("ssm_out_proj"):
+        return y @ p["out_proj"], lax.stop_gradient(state_rms), low

@@ -103,11 +103,13 @@ from jax import lax
 from mgwfbp_tpu.models.lm_parts import (
     MOE_DROPPED_KEY,
     MOE_TOKENS_KEY,
+    _bias_init,
     _Leaves,
     apply_rope,
     held_experts,
     rms_norm,
     routing_counters,
+    sigmoid_bias_route as route,
     swiglu,
     token_losses,
     yarn_inv_freq,
@@ -220,13 +222,6 @@ def _b_init(key, shape, dtype=jnp.float32):
         [jnp.zeros((2 * n,), dtype), 2.0 * jnp.eye(n, dtype=dtype).reshape(-1)])
 
 
-def _bias_init(width: float):
-    def init(key, shape, dtype=jnp.float32):
-        return jax.random.uniform(key, shape, dtype, -width, width)
-
-    return init
-
-
 def rope_inv_freq(s: Xing4Shape) -> jax.Array:
     return yarn_inv_freq(
         s.qk_rope_head_dim, s.rope_theta, s.yarn_factor, s.yarn_original_len,
@@ -318,26 +313,6 @@ def latent_attention(p: dict, u: jax.Array, s: Xing4Shape, block: int):
         a = blockwise_attention(q, k, v, block=block, scale=1.0)
     with jax.named_scope("mla_out_proj"):
         return a.reshape(b, t, h * dv) @ p["wo"], latent_rms
-
-
-def route(u: jax.Array, router: jax.Array, bias: jax.Array, top_k: int,
-          scaling: float):
-    """`noaux_tc`: s = sigmoid(u W_r) over ALL experts in float32 (operands
-    as stored, product at `highest`); the `top_k` largest of s + bias are
-    chosen, and weigh s (not s + bias) over the sum of the chosen, times
-    `scaling`. (indices (N, k), weights (N, k), share of the N x k choices
-    that s alone would not have made)."""
-    scores = jax.nn.sigmoid(jnp.dot(
-        u.astype(jnp.float32), router.astype(jnp.float32),
-        precision=lax.Precision.HIGHEST,
-    ))
-    _, idx = lax.top_k(scores + bias.astype(jnp.float32), top_k)
-    top = jnp.take_along_axis(scores, idx, axis=-1)
-    weights = top / jnp.sum(top, axis=-1, keepdims=True) * scaling
-    _, unbiased = lax.top_k(scores, top_k)
-    swapped = jnp.mean(jnp.all(
-        idx[:, :, None] != unbiased[:, None, :], axis=-1).astype(jnp.float32))
-    return idx, weights, lax.stop_gradient(swapped)
 
 
 def sparse_block(p: dict, x: jax.Array, s: Xing4Shape, first: int):

@@ -78,7 +78,12 @@ over the sub-layers held; `mla_kv_latent_rms`, the root mean square of latent
 attention's c_kv before its norm, mean over the layers held;
 `moe_bias_swap_share`, the share of the tokens' expert choices that score +
 selection bias made and the score alone would not have, mean over the sparse
-layers held; read likewise.
+layers held; read likewise. models/nemotronh.py:
+Granite's two, Mellum 2's four and `moe_bias_swap_share` as above, and
+`moe_latent_rms`, the root mean square of the latent its experts read, and
+`moe_relu2_active`, the share of the held experts' hidden units, over the
+rows in a group, that relu left above zero, both means over the `E` layers
+held; read likewise.
 
 The host runs about one step ahead of the chip: no span after the dispatch
 of step k needs step k itself. What stops it is the first read of step

@@ -599,7 +599,7 @@ class Trainer:
     # ------------------------------------------------------------------
     def _create_model(self, num_classes: Optional[int] = None):
         """(module, meta) for the configuration: the model, and the part of
-        it this chip holds (--layers-held, --experts-held)."""
+        it this chip holds (--layers-held, --experts-held, --tensor-share)."""
         config = self.config
         experts = None
         if config.experts_held:
@@ -614,6 +614,7 @@ class Trainer:
         return zoo.create_model(
             config.dnn, dataset=config.dataset, num_classes=num_classes,
             layers_held=config.layers_held, experts_held=experts,
+            tensor_share=config.tensor_share,
         )
 
     def _build_loaders(self):

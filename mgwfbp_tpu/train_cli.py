@@ -106,18 +106,28 @@ def build_parser() -> argparse.ArgumentParser:
                         "in part, starting at layer FIRST: one pipeline "
                         "stage's share. A bare N is 0:N, the first N "
                         "(mellum2, granite4h, laguna_xs2, qwen3next take no "
-                        "other FIRST; phi4flash's and xing4's stage may start "
-                        "anywhere and its layers keep their published "
-                        "indices)")
+                        "other FIRST; phi4flash's, xing4's and nemotron3s's "
+                        "stage may start anywhere and its layers keep their "
+                        "published indices)")
     p.add_argument("--experts-held", dest="experts_held", default=None,
                    metavar="FIRST:COUNT",
                    help="hold only COUNT experts of every sparse layer, "
                         "starting at expert FIRST (mellum2, laguna_xs2, "
-                        "qwen3next, xing4): one chip's share of an "
+                        "qwen3next, xing4, nemotron3s): one chip's share of an "
                         "expert-parallel "
                         "group. The router still scores all experts; what "
                         "the absent ones "
                         "would add is left out")
+    p.add_argument("--tensor-share", dest="tensor_share", default=None,
+                   metavar="INDEX:OF",
+                   help="hold what member INDEX of OF chips that share each "
+                        "layer's heads holds (nemotron3s): its Mamba heads "
+                        "with the B/C groups they read, its query heads with "
+                        "their key-value head, its columns of the shared "
+                        "expert; router, latent projections and norms whole. "
+                        "One chip's share of a tensor-parallel group, run "
+                        "without the group's all-reduce: the partial result "
+                        "goes on to the next layer")
     p.add_argument("--vocab-size", dest="vocab_size", type=int, default=None,
                    help="--dataset tokens: ids 0..N-1, which are also the "
                         "rows of the embedding and the head held here")
@@ -219,7 +229,7 @@ def config_from_args(args: argparse.Namespace) -> TrainConfig:
             "comm_op", "dcn_slices", "autotune_steps", "schedule_cache",
             "telemetry_dir", "ckpt_every_steps", "bad_step_limit",
             "metrics_port", "ckpt_format", "layers_held", "experts_held",
-            "vocab_size", "optimizer",
+            "tensor_share", "vocab_size", "optimizer",
         )
         if getattr(args, k, None) is not None
     }

@@ -25,7 +25,8 @@ ALL_IMAGE_MODELS = [
     if n not in ("lstm", "lstman4", "transformer", "mellum2", "mellum2_tiny",
                  "granite4h", "granite4h_tiny", "laguna_xs2",
                  "laguna_xs2_tiny", "phi4flash", "phi4flash_tiny",
-                 "qwen3next", "qwen3next_tiny", "xing4", "xing4_tiny")
+                 "qwen3next", "qwen3next_tiny", "xing4", "xing4_tiny",
+                 "nemotron3s", "nemotron3s_tiny")
 ]
 
 
@@ -253,6 +254,55 @@ def test_xing4_traces_and_counts_its_parameters(name, share, want, layers):
         assert stats["health/mla_kv_latent_rms"].shape == (3,)
         assert stats["health/moe_bias_swap"].shape == (2,)  # sparse layers
         assert stats["health/moe_tokens"].shape == (2, 4)
+
+
+@pytest.mark.parametrize("name,share,want,layers", [
+    # the whole model: 40 Mamba-2, 40 LatentMoE and 8 attention layers of one
+    # mixer each, the untied vocabulary: the published 120.67 B less the
+    # multi-token-prediction module
+    ("nemotron3s", {}, 120_668_707_840, tuple(range(88))),
+    # one chip's share: the first whole period (layers 26 to 36), 8 of 512
+    # experts, member 0 of 8 chips' heads, an eighth of the ids
+    ("nemotron3s", dict(num_classes=16384, layers_held="26:11",
+                        experts_held=(0, 8), tensor_share="0:8"),
+     508_189_680, tuple(range(26, 37))),
+    ("nemotron3s_tiny", dict(layers_held=(1, 5), experts_held=(2, 4),
+                             tensor_share=(1, 2)), None, (1, 2, 3, 4, 5)),
+])
+def test_nemotron3s_traces_and_counts_its_parameters(name, share, want, layers):
+    model, meta = zoo.create_model(name, **share)
+    assert meta.task == "lm" and not meta.has_carry and meta.fused_loss
+    assert meta.dataset == "tokens"
+    x = _example_input(meta)
+    variables = jax.eval_shape(
+        lambda: model.init({"params": jax.random.PRNGKey(0)}, x, train=False))
+    params = variables["params"]
+    if want is not None:
+        assert sum(int(np.prod(leaf.shape))
+                   for leaf in jax.tree_util.tree_leaves(params)) == want
+    # a stage's layers keep their published numbers
+    assert {k for k in params if k.startswith("layer_")} \
+        == {f"layer_{i}" for i in layers}
+    assert set(params["out"]) == {"norm", "head"}  # untied
+    # a layer is ONE mixer behind ONE norm, its kind the pattern's letter
+    pattern = model.shape.pattern
+    for i in layers:
+        leaves = set(params[f"layer_{i}"])
+        assert {"M": "in_proj", "E": "router", "*": "wq"}[pattern[i]] in leaves
+        assert len(leaves & {"in_proj", "router", "wq"}) == 1
+        assert [k for k in leaves if k.endswith("norm")] in (
+            ["norm"], ["gate_norm", "norm"], ["norm", "gate_norm"])
+    if name == "nemotron3s_tiny":
+        logits = jax.eval_shape(lambda v: model.apply(v, x), variables)
+        assert logits.shape == (2, 64, meta.num_classes)
+        per_token, stats = jax.eval_shape(
+            lambda v: model.apply(v, x, targets=x, train=True), variables)
+        assert per_token.shape == (2, 64)
+        assert stats["health/ssm_state"].shape == (2,)  # Mamba layers held
+        assert stats["health/moe_tokens"].shape == (2, 4)  # `E` layers held
+        assert stats["health/moe_bias_swap"].shape == (2,)
+        assert stats["health/moe_latent_rms"].shape == (2,)
+        assert stats["health/moe_relu2_active"].shape == (2,)
 
 
 @pytest.mark.parametrize(

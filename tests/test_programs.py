@@ -28,7 +28,7 @@ from mgwfbp_tpu.ops import (
 
 PACKAGE = pathlib.Path(mgwfbp_tpu.__file__).parent
 DECODERS = ("mellum", "granite", "laguna", "phi4flash", "qwen3next",
-            "xing4")
+            "xing4", "nemotronh")
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 
 

@@ -13,7 +13,7 @@ import numpy as np
 import pytest
 
 from mgwfbp_tpu.models import granite
-from mgwfbp_tpu.ops import programs, ssd
+from mgwfbp_tpu.ops import programs, shortconv, ssd
 from mgwfbp_tpu.ops.ssd import ssd_scan
 
 HI = jax.lax.Precision.HIGHEST
@@ -287,7 +287,7 @@ def _parent_mamba_mixer(p, u, shape, scan_block):
         xbc = zxbcdt[..., inner:inner + shape.conv_channels]
         dt = zxbcdt[..., inner + shape.conv_channels:]
     with jax.named_scope("ssm_conv"):
-        xbc = granite.shortconv.causal_conv_silu(
+        xbc = shortconv.causal_conv_silu(
             xbc, p["conv_w"], p["conv_b"])
     with jax.named_scope("ssm_scan"):
         xs = xbc[..., :inner].reshape(b, t, heads, hd)

@@ -362,6 +362,37 @@ def test_report_says_the_residual_streams_counters():
         [header, step(1, delta_state_rms=0.1)])
 
 
+def test_report_says_the_latent_experts_counters():
+    """`moe_latent_rms`, `moe_relu2_active` of the `step` records
+    (models/nemotronh.py, by the health drain's road) on a line of their own
+    with the selection bias's share, beside the routing line every
+    `held_experts` model has; the selection bias's share alone names neither
+    that family nor the residual streams'."""
+    import telemetry_report
+
+    def step(i, **counters):
+        return {"event": "step", "step": i, "epoch": 0, "start_s": float(i),
+                "dur_s": 0.1, "phases": {"guard": [i + 0.2, 0.1]}, **counters}
+
+    header = {"event": "header", "schema_version": 2, "wall": 0.0}
+    routing = dict(moe_here=0.0156, moe_load_max=400.0, moe_load_mean=352.0,
+                   moe_dropped=0.0)
+    report = telemetry_report.format_report([
+        header, step(1),
+        step(2, moe_latent_rms=0.5, moe_relu2_active=0.50,
+             moe_bias_swap_share=0.10, ssm_state_rms=0.1, **routing),
+        step(3, moe_latent_rms=0.7, moe_relu2_active=0.48,
+             moe_bias_swap_share=0.20, ssm_state_rms=0.1, **routing)])
+    assert ("latent experts (2 steps): latent's rms 0.6; share of hidden "
+            "units relu left on 0.49; share of choices the selection bias "
+            "made 0.15") in report
+    assert "expert routing (2 steps)" in report
+    assert "residual streams" not in report and "hybrid decoder" not in report
+    alone = telemetry_report.format_report(
+        [header, step(1, moe_bias_swap_share=0.1)])
+    assert "latent experts" not in alone and "residual streams" not in alone
+
+
 def test_report_says_the_way_the_short_convolutions_went():
     """The newest `conv_program` record (ops/shortconv.py) under the phase
     table, whatever the model family; no line where it counts none (the

@@ -1,5 +1,6 @@
 """Ask the TPU's compiler, without a chip: whole step programs (ResNet-50 on
-one chip, the merged exchange over four, the Qwen3-Next cell's step lowered).
+one chip, the merged exchange over four, the Qwen3-Next and Xing4.0 cells'
+steps lowered, the Nemotron 3 Super cell's compiled).
 
 libtpu is installed here and compiles for a chip that is described, not
 attached (`topologies.get_topology_desc`, topology v5e:2x2), so what the
@@ -304,6 +305,56 @@ def test_xing4_step_counts_five_cores_through_the_kernel(topo, monkeypatch):
     # the streams between the sub-layers are bf16, four times the hidden size
     assert "4x1x8192x3584xbf16" in text
     assert "4x1x8192x3584xf32" not in text.split("func.func")[1][:2000]
+
+
+def test_nemotron3s_step_compiles_and_fits_one_v5e_chip(topo, monkeypatch):
+    """The Nemotron 3 Super cell's step (layers 26 to 36, 8 of 512 experts,
+    member 0 of 8 chips' heads, 16,384 ids, one sequence of 8,192, AdamW as
+    the cell trains) traced as for a TPU (said so by the test:
+    `traced_for_tpu` asks the default backend, which is the CPU here) and
+    COMPILED for the described chip, the second long compile of this file
+    (two minutes): 508,189,680 parameters are held, every op goes down its
+    kernels (the five scans at 16 heads over one group at the published chunk
+    of 128, the five convolutions at 1,280 channels, the core at 4 query
+    heads over 1, the ten grouped products at 180,224 rows), and the state
+    (5.7 GiB: float32 weights and two moments) with the step's temporaries
+    fits 16 GB with room: the N x k-row arrays of the five `E` layers are the
+    largest of them."""
+    from mgwfbp_tpu.ops import programs
+
+    monkeypatch.setattr(programs, "traced_for_tpu", lambda: True)
+    mesh = Mesh(np.asarray(topo.devices[:1]), (DATA_AXIS,))
+    model, meta = zoo.create_model(
+        "nemotron3s", num_classes=16384, layers_held="26:11",
+        experts_held=(0, 8), tensor_share="0:8")
+    tx = make_optimizer(
+        3e-4, momentum=0.9, weight_decay=0.1, lr_schedule="cosine",
+        dataset="tokens", max_epochs=40, warmup_epochs=5,
+        num_batches_per_epoch=32, norm_clip=1.0, optimizer="adamw", b2=0.95,
+    )[0]
+    state, batch = _abstract_step_args(model, meta, tx, mesh, 1)
+    assert sum(int(np.prod(leaf.shape)) for leaf in
+               jax.tree_util.tree_leaves(state.params)) == 508189680
+    batch["y"] = jax.ShapeDtypeStruct(
+        batch["x"].shape, jnp.int32, sharding=batch["x"].sharding)
+    step = make_train_step(
+        model, meta, tx, mesh, None, compute_dtype=jnp.bfloat16, donate=True)
+    lowered = step.lower(state, batch)
+    traced = step.traced_programs
+    assert traced["attention"] == {"kernel": 1, "blocks": 0}
+    assert traced["ssd"] == {"kernel": 5, "plain": 0, "programs": 2}
+    assert traced["conv"] == {"kernel": 5, "plain": 0, "programs": 2}
+    assert traced["experts"] == {"kernel": 10, "ragged": 0, "programs": 4}
+    assert traced["rows"]["rows_programs"] == 1
+    # the residual stream between the layers is bf16 at the full 4,096, the
+    # latent the experts read is 1,024 wide, a row an assignment
+    text = lowered.as_text()
+    assert "1x8192x4096xbf16" in text and "180224x1024xbf16" in text
+    mem = lowered.compile().memory_analysis()
+    need = mem.temp_size_in_bytes + mem.argument_size_in_bytes
+    assert 5.6 * 2 ** 30 < mem.argument_size_in_bytes < 5.8 * 2 ** 30
+    assert need < 12 * 2 ** 30, f"{need / 2 ** 30:.2f} GiB"
+    assert need < HBM_BYTES
 
 
 def test_granite_mixer_compiles_with_the_scan_as_two_kernel_programs(

@@ -29,14 +29,16 @@ pytestmark = pytest.mark.usefixtures("_compile_cache_off")
     (1, 80, 40, 64, None, 0.125),
     (1, 80, 40, 64, 512, 0.125),
     (2, 16, 2, 256, None, 1.0),
+    (1, 4, 1, 128, None, 128 ** -0.5),
 ], ids=["xing4-latent-scores-192-values-128",
         "mellum2-full", "mellum2-window-1024", "granite4h",
         "laguna-xs2-window-512-64-heads", "laguna-xs2-full-48-heads-groups-of-6",
         "phi4flash-full-80-stacked-heads", "phi4flash-window-512-80-stacked-heads",
-        "qwen3next-full-head-256-groups-of-8"])
+        "qwen3next-full-head-256-groups-of-8",
+        "nemotron3s-a-tensor-share-4-heads-over-1"])
 def test_attention_core_compiles_for_v5e_at_the_cells_shapes(
         topo, b, h, hkv, d, window, scale):
-    """ops/blockattn.py's fused kernel, forward and backward, at the nine
+    """ops/blockattn.py's fused kernel, forward and backward, at the ten
     call shapes of the language cells (T 8,192, bf16; the newest scores over
     192 and sums values of 128) and the tiles the shape test gives them: the
     tiles fit VMEM and the backward compiles. The kernel path is called

@@ -28,8 +28,12 @@ pytestmark = pytest.mark.usefixtures("_compile_cache_off")
     (131072, 2304, 896, 16), (131072, 896, 2304, 16),
     (65536, 2048, 512, 32), (65536, 512, 2048, 32),
     (163840, 2048, 512, 32), (163840, 512, 2048, 32),
+    # 8,192 tokens x 22: the widest routing; N 2,688 is 21 lane tiles, which
+    # `_fitted` halves to 1,408 with a remainder tile of 1,280
+    (180224, 1024, 2688, 8), (180224, 2688, 1024, 8),
 ], ids=["mellum2-gate-up", "mellum2-down", "laguna-xs2-gate-up",
-        "laguna-xs2-down", "qwen3next-gate-up", "qwen3next-down"])
+        "laguna-xs2-down", "qwen3next-gate-up", "qwen3next-down",
+        "nemotron3s-up-from-the-latent", "nemotron3s-down-to-the-latent"])
 def test_grouped_product_compiles_for_v5e_at_the_cells_shapes(
         topo, m, k, n, groups):
     """ops/groupmm.py's tiled kernel with both transposes at the sparse
@@ -57,9 +61,10 @@ def test_grouped_product_compiles_for_v5e_at_the_cells_shapes(
 
 # Qwen3-Next's k 10: a block's 256 x 10 scalars are no whole SMEM tiles, which
 # Mosaic refuses ("not divisible by tiling"); the kernel pads them (PR 40)
+# Nemotron 3 Super's k 22 over the latent of 1,024: 180,224 rows
 CELLS = [(16384, 8, 2304, 896, 16), (8192, 8, 2048, 512, 32),
-         (16384, 10, 2048, 512, 32)]
-CELL_IDS = ["mellum2", "laguna-xs2", "qwen3next"]
+         (16384, 10, 2048, 512, 32), (8192, 22, 1024, 2688, 8)]
+CELL_IDS = ["mellum2", "laguna-xs2", "qwen3next", "nemotron3s"]
 
 
 @pytest.mark.parametrize("n,k,d,f,groups", CELLS, ids=CELL_IDS)
@@ -109,8 +114,10 @@ def test_held_experts_compiles_for_v5e_with_no_gather_from_all_rows(
     """The whole expert block as the models call it (under `jax.checkpoint`),
     value and gradients, at the sparse cells' shapes, traced as for a TPU:
     its `tpu_custom_call`s are the three grouped products' (forward, again in
-    the recomputation, and two transposes each) and the combine's (forward
-    and as d `u`): 3 + 3 + 6 + 2, no `ragged-dot`, and the only `gather`s
+    the recomputation, and two transposes each; two products where the
+    experts have no gate: `held_relu2_experts` at k 22) and the combine's
+    (forward and as d `u`): 3 + 3 + 6 + 2, no `ragged-dot`, and the only
+    `gather`s
     that produce an (M, D) array are the dispatch's own from the (N, D)
     table, forward and recomputed (the parent's program held six)."""
     from mgwfbp_tpu.models import lm_parts
@@ -124,17 +131,22 @@ def test_held_experts_compiles_for_v5e_with_no_gather_from_all_rows(
     def shape(dims, dtype=bf):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
 
+    products = 2 if k == 22 else 3  # experts of relu(u W_up)^2 W_down
+    experts = lm_parts.held_experts if products == 3 \
+        else lm_parts.held_relu2_experts
+
     def loss(u, weights, ws, idx):
-        y, _, dropped = jax.checkpoint(
-            lm_parts.held_experts, static_argnums=6)(u, idx, weights, *ws, 0)
+        y, _, dropped, *_ = jax.checkpoint(
+            experts, static_argnums=3 + products)(u, idx, weights, *ws, 0)
         return jnp.sum(y.astype(jnp.float32)) + dropped
 
     compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
         shape((n, d)), shape((n, k), jnp.float32),
-        (shape((groups, d, f)), shape((groups, d, f)), shape((groups, f, d))),
+        (*(shape((groups, d, f)) for _ in range(products - 1)),
+         shape((groups, f, d))),
         shape((n, k), jnp.int32)).compile()
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") == 3 + 3 + 6 + 2
+    assert text.count("tpu_custom_call") == 4 * products + 2
     assert "ragged-dot" not in text
     assert 1 <= len(
         re.findall(rf"= \w+\[{m},{d}\]\S* gather\(", text)) <= 2

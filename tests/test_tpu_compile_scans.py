@@ -263,6 +263,9 @@ def test_selective_scan_kernels_keep_the_state_off_hbm_on_a_v5e(topo):
     (jnp.float32, 2, 512, 4, 128, 256, 128),   # whole lane tiles, two of state
     (jnp.bfloat16, 2, 512, 4, 64, 128, 128),   # half lane tiles, short chunks
     (jnp.bfloat16, 1, 512, 2, 128, 128, 256),  # one head a lane tile
+    # the Nemotron 3 Super cell: a tensor share's 16 heads over ONE B/C
+    # group, in place at 1,280 columns, the published chunk of 128
+    (jnp.bfloat16, 1, 8192, 16, 64, 128, 128),
 ])
 def test_ssd_kernels_compile_for_v5e_wherever_they_are_chosen(
         topo, dtype, bsz, t, h, p, n, chunk):

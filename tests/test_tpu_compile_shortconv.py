@@ -21,11 +21,13 @@ ROWS = shortconv._ROWS
 
 
 @pytest.mark.parametrize("b,t,c,k,dtype,bias", [
-    # the three cells: Qwen3-Next's q | k | v, Granite 4.0-H's xBC,
+    # three older cells: Qwen3-Next's q | k | v, Granite 4.0-H's xBC,
     # Phi-4-mini-flash's xs
     (2, 8192, 8192, 4, jnp.bfloat16, False),
     (1, 8192, 4352, 4, jnp.bfloat16, True),
     (1, 8192, 5120, 4, jnp.bfloat16, True),
+    # Nemotron 3 Super's xBC at a tensor share: 16 heads of 64 and one group
+    (1, 8192, 1280, 4, jnp.bfloat16, True),
     # the corners the rule admits: float32; one lane tile and one block of
     # positions; one tap and a sublane tile of taps; strips in uneven groups
     (2, 2 * ROWS, 2688, 4, jnp.float32, True),

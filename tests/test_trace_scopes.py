@@ -290,3 +290,36 @@ XING4_SCOPES = [
 ])
 def test_xing4_scopes_classify(op_name, want):
     assert trace_scopes.classify(op_name, XING4_SCOPES) == want
+
+
+NEMOTRON3S_SCOPES = [
+    "ssm_in_proj", "ssm_conv", "ssm_scan", "ssm_gate_norm", "ssm_out_proj",
+    "attn_proj", "attn_full", "moe_route", "moe_latent_down", "moe_experts",
+    "moe_latent_up", "moe_shared", "lm_head", "loss",
+]
+
+
+@pytest.mark.parametrize("op_name,want", [
+    # the scopes of a decoder whose layer is one mixer (models/nemotronh.py):
+    # every layer is under jax.checkpoint; `moe_latent_down` and
+    # `moe_latent_up` are no prefixes of one another or of `moe_experts`
+    ("jit(step)/jvp(NemotronHLM)/checkpoint/moe_latent_down/dot_general",
+     ("moe_latent_down", "forward")),
+    ("jit(step)/transpose(jvp(NemotronHLM))/checkpoint/rematted_computation/"
+     "moe_latent_up/dot_general", ("moe_latent_up", "backward")),
+    ("jit(step)/transpose(jvp(NemotronHLM))/checkpoint/moe_experts/"
+     "pallas_call", ("moe_experts", "backward")),
+    ("jit(step)/jvp(NemotronHLM)/checkpoint/moe_shared/integer_pow",
+     ("moe_shared", "forward")),
+    ("jit(step)/jvp(NemotronHLM)/checkpoint/ssm_scan/pallas_call",
+     ("ssm_scan", "forward")),
+    ("jit(step)/transpose(jvp(NemotronHLM))/checkpoint/ssm_gate_norm/mul",
+     ("ssm_gate_norm", "backward")),
+    ("jit(step)/jvp(NemotronHLM)/checkpoint/attn_full/pallas_call",
+     ("attn_full", "forward")),
+    # a layer's function name is no scope
+    ("jit(step)/jvp(NemotronHLM)/checkpoint/layer/add",
+     ("(model, no scope)", "forward")),
+])
+def test_nemotron3s_scopes_classify(op_name, want):
+    assert trace_scopes.classify(op_name, NEMOTRON3S_SCOPES) == want

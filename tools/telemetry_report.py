@@ -83,7 +83,9 @@ def _phase_section(steps: list[dict], scans: list[dict] = (),
     `shared_gate_mean`) with the way its delta rules did (`deltas`: the
     `delta_program` records), and a decoder's with several residual streams,
     latent attention and a selection bias (`mhc_res_gap`, `mhc_res_offdiag`,
-    `mla_kv_latent_rms`, `moe_bias_swap_share`), and the way the short
+    `mla_kv_latent_rms`, `moe_bias_swap_share`), and a decoder's whose
+    experts compute in a latent (`moe_latent_rms`, `moe_relu2_active`, with
+    the selection bias's share on its line too), and the way the short
     convolutions of any of
     them did (`convs`: the `conv_program` records; no line where the newest
     counts none), the passes over several residual streams (`streams`:
@@ -176,10 +178,18 @@ def _phase_section(steps: list[dict], scans: list[dict] = (),
                 ("mhc_res_offdiag", "H_res's mass off its diagonal"),
                 ("mla_kv_latent_rms", "latent c_kv's rms"),
                 ("moe_bias_swap_share",
+                 "share of choices the selection bias made")), (), "", ""),
+            # models/nemotronh.py: its scans' record has a line below
+            ("latent experts", (
+                ("moe_latent_rms", "latent's rms"),
+                ("moe_relu2_active", "share of hidden units relu left on"),
+                ("moe_bias_swap_share",
                  "share of choices the selection bias made")), (), "", "")):
         values = {said: [s[name] for s in steps if name in s]
                   for name, said in counters}
-        if not any(values.values()):
+        # the selection bias's share is two families': alone it names none
+        if not any(v for (name, _), v in zip(counters, values.values())
+                   if name != "moe_bias_swap_share"):
             continue
         says = [f"{said} {sum(v) / len(v):.4g}"
                 for said, v in values.items() if v]

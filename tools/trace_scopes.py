@@ -15,7 +15,9 @@ loss; models/qwen3next.py's are gdn_in_proj, gdn_conv, gdn_delta,
 gdn_gate_norm, gdn_out_proj, attn_proj, attn_full, attn_gate, moe_route,
 moe_shared, moe_experts, lm_head, loss; models/xing4.py's are mhc_map,
 mhc_mix, mla_q_proj, mla_kv_proj, attn_full, mla_out_proj, mlp, moe_route,
-moe_shared, moe_experts, lm_head, loss.)
+moe_shared, moe_experts, lm_head, loss; models/nemotronh.py's are Granite's
+ssm_* and attn_* and moe_route, moe_latent_down, moe_experts, moe_latent_up,
+moe_shared, lm_head, loss.)
 
 A TPU trace names each event of the `XLA Ops` line after the HLO instruction
 it ran; the compiled module's per-instruction `op_name` metadata still carries
