@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from mgwfbp_tpu.ops import shortconv
+from mgwfbp_tpu.ops import programs, shortconv
 from mgwfbp_tpu.ops.groupmm import grouped_product
 from mgwfbp_tpu.ops.rowperm import combine_rows, take_rows
 from mgwfbp_tpu.ops.ssd import ssd_scan, ssd_scan_in_place
@@ -161,22 +161,51 @@ def sigmoid_bias_route(u: jax.Array, router: jax.Array, bias: jax.Array,
     return idx, weights, lax.stop_gradient(swapped)
 
 
+def _held_first(local, held, weights, count: int):
+    """A token's held choices first, in their order j, over `count` slots:
+    (keys (N, count), weights (N, count)), an empty slot keyed `count` (no
+    group) under weight 0. `lax.top_k` hands a token DISTINCT experts, so
+    no token holds more than `count`. A masked sum over an (N, k, count)
+    one-hot, exact (one term a slot is non-zero) and its own transpose's
+    mask: XLA's gathers of single scalars read 1.84 ms each on the chip at
+    these sizes (PERF.md section 5), a scatter-add on the way back more."""
+    slot = jnp.cumsum(held, axis=1, dtype=jnp.int32) - 1
+    into = held[:, :, None] & (slot[:, :, None] == jnp.arange(count))
+    # an empty slot sums nothing and keeps `count`
+    keys = count + jnp.sum(
+        jnp.where(into, (local - count)[:, :, None], 0), axis=1)
+    return keys, jnp.sum(jnp.where(into, weights[:, :, None], 0), axis=1)
+
+
 def _grouped_experts(u, idx, weights, count: int, first: int, experts):
     """The held experts' part of the sparse block for tokens u (N, D): the
-    N x k assignments sorted by held expert, `experts(rows (N * k, D), sizes)
-    -> (out (N * k, D'), a statistic)` on the rows as grouped, and the k
-    terms of a token added up under its weights. Returns (y (N, D'), tokens
-    per held expert (E,), assignments to a held expert that no group took (a
-    count; 0 by construction), the statistic)."""
+    N x c assignments that CAN be held, c = min(k, count), sorted by held
+    expert, `experts(rows (N * c, D), sizes) -> (out (N * c, D'), a
+    statistic)` on the rows as grouped, and the c terms of a token added up
+    under their weights. Where a token routes to more experts than are held
+    (k > count) its held choices are compacted to c slots first
+    (`_held_first`): the grouped arrays then have N x count rows for any
+    routing, the rows in a group the same in the same order; where k <=
+    count not one operation is added. Which of the two a call took is noted
+    (`ops/programs.py`, op `groups`). Returns (y (N, D'), tokens per held
+    expert (E,), assignments to a held expert that no group took (a count; 0
+    by construction, `held` counted BEFORE the compaction), the
+    statistic)."""
     local = idx - first
     held = (local >= 0) & (local < count)
-    # unheld assignments sort behind every held expert, into no group
-    keys = jnp.where(held, local, count).reshape(-1)
+    if idx.shape[1] > count:
+        programs.note("groups", "bounded")
+        keys, weights = _held_first(local, held, weights, count)
+    else:
+        programs.note("groups", "whole")
+        # unheld assignments sort behind every held expert, into no group
+        keys = jnp.where(held, local, count)
+    keys = keys.reshape(-1)
     order = jnp.argsort(keys, stable=True)
     inverse = jnp.argsort(order)
     sizes = jnp.sum(
         keys[:, None] == jnp.arange(count)[None, :], axis=0, dtype=jnp.int32)
-    # (N * k, D), grouped by expert; the rows past the last group belong to
+    # (N * c, D), grouped by expert; the rows past the last group belong to
     # no expert: a grouped product leaves them UNWRITTEN on the chip (zero
     # only on the CPU), forward and backward, and neither permutation moves
     # or reads them (ops/rowperm.py): never trusted
@@ -192,7 +221,8 @@ def held_experts(u, idx, weights, w_gate, w_up, w_down, first: int):
     three products (SwiGLU).
 
     u (N, D); idx, weights (N, k) from `route`; w_gate, w_up (E, D, F) and
-    w_down (E, F, D) the E held experts, expert `first` of the model first.
+    w_down (E, F, D) the E held experts, expert `first` of the model first;
+    the grouped arrays have N x min(k, E) rows (`_grouped_experts`).
     Returns (y (N, D), tokens per held expert (E,), assignments to a held
     expert that no group took (a count; 0 by construction))."""
     def experts(rows, sizes):
@@ -210,7 +240,9 @@ def held_relu2_experts(u, idx, weights, w_up, w_down, first: int):
     W_up)^2 W_down, w_up (E, D, F), w_down (E, F, D). Returns a fourth
     value: the share of the held experts' hidden units, over the rows in a
     group, that relu left above zero (float32, no gradient; 0 where no row
-    is in a group)."""
+    is in a group). The passes over the hidden units (the count, relu^2 and
+    their transposes) run over all N x min(k, E) grouped rows, in a group or
+    not: `_grouped_experts` keeps that number at what a routing can fill."""
     def experts(rows, sizes):
         up = grouped_product(rows, w_up, sizes).astype(jnp.float32)
         # the rows past the last group are unwritten: not counted

@@ -66,9 +66,11 @@ built.
 `jax.checkpoint`, so the forward pass keeps the residual stream once a layer
 (64 MiB in bf16 at T 8,192) and the backward pass recomputes a layer before
 it differentiates it. The large temporaries are `held_relu2_experts`': its
-grouped arrays have a row for each of the N x 22 assignments (180,224 at T
-8,192, of which 1.56% are in a held group at 8 of 512), (M, 1,024) and
-(M, 2,688) in the compute dtype, alive inside one `E` layer's backward pass.
+grouped arrays have a row for each assignment that CAN be held, N x min(22,
+experts held) of them (M = 65,536 at T 8,192 and 8 of 512 held, where N x 22
+would be 180,224; 4.3% of the M are in a held group at even routing), (M,
+1,024) and (M, 2,688) in the compute dtype (128 and 336 MiB in bf16), alive
+inside one `E` layer's backward pass.
 
 **Counters.** With `targets` the model returns, beside the per-token loss:
 the scan's `health/ssm_state` and `health/ssm_log_decay_min` (as Granite's),

@@ -7,7 +7,10 @@ Each entry point (`blockattn.blockwise_attention`, `groupmm.grouped_product`,
 down by platform (`traced_for_tpu`) and shape, and says which it took with
 `note(op, way, programs)`: the call, and the keys of the kernel programs
 that it and its transposes need, as jax tells programs apart (what makes two
-programs distinct is the op's own knowledge: its `_programs`).
+programs distinct is the op's own knowledge: its `_programs`). One caller
+of theirs notes its way here too: the held experts' block
+(`models/lm_parts._grouped_experts`, op `groups`), which sizes the grouped
+arrays it hands `groupmm` and `rowperm` by its shapes alone.
 
 `make_train_step` reads the notes round the trace of its step (`traced_into`)
 and the Trainer writes them as the records of `RECORDS`, one a step program
@@ -31,6 +34,7 @@ OPS: dict[str, tuple[tuple[str, ...], str | None]] = {
     "attention": (("kernel", "blocks"), None),
     "experts": (("kernel", "ragged"), "programs"),
     "rows": (("rows_held", "rows_all"), "rows_programs"),
+    "groups": (("bounded", "whole"), None),
     "scan": (("kernel", "plain"), "programs"),
     "delta": (("kernel", "plain"), "programs"),
     "conv": (("kernel", "plain"), "programs"),
@@ -43,12 +47,14 @@ RECORDS: tuple[tuple[str, tuple[str, ...], str], ...] = (
     ("attention_program", ("attention",),
      "attention: %(kernel)d core(s) of the step through the fused kernel, "
      "%(blocks)d through the plain blocks"),
-    ("experts_program", ("experts", "rows"),
+    ("experts_program", ("experts", "rows", "groups"),
      "experts: %(kernel)d grouped product(s) of the step through the tiled "
      "kernel (%(programs)d distinct kernel program(s)), %(ragged)d through "
      "ragged_dot; %(rows_held)d row permutation(s) moving only the rows in a "
      "group (%(rows_programs)d distinct kernel program(s)), %(rows_all)d "
-     "moving every assignment's row"),
+     "moving every assignment's row; %(bounded)d expert block(s) grouping a "
+     "token's held choices alone (tokens x experts held rows), %(whole)d "
+     "every choice (tokens x k rows)"),
     ("scan_program", ("scan",),
      "scan: %(kernel)d selective scan(s) of the step through the kernels "
      "with the state in VMEM (%(programs)d distinct kernel program(s)), "

@@ -5,7 +5,10 @@ groups (dispatch) and `combine_rows` adds a token's weighted rows back
 **The contract.** `order` (M,) lists the M = N x k (token, k) assignments
 sorted by group and, inside a group, by token (a stable sort by group);
 `inverse` (M,) is where each assignment went; `sizes` (G,) the groups' row
-counts, `valid` their sum. Sorted rows from `valid` on are in no group.
+counts, `valid` their sum. Sorted rows from `valid` on are in no group. k is
+the `weights`' second dimension, whatever the router's: where a token chooses
+more experts than are held, `lm_parts._grouped_experts` hands over a token's
+held choices alone, k = the experts held.
 
   * `take_rows(src (N, D), order, inverse, sizes)` -> (M, D): row r is
     `src[order[r] // k]` for r < `valid`; rows from `valid` on hold ANYTHING

@@ -96,11 +96,14 @@ EVENT_TYPES: dict[str, tuple[str, ...]] = {
     # former with their transposes; and the row permutations round them
     # (ops/rowperm.py: 2 a sparse layer held; `rows_held` move only the rows
     # in a group, `rows_all` every assignment's row, `rows_programs` the
-    # distinct kernel programs they and their transposes need); all 0 for a
-    # model without experts
+    # distinct kernel programs they and their transposes need); and how the
+    # expert blocks sized their grouped arrays (models/lm_parts.py: 1 a
+    # sparse layer held; `bounded` tokens x experts held rows, where a token
+    # routes to more experts than are held, `whole` tokens x k rows); all 0
+    # for a model without experts
     "experts_program": (
         "step", "kernel", "ragged", "programs", "rows_held", "rows_all",
-        "rows_programs"),
+        "rows_programs", "bounded", "whole"),
     # the same for the selective scans (ops/selscan.py): how many went
     # through the kernels with the state in VMEM and how many through the
     # plain chunked form (1 a Mamba-1 layer held), and the distinct kernel

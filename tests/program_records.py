@@ -10,8 +10,8 @@ import telemetry_report
 
 from mgwfbp_tpu.telemetry.events import events_of, read_events
 
-OPS = ("attention", "experts", "rows", "scan", "delta", "conv", "streams",
-       "ssd")
+OPS = ("attention", "experts", "rows", "groups", "scan", "delta", "conv",
+       "streams", "ssd")
 # op -> (its record, its part of the Trainer's log line, its part of the
 # report's line)
 SAID = {
@@ -36,6 +36,14 @@ SAID = {
         "; {rows_held} row permutation(s) moving only the rows in a group "
         "({rows_programs} distinct kernel program(s)), {rows_all} moving "
         "every assignment's row"),
+    "groups": (
+        "experts_program",
+        "; {bounded} expert block(s) grouping a token's held choices alone "
+        "(tokens x experts held rows), {whole} every choice (tokens x k "
+        "rows)",
+        "; {bounded} expert block(s) grouping a token's held choices alone "
+        "(tokens x experts held rows), {whole} every choice (tokens x k "
+        "rows)"),
     "scan": (
         "scan_program",
         "scan: {kernel} selective scan(s) of the step through the kernels "
@@ -103,6 +111,6 @@ def holds(run: tuple, op: str, want: dict) -> None:
     assert program["step"] == 1
     assert {name: program[name] for name in want} == want
     assert logged.format(**want) in log
-    if op in ("attention", "experts", "rows") or any(want.values()):
+    if op in ("attention", "experts", "rows", "groups") or any(want.values()):
         assert reported.format(**want) in telemetry_report.format_report(
             records)

@@ -117,9 +117,10 @@ def test_kernel_against_a_dense_product_a_group(name, which):
 
 
 def lowered_since(before):
-    """The experts' record: the grouped products' fields and the rows'."""
+    """The experts' record: the grouped products' fields, the rows' and the
+    expert blocks'."""
     made = programs.lowered_since(before)
-    return {**made["experts"], **made["rows"]}
+    return {**made["experts"], **made["rows"], **made["groups"]}
 
 
 def traced_ways(fn, *args):
@@ -145,7 +146,8 @@ def test_falls_back_to_ragged_dot_off_the_tpu_and_on_a_shape_that_misfits(
 
     def ways(kernel, ragged, programs):  # no row permutation beside them
         return {"kernel": kernel, "ragged": ragged, "programs": programs,
-                "rows_held": 0, "rows_all": 0, "rows_programs": 0}
+                "rows_held": 0, "rows_all": 0, "rows_programs": 0,
+                "bounded": 0, "whole": 0}
 
     ragged = (ways(0, 1, 0), False)
     assert not programs.traced_for_tpu()
@@ -165,7 +167,8 @@ def test_falls_back_to_ragged_dot_off_the_tpu_and_on_a_shape_that_misfits(
 def expert_blocks(layers: int):
     """Forward + gradient of `layers` checkpointed expert blocks as the
     models call them, at a size the products' tiles and the permutations'
-    blocks divide: 256 tokens x 8 = 2,048 rows, 4 experts held of 8."""
+    blocks divide: 256 tokens, 8 choices each over 4 experts held: the 256 x
+    4 = 1,024 rows that can be in a group."""
     from mgwfbp_tpu.models import lm_parts
 
     tokens, d, f, experts, k = 256, 256, 128, 4, 8
@@ -210,6 +213,7 @@ def test_four_layers_lower_no_more_kernel_programs_than_one(monkeypatch):
         assert (counted["kernel"], counted["ragged"]) == (3 * layers, 0)
         # a layer's combine through its kernel; its dispatch is XLA's gather
         assert (counted["rows_held"], counted["rows_all"]) == (layers, layers)
+        assert (counted["bounded"], counted["whole"]) == (layers, 0)
         distinct = {
             hashlib.sha256(config.encode()).hexdigest() for config in
             re.findall(r'backend_config = "([^"]*)"', text)}

@@ -109,6 +109,7 @@ def test_fit_loss_falls_health_and_counters_every_step(fit_run):
     ("attention", {"kernel": 0, "blocks": 4}),
     ("experts", {"kernel": 0, "ragged": 12, "programs": 0}),
     ("rows", {"rows_held": 0, "rows_all": 8, "rows_programs": 0}),
+    ("groups", {"bounded": 0, "whole": 4}),
     ("scan", {"kernel": 0, "plain": 0, "programs": 0}),
     ("delta", {"kernel": 0, "plain": 0, "programs": 0}),
     ("conv", {"kernel": 0, "plain": 0, "programs": 0}),

@@ -18,6 +18,7 @@ below). A tiny decoder is trained in tests/test_nemotronh_trainer.py, not
 here.
 """
 
+import functools
 import os
 import sys
 
@@ -334,6 +335,176 @@ def test_held_experts_of_two_products_against_a_dense_loop_and_gated_unchanged()
                 np.asarray(u)[(held == e).any(axis=1)] @ np.asarray(w_up[e])
                 for e in range(count)])
             assert float(rest[0]) == pytest.approx((rows > 0).mean(), abs=1e-6)
+
+
+# --- k > experts held: the grouped arrays sized by the rows that CAN be in a
+# group (`lm_parts._grouped_experts`, `_held_first`). `tiny-nemotron3s-f32`,
+# the tiny cell above and in tests/test_nemotronh_trainer.py, routes 3 over 4
+# held and does NOT exercise the bound; these do: 16 experts, 5 a token,
+# experts 6 to 8 held, so at most 3 of a token's 5 choices are held.
+B_N, B_D, B_F, B_K, B_FIRST, B_COUNT = 64, 16, 24, 5, 6, 3
+PARTS = ("y", "sizes", "dropped", "d_u", "d_weights", "d_w_up", "d_w_down")
+# (experts of two products or of three, what is compared): with the share of
+# hidden units relu left on where there is one, the gate's gradient where not
+KIND_PARTS = [
+    (kind, part) for kind, more in (("relu2", "active"), ("swiglu", "d_w_gate"))
+    for part in (*PARTS, more)]
+
+
+def grouped_whole(u, idx, weights, count, first, experts):
+    """`lm_parts._grouped_experts` as PR 47 had it: every one of the N x k
+    assignments a sorted row, whatever number of them can be held."""
+    from mgwfbp_tpu.ops import rowperm
+
+    local = idx - first
+    held = (local >= 0) & (local < count)
+    keys = jnp.where(held, local, count).reshape(-1)
+    order = jnp.argsort(keys, stable=True)
+    inverse = jnp.argsort(order)
+    sizes = jnp.sum(
+        keys[:, None] == jnp.arange(count)[None, :], axis=0, dtype=jnp.int32)
+    rows = rowperm.take_rows(u, order, inverse, sizes)
+    out, stat = experts(rows, sizes)
+    y = rowperm.combine_rows(out, order, inverse, weights, sizes)
+    return y, sizes, jnp.sum(held) - jnp.sum(sizes), stat
+
+
+def bounded_operands():
+    rng = np.random.RandomState(48)
+    idx = np.stack([rng.permutation(16)[:B_K] for _ in range(B_N)])
+    idx[0] = [7, 1, 8, 6, 2]  # all three held, out of their order
+    idx[1] = [0, 1, 7, 2, 3]  # fewer held than slots: one, in the middle
+    idx[2] = [0, 1, 2, 3, 4]  # none held
+    idx[3] = [9, 10, 11, 8, 6]  # two held, last
+    u = jnp.asarray(rng.standard_normal((B_N, B_D)), jnp.float32)
+    weights = jnp.asarray(rng.uniform(0.1, 1.0, (B_N, B_K)), jnp.float32)
+    w_gate, w_up = (jnp.asarray(
+        rng.standard_normal((B_COUNT, B_D, B_F)) * 0.3, jnp.float32)
+        for _ in range(2))
+    w_down = jnp.asarray(
+        rng.standard_normal((B_COUNT, B_F, B_D)) * 0.3, jnp.float32)
+    ct = jnp.asarray(rng.standard_normal((B_N, B_D)), jnp.float32)
+    return jnp.asarray(idx), u, weights, w_gate, w_up, w_down, ct
+
+
+@functools.lru_cache(maxsize=None)
+def bounded_block(kind: str, form: str) -> dict:
+    """Value, counters and gradients of one expert block at k 5 over 3 held,
+    `form` "bounded" (the program), "whole" (the N x k form in the program's
+    place) or "dense" (a loop over the held experts under a mask)."""
+    idx, u, weights, w_gate, w_up, w_down, ct = bounded_operands()
+
+    def relu2(v, up, down):
+        return jnp.square(jax.nn.relu(v @ up)) @ down
+
+    def swiglu(v, gate, up, down):
+        return (jax.nn.silu(v @ gate) * (v @ up)) @ down
+
+    stacks = (w_up, w_down) if kind == "relu2" else (w_gate, w_up, w_down)
+
+    def block(u, weights, *stacks):
+        if form == "dense":
+            y = dense_experts(u, idx, weights, B_FIRST,
+                              relu2 if kind == "relu2" else swiglu, *stacks)
+            return jnp.sum(y * ct), (y,)
+        fn = lm_parts.held_relu2_experts if kind == "relu2" \
+            else lm_parts.held_experts
+        y, *rest = fn(u, idx, weights, *stacks, B_FIRST)
+        return jnp.sum(y * ct), (y, *rest)
+
+    with pytest.MonkeyPatch.context() as patch:
+        if form == "whole":
+            patch.setattr(lm_parts, "_grouped_experts", grouped_whole)
+        (_, (y, *rest)), grads = jax.value_and_grad(
+            block, argnums=tuple(range(2 + len(stacks))), has_aux=True)(
+                u, weights, *stacks)
+    names = ("d_u", "d_weights", *(("d_w_gate",) if kind == "swiglu" else ()),
+             "d_w_up", "d_w_down")
+    out = {"y": y, **dict(zip(names, grads)),
+           **dict(zip(("sizes", "dropped", "active"), rest))}
+    return {name: np.asarray(a) for name, a in out.items()}
+
+
+def test_the_bounded_cases_hold_what_their_comments_say():
+    held = np.asarray(bounded_operands()[0]) - B_FIRST
+    held = ((held >= 0) & (held < B_COUNT)).sum(axis=1)
+    assert list(held[:4]) == [3, 1, 0, 2]
+    assert set(held) == {0, 1, 2, 3} and B_K > B_COUNT
+
+
+@pytest.mark.parametrize("kind,part", KIND_PARTS)
+def test_more_choices_than_experts_held_against_a_dense_loop(kind, part):
+    """`held_relu2_experts` and `held_experts` at k > count, experts 6 to 8
+    of 16 held: value, tokens per held expert, nothing dropped, the share of
+    hidden units relu left on, and the gradients of u, the weights and the
+    stacks against a loop over the held experts under a mask; an unheld
+    choice's weight gets exactly 0."""
+    got = bounded_block(kind, "bounded")
+    want = bounded_block(kind, "dense")
+    idx, u, _, _, w_up, _, _ = (np.asarray(a) for a in bounded_operands())
+    if part == "sizes":
+        np.testing.assert_array_equal(
+            got["sizes"],
+            [(idx == B_FIRST + e).sum() for e in range(B_COUNT)])
+    elif part == "dropped":
+        assert int(got["dropped"]) == 0
+    elif part == "active":  # over the rows in a group
+        rows = np.concatenate([
+            u[(idx == B_FIRST + e).any(axis=1)] @ w_up[e]
+            for e in range(B_COUNT)])
+        assert float(got["active"]) == pytest.approx(
+            (rows > 0).mean(), abs=1e-6)
+    else:
+        assert got[part].shape == want[part].shape
+        assert rel(got[part], want[part]) < RTOL
+    if part == "d_weights":
+        unheld = (idx < B_FIRST) | (idx >= B_FIRST + B_COUNT)
+        assert (got[part][unheld] == 0).all()
+        assert (got[part][~unheld] != 0).all()
+
+
+@pytest.mark.parametrize("kind,part", KIND_PARTS)
+def test_more_choices_than_experts_held_is_the_whole_form_bit_for_bit(
+        kind, part):
+    """The same arithmetic in the same order: the N x count form's value,
+    counters and every gradient EQUAL the N x k form's on the CPU (the rows
+    in a group are the same rows at the same offsets; a token's held terms
+    are added in the order j, and what the N x k form adds beside them is
+    weight x 0)."""
+    got, want = bounded_block(kind, "bounded"), bounded_block(kind, "whole")
+    assert got.keys() == want.keys()
+    np.testing.assert_array_equal(got[part], want[part])
+
+
+@pytest.mark.parametrize("k,count,rows", [
+    (5, 3, 64 * 3), (8, 2, 64 * 2), (3, 3, 64 * 3), (2, 4, 64 * 2)],
+    ids=["k5-of-3-held", "k8-of-2-held", "k3-of-3-held", "k2-of-4-held"])
+def test_the_grouped_arrays_have_a_row_for_each_choice_that_can_be_held(
+        k, count, rows):
+    """N x min(k, count) rows reach `experts`, by the shapes alone; where k
+    <= count the traced block holds no compaction: no `cumsum`, no (N, k,
+    count) array; where k > count it holds both."""
+    from mgwfbp_tpu.ops import programs
+
+    seen = []
+
+    def experts(rows, sizes):
+        seen.append(rows.shape)
+        return rows, None
+
+    before = programs.LOWERED.copy()
+    text = str(jax.make_jaxpr(
+        lambda u, idx, weights: lm_parts._grouped_experts(
+            u, idx, weights, count, B_FIRST, experts))(
+        jax.ShapeDtypeStruct((64, B_D), jnp.float32),
+        jax.ShapeDtypeStruct((64, k), jnp.int32),
+        jax.ShapeDtypeStruct((64, k), jnp.float32)))
+    assert seen == [(rows, B_D)]
+    bounded = k > count
+    assert programs.lowered_since(before)["groups"] == {
+        "bounded": int(bounded), "whole": int(not bounded)}
+    assert ("cumsum" in text) == bounded
+    assert (f"[64,{k},{count}]" in text) == bounded
 
 
 @pytest.mark.parametrize("share,message", [

@@ -1,10 +1,12 @@
 """ops/programs.py: the one registry of what the entry points of `ops/` trace.
 A call of each op notes its own way and kernel programs and nothing under
-another op, traced for the CPU and traced as for a TPU; `counted` notes for a
-cached trace whatever its first trace noted, for every op at once, and a step
-built a second time in one process reads what the first read; and the two
-rules of where shared code lives (no decoder imports another's file, no op
-imports `ops/blockattn.py`) hold for every file, by its imports."""
+another op (the held experts' block, op `groups`, beside the two row
+permutations it calls), traced for the CPU and traced as for a TPU; `counted`
+notes for a cached trace whatever its first trace noted, for every op at
+once, and a step built a second time in one process reads what the first
+read; and the two rules of where shared code lives (no decoder imports
+another's file, no op imports `ops/blockattn.py`) hold for every file, by its
+imports."""
 
 import ast
 import pathlib
@@ -14,6 +16,7 @@ import jax.numpy as jnp
 import pytest
 
 import mgwfbp_tpu
+from mgwfbp_tpu.models import lm_parts
 from mgwfbp_tpu.ops import (
     blockattn,
     deltarule,
@@ -39,7 +42,8 @@ def a(*shape, dtype=BF16):
 T = 2 * selscan._ROWS
 # op -> (one call of its entry point, arguments at a shape its kernels take,
 # what that call notes traced for the CPU, and traced as for a TPU: there
-# with the kernel programs it and its transposes need)
+# with the kernel programs it and its transposes need; for an entry point
+# that calls another op's, what those note the two ways)
 CALLS = {
     "attention": (
         lambda q, kv: blockattn.blockwise_attention(q, kv, kv),
@@ -56,6 +60,15 @@ CALLS = {
          a(256, 8, dtype=F32), a(4, dtype=I32)),
         {"rows_held": 0, "rows_all": 1, "rows_programs": 0},
         {"rows_held": 1, "rows_all": 0, "rows_programs": 1}),
+    # k 8 over 4 held: the block is sized by its shapes alone, on any
+    # platform; the experts here hand the rows back
+    "groups": (
+        lambda u, idx, weights: lm_parts._grouped_experts(
+            u, idx, weights, 4, 0, lambda rows, sizes: (rows, None)),
+        (a(256, 256), a(256, 8, dtype=I32), a(256, 8, dtype=F32)),
+        {"bounded": 1, "whole": 0}, {"bounded": 1, "whole": 0},
+        {"rows": ({"rows_held": 0, "rows_all": 2, "rows_programs": 0},
+                  {"rows_held": 1, "rows_all": 1, "rows_programs": 1})}),
     "scan": (
         selscan.selective_scan,
         (a(1, T, 256, dtype=F32), a(1, T, 256, dtype=F32),
@@ -104,14 +117,16 @@ def test_a_call_notes_its_own_way_and_nothing_under_another_op(
         monkeypatch, op, tpu):
     """Nothing runs: a kernel traced for a TPU cannot on the CPU. The one
     `traced_for_tpu` steers every op's entry point."""
-    fn, args, plain, kernel = CALLS[op]
+    fn, args, plain, kernel, *calls = CALLS[op]
     if tpu:
         monkeypatch.setattr(programs, "traced_for_tpu", lambda: True)
     before = programs.LOWERED.copy()
     # a fresh function each time: a cached trace calls nothing, notes nothing
     jaxpr = jax.make_jaxpr(lambda *x: fn(*x))(*args)
     made = programs.lowered_since(before)
-    assert made == {**NOTHING, op: kernel if tpu else plain}
+    beside = {
+        of: ways[tpu] for of, ways in (calls[0] if calls else {}).items()}
+    assert made == {**NOTHING, **beside, op: kernel if tpu else plain}
     assert ("pallas_call" in str(jaxpr)) == tpu
 
 

@@ -217,6 +217,7 @@ def test_trains_with_counters_and_every_leaf_reduces_like_pmean(
     ("attention", {"kernel": 0, "blocks": 1}),
     ("experts", {"kernel": 0, "ragged": 12, "programs": 0}),
     ("rows", {"rows_held": 0, "rows_all": 8, "rows_programs": 0}),
+    ("groups", {"bounded": 0, "whole": 4}),
     ("scan", {"kernel": 0, "plain": 0, "programs": 0}),
     ("delta", {"kernel": 0, "plain": 3, "programs": 0}),
     ("conv", {"kernel": 0, "plain": 3, "programs": 0}),

@@ -535,6 +535,7 @@ def test_trainer_smoke_emits_step_and_group_events(smoke_run):
     ("attention", ("kernel", "blocks")),
     ("experts", ("kernel", "ragged", "programs")),
     ("rows", ("rows_held", "rows_all", "rows_programs")),
+    ("groups", ("bounded", "whole")),
     ("scan", ("kernel", "plain", "programs")),
     ("delta", ("kernel", "plain", "programs")),
     ("conv", ("kernel", "plain", "programs")),

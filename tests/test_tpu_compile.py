@@ -316,10 +316,11 @@ def test_nemotron3s_step_compiles_and_fits_one_v5e_chip(topo, monkeypatch):
     (two minutes): 508,189,680 parameters are held, every op goes down its
     kernels (the five scans at 16 heads over one group at the published chunk
     of 128, the five convolutions at 1,280 channels, the core at 4 query
-    heads over 1, the ten grouped products at 180,224 rows), and the state
-    (5.7 GiB: float32 weights and two moments) with the step's temporaries
-    fits 16 GB with room: the N x k-row arrays of the five `E` layers are the
-    largest of them."""
+    heads over 1, the ten grouped products at the 65,536 rows that can be in
+    a group: 8,192 tokens x the 8 experts held of the 22 a token chooses),
+    and the state (5.7 GiB: float32 weights and two moments) with the step's
+    temporaries fits 16 GB with room; no array of the N x k = 180,224 rows
+    is left in the step."""
     from mgwfbp_tpu.ops import programs
 
     monkeypatch.setattr(programs, "traced_for_tpu", lambda: True)
@@ -346,10 +347,13 @@ def test_nemotron3s_step_compiles_and_fits_one_v5e_chip(topo, monkeypatch):
     assert traced["conv"] == {"kernel": 5, "plain": 0, "programs": 2}
     assert traced["experts"] == {"kernel": 10, "ragged": 0, "programs": 4}
     assert traced["rows"]["rows_programs"] == 1
+    assert traced["groups"] == {"bounded": 5, "whole": 0}
     # the residual stream between the layers is bf16 at the full 4,096, the
-    # latent the experts read is 1,024 wide, a row an assignment
+    # latent the experts read is 1,024 wide, a row an assignment that can be
+    # held
     text = lowered.as_text()
-    assert "1x8192x4096xbf16" in text and "180224x1024xbf16" in text
+    assert "1x8192x4096xbf16" in text and "65536x1024xbf16" in text
+    assert "180224x" not in text
     mem = lowered.compile().memory_analysis()
     need = mem.temp_size_in_bytes + mem.argument_size_in_bytes
     assert 5.6 * 2 ** 30 < mem.argument_size_in_bytes < 5.8 * 2 ** 30

@@ -28,18 +28,20 @@ pytestmark = pytest.mark.usefixtures("_compile_cache_off")
     (131072, 2304, 896, 16), (131072, 896, 2304, 16),
     (65536, 2048, 512, 32), (65536, 512, 2048, 32),
     (163840, 2048, 512, 32), (163840, 512, 2048, 32),
-    # 8,192 tokens x 22: the widest routing; N 2,688 is 21 lane tiles, which
-    # `_fitted` halves to 1,408 with a remainder tile of 1,280
-    (180224, 1024, 2688, 8), (180224, 2688, 1024, 8),
+    # 8,192 tokens x the 8 experts held of the 22 a token chooses (the rows
+    # that can be in a group: `lm_parts._grouped_experts`); N 2,688 is 21
+    # lane tiles, which `_fitted` halves to 1,408 with a remainder tile of
+    # 1,280
+    (65536, 1024, 2688, 8), (65536, 2688, 1024, 8),
 ], ids=["mellum2-gate-up", "mellum2-down", "laguna-xs2-gate-up",
         "laguna-xs2-down", "qwen3next-gate-up", "qwen3next-down",
         "nemotron3s-up-from-the-latent", "nemotron3s-down-to-the-latent"])
 def test_grouped_product_compiles_for_v5e_at_the_cells_shapes(
         topo, m, k, n, groups):
     """ops/groupmm.py's tiled kernel with both transposes at the sparse
-    cells' call shapes (every assignment's row, bf16) and the tiles the shape
-    test gives them: each of the three fits VMEM. The kernel path is called
-    outright: this process traces for the CPU."""
+    cells' call shapes (a row for every assignment that can be held, bf16)
+    and the tiles the shape test gives them: each of the three fits VMEM.
+    The kernel path is called outright: this process traces for the CPU."""
     from mgwfbp_tpu.ops import groupmm
 
     one = SingleDeviceSharding(topo.devices[0])
@@ -61,7 +63,8 @@ def test_grouped_product_compiles_for_v5e_at_the_cells_shapes(
 
 # Qwen3-Next's k 10: a block's 256 x 10 scalars are no whole SMEM tiles, which
 # Mosaic refuses ("not divisible by tiling"); the kernel pads them (PR 40)
-# Nemotron 3 Super's k 22 over the latent of 1,024: 180,224 rows
+# Nemotron 3 Super's k 22 over 8 experts held, in the latent of 1,024: the
+# permutations are called at min(k, held) = 8 choices a token, 65,536 rows
 CELLS = [(16384, 8, 2304, 896, 16), (8192, 8, 2048, 512, 32),
          (16384, 10, 2048, 512, 32), (8192, 22, 1024, 2688, 8)]
 CELL_IDS = ["mellum2", "laguna-xs2", "qwen3next", "nemotron3s"]
@@ -71,14 +74,16 @@ CELL_IDS = ["mellum2", "laguna-xs2", "qwen3next", "nemotron3s"]
 def test_row_permutations_compile_for_v5e_at_the_cells_shapes(
         topo, n, k, d, f, groups):
     """ops/rowperm.py's ways down on the chip with their transposes at the
-    sparse cells' shapes (every assignment's row, bf16): `take_rows`, XLA's
-    one gather from the (N, D) table, whose transpose is the combine kernel;
-    and `combine_rows`' kernel, whose window fits VMEM, with the loop of
-    block gathers as its transpose and no gather of all M rows. Called
-    outright: this process traces for the CPU."""
+    sparse cells' shapes (a row for every assignment that can be held,
+    bf16): `take_rows`, XLA's one gather from the (N, D) table, whose
+    transpose is the combine kernel; and `combine_rows`' kernel, whose
+    window fits VMEM, with the loop of block gathers as its transpose and no
+    gather of all M rows. Called outright: this process traces for the
+    CPU."""
     from mgwfbp_tpu.ops import rowperm
 
     one = SingleDeviceSharding(topo.devices[0])
+    k = min(k, groups)  # as `lm_parts._grouped_experts` calls them
     m = n * k
     src = jax.ShapeDtypeStruct((n, d), jnp.bfloat16, sharding=one)
     rows = jax.ShapeDtypeStruct((m, d), jnp.bfloat16, sharding=one)
@@ -119,14 +124,16 @@ def test_held_experts_compiles_for_v5e_with_no_gather_from_all_rows(
     (forward and as d `u`): 3 + 3 + 6 + 2, no `ragged-dot`, and the only
     `gather`s
     that produce an (M, D) array are the dispatch's own from the (N, D)
-    table, forward and recomputed (the parent's program held six)."""
+    table, forward and recomputed (the parent's program held six). M is N x
+    min(k, experts held): at k 22 over 8 held no array of the N x 22 =
+    180,224 rows is left in the compiled text."""
     from mgwfbp_tpu.models import lm_parts
     from mgwfbp_tpu.ops import programs
 
     monkeypatch.setattr(programs, "traced_for_tpu", lambda: True)
     one = SingleDeviceSharding(topo.devices[0])
     bf = jnp.bfloat16
-    m = n * k
+    m = n * min(k, groups)
 
     def shape(dims, dtype=bf):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
@@ -150,6 +157,8 @@ def test_held_experts_compiles_for_v5e_with_no_gather_from_all_rows(
     assert "ragged-dot" not in text
     assert 1 <= len(
         re.findall(rf"= \w+\[{m},{d}\]\S* gather\(", text)) <= 2
+    if k > groups:
+        assert not re.search(rf"\[{n * k}[,\]]", text)
     memory = compiled.memory_analysis()
     assert (memory.temp_size_in_bytes + memory.argument_size_in_bytes
             < HBM_BYTES)

@@ -205,6 +205,7 @@ def test_trains_with_counters_and_every_leaf_reduces_like_pmean(wfbp_run):
     ("attention", {"kernel": 0, "blocks": 3}),
     ("experts", {"kernel": 0, "ragged": 6, "programs": 0}),
     ("rows", {"rows_held": 0, "rows_all": 4, "rows_programs": 0}),
+    ("groups", {"bounded": 0, "whole": 2}),
     ("scan", {"kernel": 0, "plain": 0, "programs": 0}),
     ("delta", {"kernel": 0, "plain": 0, "programs": 0}),
     ("conv", {"kernel": 0, "plain": 0, "programs": 0}),

@@ -296,7 +296,9 @@ def format_report(records: list[dict]) -> str:
             f"{prog.get('rows_held')} row permutation(s) moving only the "
             f"rows in a group ({prog.get('rows_programs')} distinct kernel "
             f"program(s)), {prog.get('rows_all')} moving every assignment's "
-            f"row"
+            f"row; {prog.get('bounded')} expert block(s) grouping a token's "
+            f"held choices alone (tokens x experts held rows), "
+            f"{prog.get('whole')} every choice (tokens x k rows)"
         )
     if steps:
         durs = [float(s["dur_s"]) for s in steps]
@@ -911,7 +913,7 @@ def _synthetic_stream(path: str) -> None:
            compiler_options=["xla_enable_async_all_reduce"])
     w.emit("attention_program", step=1, kernel=1, blocks=3)
     w.emit("experts_program", step=1, kernel=12, ragged=0, programs=4,
-           rows_held=4, rows_all=4, rows_programs=1)
+           rows_held=4, rows_all=4, rows_programs=1, bounded=0, whole=4)
     # what set-up was made of, as the Trainer writes it once step 1's
     # results are read (telemetry/phases.py)
     w.emit("setup", origin_wall=1790736000.0, spans={
