@@ -1,0 +1,9 @@
+"""Device time a step under the scopes the model declares for its attention
+layer (`attn_*`, `mla_*`), both passes: the traced epoch reduced by the
+program's step map, mean over the chips; 0 for a model without such a layer."""
+
+import scope_spans
+
+
+def read(run: dict):
+    return scope_spans.layer_ms(run, "attention")

@@ -69,6 +69,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from mgwfbp_tpu.models.lm_parts import (
+    ATTENTION as ATTENTION_LAYER,
+    SCOPES,
     _Leaves,
     gated_mlp,
     mamba2_leaves,
@@ -200,6 +202,13 @@ class Granite4HLM(nn.Module):
     attn_block: int = 512
     loss_block: int = 2048
     scan_block: int = 8  # chunks of the scan recomputed together
+    # the scopes `__call__` enters, here and through lm_parts, each with its
+    # layer of PERF.md's map (profiling.classify; Trainer._note_first_dispatch)
+    scopes = {
+        **SCOPES["mamba2_mixer"], **SCOPES["gated_mlp"],
+        "attn_proj": ATTENTION_LAYER, "attn_full": ATTENTION_LAYER,
+        **SCOPES["token_losses"],
+    }
     # what `__call__` puts among the step's metrics, and `step_counters`
     # takes back on the host (Trainer._drain_health)
     health_keys = (SSM_STATE_KEY, SSM_LOG_DECAY_KEY)

@@ -82,9 +82,13 @@ import numpy as np
 from jax import lax
 
 from mgwfbp_tpu.models.lm_parts import (
+    ATTENTION,
+    EXPERTS,
     FULL,
+    MLP,
     MOE_DROPPED_KEY,
     MOE_TOKENS_KEY,
+    SCOPES,
     SLIDING,
     _Leaves,
     held_experts,
@@ -290,6 +294,14 @@ class LagunaLM(nn.Module):
     experts_held: tuple[int, int] = (0, LAGUNA_XS2.num_experts)
     attn_block: int = 512  # queries a block; a window layer takes fewer
     loss_block: int = 2048
+    # the scopes `__call__` enters, here and through lm_parts, each with its
+    # layer of PERF.md's map (profiling.classify; Trainer._note_first_dispatch)
+    scopes = {
+        "attn_proj": ATTENTION, "attn_gate": ATTENTION,
+        "attn_window": ATTENTION, "attn_full": ATTENTION, "mlp": MLP,
+        "moe_route": EXPERTS, "moe_shared": EXPERTS, "moe_experts": EXPERTS,
+        **SCOPES["token_losses"],
+    }
     # what `__call__` puts among the step's metrics, and `step_counters`
     # takes back on the host (Trainer._drain_health)
     health_keys = (

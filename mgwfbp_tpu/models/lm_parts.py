@@ -28,6 +28,23 @@ SLIDING, FULL = "sliding_attention", "full_attention"
 MOE_TOKENS_KEY = "health/moe_tokens"
 MOE_DROPPED_KEY = "health/moe_dropped"
 _CONV_TAPS = 4  # of every mixer's short convolution (`mamba_conv`, `linear_conv`)
+# the layers of PERF.md's map that a `jax.named_scope` of a decoder belongs
+# to: a decoder's `scopes` gives each scope it enters one of them, and
+# `profiling.classify` reads an instruction's name stack by that declaration
+ATTENTION, EXPERTS, STATE_SPACE = "attention", "experts", "state space"
+LINEAR_ATTENTION, STREAMS = "linear attention", "residual streams"
+MLP, HEAD = "mlp", "head and loss"
+# the scopes this file's functions enter, for the `scopes` of a decoder that
+# calls them (tests/test_step_map.py holds both to the code)
+SCOPES = {
+    "gated_mlp": {"mlp": MLP},
+    "token_losses": {"lm_head": HEAD, "loss": HEAD},
+    "mamba2_mixer": {
+        "ssm_in_proj": STATE_SPACE, "ssm_conv": STATE_SPACE,
+        "ssm_scan": STATE_SPACE, "ssm_gate_norm": STATE_SPACE,
+        "ssm_out_proj": STATE_SPACE,
+    },
+}
 
 
 class _Leaves(nn.Module):

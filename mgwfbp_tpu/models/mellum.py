@@ -57,9 +57,12 @@ import jax.numpy as jnp
 from jax import lax  # noqa: F401
 
 from mgwfbp_tpu.models.lm_parts import (
+    ATTENTION,
+    EXPERTS,
     FULL,
     MOE_DROPPED_KEY,
     MOE_TOKENS_KEY,
+    SCOPES,
     SLIDING,
     _Leaves,
     apply_rope,
@@ -183,6 +186,13 @@ class Mellum2LM(nn.Module):
     attn_block: int = 512  # queries a block; a window layer takes fewer
     loss_block: int = 2048
 
+    # the scopes `__call__` enters, here and through lm_parts, each with its
+    # layer of PERF.md's map (profiling.classify; Trainer._note_first_dispatch)
+    scopes = {
+        "attn_proj": ATTENTION, "attn_window": ATTENTION,
+        "attn_full": ATTENTION, "moe_route": EXPERTS, "moe_experts": EXPERTS,
+        **SCOPES["token_losses"],
+    }
     # what `__call__` puts among the step's metrics, and `step_counters`
     # takes back on the host (Trainer._drain_health)
     health_keys = (MOE_TOKENS_KEY, MOE_DROPPED_KEY)

@@ -115,8 +115,11 @@ import numpy as np
 from jax import lax
 
 from mgwfbp_tpu.models.lm_parts import (
+    ATTENTION as ATTENTION_LAYER,
+    EXPERTS,
     MOE_DROPPED_KEY,
     MOE_TOKENS_KEY,
+    SCOPES,
     _bias_init,
     _Leaves,
     held_relu2_experts,
@@ -374,6 +377,15 @@ class NemotronHLM(nn.Module):
     attn_block: int = 512  # queries a block of the plain blocks
     loss_block: int = 2048
     scan_block: int = 8  # chunks of the scan recomputed together
+    # the scopes `__call__` enters, here and through lm_parts, each with its
+    # layer of PERF.md's map (profiling.classify; Trainer._note_first_dispatch)
+    scopes = {
+        **SCOPES["mamba2_mixer"], "attn_proj": ATTENTION_LAYER,
+        "attn_full": ATTENTION_LAYER, "moe_route": EXPERTS,
+        "moe_latent_down": EXPERTS, "moe_experts": EXPERTS,
+        "moe_latent_up": EXPERTS, "moe_shared": EXPERTS,
+        **SCOPES["token_losses"],
+    }
     # what `__call__` puts among the step's metrics, and `step_counters`
     # takes back on the host (Trainer._drain_health)
     health_keys = (

@@ -103,6 +103,9 @@ import numpy as np
 from jax import lax
 
 from mgwfbp_tpu.models.lm_parts import (
+    ATTENTION,
+    SCOPES,
+    STATE_SPACE,
     _Leaves,
     _conv_init,
     _dt_bias_init,
@@ -265,8 +268,9 @@ def differential_attention(p: dict, q, k, v, s: Phi4FlashShape, kind: str,
     if window is not None:
         # a quarter of the window a plain block (models/mellum.py)
         block = min(block, max(window // 4, 1))
-    scope = {WINDOW: "attn_window", FULL: "attn_full", CROSS: "attn_cross"}
-    with jax.named_scope(scope[kind]):
+    with jax.named_scope({
+            WINDOW: "attn_window", FULL: "attn_full", CROSS: "attn_cross",
+    }[kind]):
         q1, q2 = q[:, :, 0::2], q[:, :, 1::2]
         k1, k2 = k[:, :, 0::2], k[:, :, 1::2]
         v1, v2 = v[:, :, 0::2], v[:, :, 1::2]
@@ -369,6 +373,17 @@ class Phi4FlashLM(nn.Module):
     attn_block: int = 512
     loss_block: int = 2048
     scan_block: int = 16  # chunks of the scan recomputed together
+    # the scopes `__call__` enters, here and through lm_parts, each with its
+    # layer of PERF.md's map (profiling.classify; Trainer._note_first_dispatch)
+    scopes = {
+        "ssm_in_proj": STATE_SPACE, "ssm_conv": STATE_SPACE,
+        "ssm_dt_proj": STATE_SPACE, "ssm_sel_scan": STATE_SPACE,
+        "ssm_out_proj": STATE_SPACE, "gmu": STATE_SPACE,
+        "attn_proj": ATTENTION, "attn_window": ATTENTION,
+        "attn_full": ATTENTION, "attn_cross": ATTENTION,
+        "attn_diff": ATTENTION, **SCOPES["gated_mlp"],
+        **SCOPES["token_losses"],
+    }
     # what `__call__` puts among the step's metrics, and `step_counters`
     # takes back on the host (Trainer._drain_health)
     health_keys = (SEL_SCAN_STATE_KEY, GMU_GATE_KEY, DIFF_LAMBDA_KEY)

@@ -104,8 +104,12 @@ import numpy as np
 from jax import lax
 
 from mgwfbp_tpu.models.lm_parts import (
+    ATTENTION,
+    EXPERTS,
+    LINEAR_ATTENTION,
     MOE_DROPPED_KEY,
     MOE_TOKENS_KEY,
+    SCOPES,
     _Leaves,
     _conv_init,
     _dt_bias_init,
@@ -361,6 +365,16 @@ class Qwen3NextLM(nn.Module):
     attn_block: int = 512
     loss_block: int = 2048
     delta_block: int = 8  # chunks of the delta rule recomputed together
+    # the scopes `__call__` enters, here and through lm_parts, each with its
+    # layer of PERF.md's map (profiling.classify; Trainer._note_first_dispatch)
+    scopes = {
+        "gdn_in_proj": LINEAR_ATTENTION, "gdn_conv": LINEAR_ATTENTION,
+        "gdn_delta": LINEAR_ATTENTION, "gdn_gate_norm": LINEAR_ATTENTION,
+        "gdn_out_proj": LINEAR_ATTENTION, "attn_proj": ATTENTION,
+        "attn_full": ATTENTION, "attn_gate": ATTENTION,
+        "moe_route": EXPERTS, "moe_shared": EXPERTS, "moe_experts": EXPERTS,
+        **SCOPES["token_losses"],
+    }
     # what `__call__` puts among the step's metrics, and `step_counters`
     # takes back on the host (Trainer._drain_health)
     health_keys = (

@@ -101,8 +101,13 @@ import numpy as np
 from jax import lax
 
 from mgwfbp_tpu.models.lm_parts import (
+    ATTENTION,
+    EXPERTS,
+    MLP,
     MOE_DROPPED_KEY,
     MOE_TOKENS_KEY,
+    SCOPES,
+    STREAMS,
     _bias_init,
     _Leaves,
     apply_rope,
@@ -419,6 +424,15 @@ class Xing4LM(nn.Module):
     experts_held: tuple[int, int] = (0, XING4.num_experts)
     attn_block: int = 512  # queries a block of the plain blocks
     loss_block: int = 2048
+    # the scopes `__call__` enters, here and through lm_parts, each with its
+    # layer of PERF.md's map (profiling.classify; Trainer._note_first_dispatch)
+    scopes = {
+        "mhc_map": STREAMS, "mhc_mix": STREAMS, "mla_q_proj": ATTENTION,
+        "mla_kv_proj": ATTENTION, "attn_full": ATTENTION,
+        "mla_out_proj": ATTENTION, "mlp": MLP, "moe_route": EXPERTS,
+        "moe_shared": EXPERTS, "moe_experts": EXPERTS,
+        **SCOPES["token_losses"],
+    }
     # what `__call__` puts among the step's metrics, and `step_counters`
     # takes back on the host (Trainer._drain_health)
     health_keys = (

@@ -28,9 +28,12 @@ parts"), so
 
 from __future__ import annotations
 
+import collections
 import dataclasses
+import logging
+import re
 import time
-from typing import Any, Callable, Optional, Sequence
+from typing import Any, Callable, Iterable, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -40,6 +43,12 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from mgwfbp_tpu.parallel.costmodel import AlphaBeta, fit_alpha_beta
 from mgwfbp_tpu.parallel.mesh import DATA_AXIS
+
+_log = logging.getLogger("mgwfbp.profiling")
+# a child of the Trainer's logger (utils/logging.get_logger: handlers of its
+# own, no propagation), so that what building the step's map cost stands in
+# the run's own log
+_run_log = logging.getLogger("mgwfbp.trainer.profiling")
 
 # Reference sweep: 8K..504K float32 elements in 8K steps (profiling.py:158-160)
 # extended upward: TPU interconnects only hit peak bandwidth at MBs.
@@ -633,11 +642,13 @@ def _with_trace_events(
     run: Callable[[], None],
     logdir: Optional[str] = None,
     prefix: str = "mgwfbp_trace_",
-) -> list[tuple[str, float]]:
-    """Run `run()` under `jax.profiler.trace` and return the collected
-    (identifier, duration_us) rows. Owns (and removes) a temporary logdir
-    when none is given — the shared scaffolding of every trace-attribution
-    path (`trace_layerwise_backward`, `trace_group_times`)."""
+    read: Callable[[str], Any] = _trace_events,
+) -> Any:
+    """Run `run()` under `jax.profiler.trace` and return what `read` makes
+    of the trace dir: the collected (identifier, duration_us) rows. Owns
+    (and removes) a temporary logdir when none is given — the shared
+    scaffolding of every trace-attribution path
+    (`trace_layerwise_backward`, `trace_group_times`, `trace_step_split`)."""
     import shutil
     import tempfile
 
@@ -646,7 +657,7 @@ def _with_trace_events(
     try:
         with jax.profiler.trace(logdir):
             run()
-        return _trace_events(logdir)
+        return read(logdir)
     finally:
         if own:
             shutil.rmtree(logdir, ignore_errors=True)
@@ -910,79 +921,475 @@ def load_layer_profile(path: str) -> dict:
     return d
 
 
-def hlo_collective_scope_map(
-    hlo_text: str, tag: str = "mgwfbp_group",
-) -> dict[str, str]:
-    """HLO instruction name -> merge-group scope, from COMPILED
-    (post-optimization) HLO text.
+# ---------------------------------------------------------------------------
+# The compiled step as ONE map: instruction -> its `op_name` (the whole name
+# stack: scope, pass, merge group) and its kind; a device trace reduced by
+# it. The compiled module's per-instruction `op_name` metadata carries the
+# name stack (`jit(step)/jvp(Model)/attn_window/...`, `transpose(` on the way
+# back, `mgwfbp_groupNNNN` round a merge group) and a trace names each event
+# after the instruction it ran, on the TPU (`XLA Ops`) and on the CPU mesh
+# (the host threads' events), so the join needs no name stack in the trace.
+# ---------------------------------------------------------------------------
 
-    Backends that drop the jax name stack from profiler-trace event
-    metadata (the virtual CPU mesh) still name each trace event after the
-    HLO instruction it executed (``all-reduce.2``), and the compiled
-    module's text keeps every instruction's ``metadata={op_name=...}`` —
-    which carries the ``mgwfbp_groupNNNN`` scope the jaxpr verifier
-    matches on. This map is the join key between the two: it lets
-    `trace_group_times` attribute device time per merge group even where
-    the name-stack path yields nothing (the live /profile endpoint's
-    CPU-mesh regime)."""
-    import re as _re
+_HLO_COLLECTIVES = frozenset((
+    "all-reduce", "all-gather", "reduce-scatter", "collective-permute",
+    "all-to-all", "collective-broadcast",
+))
+# loops and calls span their bodies' events, which a trace lists as well
+_HLO_CONTAINERS = frozenset(("while", "conditional", "call"))
+_HLO_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*)$")
+_HLO_COMPUTATION = re.compile(
+    r"^\s*(?:ENTRY\s+)?%?([\w.\-]+)\s*(?:\(.*)?\{\s*$")
+_HLO_OPCODE = re.compile(r"\s*([\w\-]+)\(")
+_HLO_CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
+_HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
 
-    instr = _re.compile(r"%([\w.\-]+)\s*=\s")
-    scope = _re.compile(rf"op_name=\"[^\"]*?({_re.escape(tag)}\d+)")
-    out: dict[str, str] = {}
+_ASYNC_START, _ASYNC_DONE = "async-collective-start", "async-collective-done"
+COLLECTIVE_KINDS = ("collective", "collective_start", "collective_done")
+# what `classify` calls an instruction that no declared scope holds
+MODEL_NO_SCOPE = "(model, no scope)"
+NO_METADATA = "(no metadata)"
+OUTSIDE_MODEL = "(outside the model)"
+# the layer of the step's own scopes (train/step.py) in a declaration
+UPDATE_LAYER = "update"
+
+
+class Instruction(NamedTuple):
+    op_name: Optional[str]  # None: the compiler gave it no metadata
+    kind: str  # compute | container | collective | collective_start | _done
+
+
+def _instruction_kind(opcode: str, name: str = "") -> str:
+    """`container` for a loop, a branch or a call, whose bodies' events a
+    trace lists as well; `collective` for a synchronous one (by OPCODE: a
+    `psum` by name is an `all-reduce`); `collective_start` / `collective_done`
+    for the halves of an asynchronous one and for a TPU async collective
+    fusion (`%async-collective-start.N = ... fusion(...)`: the compiler wraps
+    the collective and the compute steps that drive it in one kernel; its
+    `-done` twin is where the core waits); else `compute`."""
+    if opcode in _HLO_CONTAINERS:
+        return "container"
+    if opcode in _HLO_COLLECTIVES:
+        return "collective"
+    for half, fusion in (("start", _ASYNC_START), ("done", _ASYNC_DONE)):
+        if name.startswith(fusion) or (
+            opcode.endswith("-" + half)
+            and opcode[: -len(half) - 1] in _HLO_COLLECTIVES
+        ):
+            return "collective_" + half
+    return "compute"
+
+
+def _hlo_opcode(rest: str) -> str:
+    """The opcode of an instruction's text after its `=`. The result's shape
+    comes first: a tuple's stands in parentheses, which layouts nest inside
+    (`{1,0:T(8,128)(2,1)}`); any other holds no space."""
+    if rest.startswith("("):
+        depth = 0
+        for at, char in enumerate(rest):
+            depth += (char == "(") - (char == ")")
+            if depth == 0:
+                break
+        rest = rest[at + 1:]
+    else:
+        rest = rest.partition(" ")[2]
+    m = _HLO_OPCODE.match(rest)
+    return m.group(1) if m is not None else ""
+
+
+def hlo_instruction_map(hlo_text: str) -> dict[str, Instruction]:
+    """{instruction name: (its op_name, its kind)} of a COMPILED
+    (post-optimization) module's text, in one pass: THE parser of that text.
+
+    Every computation's instructions but those of a fusion's called
+    computation: a fusion runs as one instruction and is counted under the
+    scope its own metadata names, its body never a second time. A Pallas
+    kernel's custom call is printed over several lines (its
+    `kernel_metadata` holds a JSON string with line breaks in it) and its
+    `metadata={op_name=...}` stands on the last of them: lines that start
+    neither an instruction nor a computation belong to the instruction
+    before, and an instruction without metadata of its own inherits none
+    (but a TPU `async-collective-start.N` fusion, which takes its
+    `async-collective-done.N`'s)."""
+    computations: dict[str, dict[str, Instruction]] = {}
+    inside = computations.setdefault("", {})
+    fused = set()
+    waiting = None  # the instruction whose op_name has not been seen yet
     for line in hlo_text.splitlines():
-        m = instr.search(line)
-        if m is None:
-            continue
-        s = scope.search(line)
-        if s is not None:
-            out[m.group(1)] = s.group(1)
+        m = _HLO_INSTRUCTION.match(line)
+        if m is not None:
+            waiting, rest = m.groups()
+            opcode = _hlo_opcode(rest)
+            if opcode == "fusion":
+                fused.update(_HLO_CALLS.findall(rest))
+            inside[waiting] = Instruction(
+                None, _instruction_kind(opcode, waiting))
+        else:
+            header = _HLO_COMPUTATION.match(line)
+            if header is not None:
+                inside = computations.setdefault(header.group(1), {})
+                waiting = None
+                continue
+        if waiting is not None:
+            meta = _HLO_OP_NAME.search(line)
+            if meta is not None:
+                inside[waiting] = inside[waiting]._replace(
+                    op_name=meta.group(1))
+                waiting = None
+    out = {
+        name: instruction
+        for computation, body in computations.items()
+        if computation not in fused
+        for name, instruction in body.items()
+    }
+    # the TPU compiler gives an async collective fusion's start no metadata
+    # of its own (asked here for a v5e, PR 49): it is the carrier of the
+    # `-done` of its number, whose name stack names the merge group
+    for name, instruction in out.items():
+        if instruction.op_name is None and name.startswith(_ASYNC_START):
+            done = out.get(_ASYNC_DONE + name[len(_ASYNC_START):])
+            if done is not None:
+                out[name] = instruction._replace(op_name=done.op_name)
     return out
 
 
-_HLO_COLLECTIVES = (
-    "all-reduce|all-gather|reduce-scatter|collective-permute|all-to-all"
-    "|collective-broadcast"
-)
+def hlo_collective_scope_map(
+    hlo_text: str, tag: str = "mgwfbp_group",
+) -> dict[str, str]:
+    """HLO instruction name -> merge-group scope: the instructions of
+    `hlo_instruction_map` whose name stack passes through a ``<tag>NNNN``
+    scope (the scope the jaxpr verifier matches on)."""
+    return _scope_map(hlo_instruction_map(hlo_text), tag)
+
+
+def _scope_map(
+    instructions: dict[str, Instruction], tag: str,
+) -> dict[str, str]:
+    scope = re.compile(rf"{re.escape(tag)}\d+")
+    found = ((name, scope.search(instruction.op_name or ""))
+             for name, instruction in instructions.items())
+    return {name: m.group(0) for name, m in found if m is not None}
+
+
+def collective_counts(instructions: dict[str, Instruction]) -> dict[str, int]:
+    """How many collectives a program issues, and how many of them
+    asynchronously: `{"collectives": n, "async_collectives": k}`. The `-done`
+    halves are counted with their start; the bare opcode INSIDE a fusion's
+    called computation is that fusion's body (`hlo_instruction_map` leaves it
+    out)."""
+    kinds = collections.Counter(i.kind for i in instructions.values())
+    return {
+        "collectives": kinds["collective"] + kinds["collective_start"],
+        "async_collectives": kinds["collective_start"],
+    }
 
 
 def hlo_collective_counts(hlo_text: str) -> dict[str, int]:
-    """How many collectives a COMPILED program issues, and how many of them
-    asynchronously: `{"collectives": n, "async_collectives": k}`.
+    """`collective_counts` of a COMPILED program's text."""
+    return collective_counts(hlo_instruction_map(hlo_text))
 
-    Asynchronous is a `<collective>-start` instruction or, on a TPU, an
-    async collective fusion (`%async-collective-start.N = ... fusion(...)`:
-    the compiler wraps the collective and the compute steps that drive it
-    in one kernel; its `-done` twin is where the core waits). Synchronous
-    is the bare opcode on the computation's own instruction stream. The
-    bare opcode INSIDE a fusion's called computation is that fusion's body
-    and is not counted a second time."""
-    import re as _re
 
-    fused = set(_re.findall(r"\bfusion\(.*?calls=%([\w.\-]+)", hlo_text))
-    header = _re.compile(r"^(?:ENTRY\s+)?%([\w.\-]+)\s*\(.*\{\s*$")
-    sync = _re.compile(
-        r"^\s*(?:ROOT\s+)?%[\w.\-]+\s*=\s.*?[\s)](?:"
-        + _HLO_COLLECTIVES + r")\("
-    )
-    started = _re.compile(
-        r"^\s*(?:ROOT\s+)?%(?:async-collective-start[.\d]*\s*=\s.*?\bfusion\("
-        r"|[\w.\-]+\s*=\s.*?[\s)](?:" + _HLO_COLLECTIVES + r")-start\()"
-    )
-    n_sync = n_async = 0
-    inside = None
-    for line in hlo_text.splitlines():
-        h = header.match(line)
-        if h is not None:
-            inside = h.group(1)
+def classify(
+    op_name: Optional[str], scopes: Sequence[str],
+) -> tuple[str, str]:
+    """(scope, pass) of one instruction's op_name: the first of the declared
+    `scopes` in its name stack, `backward` where the stack holds
+    `transpose(` (recomputation with it); else `(model, no scope)` where the
+    stack passes through autodiff (`jvp(`: norms, residual adds, the
+    embedding), else `(outside the model)`."""
+    if op_name is None:
+        return NO_METADATA, "-"
+    parts = op_name.split("/")
+    direction = "backward" if "transpose(" in op_name else "forward"
+    for scope in scopes:
+        if scope in parts:
+            return scope, direction
+    if "jvp(" in op_name:
+        return MODEL_NO_SCOPE, direction
+    return OUTSIDE_MODEL, "-"
+
+
+@dataclasses.dataclass
+class StepMap:
+    """The compiled step as a map, with what it takes to reduce a trace of
+    it: the instructions, the scopes its model and `train/step.py` declare
+    (scope -> layer of PERF.md's map, the model's first), the run's log
+    directory, and what building it cost."""
+
+    instructions: dict[str, Instruction]
+    scopes: dict[str, str]
+    logdir: Optional[str] = None
+    hlo_bytes: int = 0
+    build_s: float = 0.0
+
+
+def _event_instruction(event_name: str) -> str:
+    """`%fusion.7 = f32[8]{0} fusion(%a)` -> `fusion.7`: a trace event names
+    the instruction it ran, with or without its text."""
+    return event_name.split(" = ", 1)[0].strip().lstrip("%")
+
+
+def split_trace(
+    events: Iterable[tuple[str, float, float]],
+    step_map: StepMap,
+    window: Optional[tuple[float, float]] = None,
+    steps: int = 1,
+    top: int = 25,
+) -> dict:
+    """THE reduction of a device trace by the step map. `events`: (name,
+    start_ns, duration_ns) of the instruction stream (`XLA Ops`), those that
+    start inside `window` counted; `steps`: the executions of the step they
+    cover (traced steps x the devices whose events are in the list, which
+    makes every number a mean over the devices). Milliseconds a step.
+
+    Containers are skipped. An instruction under a merge-group scope or of
+    collective kind is the EXCHANGE's (`device_ms`: packing, carriers, waits;
+    of it `wait_ms`, the `-done` halves and the synchronous collectives: the
+    core stands in them; `calls`, collectives started a step, asynchronous
+    ones counted) and, under a group scope, its group's (`groups`, by group
+    index, every group of the map). Every other one goes by `classify` to
+    `scopes` as name -> [forward_ms, backward_ms] (a name without a pass in
+    the first place). `top`: the longest instructions as [ms, instruction,
+    scope]. `total_ms` is the sum of all of it."""
+    from mgwfbp_tpu.parallel.allreduce import GROUP_SCOPE_PREFIX
+
+    declared = list(step_map.scopes)
+    steps = max(int(steps), 1)
+    group_of = {
+        name: int(scope[len(GROUP_SCOPE_PREFIX):]) for name, scope
+        in _scope_map(step_map.instructions, GROUP_SCOPE_PREFIX).items()}
+    groups = [0.0] * (max(group_of.values(), default=-1) + 1)
+    scopes: dict[str, list[float]] = {}
+    exchange = {"device_ms": 0.0, "wait_ms": 0.0, "calls": 0.0}
+    by_instruction: collections.Counter = collections.Counter()
+    # by the event's own name: (instruction, kind, scope, pass)
+    classified: dict[str, tuple[str, str, str, str]] = {}
+    counted = 0
+    for event, start, dur in events:
+        if window is not None and not window[0] <= start < window[1]:
             continue
-        if inside in fused:
+        if event not in classified:
+            name = _event_instruction(event)
+            known = step_map.instructions.get(name)
+            kind = known.kind if known is not None else _instruction_kind(
+                re.sub(r"\.\d+$", "", name), name)
+            classified[event] = (name, kind, *classify(
+                known.op_name if known is not None else None, declared))
+        name, kind, scope, direction = classified[event]
+        if kind == "container":
             continue
-        if started.match(line):
-            n_async += 1
-        elif sync.match(line):
-            n_sync += 1
-    return {"collectives": n_sync + n_async, "async_collectives": n_async}
+        counted += 1
+        ms = dur * 1e-6 / steps
+        if name in group_of or kind in COLLECTIVE_KINDS:
+            scope = "exchange"
+            exchange["device_ms"] += ms
+            if kind in ("collective", "collective_done"):
+                exchange["wait_ms"] += ms
+            if kind in ("collective", "collective_start"):
+                exchange["calls"] += 1.0 / steps
+            if name in group_of:
+                groups[group_of[name]] += ms
+        else:
+            scopes.setdefault(scope, [0.0, 0.0])[
+                direction == "backward"] += ms
+        by_instruction[(name, scope)] += ms
+    return {
+        "events": counted,
+        "total_ms": exchange["device_ms"] + sum(map(sum, scopes.values())),
+        "scopes": scopes,
+        "layers": dict(step_map.scopes),
+        "groups": groups,
+        "exchange": exchange,
+        "top": [[ms, name, scope] for (name, scope), ms
+                in by_instruction.most_common(top)],
+    }
+
+
+def layer_ms(split: dict, *layers: str) -> float:
+    """Both passes of the scopes a split's declaration puts in `layers`."""
+    return sum(
+        (sum(ms) for scope, ms in split["scopes"].items()
+         if split["layers"].get(scope) in layers), 0.0)
+
+
+def split_sums(split: dict) -> dict:
+    """A split by what the benchmark's metrics and the report's totals read:
+    `unscoped` (`(model, no scope)` + `(no metadata)`: the whole model where
+    it declares no scope), `update` (the step's own scopes and whatever else
+    is outside the model and not the exchange: optimizer, guard, statistics),
+    `forward` / `backward` (the model's instructions by pass, scoped or
+    not), `no_metadata`. The model's layers (`layer_ms`), `unscoped`,
+    `update` and the exchange add up to `total_ms`; so do `forward`,
+    `backward`, `update`, the exchange and `no_metadata`."""
+    scopes, layers = split["scopes"], split["layers"]
+    zero = [0.0, 0.0]
+    model = [ms for scope, ms in scopes.items()
+             if scope == MODEL_NO_SCOPE
+             or layers.get(scope, UPDATE_LAYER) != UPDATE_LAYER]
+    no_metadata = sum(scopes.get(NO_METADATA, zero))
+    return {
+        "unscoped": sum(scopes.get(MODEL_NO_SCOPE, zero)) + no_metadata,
+        "update": sum(scopes.get(OUTSIDE_MODEL, zero))
+        + layer_ms(split, UPDATE_LAYER),
+        "forward": sum(ms[0] for ms in model),
+        "backward": sum(ms[1] for ms in model),
+        "no_metadata": no_metadata,
+    }
+
+
+def split_summary(split: dict) -> str:
+    """A split in one line: each layer's total, the passes, the exchange."""
+    sums = split_sums(split)
+    totals = {layer: layer_ms(split, layer)
+              for layer in dict.fromkeys(split["layers"].values())
+              if layer != UPDATE_LAYER}
+    totals.update(
+        unscoped=sums["unscoped"], update=sums["update"],
+        exchange=split["exchange"]["device_ms"])
+    return (
+        f"{split['total_ms']:.3f} ms of device ops a step in "
+        f"{split['events']} events: "
+        + ", ".join(f"{layer} {ms:.3f}" for layer, ms in totals.items() if ms)
+        + f"; forward {sums['forward']:.3f}, backward "
+        f"{sums['backward']:.3f}, no metadata {sums['no_metadata']:.3f}; "
+        f"the exchange waits {split['exchange']['wait_ms']:.3f} ms in "
+        f"{split['exchange']['calls']:g} collective(s) a step"
+        + ("; by group " + " ".join(f"{ms:.3f}" for ms in split["groups"])
+           if split["groups"] else ""))
+
+
+def split_lines(split: dict) -> list[str]:
+    """A split as a table: its summary, every scope with its layer, forward
+    and backward, longest first, and the longest instructions. What
+    `tools/telemetry_report.py` and the benchmark's `[scopes]` phase lines
+    print."""
+    whole = split["total_ms"] or 1.0
+    rows = [(scope, split["layers"].get(scope, "-"), fwd, bwd)
+            for scope, (fwd, bwd) in split["scopes"].items()]
+    rows.append(("exchange", "exchange", split["exchange"]["device_ms"], 0.0))
+    rows.sort(key=lambda r: -(r[2] + r[3]))
+    lines = [
+        split_summary(split),
+        f"  {'scope':>22} {'layer':>16} {'forward':>10} {'backward':>10} "
+        f"{'ms':>10} {'%':>6}",
+    ]
+    for scope, layer, fwd, bwd in rows:
+        lines.append(
+            f"  {scope:>22} {layer:>16} {fwd:10.3f} {bwd:10.3f} "
+            f"{fwd + bwd:10.3f} {100.0 * (fwd + bwd) / whole:6.1f}")
+    lines.append("  longest instructions (ms a step):")
+    lines.extend(f"  {ms:10.3f} {name} [{scope}]"
+                 for ms, name, scope in split["top"])
+    return lines
+
+
+# What the last step program dispatched in this process takes to be mapped,
+# kept by the Trainer after the program's first dispatch (`note_step`): the
+# jitted step and its arguments as shapes, dtypes and shardings (no device
+# buffer), the declared scopes, the run's log directory. As
+# `phases.setup_record()` it outlives the Trainer: the benchmark's readers
+# (`benchmarks/scope_spans.py`) ask once the Trainer is closed.
+_step: Optional[dict] = None
+_step_map: Optional[StepMap] = None
+
+
+def note_step(
+    jitted: Any, args: Any, scopes: dict[str, str],
+    logdir: Optional[str] = None,
+) -> None:
+    """A step program has been dispatched for the first time: keep what it
+    takes to map it later. A rebuilt step replaces the one before and drops
+    its map. Nothing is lowered, compiled or read here."""
+    global _step, _step_map
+    _step = {"jitted": jitted, "args": args, "scopes": dict(scopes),
+             "logdir": logdir}
+    _step_map = None
+
+
+def step_map() -> Optional[StepMap]:
+    """The map of the step program last dispatched in this process, built
+    at the FIRST request and held from then on; None where no step was noted
+    or its compiled text cannot be had. An untraced run never asks and pays
+    nothing. No second compilation: the noted arguments describe the
+    dispatch that built the program, so the lowering is jax's cached one and
+    the executable the one in memory, or, for a step built with compile
+    options (jax keeps no executable in memory for those), a read of the
+    persistent compile cache that the dispatch wrote."""
+    global _step_map
+    if _step_map is not None or _step is None:
+        return _step_map
+    t0 = time.perf_counter()
+    try:
+        text = _step["jitted"].lower(*_step["args"]).compile().as_text()
+    except Exception as e:  # noqa: BLE001 — a description of the program,
+        # never a reason to stop training it
+        _run_log.info("step map: compiled text unavailable (%s)", e)
+        return None
+    _step_map = StepMap(
+        hlo_instruction_map(text), _step["scopes"], _step["logdir"],
+        len(text))
+    _step_map.build_s = time.perf_counter() - t0
+    _run_log.info(
+        "step map: %d instructions of %d bytes of compiled text in %.3f s",
+        len(_step_map.instructions), len(text), _step_map.build_s)
+    return _step_map
+
+
+def trace_op_events(
+    trace_dir: str, step_map: StepMap,
+) -> tuple[list[tuple[str, float, float]], int]:
+    """The instruction stream of the newest trace under `trace_dir` as
+    (events, devices): every device plane's `XLA Ops` line, or, on a backend
+    whose trace has no device plane (the CPU mesh), the host threads' events
+    that are named after an instruction of the map, which are every local
+    device's."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    paths = sorted(glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb")))
+    if not paths:
+        return [], 0
+    planes = list(ProfileData.from_file(paths[-1]).planes)
+    on_device = [p for p in planes
+                 if re.match(r"^/device:(TPU|GPU):\d+$", p.name)]
+    if on_device:
+        return [
+            (e.name, e.start_ns, e.duration_ns)
+            for plane in on_device for line in plane.lines
+            if line.name == "XLA Ops" for e in line.events
+        ], len(on_device)
+    return [
+        (e.name, e.start_ns, e.duration_ns)
+        for plane in planes if plane.name.startswith("/host:")
+        for line in plane.lines for e in line.events
+        if e.name in step_map.instructions
+    ], jax.local_device_count()
+
+
+def trace_step_split(
+    run_steps: Callable[[], None],
+    step_map: Optional[StepMap],
+    steps: int,
+    logdir: Optional[str] = None,
+) -> Optional[dict]:
+    """`split_trace` of `steps` live steps that `run_steps()` executes (and
+    blocks on) under the profiler, a mean over the local devices; None
+    without a map or where the trace holds no event of its instructions.
+    The trace slice stays in `logdir` where one is given."""
+
+    def read(trace_dir: str) -> Optional[dict]:
+        if step_map is None:
+            return None
+        events, devices = trace_op_events(trace_dir, step_map)
+        if not events:
+            return None
+        return split_trace(events, step_map, steps=steps * devices)
+
+    return _with_trace_events(
+        run_steps, logdir, prefix="mgwfbp_profile_", read=read)
 
 
 def _group_times_from_scopes(

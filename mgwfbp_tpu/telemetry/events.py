@@ -84,6 +84,18 @@ EVENT_TYPES: dict[str, tuple[str, ...]] = {
     # how many collectives it issues and how many of them the compiler
     # made asynchronous; `compiler_options` (names) rides along
     "step_program": ("step", "collectives", "async_collectives"),
+    # a profile window's device trace reduced by the step's map
+    # (profiling.split_trace, Trainer._run_profile_window), milliseconds a
+    # step over `steps` traced steps, a mean over the local devices:
+    # `scopes` as name -> [forward_ms, backward_ms] (the declared scopes,
+    # `(model, no scope)`, and in the first place `(no metadata)` and
+    # `(outside the model)`); `groups`, by merge group; `exchange`
+    # ({device_ms, wait_ms, calls}: under a merge-group scope or of
+    # collective kind; of it the `-done` halves and the synchronous
+    # collectives; collectives started); `top`, the longest instructions as
+    # [ms, instruction, scope]. `layers` (declared scope -> layer of
+    # PERF.md's map), `total_ms` and `events` ride along
+    "step_scopes": ("step", "steps", "scopes", "groups", "exchange", "top"),
     # one built step program, counted while it was traced (ops/programs.py
     # has the ops and the records' table; Trainer._note_traced_programs
     # writes them): how many of its attention cores

@@ -203,6 +203,10 @@ SETUP_SPANS = (
     "cache_load",        #       the persistent cache's read
     "first_result",      #   step 1's dispatch returned -> its results read
     "program_read",      #     _note_step_program, _note_traced_programs
+    "step_map",          #       profiling.step_map() where the step has
+                         #       gradient collectives to count; a
+                         #       one-device run builds it only when a
+                         #       profile window or a trace's reader asks
 )
 
 _TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"

@@ -159,6 +159,15 @@ def _nonfinite_count(tree: Any) -> jax.Array:
     return sum(counts).astype(jnp.float32)
 
 
+# the `jax.named_scope`s `make_train_step`'s program enters outside the model
+# (beside the reducer's `mgwfbp_groupNNNN`): with the model's `scopes` they
+# are the declaration `profiling.classify` reads a name stack by, so that the
+# split of a traced step names optimizer, guard and statistics apart
+# (tests/test_step_map.py holds the tuple to the code)
+STEP_SCOPES = (
+    "optimizer", "finite_check", "health_stats", "bad_step_guard",
+    "metrics_reduce", "bstats_reduce", "flat_grad_reduce",
+)
 # the trainer recognizes (and strips) health statistics in the step's
 # metrics dict by this prefix — keys below it never reach the log line or
 # the scalar writer; they drain one step late through the health deque
@@ -666,10 +675,11 @@ def make_train_step(
             if grad_guard:
                 with jax.named_scope("finite_check"):
                     metrics["grads_nonfinite"] = _nonfinite_count(grads)
-            updates, new_opt_state = tx.update(
-                grads, state.opt_state, state.params
-            )
-            new_params = optax.apply_updates(state.params, updates)
+            with jax.named_scope("optimizer"):
+                updates, new_opt_state = tx.update(
+                    grads, state.opt_state, state.params
+                )
+                new_params = optax.apply_updates(state.params, updates)
         if health_stats:
             with jax.named_scope("health_stats"):
                 metrics.update(_health_stat_entries(

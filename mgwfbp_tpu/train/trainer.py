@@ -111,12 +111,17 @@ def _poison_batch(batch: Any) -> tuple[Any, bool]:
 
 
 def _describe_args(args: Any) -> Any:
-    """`args` as ShapeDtypeStructs with the arrays' own shardings: what a
-    dispatch was traced and lowered for, still there after it has donated
-    the arrays themselves."""
+    """`args` as ShapeDtypeStructs with the shardings the arrays were
+    COMMITTED to: what a dispatch was traced and lowered for, still there
+    after it has donated the arrays themselves. An uncommitted array (one
+    device's batch) is lowered with no sharding of its own, and described
+    with one it would be lowered anew and miss every cache the dispatch
+    filled (`profiling.step_map` then compiles the step a second time: 23
+    to 103 s a cell, my chip runs, PR 49)."""
     return jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(
-            a.shape, a.dtype, sharding=getattr(a, "sharding", None)
+            a.shape, a.dtype,
+            sharding=a.sharding if getattr(a, "committed", False) else None,
         ),
         args,
     )
@@ -712,10 +717,8 @@ class Trainer:
         # rebuilds mid-run) — restore the watchdog's compile allowance
         self._train_step_compiled = False
         self._eval_step_compiled = False
-        # compiled-HLO text of the live step (the /profile window's
-        # trace-event join key) describes the OLD program, and so does the
-        # count of its collectives (_note_step_program)
-        self._step_hlo_cache = None
+        # the count of the OLD program's collectives (_note_step_program);
+        # its map (profiling.step_map) goes when the new one is dispatched
         self._step_program = None
         # a one-device step has no gradient collective to count
         self._step_program_noted = self.data_size * self.seq_size <= 1
@@ -1043,42 +1046,17 @@ class Trainer:
         if steps > 0:
             self._run_profile_window(steps)
 
-    def _live_step_hlo_text(self, sample_batch) -> Optional[str]:
-        """COMPILED (post-optimization) HLO text of the live jitted step.
-
-        The /profile attribution join key: backends that drop the jax
-        name stack from trace-event metadata (the CPU mesh) name each
-        event after the HLO instruction it ran, and the compiled module's
-        per-instruction op_name metadata still carries the
-        mgwfbp_groupNNNN scope (profiling.hlo_collective_scope_map).
-        Cached per step-program build; lowering never consumes donated
-        buffers."""
-        if self._step_hlo_cache is not None:
-            return self._step_hlo_cache
-        try:
-            args = [self.state, sample_batch]
-            if self.meta.has_carry:
-                if self.carry is None:
-                    self.carry = self._globalize(
-                        self.model.initial_carry(self.process_batch), axes=0
-                    )
-                args.append(self.carry)
-            self._step_hlo_cache = (
-                self.train_step.lower(*args).compile().as_text()
-            )
-        except Exception as e:  # noqa: BLE001 — the join is an
-            # attribution upgrade; without it the window still writes the
-            # trace slice
-            self.log.info("profile: live-step HLO unavailable (%s)", e)
-        return self._step_hlo_cache
-
     def _run_profile_window(self, steps: int) -> None:
         """Trace `steps` live training steps (state carried — genuine
         optimizer steps, nothing replayed or lost), write the Chrome-trace
-        slice next to the run's logs, attribute per-merge-group device
-        time, gather it across processes, and feed the drift detector's
-        ABSOLUTE per-group residual channel — a straggler whose slowness
-        is purely device-side becomes visible live, not only post-hoc."""
+        slice next to the run's logs, reduce the trace by the step's map
+        (`profiling.split_trace`: device time by scope and pass, by merge
+        group, the exchange's carriers and waits; the `step_scopes`
+        record), gather the groups' times across processes, and feed the
+        drift detector's ABSOLUTE per-group residual channel — a straggler
+        whose slowness is purely device-side becomes visible live, not
+        only post-hoc."""
+        from mgwfbp_tpu import profiling
         from mgwfbp_tpu.telemetry.serve import PROFILE_MAX_STEPS
 
         steps = max(1, min(int(steps), PROFILE_MAX_STEPS))
@@ -1116,14 +1094,12 @@ class Trainer:
 
             wd.beat(f"profile window ({steps} steps)",
                     allow_s=COMPILE_ALLOW_S)
-        import itertools
-
         batch_iter = self._autotune_batches()
-        sample_batch = next(batch_iter)
-        batch_iter = itertools.chain([sample_batch], batch_iter)
-        hlo_text = (
-            self._live_step_hlo_text(sample_batch) if num_groups else None
-        )
+        # the join key of trace events to scopes and groups: a backend whose
+        # trace drops the jax name stack (the CPU mesh) still names each
+        # event after the instruction it ran. Built here where no reader
+        # asked before (a window syncs the device anyway)
+        step_map = profiling.step_map()
 
         def run():
             # annotated like train_epoch's iterations (telemetry/phases.py),
@@ -1147,20 +1123,15 @@ class Trainer:
                 self.iteration += 1
             jax.block_until_ready(self.state)
 
+        # the steps already dispatched run to their end first, so that the
+        # trace holds `steps` executions of every instruction and no tail of
+        # an earlier one (the split divides by them)
+        jax.block_until_ready(self.state)
         t0 = time.perf_counter()
         try:
-            if num_groups:
-                from mgwfbp_tpu.profiling import trace_group_times
-
-                measured = trace_group_times(
-                    run, num_groups, iters=steps, logdir=trace_dir,
-                    hlo_text=hlo_text,
-                )
-            else:
-                from mgwfbp_tpu.profiling import _with_trace_events
-
-                _with_trace_events(run, logdir=trace_dir)
-                measured = None
+            split = profiling.trace_step_split(
+                run, step_map, steps, logdir=trace_dir
+            )
         except Exception as e:  # noqa: BLE001 — observability must never
             # kill the run it observes
             self.log.warning("profile window failed (%s)", e)
@@ -1172,6 +1143,18 @@ class Trainer:
                 wd.beat("profile window done")
         wall_s = time.perf_counter() - t0
         self._train_step_compiled = True
+        measured = None
+        if split is not None:
+            self._emit_event(
+                "step_scopes", step=int(self.iteration), steps=int(steps),
+                **{k: split[k] for k in (
+                    "scopes", "layers", "groups", "exchange", "top",
+                    "total_ms", "events")},
+            )
+            self.log.info("step by scope: %s", profiling.split_summary(split))
+            groups_s = [ms * 1e-3 for ms in split["groups"][:num_groups]]
+            if num_groups and len(groups_s) == num_groups and all(groups_s):
+                measured = groups_s  # partial attribution is worse than none
         attribution = "trace" if measured is not None else "none"
         groups_doc: list[dict] = []
         if self.reducer is not None:
@@ -1317,7 +1300,12 @@ class Trainer:
         """The first dispatch of a newly built step program has returned:
         the open set-up takes that step's span from its record (`rec` holds
         it), a rebuild's set-up, which waits for no result, is written, and
-        the program is read (`program_read`)."""
+        the program is read (`program_read`). What it takes to map the
+        compiled program later is kept for the process
+        (`profiling.note_step`: the jitted step, its arguments as shapes and
+        shardings, the scopes the model and the step declare, the log
+        directory; no device buffer): nothing is built until a profile
+        window or a reader of a trace asks `profiling.step_map()`."""
         setup = self._setup if rec is not None else None
         if setup is not None and setup.first_step is None:
             setup.dispatched(self.iteration, *rec.dispatch_span())
@@ -1325,7 +1313,17 @@ class Trainer:
                 self._write_setup()
         with self._setup_span("program_read"):
             if step_args is not None:
-                self._note_step_program(step_args)
+                from mgwfbp_tpu import profiling
+                from mgwfbp_tpu.train.step import STEP_SCOPES
+
+                profiling.note_step(
+                    self.train_step, step_args,
+                    {**getattr(self.model, "scopes", {}),
+                     **dict.fromkeys(STEP_SCOPES, profiling.UPDATE_LAYER)},
+                    self.config.logdir,
+                )
+                if not self._step_program_noted:
+                    self._note_step_program()
             self._note_traced_programs()
 
     def _setup_results_read(self, step: int) -> None:
@@ -1350,22 +1348,24 @@ class Trainer:
         self._emit_event("setup", **record)
         self.log.info("%s", phases.setup_line(record))
 
-    def _note_step_program(self, step_args) -> None:
+    def _note_step_program(self) -> None:
         """Once per step-program build, after its first dispatch: count the
         compiled program's collectives and how many of them the compiler
-        made asynchronous (profiling.hlo_collective_counts), for the log,
-        the `step_program` telemetry record and _schedule_state_doc. Only
-        a program with gradient collectives is read (_build_steps marks a
-        one-device step as noted: it has none).
+        made asynchronous (profiling.collective_counts), for the log, the
+        `step_program` telemetry record and _schedule_state_doc. Only a
+        program with gradient collectives is read (_build_steps marks a
+        one-device step as noted: it has none); the read fills the
+        process's step map (`profiling.step_map()`, the `step_map` set-up
+        span), which a one-device run builds only when asked.
 
-        No second compilation: `step_args` describe the dispatch that just
-        built the program, so the lowering is jax's cached one, and the
-        executable is the one in memory, or, for a step built with
+        No second compilation: the noted arguments describe the dispatch
+        that just built the program, so the lowering is jax's cached one,
+        and the executable is the one in memory, or, for a step built with
         compile options (train/step.py: jax keeps no executable in memory
         for those), a read of the persistent compile cache that the
         dispatch just wrote."""
         self._step_program_noted = True
-        from mgwfbp_tpu.profiling import hlo_collective_counts
+        from mgwfbp_tpu import profiling
         from mgwfbp_tpu.train.step import async_collective_options
 
         red_axes = tuple(self.data_axes) + (
@@ -1379,15 +1379,13 @@ class Trainer:
                 "%s and no persistent compile cache)", ", ".join(options),
             )
             return
-        try:
-            text = self.train_step.lower(*step_args).compile().as_text()
-        except Exception as e:  # noqa: BLE001 — a description of the
-            # program, never a reason to stop training it
-            self.log.info("step program: compiled text unavailable (%s)", e)
+        with self._setup_span("step_map"):
+            step_map = profiling.step_map()
+        if step_map is None:  # the compiled text cannot be had (logged)
             return
-        self._step_hlo_cache = text
         self._step_program = {
-            **hlo_collective_counts(text), "compiler_options": options,
+            **profiling.collective_counts(step_map.instructions),
+            "compiler_options": options,
         }
         self.log.info(
             "merge schedule: the compiled step issues %d collectives, %d of "
@@ -3243,10 +3241,10 @@ class Trainer:
                         allow_s=COMPILE_ALLOW_S)
             self._local_busy_s += time.perf_counter() - t_anchor
             # a fresh step program's arguments, described before the
-            # dispatch donates them (_note_step_program reads the compiled
-            # program once this dispatch has built it)
+            # dispatch donates them (_note_first_dispatch keeps them for
+            # whoever reads the compiled program this dispatch builds)
             step_args = (
-                None if self._step_program_noted
+                None if self._traced_programs_noted
                 else _describe_args(
                     (self.state, batch, self.carry) if self.meta.has_carry
                     else (self.state, batch)

@@ -12,6 +12,7 @@ detector's mid-run switch to the absolute per-group residual channel)."""
 import glob
 import json
 import os
+import re
 import socket
 import threading
 import time
@@ -697,4 +698,62 @@ def test_profile_window_live_lenet(tmp_path, monkeypatch):
     assert len(prof[0]["device_s"]) == num_groups
     code, body = _get(port, "/metrics")
     assert "mgwfbp_profile_windows_total 1" in body
+
+
+def test_profile_window_writes_the_step_by_scope(tmp_path, monkeypatch):
+    """ISSUE 49: the same window reduces its trace by the step's map
+    (`profiling.split_trace`) and writes ONE `step_scopes` record: `groups`
+    are what `trace_group_times` returned for the window before (the HLO
+    join's mean event of each instruction under the group's scope), the
+    exchange holds the groups' all-reduces and the metrics' own, the parts
+    add up, and `tools/telemetry_report.py` prints the table."""
+    import telemetry_report
+    from mgwfbp_tpu import profiling
+    from mgwfbp_tpu.train.trainer import Trainer
+
+    monkeypatch.setenv("MGWFBP_LOG_INTERVAL", "3")
+    cfg = make_config(
+        "lenet", lr=0.01, max_epochs=1, logdir=str(tmp_path), seed=3,
+        batch_size=8, num_batches_per_epoch=6, metrics_port=0,
+    )
+    t = Trainer(cfg, synthetic_data=True, profile_backward=False)
+    try:
+        code, body = _get(t._metrics_server.port, "/profile?steps=2")
+        assert code == 200 and json.loads(body)["armed"], body
+        t.train_epoch(0)
+        res = json.loads(_get(t._metrics_server.port, "/profile")[1])["result"]
+    finally:
+        t.close()
+    num_groups = t.reducer.layout.num_groups
+    recs = read_event_set(
+        glob.glob(str(tmp_path / "*/telemetry.jsonl"))[0]
+    )
+    (scopes,) = events_of(recs, "step_scopes")
+    (prof,) = events_of(recs, "profile")
+    assert scopes["steps"] == 2 and scopes["step"] == prof["step"]
+    groups_s = [ms * 1e-3 for ms in scopes["groups"]]
+    assert groups_s == pytest.approx(prof["device_s"])
+    assert groups_s == pytest.approx(
+        [row["device_s"] for row in res["groups"]])
+    # ... and what the join of the same trace gave before this record
+    step_map = profiling.step_map()
+    text = profiling._step["jitted"].lower(
+        *profiling._step["args"]).compile().as_text()
+    before = profiling._group_times_from_hlo_join(
+        profiling._trace_events(res["trace_dir"]), num_groups, text)
+    assert groups_s == pytest.approx(before)
+    exchange = scopes["exchange"]
+    assert exchange["calls"] == num_groups + 1  # and the metrics' own
+    assert exchange["calls"] == profiling.collective_counts(
+        step_map.instructions)["collectives"]
+    assert 0 < exchange["wait_ms"] <= exchange["device_ms"]
+    assert exchange["device_ms"] >= sum(scopes["groups"])
+    assert scopes["total_ms"] == pytest.approx(
+        exchange["device_ms"] + sum(map(sum, scopes["scopes"].values())))
+    assert len(scopes["top"]) == 25
+    assert scopes["layers"]["optimizer"] == profiling.UPDATE_LAYER
+    report = telemetry_report.format_report(recs)
+    assert "step by scope (profile window of 2 step(s) to step" in report
+    assert f"in {num_groups + 1} collective(s) a step; by group" in report
+    assert re.search(r"\(model, no scope\) +- +\d", report)
     t.close()

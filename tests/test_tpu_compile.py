@@ -183,6 +183,35 @@ def test_four_chip_step_gets_asynchronous_allreduces(
         assert counts["async_collectives"] == 0
 
 
+def test_four_chip_steps_exchange_reads_the_same_from_text_and_trace(topo):
+    """ISSUE 49, asked of the chip's compiler: the map of a four-chip step's
+    compiled text knows its asynchronous all-reduces by kind (the start
+    fusions carry no metadata of their own and take their `-done`'s merge
+    group), and a trace with one event an instruction, reduced by that map,
+    starts as many collectives a step as the text issues and puts every one
+    of the eight merge groups' time under its group."""
+    from mgwfbp_tpu import profiling
+    from mgwfbp_tpu.train.step import STEP_SCOPES
+
+    instructions = profiling.hlo_instruction_map(
+        _four_chip_mlp_step_text(topo))
+    counts = profiling.collective_counts(instructions)
+    assert counts["collectives"] == 8 + 1 and counts["async_collectives"] >= 3
+    starts = {name: i for name, i in instructions.items()
+              if i.kind == "collective_start"}
+    assert len(starts) == counts["async_collectives"]
+    assert all("mgwfbp_group" in i.op_name for i in starts.values())
+    step_map = profiling.StepMap(
+        instructions, dict.fromkeys(STEP_SCOPES, profiling.UPDATE_LAYER))
+    events = [(f"%{name} = f32[] op()", 10.0, 1000.0)
+              for name in instructions] * 2
+    out = profiling.split_trace(events, step_map, (0.0, 100.0), steps=2)
+    assert out["exchange"]["calls"] == counts["collectives"]
+    assert len(out["groups"]) == 8 and all(out["groups"])
+    assert out["exchange"]["device_ms"] >= sum(out["groups"])
+    assert "optimizer" in out["scopes"]
+
+
 def _gradient_kernels_fed_by_a_reduced_bucket(text):
     """(n, fed): of the compiled entry computation's `n` kernels that
     compute a gradient (a fusion under `transpose(jvp(...))/.../dot_general`)

@@ -1,10 +1,35 @@
-"""tools/trace_scopes.py on a hand-made HLO text and event list: the join of
-instruction names to `op_name` scopes, forward against backward, loops not
-counted twice, a custom call without a name stack taken by its prefix."""
+"""`profiling.hlo_instruction_map`, `classify` and `split_trace` on a hand-made
+HLO text and event list (the cases of the by-hand tool whose library they
+were until PR 49): the join of instruction names to `op_name` scopes, forward
+against backward, loops not counted twice, a custom call without a name
+stack outside the model."""
 
 import pytest
 
-import trace_scopes
+from mgwfbp_tpu import profiling
+
+
+def op_names(hlo: str) -> dict:
+    """{instruction: its op_name} of those that have one."""
+    return {name: i.op_name
+            for name, i in profiling.hlo_instruction_map(hlo).items()
+            if i.op_name is not None}
+
+
+def split(events, hlo: str, scopes):
+    """{(scope, pass): ns} and the longest instructions as (ns, instruction,
+    scope), of `split_trace` over one step."""
+    out = profiling.split_trace(events, profiling.StepMap(
+        profiling.hlo_instruction_map(hlo), dict.fromkeys(scopes, "model")))
+    no_pass = (profiling.NO_METADATA, profiling.OUTSIDE_MODEL)
+    totals = {}
+    for scope, (forward, backward) in out["scopes"].items():
+        passes = ("-",) if scope in no_pass else ("forward", "backward")
+        for direction, ms in zip(passes, (forward, backward)):
+            if ms:
+                totals[(scope, direction)] = round(ms * 1e6)
+    top = [(round(ms * 1e6), name, scope) for ms, name, scope in out["top"]]
+    return totals, top
 
 HLO = """
 HloModule jit_step
@@ -31,18 +56,20 @@ SCOPES = ["attn_window", "attn_full", "moe_experts"]
 
 
 def test_join_and_split():
-    names = trace_scopes.hlo_op_names(HLO)
+    names = op_names(HLO)
     assert names["fusion.7"].endswith("attn_full/exp")
     assert names["fusion.11"] == "jit(step)/add"
-    totals, top = trace_scopes.split(
-        EVENTS, names, SCOPES, {"ragged-dot": "moe_experts"})
+    # the fusion's body is its own: counted once, under the fusion
+    assert "exp.1" not in names
+    totals, top = split(EVENTS, HLO, SCOPES)
     assert totals[("attn_full", "forward")] == 200
     assert totals[("attn_full", "backward")] == 300
     # the loop spans its body's events: only the body's are counted
     assert totals[("moe_experts", "forward")] == 40
-    assert totals[("moe_experts", "-")] == 20  # the custom call, by prefix
     assert totals[("(model, no scope)", "forward")] == 5
-    assert totals[("(outside the model)", "-")] == 7
+    # with the custom call whose metadata holds no name stack (the kernels
+    # in use since PR 35 keep theirs: the tool's `--prefix` went with it)
+    assert totals[("(outside the model)", "-")] == 7 + 20
     assert totals[("(no metadata)", "-")] == 1
     assert sum(totals.values()) == 573
     assert top[0] == (300, "fusion.8", "attn_full")
@@ -64,11 +91,11 @@ def test_a_kernels_custom_call_printed_over_several_lines_keeps_its_scope():
         '  %bare.1 = f32[8]{0} copy(%x)\n'
         '  %fusion.3 = f32[8]{0} fusion(%y), kind=kLoop, calls=%fc, '
         'metadata={op_name="jit(step)/jvp(M)/attn_window/mul"}\n')
-    names = trace_scopes.hlo_op_names(hlo)
+    names = op_names(hlo)
     assert set(names) == {"splash_mha_dkv_no_residuals.7", "fusion.3"}
-    totals, _ = trace_scopes.split(
+    totals, _ = split(
         [("splash_mha_dkv_no_residuals.7", 0, 17), ("bare.1", 20, 1),
-         ("fusion.3", 30, 2)], names, SCOPES)
+         ("fusion.3", 30, 2)], hlo, SCOPES)
     assert totals == {("attn_full", "backward"): 17,
                       ("(no metadata)", "-"): 1,
                       ("attn_window", "forward"): 2}
@@ -93,11 +120,11 @@ def test_the_grouped_products_kernels_land_in_the_experts_scope():
             ("gmm.18", back + "rematted_computation/jit(gmm)"),
             ("gmm.14", back + "jit(gmm)"),
             ("tgmm.6", back + "jit(tgmm)")])
-    names = trace_scopes.hlo_op_names(hlo)
+    names = op_names(hlo)
     assert set(names) == {"gmm.1", "gmm.18", "gmm.14", "tgmm.6"}
-    totals, top = trace_scopes.split(
+    totals, top = split(
         [("gmm.1", 0, 10), ("gmm.18", 20, 11), ("gmm.14", 40, 12),
-         ("tgmm.6", 60, 13)], names, SCOPES)
+         ("tgmm.6", 60, 13)], hlo, SCOPES)
     assert totals == {("moe_experts", "forward"): 10,
                       ("moe_experts", "backward"): 36}
     assert top[0] == (13, "tgmm.6", "moe_experts")
@@ -112,7 +139,7 @@ def test_the_grouped_products_kernels_land_in_the_experts_scope():
     (None, ("(no metadata)", "-")),
 ])
 def test_classify(op_name, want):
-    assert trace_scopes.classify(op_name, SCOPES) == want
+    assert profiling.classify(op_name, SCOPES) == want
 
 
 GRANITE_SCOPES = [
@@ -145,7 +172,7 @@ GRANITE_SCOPES = [
      ("(model, no scope)", "forward")),
 ])
 def test_classify_the_state_space_scopes(op_name, want):
-    assert trace_scopes.classify(op_name, GRANITE_SCOPES) == want
+    assert profiling.classify(op_name, GRANITE_SCOPES) == want
 
 
 PHI4FLASH_SCOPES = [
@@ -177,7 +204,7 @@ PHI4FLASH_SCOPES = [
      ("(model, no scope)", "forward")),
 ])
 def test_classify_the_hybrid_decoders_scopes(op_name, want):
-    assert trace_scopes.classify(op_name, PHI4FLASH_SCOPES) == want
+    assert profiling.classify(op_name, PHI4FLASH_SCOPES) == want
 
 
 
@@ -214,7 +241,7 @@ QWEN3NEXT_SCOPES = [
      ("(model, no scope)", "forward")),
 ])
 def test_classify_the_linear_attention_scopes(op_name, want):
-    assert trace_scopes.classify(op_name, QWEN3NEXT_SCOPES) == want
+    assert profiling.classify(op_name, QWEN3NEXT_SCOPES) == want
 
 
 def test_the_selective_scans_kernels_land_in_its_scope():
@@ -243,13 +270,13 @@ def test_the_selective_scans_kernels_land_in_its_scope():
             ("selective_scan_backward.4",
              back + "ssm_sel_scan/jit(_backward_kernel)/"
              "selective_scan_backward")])
-    names = trace_scopes.hlo_op_names(hlo)
+    names = op_names(hlo)
     assert set(names) == {
         "selective_scan_forward.4", "selective_scan_forward.6",
         "selective_scan_backward.4"}
-    totals, top = trace_scopes.split(
+    totals, top = split(
         [("selective_scan_forward.4", 0, 17), ("selective_scan_forward.6", 20,
-         18), ("selective_scan_backward.4", 40, 36)], names, PHI4FLASH_SCOPES)
+         18), ("selective_scan_backward.4", 40, 36)], hlo, PHI4FLASH_SCOPES)
     assert totals == {("ssm_sel_scan", "forward"): 17,
                       ("ssm_sel_scan", "backward"): 54}
     assert top[0] == (36, "selective_scan_backward.4", "ssm_sel_scan")
@@ -289,7 +316,7 @@ XING4_SCOPES = [
      ("(model, no scope)", "forward")),
 ])
 def test_xing4_scopes_classify(op_name, want):
-    assert trace_scopes.classify(op_name, XING4_SCOPES) == want
+    assert profiling.classify(op_name, XING4_SCOPES) == want
 
 
 NEMOTRON3S_SCOPES = [
@@ -322,4 +349,4 @@ NEMOTRON3S_SCOPES = [
      ("(model, no scope)", "forward")),
 ])
 def test_nemotron3s_scopes_classify(op_name, want):
-    assert trace_scopes.classify(op_name, NEMOTRON3S_SCOPES) == want
+    assert profiling.classify(op_name, NEMOTRON3S_SCOPES) == want

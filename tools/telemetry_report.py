@@ -281,6 +281,16 @@ def format_report(records: list[dict]) -> str:
             f"{prog.get('async_collectives')} asynchronous; compile options: "
             + (", ".join(prog.get("compiler_options") or ()) or "none")
         )
+    for rec in events_of(records, "step_scopes"):
+        from mgwfbp_tpu.profiling import split_lines
+
+        lines.append(
+            f"step by scope (profile window of {rec.get('steps')} step(s) "
+            f"to step {rec.get('step')}, ms of device ops a step):")
+        lines.extend("  " + line for line in split_lines({
+            "layers": {}, "events": 0, **rec,
+            "total_ms": rec["exchange"]["device_ms"]
+            + sum(map(sum, rec["scopes"].values()))}))
     for prog in events_of(records, "attention_program"):
         lines.append(
             f"attention (step program built by step {prog.get('step')}): "
@@ -911,6 +921,17 @@ def _synthetic_stream(path: str) -> None:
     # records them after the first dispatch
     w.emit("step_program", step=1, collectives=33, async_collectives=5,
            compiler_options=["xla_enable_async_all_reduce"])
+    # a profile window's trace reduced by the step's map, as
+    # Trainer._run_profile_window records it (profiling.split_trace)
+    w.emit("step_scopes", step=12, steps=2,
+           scopes={"attn_full": [3.0, 6.5], "moe_experts": [4.0, 8.0],
+                   "optimizer": [1.5, 0.0], "(model, no scope)": [0.5, 1.0],
+                   "(no metadata)": [0.25, 0.0]},
+           layers={"attn_full": "attention", "moe_experts": "experts",
+                   "optimizer": "update"},
+           groups=[0.75, 0.5], exchange={
+               "device_ms": 1.25, "wait_ms": 1.0, "calls": 33.0},
+           top=[[8.0, "gmm.14", "moe_experts"], [6.5, "fusion.8", "attn_full"]])
     w.emit("attention_program", step=1, kernel=1, blocks=3)
     w.emit("experts_program", step=1, kernel=12, ragged=0, programs=4,
            rows_held=4, rows_all=4, rows_programs=1, bounded=0, whole=4)
@@ -1025,6 +1046,20 @@ def selftest() -> int:
             "step program (built by step 1): 33 collectives, 5 asynchronous; "
             "compile options: xla_enable_async_all_reduce" in report
         ), report
+        # ISSUE 49: a profile window's device time by scope, as a table
+        # with each layer's total
+        assert (
+            "step by scope (profile window of 2 step(s) to step 12, ms of "
+            "device ops a step):" in report
+        ), report
+        assert (
+            "26.000 ms of device ops a step in 0 events: attention 9.500, "
+            "experts 12.000, unscoped 1.750, update 1.500, exchange 1.250; "
+            "forward 7.500, backward 15.500, no metadata 0.250; the "
+            "exchange waits 1.000 ms in 33 collective(s) a step; by group "
+            "0.750 0.500" in report
+        ), report
+        assert "8.000 gmm.14 [moe_experts]" in report, report
         # ISSUE 31: which way the step's attention cores went down
         assert (
             "attention (step program built by step 1): 1 core(s) through "
